@@ -44,20 +44,6 @@ elif ("JAX_DEFAULT_MATMUL_PRECISION" not in _os.environ
     # only when the user expressed no preference of their own
     _jax.config.update("jax_default_matmul_precision", "highest")
 
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 compatibility: the public ``jax.shard_map`` (kwarg
-    # ``check_vma``) lives at jax.experimental.shard_map.shard_map
-    # (kwarg ``check_rep``) on older releases still in the wild; every
-    # driver here calls the public spelling.
-    from jax.experimental.shard_map import shard_map as _esm
-
-    def _shard_map_compat(f, *, mesh, in_specs, out_specs,
-                          check_vma=True, **kw):
-        return _esm(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_rep=check_vma, **kw)
-
-    _jax.shard_map = _shard_map_compat
-
 from .version import __version__, version, id  # noqa: A004
 
 from .types import (

@@ -25,11 +25,8 @@ def build_library(force: bool = False) -> str | None:
     """Compile (once) and return the path of libslate_tpu_c.so.
     Rebuilds when the source is newer than the library."""
     if os.path.exists(_SO) and not force:
-        try:
-            src_mtime = max(os.path.getmtime(_SRC),
-                            os.path.getmtime(HEADER))
-        except OSError:
-            return _SO   # sources absent: the prebuilt library stands
+        src_mtime = max(os.path.getmtime(_SRC),
+                        os.path.getmtime(HEADER))
         if os.path.getmtime(_SO) >= src_mtime:
             return _SO
     inc = sysconfig.get_paths()["include"]

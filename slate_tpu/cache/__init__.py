@@ -28,6 +28,7 @@ from .jitcache import CachedJit, cached_jit, clear_in_process
 from .store import (ENV_CACHE, ENV_CACHE_DIR, cache_dir, clear,
                     enabled, fingerprint, fp_digest, reset_cache_dir,
                     set_cache_dir, stats)
+from .xla_cache import place_jax_compile_cache
 
 __all__ = [
     "CachedJit", "cached_jit", "clear_in_process",

@@ -246,4 +246,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from . import place_jax_compile_cache
+    place_jax_compile_cache()
     sys.exit(main())

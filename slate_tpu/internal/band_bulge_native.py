@@ -65,16 +65,10 @@ def get_lib():
     _tried = True
     if os.environ.get("SLATE_TPU_NO_NATIVE"):
         return None
-    try:
-        src_mtime = os.path.getmtime(_SRC)
-    except OSError:
-        src_mtime = None          # source not shipped; use .so if present
-    if src_mtime is not None and not (
-            os.path.exists(_SO) and os.path.getmtime(_SO) >= src_mtime):
+    if not (os.path.exists(_SO)
+            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
         if not _build():
             return None
-    if not os.path.exists(_SO):
-        return None
     try:
         lib = ctypes.CDLL(_SO)
         if lib.slate_bulge_version() != _VER:

@@ -47,13 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    pl = pltpu = None
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _BS = 128  # in-kernel panel width (one lane tile)
 
@@ -102,8 +97,6 @@ def pallas_supported(nb: int, dtype, platform: str | None = None,
     """Explicit capability table (dtype × nb × platform) answering
     "can this rung run here" — shared by the backend ladder's dispatch
     gates and the autotuner's candidate enumeration."""
-    if not HAVE_PALLAS:
-        return False
     if platform is None:
         platform = jax.default_backend()
     spec = CAPABILITY.get(platform, {}).get(kernel, {}).get(

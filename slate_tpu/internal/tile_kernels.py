@@ -21,11 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # LAPACK-layout Householder QR (geqrf). Public until jax 0.8;
-    # the primitive is still maintained under jax._src.lax.linalg.
-    from jax.lax.linalg import geqrf as _geqrf
-except ImportError:  # pragma: no cover
-    from jax._src.lax.linalg import geqrf as _geqrf
+# LAPACK-layout Householder QR (geqrf): no public name in jax 0.9
+from jax._src.lax.linalg import geqrf as _geqrf
 
 
 # ---------------------------------------------------------------------------

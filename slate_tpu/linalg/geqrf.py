@@ -74,9 +74,8 @@ def _qr_panel_mode(A):
     path; None keeps XLA panels. SLATE_QR_PANEL=1 forces (interpret on
     CPU — tests), =0 disables."""
     import os
-    from ..internal import panel_qr
     flag = os.environ.get("SLATE_QR_PANEL", "")
-    if flag == "0" or not panel_qr.HAVE_PALLAS:
+    if flag == "0":
         return None
     on_tpu = A.grid.devices[0].platform == "tpu"
     if flag == "1":

@@ -362,9 +362,8 @@ def _fast_path_mode(A, piv_mode) -> str | None:
     =0 disables.
     """
     import os
-    from ..internal import panel_plu
     flag = os.environ.get("SLATE_LU_FAST", "")
-    if flag == "0" or not panel_plu.HAVE_PALLAS:
+    if flag == "0":
         return None
     kt = min(A.mt, A.nt)
     mtl, ntl = A.data.shape[2], A.data.shape[3]
@@ -565,39 +564,35 @@ def _getrf_fast_group_core(a, content, info, g0, gsz, nb,
     return a, content, o_g, info
 
 
+def _fast_group_program(dev):
+    """The per-group donated program for ``dev`` with PINNED row-major
+    layouts: XLA's layout assignment otherwise gives the [n, n]
+    parameter the transposed {0,1} layout (preferred by the row-gather
+    compaction), which inserts a matrix-sized layout-conversion copy
+    AND defeats donation — measured 19.6 GB peak at n=45056 vs ~9 GB
+    pinned.
+
+    ``cached_jit`` memoizes on (fn, options) and the layout Formats
+    carry the device — so each device gets exactly one wrapper, and the
+    compiled group programs participate in the on-disk executable
+    store like every other driver program."""
+    from jax.experimental.layout import Format, Layout
+    sh = jax.sharding.SingleDeviceSharding(dev)
+    f2 = Format(Layout((0, 1)), sh)
+    f1 = Format(Layout((0,)), sh)
+    f0 = Format(Layout(()), sh)
+    return cached_jit(_getrf_fast_group_core,
+                      routine="getrf.fast_group",
+                      donate_argnums=(0, 1),
+                      static_argnums=(3, 4, 5, 6, 7, 8),
+                      in_shardings=(f2, f1, f0),
+                      out_shardings=(f2, f1, f1, f0))
+
+
 def _getrf_fast_group_jit(a, content, info, g0, gsz, nb, interpret,
                           fold, tier=None):
-    """Per-group donated program with PINNED row-major layouts: XLA's
-    layout assignment otherwise gives the [n, n] parameter the
-    transposed {0,1} layout (preferred by the row-gather compaction),
-    which inserts a matrix-sized layout-conversion copy AND defeats
-    donation — measured 19.6 GB peak at n=45056 vs ~9 GB pinned.
-
-    The per-device wrapper memo that used to live here
-    (``_group_jit_cache``) is now the cache layer's instance table:
-    ``cached_jit`` memoizes on (fn, options), and the layout Formats
-    carry the device — so each device still gets exactly one wrapper,
-    and the compiled group programs participate in the on-disk
-    executable store like every other driver program."""
-    dev = next(iter(a.devices()))
-    try:
-        from jax.experimental.layout import Format, Layout
-        sh = jax.sharding.SingleDeviceSharding(dev)
-        f2 = Format(Layout((0, 1)), sh)
-        f1 = Format(Layout((0,)), sh)
-        f0 = Format(Layout(()), sh)
-        jf = cached_jit(_getrf_fast_group_core,
-                        routine="getrf.fast_group",
-                        donate_argnums=(0, 1),
-                        static_argnums=(3, 4, 5, 6, 7, 8),
-                        in_shardings=(f2, f1, f0),
-                        out_shardings=(f2, f1, f1, f0))
-    except Exception:  # pragma: no cover — older layout API
-        jf = cached_jit(_getrf_fast_group_core,
-                        routine="getrf.fast_group",
-                        donate_argnums=(0, 1),
-                        static_argnums=(3, 4, 5, 6, 7, 8))
-    return jf(a, content, info, g0, gsz, nb, interpret, fold, tier)
+    return _fast_group_program(next(iter(a.devices())))(
+        a, content, info, g0, gsz, nb, interpret, fold, tier)
 
 
 def getrf_dense_inplace(a, nb: int = 1024, opts=None):

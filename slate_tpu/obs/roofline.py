@@ -12,7 +12,7 @@ every kernel before optimizing it:
 * **classification** — ``compute`` when the compute-time term of the
   roofline dominates (AI above the ridge point), ``memory`` when the
   bandwidth term dominates, ``latency`` when the roofline expects the
-  work to take well under the measured wall (dispatch/tunnel/compile
+  work to take well under the measured wall (dispatch/compile
   overheads own the span, not the device), ``host`` when the span
   carries no attributable routine at all;
 * **expected vs measured** — ``expected_s = max(flops/peak,
@@ -78,7 +78,7 @@ DCN_GBS = {
 
 # a span is latency-bound when the roofline expects under this
 # fraction of the measured wall — the device work cannot explain the
-# time; dispatch/tunnel/pipeline bubbles own it
+# time; dispatch/pipeline bubbles own it
 LATENCY_FRACTION = 0.1
 
 _DIM_KEYS = ("m", "n", "k", "nb", "b", "nrhs", "side")

@@ -129,7 +129,7 @@ def span(name: str, **labels):
 
 def record_span(name: str, seconds: float, **labels) -> None:
     """Log an externally-timed region (duration measured by the
-    caller — e.g. the bench's median-of-iters with tunnel-latency
+    caller — e.g. the bench's median-of-iters with round-trip
     subtraction) as a span ending now."""
     if not (_enabled or _metrics.enabled() or _flight.enabled()):
         return

@@ -35,10 +35,14 @@ _DAG_CB = ctypes.CFUNCTYPE(None, ctypes.c_void_p, ctypes.c_int64)
 
 def _build() -> str | None:
     from ..robust.watchdog import checked_run
+    # private temp path + atomic rename: racing builders (pytest
+    # workers) each land a complete .so
+    tmp = f"{_SO}.tmp.{os.getpid()}"
     cmd = ["g++", "-O3", "-fopenmp", "-shared", "-fPIC", "-std=c++17",
-           _SRC, "-o", _SO]
+           _SRC, "-o", tmp]
     try:
         checked_run(cmd, timeout=120, what="slate_runtime")
+        os.replace(tmp, _SO)
         return _SO
     except (OSError, subprocess.SubprocessError):
         return None
@@ -56,7 +60,11 @@ def _load():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        so = _SO if os.path.exists(_SO) else _build()
+        # rebuild when the source is newer than the library, as the
+        # c_api and band_bulge loaders do
+        fresh = (os.path.exists(_SO)
+                 and os.path.getmtime(_SO) >= os.path.getmtime(_SRC))
+        so = _SO if fresh else _build()
         if so is None:
             return None
         try:

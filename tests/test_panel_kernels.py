@@ -17,10 +17,6 @@ from slate_tpu.internal import pallas_kernels as pk
 from slate_tpu.internal.precision import TIERS
 from tests.conftest import rand, spd
 
-pytestmark = pytest.mark.skipif(not pk.HAVE_PALLAS,
-                                reason="pallas unavailable")
-
-
 def well_conditioned_lower(n, dtype=np.float64, seed=0, unit=False):
     """Random lower-triangular with bounded condition number —
     raw ``tril(randn)`` grows solve error exponentially in n."""

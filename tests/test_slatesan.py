@@ -148,7 +148,7 @@ def test_read_after_donate_exact_eqn():
                                    jnp.ones((4, 8), jnp.float32)))
     got = _findings(rep, "donation")
     assert len(got) == 1
-    assert got[0].eqn == 1 and got[0].path.startswith("pjit:")
+    assert got[0].eqn == 1 and got[0].path.startswith("jit:")
     assert "donated invar #0" in got[0].message
 
 
@@ -280,8 +280,6 @@ def _kernel_suite_cases():
     program plus the registered VMEM_FOOTPRINTS estimate for its
     shape."""
     from slate_tpu.internal import pallas_kernels as pk
-    if not pk.HAVE_PALLAS:
-        pytest.skip("pallas unavailable")
     h, w = 256, 128
     n, m = 128, 256
     mk = (64, 128, 32)

@@ -294,7 +294,7 @@ def test_report_renders_histogram_percentiles():
 
 
 # ---------------------------------------------------------------------------
-# timing clamp (satellite: tunnel subtraction can never go negative)
+# timing clamp (satellite: round-trip subtraction can never go negative)
 # ---------------------------------------------------------------------------
 
 def test_timing_clamp_floors_at_zero_and_counts():

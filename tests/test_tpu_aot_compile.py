@@ -1,0 +1,170 @@
+"""The main path's kernels and programs, compiled for the real chip.
+
+Nothing runs: the TPU compiler installed in the sandbox compiles for a
+DESCRIBED ``v5e:2x2`` (no chip attached), which refuses what interpret
+mode cannot see — misaligned slices, too much VMEM, a kernel that
+cannot be partitioned, a program that does not fit HBM.  Real widths
+(h=16384, nb=1024); the n=16384 whole-factorization programs take
+minutes and are compiled by hand, not here.
+
+The topology is described inside a module-scoped fixture and nowhere at
+import time (one process at a time may load libtpu; every xdist worker
+imports every test file).  JAX's persistent compilation cache is off
+around these compiles: an entry written without a chip cannot be read
+back and only produces warnings.  So is x64, which tests/conftest.py
+turns on for the CPU references: the chip runs with jax's default, and
+Mosaic has no 64-bit integers.
+"""
+
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import slate_tpu as slate
+
+H, W, NB = 16384, 128, 1024
+F32 = jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / lock held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = (jax.config.jax_enable_compilation_cache,
+            jax.config.jax_enable_x64)
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev[0])
+    jax.config.update("jax_enable_x64", prev[1])
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tpu_grid22(topo):
+    return slate.Grid(2, 2, devices=list(topo.devices))
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# -- the production LU panel kernels (internal/panel_plu.py) ---------------
+
+@pytest.mark.parametrize("fold", [True, False], ids=["fold", "nofold"])
+def test_plu_subpanel_compiles(one_chip, fold):
+    from slate_tpu.internal import panel_plu
+    sub = jax.ShapeDtypeStruct((H, W), F32, sharding=one_chip)
+    act = jax.ShapeDtypeStruct((H,), F32, sharding=one_chip)
+    c = _compile(partial(panel_plu.plu_subpanel, fold=fold), sub, act)
+    assert _kernels(c) >= 3      # transpose in, factor, transpose out
+
+
+def test_fold_unfold_panel_compile(one_chip):
+    from slate_tpu.internal import panel_plu
+    flat = jax.ShapeDtypeStruct((H, NB), F32, sharding=one_chip)
+    folded = jax.ShapeDtypeStruct((8, NB, H // 8), F32, sharding=one_chip)
+    assert _kernels(_compile(panel_plu.fold_panel, flat)) == 1
+    assert _kernels(_compile(panel_plu.unfold_panel, folded)) == 1
+
+
+def test_plu_call_folded_block_compiles(one_chip):
+    from slate_tpu.internal import panel_plu
+    pcf = jax.ShapeDtypeStruct((8, NB, H // 8), F32, sharding=one_chip)
+    act = jax.ShapeDtypeStruct((8, H // 8), F32, sharding=one_chip)
+    sidx = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    assert _kernels(_compile(panel_plu.plu_call_folded_block,
+                             pcf, act, sidx)) == 1
+
+
+def test_getrf_fast_group_program_compiles_with_the_kernel(topo, one_chip):
+    """The smaller twin (n=8192, two panels) of the program slate.gesv
+    runs at n=16384 on one chip: layout-pinned, donated, Pallas
+    panels."""
+    from slate_tpu.linalg import getrf
+    n = 8192
+    a = jax.ShapeDtypeStruct((n, n), F32, sharding=one_chip)
+    content = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    info = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    c = getrf._fast_group_program(topo.devices[0]).lower(
+        a, content, info, 0, 2, NB, False, True, None).compile()
+    assert _kernels(c) > 0
+
+
+# -- the Householder panel geqrf turns on by default on the chip -----------
+
+def test_qr_panel_compiles(one_chip):
+    from slate_tpu.internal import panel_qr
+    pan = jax.ShapeDtypeStruct((H, NB), F32, sharding=one_chip)
+    assert _kernels(_compile(panel_qr.qr_panel_blocked, pan)) >= NB // W
+
+
+# -- one 2x2 super-step chunk of each factorization -------------------------
+
+def _tiles(grid, n=H, nb=NB):
+    t = n // nb
+    return jax.ShapeDtypeStruct(
+        (grid.p, grid.q, t // grid.p, t // grid.q, nb, nb), F32,
+        sharding=grid.sharding())
+
+
+def _assert_sharded_with_collectives(compiled, whole_bytes):
+    text = compiled.as_text()
+    assert "all-gather" in text or "all-reduce" in text
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert abs(per_device - whole_bytes // 4) < 2 ** 20, per_device
+
+
+def test_potrf_chunk_2x2_compiles_sharded(tpu_grid22):
+    from slate_tpu.linalg import potrf
+    data = _tiles(tpu_grid22)
+    A = slate.HermitianMatrix(data=data, m=H, n=H, nb=NB, grid=tpu_grid22)
+    info0 = jax.ShapeDtypeStruct((), jnp.int32)
+    c = potrf._potrf_chunk_jit.lower(A, info0, 0, 2,
+                                     tier="bf16_6x").compile()
+    _assert_sharded_with_collectives(c, H * H * 4)
+
+
+def test_getrf_chunk_2x2_compiles_sharded(tpu_grid22):
+    from slate_tpu.linalg import getrf
+    data = _tiles(tpu_grid22)
+    A = slate.Matrix(data=data, m=H, n=H, nb=NB, grid=tpu_grid22)
+    piv0 = jax.ShapeDtypeStruct((H // NB, NB), jnp.int32)
+    info0 = jax.ShapeDtypeStruct((), jnp.int32)
+    c = getrf._getrf_chunk_jit.lower(A, piv0, info0, 0, 2,
+                                     tier="bf16_6x").compile()
+    _assert_sharded_with_collectives(c, H * H * 4)
+
+
+# -- one served executable ---------------------------------------------------
+
+def test_served_posv_bucket_compiles(one_chip):
+    from slate_tpu.cache import buckets
+    from slate_tpu.serve import batched
+    bucket, rung = 1024, 4
+    a = jax.ShapeDtypeStruct((rung, bucket, bucket), F32,
+                             sharding=one_chip)
+    b = jax.ShapeDtypeStruct((rung, bucket, 8), F32, sharding=one_chip)
+    c = batched._posv_jit.lower(a, b, nb=buckets.default_nb(bucket),
+                                tier="bf16_6x").compile()
+    assert c.memory_analysis().argument_size_in_bytes >= a.size * 4

@@ -2,8 +2,8 @@ import os, sys, time
 import numpy as np
 sys.path.insert(0, '/root/repo')
 import jax
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.expanduser("~/.cache/slate_tpu_xla"))
+from slate_tpu.cache import place_jax_compile_cache
+place_jax_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
 import jax.numpy as jnp
 import jax.random as jrnd

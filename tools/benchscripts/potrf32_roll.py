@@ -9,8 +9,8 @@ import os, sys, time, glob
 import numpy as np
 sys.path.insert(0, '/root/repo')
 import jax
-cdir = os.path.expanduser("~/.cache/slate_tpu_xla")
-jax.config.update("jax_compilation_cache_dir", cdir)
+from slate_tpu.cache import place_jax_compile_cache
+cdir = place_jax_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
 import jax.numpy as jnp
 import slate_tpu as st

@@ -106,6 +106,8 @@ class Engine:
         self._region: tuple[int, dict] | None = None   # (epoch, vc)
         self._region_no = 0
         self._pool_tids: dict[int, int] = {}    # tid -> last region epoch
+        self._alias: dict[int, int] = {}        # OS ident -> logical tid
+        self._next_tid = 0
         self._findings: list[RaceFinding] = []
         self._seen_races: set = set()
 
@@ -147,9 +149,22 @@ class Engine:
     # -- dispatch ---------------------------------------------------------
 
     def _handle(self, ev) -> None:
+        # the OS reuses a thread ident once its thread has exited: a
+        # sync.Thread gets a fresh logical id at thread_begin (negative,
+        # so it meets no ident), or two threads that never overlapped
+        # in time would be ONE thread to the checker and their race
+        # invisible
+        raw = ev.tid
+        if ev.kind == "thread_begin":
+            self._next_tid -= 1
+            self._alias[raw] = self._next_tid
+        if raw in self._alias:
+            ev = ev._replace(tid=self._alias[raw])
         fn = getattr(self, "_on_" + ev.kind, None)
         if fn is not None:
             fn(ev)
+        if ev.kind == "thread_end":
+            self._alias.pop(raw, None)
 
     # locks
 

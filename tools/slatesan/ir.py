@@ -1,13 +1,13 @@
 """Jaxpr traversal: equation sites with their sub-jaxpr path and the
 mesh axes live at each point.
 
-``jax.make_jaxpr`` output nests programs: a driver trace is a ``pjit``
+``jax.make_jaxpr`` output nests programs: a driver trace is a ``jit``
 eqn wrapping a ``shard_map`` eqn wrapping ``scan``/``cond`` bodies.
 :func:`walk` yields every equation of every sub-jaxpr depth-first as a
 :class:`Site` carrying
 
 * ``path`` — the label chain down to the eqn's own jaxpr
-  (``pjit:potrf/shard_map/scan``), stable enough for tests to pin a
+  (``jit:potrf/shard_map/scan``), stable enough for tests to pin a
   seeded violation to its exact equation;
 * ``axis_sizes`` — the mesh axes bound by enclosing ``shard_map``
   eqns (name → size), the ground truth the collective analysis checks
@@ -27,7 +27,7 @@ from dataclasses import dataclass
 from typing import Iterator
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 _Jaxpr = jcore.Jaxpr
 _ClosedJaxpr = jcore.ClosedJaxpr

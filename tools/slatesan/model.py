@@ -3,7 +3,7 @@ and the CLI.
 
 A :class:`SanFinding` is anchored to an *equation path*: the chain of
 sub-jaxpr labels from the top-level jaxpr down to the equation
-(``pjit:potrf/shard_map/eqn[12]``), so a finding names the exact eqn
+(``jit:potrf/shard_map/eqn[12]``), so a finding names the exact eqn
 in the exact sub-program — the IR analog of slatelint's
 ``path:line:col``.  :class:`SanReport` is the per-program verdict the
 jitcache hook persists into a slatecache entry's ``meta.json`` and
@@ -26,7 +26,7 @@ SAN_VERSION = 1
 class SanFinding:
     """One verifier violation at an equation in a traced program."""
     analysis: str          # one of ANALYSES
-    path: str              # sub-jaxpr chain, e.g. "pjit:potrf/shard_map"
+    path: str              # sub-jaxpr chain, e.g. "jit:potrf/shard_map"
     eqn: int               # eqn index within that sub-jaxpr (-1 = whole)
     primitive: str         # primitive at the anchor eqn ("" = none)
     message: str
