@@ -1,0 +1,10 @@
+"""Run by hand: ``JAX_PLATFORMS=cpu python -m pytest benchmarks/tests``
+(tier-1 collects ``tests/`` only)."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
