@@ -59,6 +59,7 @@ from ..internal import comm, masks
 from ..internal.tile_kernels import panel_lu_factor, panel_lu_nopiv
 from ..internal.masks import tile_diag_pad_identity
 from ..internal.precision import resolve_tier, trailing_dot_kwargs
+from .. import obs
 from ..obs import timeline as tl
 from ..runtime import dag
 from ..utils import trace
@@ -171,7 +172,8 @@ def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
                         "getrf", new_data, chunk_idx=ci,
                         n_chunks=len(chunk_starts), nb=A.nb, p=g.p,
                         q=g.q, mt=A.mt, k0t=k0, k1t=k0 + klen)
-                    if ab is not None and int(new_info) == 0:
+                    if ab is not None and obs.sync_read(
+                            "getrf.info", int, new_info) == 0:
                         v = ab.verify(new_data, k0 + klen)
                         if not v.ok:
                             act = ab.strike(k0)
@@ -232,7 +234,8 @@ def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
                         "getrf", data, chunk_idx=0, n_chunks=1,
                         nb=A.nb, p=g.p, q=g.q, mt=A.mt, k0t=0,
                         k1t=kt)
-                    if ab is None or int(info) != 0:
+                    if ab is None or obs.sync_read(
+                            "getrf.info", int, info) != 0:
                         break
                     v = ab.verify(data, kt, phase="final")
                     if v.ok:
@@ -427,116 +430,119 @@ def _getrf_fast_group_core(a, content, info, g0, gsz, nb,
     Lf = hw // 8
     for kk in range(gsz):
         d_lo, d_hi = done + kk * nb, done + (kk + 1) * nb
-        ubuf = jnp.zeros((nb, nb), a.dtype)
-        ordp = jnp.zeros(nb, jnp.int32)
-        if folded:
-            # ONE panel fold; the kernel addresses subpanel s of the
-            # whole folded buffer by scalar-prefetched block index and
-            # factors it IN PLACE (aliased) — no per-subpanel slice /
-            # dynamic-update-slice traffic, and the intra-panel algebra
-            # stays in folded coordinates (row i ↔ (i // Lf, i % Lf))
-            pcf = fold_panel(a[done:, d_lo:d_hi], interpret)
-            actf = act.reshape(8, Lf)
-            for s in range(sb):
-                c0 = s * W
-                pcf, actf, piv_l, inf = plu_call_folded_block(
-                    pcf, actf, s, interpret)
-                subf = pcf[:, c0:c0 + W, :]
-                piv_l = piv_l[0]
-                info = info + inf[0, 0].astype(jnp.int32)
-                ordp = ordp.at[c0:c0 + W].set(piv_l)
-                rem = nb - (s + 1) * W
-                if rem > 0:
-                    # pivot-row extraction as one-hot MXU contractions
-                    # (advanced indexing on the folded axes lowers to
-                    # a while-loop gather — ~37 ms at n=16384)
-                    fold_iota = (jnp.arange(8, dtype=jnp.int32)[:, None]
-                                 * Lf
-                                 + jnp.arange(Lf, dtype=jnp.int32)[None])
-                    oh = (fold_iota[None] == piv_l[:, None, None]
-                          ).astype(a.dtype)          # [W, 8, Lf]
-                    lu11 = jnp.einsum("jsl,swl->jw", oh, subf)
-                    brows = jnp.einsum("jsl,srl->jr", oh,
-                                       pcf[:, c0 + W:, :])  # [W, rem]
-                    u = lax.linalg.triangular_solve(
-                        lu11, brows, left_side=True, lower=True,
-                        unit_diagonal=True)
-                    ubuf = ubuf.at[c0:c0 + W, c0 + W:].set(u)
-                    lsubf = jnp.where(actf[:, None, :] > 0, subf,
-                                      jnp.zeros_like(subf))
-                    pcf = pcf.at[:, c0 + W:, :].add(
-                        -jnp.einsum("swl,wr->srl", lsubf, u))
-            act = actf.reshape(hw)
-            pcols = unfold_panel(pcf, interpret)
-        else:
-            pcols = a[done:, d_lo:d_hi]              # [hw, nb]
-            for s in range(sb):
-                c0 = s * W
-                sub = pcols[:, c0:c0 + W]
-                subf, piv_l, act, inf = plu_panel(sub, act, interpret,
-                                                  fold=fold)
-                pcols = pcols.at[:, c0:c0 + W].set(subf)
-                ordp = ordp.at[c0:c0 + W].set(piv_l)
-                info = info + inf
-                rem = nb - (s + 1) * W
-                if rem > 0:
-                    lu11 = jnp.take(subf, piv_l, axis=0)
-                    brows = jnp.take(pcols[:, c0 + W:], piv_l,
-                                     axis=0)         # [W, rem]
-                    u = lax.linalg.triangular_solve(
-                        lu11, brows, left_side=True, lower=True,
-                        unit_diagonal=True)
-                    ubuf = ubuf.at[c0:c0 + W, c0 + W:].set(u)
-                    lsub = jnp.where((act > 0)[:, None], subf,
-                                     jnp.zeros_like(subf))
-                    pcols = pcols.at[:, c0 + W:].add(-(lsub @ u))
-        ordg = ordg.at[d_lo - done:d_hi - done].set(ordp)
-        upend = upend.at[d_lo - done:d_hi - done,
-                         d_lo - done:d_hi - done].set(ubuf)
-        a = a.at[done:, d_lo:d_hi].set(pcols)
+        with jax.named_scope("panel"):
+            ubuf = jnp.zeros((nb, nb), a.dtype)
+            ordp = jnp.zeros(nb, jnp.int32)
+            if folded:
+                # ONE panel fold; the kernel addresses subpanel s of the
+                # whole folded buffer by scalar-prefetched block index and
+                # factors it IN PLACE (aliased) — no per-subpanel slice /
+                # dynamic-update-slice traffic, and the intra-panel algebra
+                # stays in folded coordinates (row i ↔ (i // Lf, i % Lf))
+                pcf = fold_panel(a[done:, d_lo:d_hi], interpret)
+                actf = act.reshape(8, Lf)
+                for s in range(sb):
+                    c0 = s * W
+                    pcf, actf, piv_l, inf = plu_call_folded_block(
+                        pcf, actf, s, interpret)
+                    subf = pcf[:, c0:c0 + W, :]
+                    piv_l = piv_l[0]
+                    info = info + inf[0, 0].astype(jnp.int32)
+                    ordp = ordp.at[c0:c0 + W].set(piv_l)
+                    rem = nb - (s + 1) * W
+                    if rem > 0:
+                        # pivot-row extraction as one-hot MXU contractions
+                        # (advanced indexing on the folded axes lowers to
+                        # a while-loop gather — ~37 ms at n=16384)
+                        fold_iota = (jnp.arange(8, dtype=jnp.int32)[:, None]
+                                     * Lf
+                                     + jnp.arange(Lf, dtype=jnp.int32)[None])
+                        oh = (fold_iota[None] == piv_l[:, None, None]
+                              ).astype(a.dtype)          # [W, 8, Lf]
+                        lu11 = jnp.einsum("jsl,swl->jw", oh, subf)
+                        brows = jnp.einsum("jsl,srl->jr", oh,
+                                           pcf[:, c0 + W:, :])  # [W, rem]
+                        u = lax.linalg.triangular_solve(
+                            lu11, brows, left_side=True, lower=True,
+                            unit_diagonal=True)
+                        ubuf = ubuf.at[c0:c0 + W, c0 + W:].set(u)
+                        lsubf = jnp.where(actf[:, None, :] > 0, subf,
+                                          jnp.zeros_like(subf))
+                        pcf = pcf.at[:, c0 + W:, :].add(
+                            -jnp.einsum("swl,wr->srl", lsubf, u))
+                act = actf.reshape(hw)
+                pcols = unfold_panel(pcf, interpret)
+            else:
+                pcols = a[done:, d_lo:d_hi]              # [hw, nb]
+                for s in range(sb):
+                    c0 = s * W
+                    sub = pcols[:, c0:c0 + W]
+                    subf, piv_l, act, inf = plu_panel(sub, act, interpret,
+                                                      fold=fold)
+                    pcols = pcols.at[:, c0:c0 + W].set(subf)
+                    ordp = ordp.at[c0:c0 + W].set(piv_l)
+                    info = info + inf
+                    rem = nb - (s + 1) * W
+                    if rem > 0:
+                        lu11 = jnp.take(subf, piv_l, axis=0)
+                        brows = jnp.take(pcols[:, c0 + W:], piv_l,
+                                         axis=0)         # [W, rem]
+                        u = lax.linalg.triangular_solve(
+                            lu11, brows, left_side=True, lower=True,
+                            unit_diagonal=True)
+                        ubuf = ubuf.at[c0:c0 + W, c0 + W:].set(u)
+                        lsub = jnp.where((act > 0)[:, None], subf,
+                                         jnp.zeros_like(subf))
+                        pcols = pcols.at[:, c0 + W:].add(-(lsub @ u))
+            ordg = ordg.at[d_lo - done:d_hi - done].set(ordp)
+            upend = upend.at[d_lo - done:d_hi - done,
+                             d_lo - done:d_hi - done].set(ubuf)
+            a = a.at[done:, d_lo:d_hi].set(pcols)
         # trailing on the group's OWN remaining columns only
         if d_hi < ge:
-            lu11n = jnp.take(pcols, ordp, axis=0)
-            bright = jnp.take(a[done:, d_hi:ge], ordp, axis=0)
-            un = lax.linalg.triangular_solve(
-                jnp.tril(lu11n, -1)
-                + jnp.eye(nb, dtype=a.dtype), bright,
-                left_side=True, lower=True, unit_diagonal=True)
-            lk = jnp.where((act > 0)[:, None], pcols,
-                           jnp.zeros_like(pcols))
-            a = a.at[done:, d_hi:ge].add(
-                -jnp.matmul(lk, un, **trailing_dot_kwargs(tier, a.dtype)))
-            upend = upend.at[d_lo - done:d_hi - done,
-                             d_hi - done:].set(un)
+            with jax.named_scope("trailing"):
+                lu11n = jnp.take(pcols, ordp, axis=0)
+                bright = jnp.take(a[done:, d_hi:ge], ordp, axis=0)
+                un = lax.linalg.triangular_solve(
+                    jnp.tril(lu11n, -1)
+                    + jnp.eye(nb, dtype=a.dtype), bright,
+                    left_side=True, lower=True, unit_diagonal=True)
+                lk = jnp.where((act > 0)[:, None], pcols,
+                               jnp.zeros_like(pcols))
+                a = a.at[done:, d_hi:ge].add(
+                    -jnp.matmul(lk, un, **trailing_dot_kwargs(tier, a.dtype)))
+                upend = upend.at[d_lo - done:d_hi - done,
+                                 d_hi - done:].set(un)
 
-    o_g = jnp.take(content[done:], ordg)
-    # ---- compaction: finished rows to LAPACK order + U overlay ------
-    rank = jnp.zeros(hw, jnp.int32).at[ordg].set(
-        jnp.arange(gnb, dtype=jnp.int32))
-    key = jnp.where(act > 0, gnb + iota_hw, rank)
-    perm = jnp.argsort(key)
-    if n <= _COMPACT_TAKE_MAX_N:
-        # one full-window take: measured 2× the chunked form at 16k
-        # (6.6 vs 13.3 ms per full-size pass) at the cost of a
-        # window-sized temp — affordable below the 32k memory cliff
-        # (see _COMPACT_TAKE_MAX_N)
-        a = a.at[done:].set(jnp.take(a[done:], perm, axis=0))
-    else:
-        # column-chunked permute (window + stored-L back-pivot): each
-        # [hw, CB] block gathers and writes back in place, so the peak
-        # temporary is hw·CB instead of a second matrix-sized window —
-        # this is what admits the 45k-64k f32 class (VERDICT r3 #3)
-        CB = _COMPACT_CB
-        for c0 in range(0, n, CB):
-            cw = min(CB, n - c0)
-            a = a.at[done:, c0:c0 + cw].set(
-                jnp.take(a[done:, c0:c0 + cw], perm, axis=0))
-    content = content.at[done:].set(jnp.take(content[done:], perm))
-    i_g = jnp.arange(gnb, dtype=jnp.int32)
-    sub_end = (i_g // W + 1) * W                     # group cols
-    colmask = i_g[None, :] >= sub_end[:, None]
-    a = a.at[done:ge, done:ge].set(
-        jnp.where(colmask, upend, a[done:ge, done:ge]))
+    with jax.named_scope("pivot_gather"):
+        o_g = jnp.take(content[done:], ordg)
+        # ---- compaction: finished rows to LAPACK order + U overlay ------
+        rank = jnp.zeros(hw, jnp.int32).at[ordg].set(
+            jnp.arange(gnb, dtype=jnp.int32))
+        key = jnp.where(act > 0, gnb + iota_hw, rank)
+        perm = jnp.argsort(key)
+        if n <= _COMPACT_TAKE_MAX_N:
+            # one full-window take: measured 2× the chunked form at 16k
+            # (6.6 vs 13.3 ms per full-size pass) at the cost of a
+            # window-sized temp — affordable below the 32k memory cliff
+            # (see _COMPACT_TAKE_MAX_N)
+            a = a.at[done:].set(jnp.take(a[done:], perm, axis=0))
+        else:
+            # column-chunked permute (window + stored-L back-pivot): each
+            # [hw, CB] block gathers and writes back in place, so the peak
+            # temporary is hw·CB instead of a second matrix-sized window —
+            # this is what admits the 45k-64k f32 class (VERDICT r3 #3)
+            CB = _COMPACT_CB
+            for c0 in range(0, n, CB):
+                cw = min(CB, n - c0)
+                a = a.at[done:, c0:c0 + cw].set(
+                    jnp.take(a[done:, c0:c0 + cw], perm, axis=0))
+        content = content.at[done:].set(jnp.take(content[done:], perm))
+        i_g = jnp.arange(gnb, dtype=jnp.int32)
+        sub_end = (i_g // W + 1) * W                     # group cols
+        colmask = i_g[None, :] >= sub_end[:, None]
+        a = a.at[done:ge, done:ge].set(
+            jnp.where(colmask, upend, a[done:ge, done:ge]))
 
     # ---- deferred cross-group trailing (exact shapes) ---------------
     # U block rows by blocked forward substitution on the compacted
@@ -544,23 +550,24 @@ def _getrf_fast_group_core(a, content, info, g0, gsz, nb,
     # then ONE [hw-gnb, gnb] x [gnb, n-ge] gemm — no masked-height
     # waste, full-MXU-efficiency shapes
     if ge < n:
-        ug = []
-        for kk in range(gsz):
-            r0 = done + kk * nb
-            acc = a[r0:r0 + nb, ge:]
-            for p in range(kk):
-                acc = acc - (a[r0:r0 + nb,
-                               done + p * nb:done + (p + 1) * nb]
-                             @ ug[p])
-            lkk = a[r0:r0 + nb, done + kk * nb:done + (kk + 1) * nb]
-            ug.append(lax.linalg.triangular_solve(
-                jnp.tril(lkk, -1) + jnp.eye(nb, dtype=a.dtype), acc,
-                left_side=True, lower=True, unit_diagonal=True))
-        ugs = jnp.concatenate(ug, axis=0)            # [gnb, n-ge]
-        a = a.at[ge:, ge:].add(
-            -jnp.matmul(a[ge:, done:ge], ugs,
-                        **trailing_dot_kwargs(tier, a.dtype)))
-        a = a.at[done:ge, ge:].set(ugs)
+        with jax.named_scope("trailing"):
+            ug = []
+            for kk in range(gsz):
+                r0 = done + kk * nb
+                acc = a[r0:r0 + nb, ge:]
+                for p in range(kk):
+                    acc = acc - (a[r0:r0 + nb,
+                                   done + p * nb:done + (p + 1) * nb]
+                                 @ ug[p])
+                lkk = a[r0:r0 + nb, done + kk * nb:done + (kk + 1) * nb]
+                ug.append(lax.linalg.triangular_solve(
+                    jnp.tril(lkk, -1) + jnp.eye(nb, dtype=a.dtype), acc,
+                    left_side=True, lower=True, unit_diagonal=True))
+            ugs = jnp.concatenate(ug, axis=0)            # [gnb, n-ge]
+            a = a.at[ge:, ge:].add(
+                -jnp.matmul(a[ge:, done:ge], ugs,
+                            **trailing_dot_kwargs(tier, a.dtype)))
+            a = a.at[done:ge, ge:].set(ugs)
     return a, content, o_g, info
 
 
@@ -735,7 +742,9 @@ def pivot_order_to_ipiv(order) -> jnp.ndarray:
     import numpy as _np
     arr = order.order if isinstance(order, PivotOrder) else order
     kt, nb = arr.shape
-    ipiv = _rt.order_to_ipiv(_np.asarray(arr))
+    # the one blocking device→host read of the gesv path
+    ipiv = obs.sync_read("gesv.order_to_ipiv",
+                         lambda o: _rt.order_to_ipiv(_np.asarray(o)), arr)
     return jnp.asarray(ipiv, jnp.int32).reshape(kt, nb)
 
 
@@ -1649,14 +1658,16 @@ def getrs(LU: Matrix, piv, B: Matrix, trans: Op = Op.NoTrans, opts=None):
                          grid=LU.grid, uplo=Uplo.Upper, diag=Diag.NonUnit)
     with trace.block("getrs"):
         if trans == Op.NoTrans:
-            Bp = _apply_pivots_matrix(B, piv, forward=True)
+            with trace.block("getrs.apply_pivots"):
+                Bp = _apply_pivots_matrix(B, piv, forward=True)
             Y = trsm(Side.Left, 1.0, L, Bp, opts)
             X = trsm(Side.Left, 1.0, U, Y, opts)
             return X
         opA = transpose if trans == Op.Trans else conj_transpose
         Y = trsm(Side.Left, 1.0, opA(U), B, opts)
         Z = trsm(Side.Left, 1.0, opA(L), Y, opts)
-        return _apply_pivots_matrix(Z, piv, forward=False)
+        with trace.block("getrs.apply_pivots"):
+            return _apply_pivots_matrix(Z, piv, forward=False)
 
 
 def getrs_nopiv(LU: Matrix, B: Matrix, opts=None):
@@ -1673,6 +1684,12 @@ def getrs_nopiv(LU: Matrix, B: Matrix, opts=None):
 def gesv(A: Matrix, B: Matrix, opts=None):
     """Solve A·X = B by LU (reference src/gesv.cc).
     Returns (X, LU, piv, info)."""
+    with trace.block("slate.gesv", routine="gesv", n=A.n, nb=A.nb,
+                     nrhs=B.n, grid=f"{A.grid.p}x{A.grid.q}"):
+        return _gesv(A, B, opts)
+
+
+def _gesv(A, B, opts):
     method = MethodLU.select_algo(A, opts)
     if method == MethodLU.NoPiv:
         LU, info = getrf_nopiv(A, opts)
@@ -1687,9 +1704,11 @@ def gesv(A: Matrix, B: Matrix, opts=None):
         # neither side runs an O(n) sequential swap simulation; the
         # LAPACK ipiv of the return contract is derived on host while
         # the device runs the solve
-        data, order, info = _getrf_fast_jit(
-            Am, interpret=(fm == "interpret"), want_ipiv=False,
-            fold=_fold_now())
+        with trace.block("getrf.chunk", phase="fast_path", k0=0,
+                         klen=min(Am.mt, Am.nt)):
+            data, order, info = _getrf_fast_jit(
+                Am, interpret=(fm == "interpret"), want_ipiv=False,
+                fold=_fold_now())
         LU = Am._replace(data=data)
         X = getrs(LU, PivotOrder(order), B, Op.NoTrans, opts)
         return X, LU, pivot_order_to_ipiv(order), info

@@ -48,6 +48,7 @@ from ..internal import comm, masks
 from ..internal.tile_kernels import tile_potrf, _factor_dtype
 from ..internal.masks import tile_diag_pad_identity
 from ..internal.precision import resolve_tier, trailing_dot_kwargs
+from .. import obs
 from ..obs import timeline as tl
 from ..runtime import dag
 from ..utils import trace
@@ -170,7 +171,8 @@ def potrf(A: HermitianMatrix, opts=None, overwrite_a: bool = False,
                         "potrf", new_data, chunk_idx=ci,
                         n_chunks=len(chunk_starts), nb=A.nb, p=g.p,
                         q=g.q, mt=A.mt, k0t=k0, k1t=k0 + klen)
-                    if ab is not None and int(new_info) == 0:
+                    if ab is not None and obs.sync_read(
+                            "potrf.info", int, new_info) == 0:
                         v = ab.verify(new_data, k0 + klen)
                         if not v.ok:
                             act = ab.strike(k0)
@@ -208,7 +210,8 @@ def potrf(A: HermitianMatrix, opts=None, overwrite_a: bool = False,
                     data = _faults.maybe_bitflip_chunk(
                         "potrf", data, chunk_idx=0, n_chunks=1,
                         nb=A.nb, p=g.p, q=g.q, mt=A.mt, k0t=0, k1t=nt)
-                    if ab is None or int(info) != 0:
+                    if ab is None or obs.sync_read(
+                            "potrf.info", int, info) != 0:
                         break
                     v = ab.verify(data, nt, phase="final")
                     if v.ok:
@@ -332,25 +335,28 @@ def _potrf_dense_loop(a, nb, n, Mp, tier=None):
     info = jnp.zeros((), jnp.int32)
     for k in range(nt):
         r0 = k * nb
-        akk = a[r0:r0 + nb, r0:r0 + nb]
-        low = jnp.tril(akk)
-        strict = jnp.tril(akk, -1)
-        akk = low + (jnp.conj(strict.T) if cplx else strict.T)
-        lkk, info = finite_guard(tile_potrf(akk), info, k + 1,
-                                 diag=True, cplx=cplx)
-        a = a.at[r0:r0 + nb, r0:r0 + nb].set(jnp.tril(lkk))
+        with jax.named_scope("panel"):
+            akk = a[r0:r0 + nb, r0:r0 + nb]
+            low = jnp.tril(akk)
+            strict = jnp.tril(akk, -1)
+            akk = low + (jnp.conj(strict.T) if cplx else strict.T)
+            lkk, info = finite_guard(tile_potrf(akk), info, k + 1,
+                                     diag=True, cplx=cplx)
+            a = a.at[r0:r0 + nb, r0:r0 + nb].set(jnp.tril(lkk))
         if r0 + nb < Mp:
-            # low-precision tiles solve the panel in f32 (XLA's
-            # TriangularSolve needs >= f32; storage stays bf16)
-            fd = _factor_dtype(a.dtype)
-            pan = lax.linalg.triangular_solve(
-                lkk.astype(fd), a[r0 + nb:, r0:r0 + nb].astype(fd),
-                left_side=False, lower=True,
-                transpose_a=True, conjugate_a=cplx).astype(a.dtype)
-            pan, info = finite_guard(pan, info, k + 1, cplx=cplx)
-            a = a.at[r0 + nb:, r0:r0 + nb].set(pan)
-            a = _syrk_update_inplace(a, r0 + nb, Mp - r0 - nb, pan, cplx,
-                                     tier=tier)
+            with jax.named_scope("panel"):
+                # low-precision tiles solve the panel in f32 (XLA's
+                # TriangularSolve needs >= f32; storage stays bf16)
+                fd = _factor_dtype(a.dtype)
+                pan = lax.linalg.triangular_solve(
+                    lkk.astype(fd), a[r0 + nb:, r0:r0 + nb].astype(fd),
+                    left_side=False, lower=True,
+                    transpose_a=True, conjugate_a=cplx).astype(a.dtype)
+                pan, info = finite_guard(pan, info, k + 1, cplx=cplx)
+                a = a.at[r0 + nb:, r0:r0 + nb].set(pan)
+            with jax.named_scope("trailing"):
+                a = _syrk_update_inplace(a, r0 + nb, Mp - r0 - nb, pan, cplx,
+                                         tier=tier)
     return a, info
 
 
@@ -520,58 +526,62 @@ def _potrf_chunk_core(A, info0, k0, klen, win_hi=None, tier=None):
             sub = tl.mark(sub, "step", step=k, device=dev,
                           kind=tl.KIND_STEP, edge="b", routine="potrf",
                           ndev=ndev)
-            akk = lax.dynamic_slice(
-                sub, (k // p - r0s, k // q - c0s, 0, 0),
-                (1, 1, nb, nb))[0, 0]
-            akk = comm.bcast_from_owner(akk, k % p, k % q)
-            akk = tile_diag_pad_identity(akk, k, n, nb)
-            low = jnp.tril(akk)
-            strict = jnp.tril(akk, -1)
-            akk = low + (jnp.conj(strict.T) if cplx else strict.T)
-            lkk, info = finite_guard(tile_potrf(akk), info, k + 1,
-                                     diag=True, cplx=cplx)
+            with jax.named_scope("panel"):
+                akk = lax.dynamic_slice(
+                    sub, (k // p - r0s, k // q - c0s, 0, 0),
+                    (1, 1, nb, nb))[0, 0]
+                akk = comm.bcast_from_owner(akk, k % p, k % q)
+                akk = tile_diag_pad_identity(akk, k, n, nb)
+                low = jnp.tril(akk)
+                strict = jnp.tril(akk, -1)
+                akk = low + (jnp.conj(strict.T) if cplx else strict.T)
+                lkk, info = finite_guard(tile_potrf(akk), info, k + 1,
+                                         diag=True, cplx=cplx)
 
-            pcol = lax.dynamic_index_in_dim(sub, k // q - c0s, axis=1,
-                                            keepdims=False)
-            below = gi > k
-            solved = lax.linalg.triangular_solve(
-                jnp.broadcast_to(lkk, (msub, nb, nb)), pcol,
-                left_side=False, lower=True, transpose_a=True,
-                conjugate_a=cplx)
-            pcol_new = jnp.where(below[:, None, None], solved, pcol)
-            pcol_new = jnp.where(
-                (gi == k)[:, None, None],
-                jnp.broadcast_to(jnp.tril(lkk), (msub, nb, nb)),
-                pcol_new)
-            sub = jnp.where(
-                (c == k % q),
-                lax.dynamic_update_index_in_dim(
-                    sub, pcol_new, k // q - c0s, axis=1), sub)
+                pcol = lax.dynamic_index_in_dim(sub, k // q - c0s, axis=1,
+                                                keepdims=False)
+                below = gi > k
+                solved = lax.linalg.triangular_solve(
+                    jnp.broadcast_to(lkk, (msub, nb, nb)), pcol,
+                    left_side=False, lower=True, transpose_a=True,
+                    conjugate_a=cplx)
+                pcol_new = jnp.where(below[:, None, None], solved, pcol)
+                pcol_new = jnp.where(
+                    (gi == k)[:, None, None],
+                    jnp.broadcast_to(jnp.tril(lkk), (msub, nb, nb)),
+                    pcol_new)
+                sub = jnp.where(
+                    (c == k % q),
+                    lax.dynamic_update_index_in_dim(
+                        sub, pcol_new, k // q - c0s, axis=1), sub)
 
-            panel_masked = jnp.where(below[:, None, None], pcol_new,
-                                     jnp.zeros_like(pcol_new))
+                panel_masked = jnp.where(below[:, None, None], pcol_new,
+                                         jnp.zeros_like(pcol_new))
             panel_masked = tl.mark(panel_masked, "panel_bcast", step=k,
                                    device=dev, kind=tl.KIND_COLLECTIVE,
                                    edge="b", routine="potrf", ndev=ndev)
-            full = comm.allgather_panel_rows(panel_masked, p, k % q)
+            with jax.named_scope("panel_bcast"):
+                full = comm.allgather_panel_rows(panel_masked, p, k % q)
             full = tl.mark(full, "panel_bcast", step=k, device=dev,
                            kind=tl.KIND_COLLECTIVE, edge="e",
                            routine="potrf", ndev=ndev)
             # gathered index g = (slot−r0s)·p + r ⇒ global tile g+k0…
-            lrows = jnp.take(full, gi - r0s * p, axis=0)
-            lcols = jnp.take(
-                full, jnp.clip(gj - r0s * p, 0, msub * p - 1), axis=0)
-            if cplx:
-                lcols = jnp.conj(lcols)
+            with jax.named_scope("trailing"):
+                lrows = jnp.take(full, gi - r0s * p, axis=0)
+                lcols = jnp.take(
+                    full, jnp.clip(gj - r0s * p, 0, msub * p - 1), axis=0)
+                if cplx:
+                    lcols = jnp.conj(lcols)
             lrows = tl.mark(lrows, "trailing", step=k, device=dev,
                             kind=tl.KIND_COMPUTE, edge="b",
                             routine="potrf", ndev=ndev)
-            upd = jnp.einsum("aik,bjk->abij", lrows, lcols, **pk)
-            keep = ((gi > k) & (gi < nt))[:, None, None, None] \
-                & ((gj > k) & (gj < nt))[None, :, None, None]
-            if win_hi is not None:
-                keep = keep & (gj < win_hi)[None, :, None, None]
-            sub = sub - jnp.where(keep, upd, jnp.zeros_like(upd))
+            with jax.named_scope("trailing"):
+                upd = jnp.einsum("aik,bjk->abij", lrows, lcols, **pk)
+                keep = ((gi > k) & (gi < nt))[:, None, None, None] \
+                    & ((gj > k) & (gj < nt))[None, :, None, None]
+                if win_hi is not None:
+                    keep = keep & (gj < win_hi)[None, :, None, None]
+                sub = sub - jnp.where(keep, upd, jnp.zeros_like(upd))
             sub = tl.mark(sub, "trailing", step=k, device=dev,
                           kind=tl.KIND_COMPUTE, edge="e",
                           routine="potrf", ndev=ndev)
@@ -859,8 +869,10 @@ def potrs(L: TriangularMatrix, B: Matrix, opts=None) -> Matrix:
 def posv(A: HermitianMatrix, B: Matrix, opts=None):
     """Solve A·X = B by Cholesky (reference src/posv.cc).
     Returns (X, L, info)."""
-    L, info = potrf(A, opts)
-    X = potrs(L, B, opts)
+    with trace.block("slate.posv", routine="posv", n=A.n, nb=A.nb,
+                     nrhs=B.n, grid=f"{A.grid.p}x{A.grid.q}"):
+        L, info = potrf(A, opts)
+        X = potrs(L, B, opts)
     return X, L, info
 
 

@@ -47,10 +47,20 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .grid import Grid, default_grid, AXIS_P, AXIS_Q
 from .types import Op, Uplo, Diag
 from .errors import slate_error_if
+from . import obs
+from .utils import trace
 
 
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def _relayout_span(name: str, data):
+    """Span around re-laying ``data`` out (label ``bytes``: its storage),
+    counted as ``matrix.relayout_bytes``."""
+    nbytes = data.size * data.dtype.itemsize
+    obs.count("matrix.relayout_bytes", nbytes)
+    return trace.block(name, bytes=nbytes)
 
 
 # ---------------------------------------------------------------------------
@@ -242,18 +252,25 @@ class BaseTiledMatrix:
         """Resolve a shallow transpose flag into storage (all-to-all)."""
         if self.op == Op.NoTrans:
             return self
-        tiles = bc_to_tiles(self.data)
-        tiles = tiles.transpose(1, 0, 3, 2)
-        if self.op == Op.ConjTrans:
-            tiles = tiles.conj()
         g = self.grid
-        # crop to the true (after-op) tile counts, then re-pad for the grid
-        tiles = tiles[: self.mt, : self.nt]
-        mt_p = cdiv(tiles.shape[0], g.p) * g.p
-        nt_p = cdiv(tiles.shape[1], g.q) * g.q
-        tiles = jnp.pad(tiles, ((0, mt_p - tiles.shape[0]),
-                                (0, nt_p - tiles.shape[1]), (0, 0), (0, 0)))
-        data = jax.device_put(bc_from_tiles(tiles, g.p, g.q), g.sharding())
+        with _relayout_span("matrix.materialize", self.data):
+            with trace.block("materialize.to_tiles"):
+                tiles = bc_to_tiles(self.data)
+            with trace.block("materialize.transpose"):
+                tiles = tiles.transpose(1, 0, 3, 2)
+                if self.op == Op.ConjTrans:
+                    tiles = tiles.conj()
+                # crop to the true (after-op) tile counts, then re-pad
+                # for the grid
+                tiles = tiles[: self.mt, : self.nt]
+                mt_p = cdiv(tiles.shape[0], g.p) * g.p
+                nt_p = cdiv(tiles.shape[1], g.q) * g.q
+                tiles = jnp.pad(tiles, ((0, mt_p - tiles.shape[0]),
+                                        (0, nt_p - tiles.shape[1]),
+                                        (0, 0), (0, 0)))
+            with trace.block("materialize.device_put"):
+                data = jax.device_put(bc_from_tiles(tiles, g.p, g.q),
+                                      g.sharding())
         uplo = self.uplo
         if uplo in (Uplo.Lower, Uplo.Upper):
             uplo = Uplo.Upper if uplo == Uplo.Lower else Uplo.Lower
@@ -266,10 +283,11 @@ class BaseTiledMatrix:
         ``Matrix::redistribute``, Matrix.hh:831-862 — used by heev to
         go 2D→1D for the back-transform). One XLA all-to-all via the
         canonical tile order."""
-        A = self.materialize()
-        tiles = bc_to_tiles(A.data)[: A.mt, : A.nt]
-        return dataclasses.replace(
-            A, data=_relayout(tiles, grid), grid=grid)
+        with _relayout_span("matrix.redistribute", self.data):
+            A = self.materialize()
+            tiles = bc_to_tiles(A.data)[: A.mt, : A.nt]
+            return dataclasses.replace(
+                A, data=_relayout(tiles, grid), grid=grid)
 
     def retile(self, new_nb: int) -> "BaseTiledMatrix":
         """Change the tile size to a divisor of ``nb`` (the two-stage

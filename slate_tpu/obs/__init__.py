@@ -47,6 +47,8 @@ import json
 import os
 import time as _time
 
+from ..runtime import sync
+
 from . import (correlation, costmodel, export, flight, flops, hbm, metrics,
                overlap, roofline, timeline, timing, tracing)
 from .correlation import new_id as new_request_id
@@ -56,7 +58,8 @@ from .metrics import counter_value
 from .report import enrich_span
 from .timing import (roundtrip_latency, timed_regen_median,
                      timed_scalar_median)
-from .tracing import device_trace, instant, record_span, span
+from .tracing import (captured_spans, instant, record_span, span,
+                      sync_read)
 
 # verb-named metric entry points
 count = metrics.inc
@@ -112,6 +115,9 @@ def reset() -> None:
     timeline.reset()
     flight.reset()
     correlation.reset()
+    with _compile_lock:
+        _compile.clear()
+        _compile_by_fun.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -266,15 +272,41 @@ class link_window:
 # jit retrace / compile accounting (jax.monitoring listeners)
 # ---------------------------------------------------------------------------
 
+_COMPILE_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_compile_lock = sync.Lock(name="obs.compile_seconds")
+_compile: dict[str, list] = {}          # kind -> [seconds, count]
+_compile_by_fun: dict[str, float] = {}  # program -> trace + lower seconds
+_trace_depth: dict[int, int] = {}       # thread -> open jaxpr traces
 _jax_hooks_installed = False
 
 
+def compile_seconds() -> dict:
+    """What this process has spent getting programs ready, always
+    counted: ``{"seconds": {kind: s}, "counts": {kind: n}, "top":
+    [[program, trace + lower seconds], ...]}`` for the kinds ``trace``
+    (Python → jaxpr; only the outermost trace of a nest, so the sum is
+    wall time), ``lower`` (jaxpr → MLIR, Mosaic kernels included),
+    ``backend_compile`` (XLA, or the persistent cache's load) and
+    ``cache_retrieval``."""
+    with _compile_lock:
+        top = sorted(_compile_by_fun.items(), key=lambda kv: -kv[1])[:8]
+        return {"seconds": {k: v[0] for k, v in _compile.items()},
+                "counts": {k: v[1] for k, v in _compile.items()},
+                "top": [list(kv) for kv in top]}
+
+
 def install_jax_hooks() -> bool:
-    """Register ``jax.monitoring`` listeners that count compile/trace
-    events into ``jax.events{event=…}`` (+ duration histograms).
-    Idempotent; listeners check :func:`metrics_enabled` so disabling
-    metrics silences them without unregistering (jax only offers a
-    global clear)."""
+    """Register the ``jax.monitoring`` listeners (at import): compile
+    seconds by kind are always kept (:func:`compile_seconds`); the
+    ``jax.events{event=…}`` counters (+ duration histograms) only
+    while metrics are on. Idempotent (jax only offers a global
+    clear)."""
     global _jax_hooks_installed
     if _jax_hooks_installed:
         return True
@@ -285,18 +317,49 @@ def install_jax_hooks() -> bool:
             if metrics.enabled():
                 metrics.inc("jax.events", event=event)
 
-        def _on_duration(event, duration, **kw):
+        def _on_scalar(event, value, **kw):
+            # jax records a trace's start time as it opens: inner
+            # jitted functions are traced inside the outer one's span
+            if event == _TRACE:
+                tid = sync.get_ident()
+                _trace_depth[tid] = _trace_depth.get(tid, 0) + 1
+
+        def _on_duration(event, duration, fun_name="", **kw):
+            kind = _COMPILE_KINDS.get(event)
+            if kind is not None:
+                _compile_second(kind, duration, str(fun_name))
             if metrics.enabled():
                 metrics.inc("jax.events", event=event)
                 metrics.observe("jax.event_duration_s", duration,
                                 event=event)
 
         _mon.register_event_listener(_on_event)
+        _mon.register_scalar_listener(_on_scalar)
         _mon.register_event_duration_secs_listener(_on_duration)
         _jax_hooks_installed = True
         return True
     except Exception:  # noqa: BLE001 — observability must never crash
         return False
+
+
+def _compile_second(kind: str, duration: float, fun: str) -> None:
+    if kind == "trace":
+        tid = sync.get_ident()
+        depth = _trace_depth.get(tid, 1) - 1
+        if depth > 0:
+            _trace_depth[tid] = depth
+            return              # inside an enclosing trace's seconds
+        _trace_depth.pop(tid, None)
+    with _compile_lock:
+        rec = _compile.setdefault(kind, [0.0, 0])
+        rec[0] += duration
+        rec[1] += 1
+        if kind in ("trace", "lower"):
+            fun = fun[4:-1] if fun.startswith("jit(") else fun
+            _compile_by_fun[fun] = _compile_by_fun.get(fun, 0.0) + duration
+    if kind != "trace" and tracing.capturing():
+        # a recompile inside a traced solve shows in its span tree
+        tracing.instant("compile", kind=kind, seconds=duration, fun=fun)
 
 
 def jit_event_total() -> float:
@@ -343,4 +406,5 @@ def _dump_to(path: str) -> None:
         pass
 
 
+install_jax_hooks()
 _init_from_env()

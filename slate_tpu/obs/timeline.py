@@ -11,12 +11,9 @@ against.
 
 This module captures **device-resolved, step-indexed events**:
 
-* on platforms with a working ``jax.profiler`` the coarse envelope
-  can come from a profiler session (:func:`profiler_capture` wraps
-  :func:`tracing.device_trace` and ingests the dumped Chrome trace);
 * everywhere — including the forced multi-device CPU mesh CI runs on
   (``XLA_FLAGS=--xla_force_host_platform_device_count=8``) — the
-  primary source is **timed host-callback barriers**:
+  source is **timed host-callback barriers**:
   :func:`mark` plants a ``jax.debug.callback`` inside the SPMD step
   body whose operands are (step, device-ordinal, a scalar probe of
   the phase's input/output), so the callback cannot fire before that
@@ -269,108 +266,6 @@ class host_phase:
         if self._track is not None:
             self._emit("e")
         return False
-
-
-# ---------------------------------------------------------------------------
-# jax.profiler ingestion (device-resolved source where the platform
-# has one; the CPU mesh rides the callback barriers above)
-# ---------------------------------------------------------------------------
-
-def profiler_capture(logdir: str):
-    """Wrap a region in a ``jax.profiler`` session AND ingest the
-    dumped Chrome trace into the event buffer afterwards (tracks named
-    like devices become ``dev`` ordinals; everything else lands on
-    host tracks).  Degrades to the warned no-op of
-    :func:`tracing.device_trace` where the profiler is missing."""
-    return _ProfilerCapture(logdir)
-
-
-class _ProfilerCapture:
-    __slots__ = ("logdir", "_inner")
-
-    def __init__(self, logdir: str):
-        self.logdir = logdir
-        from . import tracing as _tracing
-        self._inner = _tracing.device_trace(logdir)
-
-    def __enter__(self):
-        self._inner.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        out = self._inner.__exit__(*exc)
-        try:
-            n = ingest_profiler_dir(self.logdir)
-            if n:
-                _metrics.inc("timeline.profiler_events", float(n))
-        except Exception:  # noqa: BLE001 — ingestion is best-effort
-            pass
-        return out
-
-
-def ingest_profiler_dir(logdir: str) -> int:
-    """Parse ``<logdir>/plugins/profile/*/ *.trace.json(.gz)`` dumps
-    (Chrome trace format) into the event buffer.  Returns the number
-    of events ingested (0 when no dump exists — e.g. the profiler was
-    a no-op on this platform)."""
-    import glob
-    import gzip
-    count = 0
-    pats = (os.path.join(logdir, "plugins", "profile", "*", "*.trace.json.gz"),
-            os.path.join(logdir, "plugins", "profile", "*", "*.trace.json"))
-    paths = [p for pat in pats for p in glob.glob(pat)]
-    for path in paths:
-        opener = gzip.open if path.endswith(".gz") else open
-        try:
-            with opener(path, "rt") as f:
-                doc = json.load(f)
-        except Exception:  # noqa: BLE001
-            continue
-        count += _ingest_chrome_events(doc.get("traceEvents") or [])
-    return count
-
-
-def _ingest_chrome_events(evs: list[dict]) -> int:
-    """Map profiler complete events onto the raw-event schema: device
-    tracks become integer ``dev`` ordinals (matched by pid/tid name
-    metadata containing 'device'/'TPU'), others become host tracks.
-    Steps are unknown to the profiler; events land step=-1 and the
-    analyzer treats them as envelope-only."""
-    names: dict[tuple, str] = {}
-    for ev in evs:
-        if ev.get("ph") == "M" and ev.get("name") in ("process_name",
-                                                      "thread_name"):
-            names[(ev.get("pid"), ev.get("tid"), ev["name"])] = (
-                (ev.get("args") or {}).get("name", ""))
-    n = 0
-    base = time.perf_counter()
-    with _lock:
-        for ev in evs:
-            if ev.get("ph") != "X":
-                continue
-            pid, tid = ev.get("pid"), ev.get("tid")
-            label = (names.get((pid, tid, "thread_name"), "")
-                     or names.get((pid, None, "process_name"), ""))
-            low = label.lower()
-            dev: int | str
-            if "device" in low or "tpu" in low or "gpu" in low:
-                dev = tid if isinstance(tid, int) else 0
-            else:
-                dev = f"host:{label or tid}"
-            t0 = base + float(ev.get("ts", 0.0)) / 1e6
-            dur = float(ev.get("dur", 0.0)) / 1e6
-            kind = (KIND_COLLECTIVE
-                    if any(s in ev.get("name", "").lower()
-                           for s in ("all-gather", "all-reduce",
-                                     "collective", "permute",
-                                     "reduce-scatter", "send", "recv"))
-                    else KIND_COMPUTE)
-            common = {"dev": dev, "step": -1, "phase": ev.get("name", "?"),
-                      "kind": kind, "routine": "profiler"}
-            _events.append({"t": t0, "edge": "b", **common})
-            _events.append({"t": t0 + dur, "edge": "e", **common})
-            n += 2
-    return n
 
 
 # ---------------------------------------------------------------------------

@@ -18,8 +18,10 @@ Extensions over the old stub:
 * :func:`finish` RESETS the session clock — a second trace session
   starts at t=0 instead of inheriting the first session's offset
   (the old stub's ``_t0`` bug);
-* :func:`device_trace` degrades to a warned no-op when
-  ``jax.profiler`` is unavailable on the platform.
+* every span knows its parent and its solve, and a solve that runs
+  inside a ``jax.profiler`` session puts its tree on the profiler's
+  host plane and into :func:`captured_spans` (docs/observability.md
+  "Under ``jax.profiler``"). No switch: capture follows the profiler.
 
 slateflight additions: every span exit / instant also lands in the
 always-on flight-recorder ring (:mod:`slate_tpu.obs.flight`) so a
@@ -35,9 +37,13 @@ manager — no allocation, no lock, a single combined boolean test.
 
 from __future__ import annotations
 
+import collections
+import contextvars
+import itertools
 import json
 import time
-import warnings
+
+import jax
 
 from . import correlation as _correlation
 from . import flight as _flight
@@ -47,7 +53,27 @@ from ..runtime import sync
 _enabled = False
 _events: list[dict] = []
 _lock = sync.Lock(name="obs.tracing.events")
-_t0 = time.perf_counter()
+# ONE clock: every record is ``time.perf_counter_ns``; the Chrome
+# buffer shows it relative to the session start ``_t0_ns`` and the
+# flight ring as wall time through one anchor sampled at import
+_t0_ns = time.perf_counter_ns()
+_WALL0 = time.time() - time.perf_counter_ns() * 1e-9
+
+# the innermost open span of this thread / task (a fresh thread starts
+# with none, so every thread grows its own tree)
+_CUR: contextvars.ContextVar = contextvars.ContextVar(
+    "slate_tpu_span", default=None)
+_ids = itertools.count(1)
+_solves = itertools.count(1)
+_profiling = getattr(jax.profiler.TraceAnnotation, "is_enabled",
+                     lambda: False)
+ANNOTATION_PREFIX = "slate."    # names on the profiler's host plane
+
+# spans kept while a ``jax.profiler`` session is on: one list per
+# finished root span ("solve"), the oldest solve dropped whole
+CAPTURE_CAP = 65_536
+_captured: collections.deque = collections.deque()
+_captured_n = 0
 
 
 def on() -> None:
@@ -79,66 +105,14 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
-class _Span:
-    """RAII span (reference trace::Block): buffers a complete event
-    when tracing is on and feeds the metrics span aggregate when
-    metrics are on."""
-
-    __slots__ = ("name", "labels", "_start")
-
-    def __init__(self, name: str, labels: dict):
-        self.name = name
-        self.labels = labels
-        self._start = 0.0
-
-    def __enter__(self):
-        self._start = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        end = time.perf_counter()
-        dur = end - self._start
-        rid = _correlation.current()
-        if _enabled:
-            ev = {"name": self.name, "ph": "X",
-                  "ts": (self._start - _t0) * 1e6,
-                  "dur": dur * 1e6, "pid": 0,
-                  "tid": sync.get_ident() % 1_000_000}
-            args = dict(self.labels) if self.labels else {}
-            if rid:
-                args["rid"] = rid
-            if args:
-                ev["args"] = args
-            with _lock:
-                _events.append(ev)
-        _metrics.record_span_stat(self.name, dur, self.labels)
-        if _flight.enabled():
-            _flight.record("span", self.name, time.time() - dur, dur,
-                           self.labels or None, rid)
-        return False
-
-
-def span(name: str, **labels):
-    """Span context manager. ``labels`` become Chrome ``args`` and the
-    metrics aggregation key; give ``routine=``/dims (``n=``, ``m=``,
-    ``k=``, ``nb=``…) to get achieved-GFLOP/s in ``obs.dump()``."""
-    if not (_enabled or _metrics.enabled() or _flight.enabled()):
-        return _NOOP
-    return _Span(name, labels)
-
-
-def record_span(name: str, seconds: float, **labels) -> None:
-    """Log an externally-timed region (duration measured by the
-    caller — e.g. the bench's median-of-iters with round-trip
-    subtraction) as a span ending now."""
-    if not (_enabled or _metrics.enabled() or _flight.enabled()):
-        return
+def _finish(name, start_ns, end_ns, labels, span=None) -> None:
+    """One finished span into every sink that is on; ``span`` is the
+    ``_Span`` of a tree (None for an externally timed region)."""
+    dur = (end_ns - start_ns) * 1e-9
     rid = _correlation.current()
     if _enabled:
-        now = time.perf_counter()
-        ev = {"name": name, "ph": "X",
-              "ts": (now - seconds - _t0) * 1e6,
-              "dur": seconds * 1e6, "pid": 0,
+        ev = {"name": name, "ph": "X", "ts": (start_ns - _t0_ns) / 1e3,
+              "dur": dur * 1e6, "pid": 0,
               "tid": sync.get_ident() % 1_000_000}
         args = dict(labels) if labels else {}
         if rid:
@@ -147,27 +121,141 @@ def record_span(name: str, seconds: float, **labels) -> None:
             ev["args"] = args
         with _lock:
             _events.append(ev)
-    _metrics.record_span_stat(name, seconds, labels)
+    _metrics.record_span_stat(name, dur, labels)
     if _flight.enabled():
-        _flight.record("span", name, time.time() - seconds, seconds,
+        _flight.record("span", name, _WALL0 + start_ns * 1e-9, dur,
                        labels or None, rid)
+    if span is not None and span._bag is not None:
+        span._bag.append(_kept(name, start_ns, end_ns, span.id,
+                               span.parent, span.solve, labels))
+
+
+def _kept(name, start_ns, end_ns, sid, parent, solve, labels) -> dict:
+    return {"name": name, "start_ns": start_ns, "end_ns": end_ns,
+            "id": sid, "parent": parent, "solve": solve,
+            "labels": dict(labels)}
+
+
+class _Span:
+    """RAII span (reference trace::Block). Knows its parent (the
+    enclosing span of the same thread) and its solve (the root's
+    sequence number, or the bound correlation rid). A root that opens
+    inside a ``jax.profiler`` session puts its whole tree on the
+    profiler's host plane as ``TraceAnnotation``s and into
+    :func:`captured_spans`."""
+
+    __slots__ = ("name", "labels", "id", "parent", "solve", "_bag",
+                 "_ann", "_up", "_start")
+
+    def __init__(self, name: str, labels: dict):
+        self.name = name
+        self.labels = labels
+
+    def __enter__(self):
+        up = self._up = _CUR.get()
+        self.id = next(_ids)
+        if up is None:
+            self.parent = 0
+            self.solve = _correlation.current() or next(_solves)
+            self._bag = [] if _profiling() else None
+        else:
+            self.parent, self.solve, self._bag = up.id, up.solve, up._bag
+        self._ann = None
+        if self._bag is not None:
+            self._ann = jax.profiler.TraceAnnotation(
+                self.name if self.name.startswith(ANNOTATION_PREFIX)
+                else ANNOTATION_PREFIX + self.name,
+                id=self.id, parent=self.parent, solve=self.solve)
+            self._ann.__enter__()
+        _CUR.set(self)
+        self._start = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        end = time.perf_counter_ns()
+        _CUR.set(self._up)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _finish(self.name, self._start, end, self.labels, self)
+        if self._bag is not None and self.parent == 0:
+            _keep(self._bag)
+        return False
+
+
+def _keep(bag: list) -> None:
+    global _captured_n
+    with _lock:
+        _captured.append(bag)
+        _captured_n += len(bag)
+        while _captured_n > CAPTURE_CAP and len(_captured) > 1:
+            _captured_n -= len(_captured.popleft())
+
+
+def captured_spans() -> list[dict]:
+    """The span trees of the solves that ran inside a ``jax.profiler``
+    session, oldest first: ``{"name", "start_ns", "end_ns"
+    (``perf_counter_ns``), "id", "parent" (0: a root), "solve",
+    "labels"}``. Empty when no session was on."""
+    with _lock:
+        return [dict(s) for bag in _captured for s in bag]
+
+
+def capturing() -> bool:
+    """Inside a span tree that is being captured?"""
+    up = _CUR.get()
+    return up is not None and up._bag is not None
+
+
+def span(name: str, **labels):
+    """Span context manager. ``labels`` become Chrome ``args`` and the
+    metrics aggregation key; give ``routine=``/dims (``n=``, ``m=``,
+    ``k=``, ``nb=``…) to get achieved-GFLOP/s in ``obs.dump()``."""
+    if not (_enabled or _metrics.enabled() or _flight.enabled()
+            or _profiling()):
+        return _NOOP
+    return _Span(name, labels)
+
+
+def sync_read(name: str, read, x):
+    """``read(x)``, a blocking device→host read (``int(info)``,
+    ``np.asarray(order)``), in a span labelled ``sync=1`` and counted
+    as ``host.sync``."""
+    with span(name, sync=1):
+        _metrics.inc("host.sync", site=name)
+        return read(x)
+
+
+def record_span(name: str, seconds: float, **labels) -> None:
+    """Log an externally-timed region (duration measured by the
+    caller — e.g. the bench's median-of-iters with round-trip
+    subtraction) as a span ending now."""
+    if not (_enabled or _metrics.enabled() or _flight.enabled()):
+        return
+    now = time.perf_counter_ns()
+    _finish(name, now - int(seconds * 1e9), now, labels)
 
 
 def instant(name: str, **labels) -> None:
     """Instant event in the timeline (Trace::comment analog) —
     demotions, injected faults, timeouts.  Always lands in the flight
-    ring (when the recorder is on), even with tracing unarmed."""
+    ring (when the recorder is on), even with tracing unarmed, and in
+    the captured tree of the solve it happened in."""
     fl = _flight.enabled()
-    if not (_enabled or fl):
+    up = _CUR.get()
+    bag = up._bag if up is not None else None
+    if not (_enabled or fl or bag is not None):
         return
+    now = time.perf_counter_ns()
     rid = _correlation.current()
+    if bag is not None:
+        bag.append(_kept(name, now, now, next(_ids), up.id, up.solve,
+                         labels))
     if fl:
-        _flight.record("instant", name, time.time(),
+        _flight.record("instant", name, _WALL0 + now * 1e-9,
                        labels=labels or None, rid=rid)
     if not _enabled:
         return
-    ev = {"name": name, "ph": "i", "s": "g",
-          "ts": (time.perf_counter() - _t0) * 1e6,
+    ev = {"name": name, "ph": "i", "s": "g", "ts": (now - _t0_ns) / 1e3,
           "pid": 0, "tid": sync.get_ident() % 1_000_000}
     args = dict(labels) if labels else {}
     if rid:
@@ -194,65 +282,30 @@ def events() -> list[dict]:
         return [dict(e) for e in _events]
 
 
-def device_trace(logdir: str):
-    """Wrap a region in a ``jax.profiler`` session (device timeline —
-    the analog of the reference's per-GPU trace rows). A warned no-op
-    when the profiler is unavailable on the platform."""
-    return _DeviceTrace(logdir)
-
-
-class _DeviceTrace:
-    __slots__ = ("logdir", "_active")
-
-    def __init__(self, logdir: str):
-        self.logdir = logdir
-        self._active = False
-
-    def __enter__(self):
-        try:
-            import jax
-            prof = getattr(jax, "profiler", None)
-            if prof is None:
-                raise AttributeError("jax.profiler unavailable")
-            prof.start_trace(self.logdir)
-            self._active = True
-        except Exception as e:  # noqa: BLE001 — degrade, don't crash
-            warnings.warn(
-                f"obs.device_trace: jax.profiler unavailable on this "
-                f"platform ({type(e).__name__}: {e}); device timeline "
-                "disabled for this region", RuntimeWarning,
-                stacklevel=2)
-        return self
-
-    def __exit__(self, *exc):
-        if self._active:
-            import jax
-            jax.profiler.stop_trace()
-            self._active = False
-        return False
-
-
 def finish(path: str = "trace.json") -> str | None:
     """Write buffered events as Chrome trace JSON and START A FRESH
     SESSION: the buffer is cleared and the session clock reset, so a
     second ``on() … finish()`` cycle gets timestamps from t=0 (the
     old stub kept the first session's ``_t0``, skewing every later
     session)."""
-    global _t0
+    global _t0_ns
     with _lock:
         if not _events:
-            _t0 = time.perf_counter()
+            _t0_ns = time.perf_counter_ns()
             return None
         with open(path, "w") as f:
             json.dump({"traceEvents": _events}, f)
         _events.clear()
-        _t0 = time.perf_counter()
+        _t0_ns = time.perf_counter_ns()
     return path
 
 
 def reset() -> None:
-    """Drop buffered events and restart the session clock (tests)."""
-    global _t0
+    """Drop buffered events and captured spans and restart the session
+    clock (tests)."""
+    global _t0_ns, _captured_n
     with _lock:
         _events.clear()
-        _t0 = time.perf_counter()
+        _captured.clear()
+        _captured_n = 0
+        _t0_ns = time.perf_counter_ns()

@@ -533,27 +533,21 @@ def trsm(side: Side, alpha, A, B: Matrix, opts=None):
     trsmA/trsmB right-side bodies — with collectives for listBcast;
     no transpose materializes, src/work/work_trsm.cc).
     """
-    if side == Side.Right:
-        # X·op(A) = alpha·B — native column substitution
+    with trace.block("trsm"):
         Am = A.materialize()  # resolves op into storage, flips uplo
         B = B.materialize()   # resolve any lazy op on B too
-        slate_error_if(Am.n != B.n, "trsm dims")
-        _check_compat(Am, B)
         lower = Am.uplo == Uplo.Lower
         unit = Am.diag == Diag.Unit
-        with trace.block("trsm"):
-            return _trsm_right_jit(jnp.asarray(alpha, B.dtype), Am, B,
-                                   lower, unit)
-
-    Am = A.materialize()  # resolves op into storage, flips uplo
-    B = B.materialize()   # resolve any lazy op on B too
-    slate_error_if(Am.m != B.m, "trsm dims")
-    _check_compat(Am, B)
-    lower = Am.uplo == Uplo.Lower
-    unit = Am.diag == Diag.Unit
-    with trace.block("trsm"):
-        return _trsm_left_jit(jnp.asarray(alpha, B.dtype), Am, B,
-                              lower, unit)
+        if side == Side.Right:
+            # X·op(A) = alpha·B — native column substitution
+            slate_error_if(Am.n != B.n, "trsm dims")
+            solve = _trsm_right_jit
+        else:
+            slate_error_if(Am.m != B.m, "trsm dims")
+            solve = _trsm_left_jit
+        _check_compat(Am, B)
+        with trace.block("trsm.launch"):
+            return solve(jnp.asarray(alpha, B.dtype), Am, B, lower, unit)
 
 
 @partial(cached_jit, static_argnames=("lower", "unit"))
@@ -573,28 +567,31 @@ def _trsm_left_jit(alpha, A, B, lower, unit):
 
         def step(t, x):
             k = t if lower else mt - 1 - t
-            akk = lax.dynamic_slice(
-                a, (k // p, k // q, 0, 0), (1, 1, nb, nb))[0, 0]
-            akk = comm.bcast_from_owner(akk, k % p, k % q)
-            akk = tile_diag_pad_identity(akk, k, A.m, nb)
-            tri = jnp.tril(akk) if lower else jnp.triu(akk)
-            if unit:
-                tri = tri - jnp.diag(jnp.diag(tri)) + jnp.eye(nb, dtype=tri.dtype)
-            # owner row solves its slots of block-row k
-            xrow = lax.dynamic_index_in_dim(x, k // p, axis=0, keepdims=False)
-            solved = lax.linalg.triangular_solve(
-                jnp.broadcast_to(tri, (ntl, nb, nb)), xrow,
-                left_side=True, lower=lower, unit_diagonal=unit)
-            xrow = jnp.where(r == k % p, solved, xrow)
-            x = lax.dynamic_update_index_in_dim(x, xrow, k // p, axis=0)
-            xrow_b = comm.bcast_from_row(xrow, k % p)    # [ntl, nb, nb]
-            # trailing update: B(i,:) -= A(i,k) · X(k,:) for remaining i
-            acol = lax.dynamic_index_in_dim(a, k // q, axis=1, keepdims=False)
-            acol = comm.bcast_from_col(acol, k % q)      # [mtl, nb, nb]
-            rem = (gi > k) if lower else (gi < k)
-            acol = jnp.where(rem[:, None, None], acol, jnp.zeros_like(acol))
-            upd = jnp.einsum("aik,bkj->abij", acol, xrow_b, **pk6)
-            return x - upd
+            with jax.named_scope("diag_solve"):
+                akk = lax.dynamic_slice(
+                    a, (k // p, k // q, 0, 0), (1, 1, nb, nb))[0, 0]
+                akk = comm.bcast_from_owner(akk, k % p, k % q)
+                akk = tile_diag_pad_identity(akk, k, A.m, nb)
+                tri = jnp.tril(akk) if lower else jnp.triu(akk)
+                if unit:
+                    tri = tri - jnp.diag(jnp.diag(tri)) + jnp.eye(nb, dtype=tri.dtype)
+                # owner row solves its slots of block-row k
+                xrow = lax.dynamic_index_in_dim(x, k // p, axis=0, keepdims=False)
+                solved = lax.linalg.triangular_solve(
+                    jnp.broadcast_to(tri, (ntl, nb, nb)), xrow,
+                    left_side=True, lower=lower, unit_diagonal=unit)
+                xrow = jnp.where(r == k % p, solved, xrow)
+                x = lax.dynamic_update_index_in_dim(x, xrow, k // p, axis=0)
+            with jax.named_scope("update"):
+                xrow_b = comm.bcast_from_row(xrow, k % p)    # [ntl, nb, nb]
+                # trailing update: B(i,:) -= A(i,k) · X(k,:) for remaining i
+                acol = lax.dynamic_index_in_dim(a, k // q, axis=1, keepdims=False)
+                acol = comm.bcast_from_col(acol, k % q)      # [mtl, nb, nb]
+                rem = (gi > k) if lower else (gi < k)
+                acol = jnp.where(rem[:, None, None], acol,
+                                 jnp.zeros_like(acol))
+                upd = jnp.einsum("aik,bkj->abij", acol, xrow_b, **pk6)
+                return x - upd
 
         x = lax.fori_loop(0, mt, step, x)
         return x[None, None]

@@ -11,9 +11,7 @@ and the reference-parity usage ``trace.on(); …; trace.finish(path)``
   feeds the per-phase metrics table when metrics are on;
 * :func:`finish` resets the session clock, so a second trace session
   starts at t=0 (the old in-module buffer kept the first session's
-  offset, skewing every later session's timestamps);
-* :func:`device_trace` is a warned no-op when ``jax.profiler`` is
-  unavailable on the platform instead of an ImportError mid-run.
+  offset, skewing every later session's timestamps).
 
 New code should import ``slate_tpu.obs`` directly.
 """
@@ -21,5 +19,5 @@ New code should import ``slate_tpu.obs`` directly.
 from __future__ import annotations
 
 from ..obs.tracing import (  # noqa: F401 — re-exported façade
-    block, comment, device_trace, finish, is_on, off, on,
+    block, comment, finish, is_on, off, on,
 )

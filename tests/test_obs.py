@@ -331,24 +331,10 @@ def test_env_activation_writes_both_exports(tmp_path):
     assert "gflops" in span                   # enriched at dump time
 
 
-# ---------------------------------------------------------------------------
-# degraded modes
-# ---------------------------------------------------------------------------
-
-def test_device_trace_warns_and_noops_without_profiler(tmp_path,
-                                                       monkeypatch):
-    import jax
-    monkeypatch.setattr(jax, "profiler", None, raising=False)
-    with pytest.warns(RuntimeWarning, match="jax.profiler unavailable"):
-        with obs.device_trace(str(tmp_path)):
-            pass                               # region still executes
-
-
 def test_utils_trace_shim_is_the_obs_layer():
     from slate_tpu.utils import trace
     assert trace.block is tracing.block
     assert trace.finish is tracing.finish
-    assert trace.device_trace is tracing.device_trace
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,393 @@
+"""The span record of PR 25: every span knows its parent and its solve,
+capture follows ``jax.profiler`` (no switch), the host's spans land on
+the profiler's host plane, and compile seconds are always counted.
+
+CPU only: what a chip run adds is in PERF.md section 6.
+"""
+
+import glob
+import os
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import slate_tpu as st
+from slate_tpu import obs
+from slate_tpu.obs import correlation, flight, metrics, tracing
+from tests.conftest import rand, spd
+
+
+@pytest.fixture(autouse=True)
+def _fresh_obs():
+    """Everything empty; the flight ring on (the default), tracing and
+    metrics as the session had them."""
+    was_metrics = obs.metrics_enabled()
+    obs.reset()
+    yield
+    if not was_metrics:
+        obs.metrics_off()
+    obs.reset()
+
+
+@pytest.fixture
+def profiler(tmp_path):
+    """A real ``jax.profiler`` session; ``stop()`` ends it and gives
+    the path of the ``.xplane.pb``."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    state = {"on": True}
+
+    def stop():
+        if state["on"]:
+            state["on"] = False
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+        return path
+
+    yield stop
+    if state["on"]:
+        jax.profiler.stop_trace()
+
+
+def _posv_operands(grid, n=256, nb=64):
+    A = st.HermitianMatrix.from_dense(spd(n, np.float32, seed=3), nb=nb,
+                                      grid=grid, uplo=st.Uplo.Lower)
+    B = st.Matrix.from_dense(rand(n, 4, np.float32, seed=4), nb=nb,
+                             grid=grid)
+    return A, B
+
+
+def _tree(spans):
+    by_id = {s["id"]: s for s in spans}
+
+    def path(s):
+        names = [s["name"]]
+        while s["parent"]:
+            s = by_id[s["parent"]]
+            names.append(s["name"])
+        return "/".join(reversed(names))
+
+    return [path(s) for s in sorted(spans, key=lambda s: s["start_ns"])]
+
+
+# ------------------------------------------------------------ the tree
+
+@pytest.mark.parametrize("grid_name", ["grid11", "grid22"])
+def test_posv_span_tree_has_parents_and_one_solve_id(grid_name, request,
+                                                     profiler):
+    grid = request.getfixturevalue(grid_name)
+    A, B = _posv_operands(grid)
+    jax.block_until_ready(st.posv(A, B))         # compile outside
+    obs.reset()
+    for _ in range(2):
+        jax.block_until_ready(st.posv(A, B))
+    profiler()
+    spans = obs.captured_spans()
+    roots = [s for s in spans if s["parent"] == 0]
+    assert [r["name"] for r in roots] == ["slate.posv", "slate.posv"]
+    assert roots[0]["solve"] != roots[1]["solve"]
+    assert roots[0]["labels"]["grid"] == f"{grid.p}x{grid.q}"
+    assert roots[0]["labels"]["routine"] == "posv"
+    for root in roots:
+        mine = [s for s in spans if s["solve"] == root["solve"]]
+        paths = _tree(mine)
+        assert paths[0] == "slate.posv"
+        assert "slate.posv/potrf" in paths
+        assert "slate.posv/potrf/potrf.chunk" in paths
+        assert paths.count("slate.posv/potrs/trsm") == 2
+        assert paths.count("slate.posv/potrs/trsm/trsm.launch") == 2
+        # only the second solve, with conj_transpose(L), re-lays storage
+        assert paths.count(
+            "slate.posv/potrs/trsm/matrix.materialize") == 1
+        for leaf in ("to_tiles", "transpose", "device_put"):
+            assert ("slate.posv/potrs/trsm/matrix.materialize/"
+                    f"materialize.{leaf}") in paths
+        # children lie inside their parents, on one clock
+        by_id = {s["id"]: s for s in mine}
+        for s in mine:
+            if s["parent"]:
+                up = by_id[s["parent"]]
+                assert up["start_ns"] <= s["start_ns"]
+                assert s["end_ns"] <= up["end_ns"]
+    chunks = [s for s in spans if s["name"] == "potrf.chunk"
+              and s["solve"] == roots[0]["solve"]]
+    assert len(chunks) == (1 if grid.size == 1 else 2)
+    (mat,) = [s for s in spans if s["name"] == "matrix.materialize"
+              and s["solve"] == roots[0]["solve"]]
+    assert mat["labels"]["bytes"] == 256 * 256 * 4
+
+
+def test_capture_follows_the_profiler(grid11, profiler):
+    A, B = _posv_operands(grid11)
+    with obs.span("inside"):
+        pass
+    profiler()                                   # session over
+    assert [s["name"] for s in obs.captured_spans()] == ["inside"]
+    jax.block_until_ready(st.posv(A, B))
+    with obs.span("after"):
+        pass
+    # profiler off: nothing more is kept, the flight ring goes on
+    assert [s["name"] for s in obs.captured_spans()] == ["inside"]
+    names = [e["name"] for e in flight.events()]
+    assert "slate.posv" in names and "after" in names
+
+
+def test_captured_inside_a_session_and_on_the_host_plane(grid11, profiler):
+    A, B = _posv_operands(grid11)
+    with jax.profiler.TraceAnnotation("bench.solve"):
+        jax.block_until_ready(st.posv(A, B))
+    assert obs.captured_spans()
+    path = profiler()
+    spans = obs.captured_spans()
+    (root,) = [s for s in spans if s["parent"] == 0]
+    found, bench = {}, None
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == "bench.solve":
+                    bench = (ev.start_ns, ev.start_ns + ev.duration_ns)
+                elif ev.name.startswith(tracing.ANNOTATION_PREFIX):
+                    found.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.duration_ns, dict(ev.stats)))
+    assert bench is not None
+    for name in ("slate.posv", "slate.potrf", "slate.potrs", "slate.trsm",
+                 "slate.matrix.materialize"):
+        assert name in found, sorted(found)
+    (start, dur, stats), = found["slate.posv"]
+    assert stats["solve"] == root["solve"] and stats["parent"] == 0
+    assert stats["id"] == root["id"]
+    assert bench[0] <= start and start + dur <= bench[1]
+    # the annotation is the span: it opens just before and closes just
+    # after (milliseconds of slack for a loaded test machine)
+    assert 0 <= dur - (root["end_ns"] - root["start_ns"]) < 5e6
+    for start, dur, stats in found["slate.trsm"]:
+        assert stats["solve"] == root["solve"] and stats["parent"] > 0
+
+
+def test_cap_drops_whole_solves(monkeypatch):
+    monkeypatch.setattr(tracing, "_profiling", lambda: True)
+    monkeypatch.setattr(tracing, "CAPTURE_CAP", 10)
+    issued = []
+    for _ in range(4):
+        with obs.span("slate.fake") as root:
+            issued.append(root.solve)
+            for _ in range(3):
+                with obs.span("child"):
+                    pass
+    spans = obs.captured_spans()
+    # 4 spans a solve: two solves fit under the cap of 10, the third
+    # pushes the oldest out whole
+    assert sorted({s["solve"] for s in spans}) == issued[-2:]
+    for solve in issued[-2:]:
+        assert len([s for s in spans if s["solve"] == solve]) == 4
+    obs.reset()
+    assert obs.captured_spans() == []
+
+
+def test_a_bound_request_id_is_the_solve_id(monkeypatch):
+    monkeypatch.setattr(tracing, "_profiling", lambda: True)
+    with correlation.bind("r-77"):
+        with obs.span("slate.fake"):
+            with obs.span("child"):
+                obs.instant("compile", kind="lower")
+    spans = obs.captured_spans()
+    assert {s["solve"] for s in spans} == {"r-77"}
+    (mark,) = [s for s in spans if s["name"] == "compile"]
+    (child,) = [s for s in spans if s["name"] == "child"]
+    assert mark["parent"] == child["id"]
+    assert mark["start_ns"] == mark["end_ns"]
+
+
+def test_threads_grow_their_own_trees(monkeypatch):
+    from slate_tpu.runtime import sync
+    monkeypatch.setattr(tracing, "_profiling", lambda: True)
+
+    def work():
+        with obs.span("slate.worker"):
+            with obs.span("child"):
+                pass
+
+    with obs.span("slate.main"):
+        t = sync.Thread(target=work)
+        t.start()
+        t.join()
+    spans = obs.captured_spans()
+    roots = {s["name"]: s for s in spans if s["parent"] == 0}
+    assert set(roots) == {"slate.main", "slate.worker"}
+    (child,) = [s for s in spans if s["name"] == "child"]
+    assert child["parent"] == roots["slate.worker"]["id"]
+    assert child["solve"] == roots["slate.worker"]["solve"]
+
+
+# ---------------------------------------------------------- host syncs
+
+def test_host_sync_once_for_gesv_fast_path_never_for_posv(grid11,
+                                                          monkeypatch):
+    monkeypatch.setenv("SLATE_LU_FAST", "1")
+    monkeypatch.setattr(tracing, "_profiling", lambda: True)
+    obs.metrics_on()
+    n, nb = 384, 128
+    A = st.Matrix.from_dense(rand(n, n, np.float32, seed=9), nb=nb,
+                             grid=grid11)
+    B = st.Matrix.from_dense(rand(n, 2, np.float32, seed=10), nb=nb,
+                             grid=grid11)
+    X, LU, piv, info = st.gesv(A, B)
+    assert int(info) == 0
+    assert metrics.counter_total("host.sync") == 1
+    spans = obs.captured_spans()
+    syncs = [s for s in spans if s["labels"].get("sync") == 1]
+    assert [s["name"] for s in syncs] == ["gesv.order_to_ipiv"]
+    paths = _tree(spans)
+    assert "slate.gesv/getrf.chunk" in paths
+    assert "slate.gesv/getrs/getrs.apply_pivots" in paths
+    assert "slate.gesv/gesv.order_to_ipiv" in paths
+    x = np.asarray(X.to_dense())
+    a, b = np.asarray(A.to_dense()), np.asarray(B.to_dense())
+    assert np.linalg.norm(a @ x - b) < 1e-3 * np.linalg.norm(b)
+
+    obs.reset()
+    Ah, Bh = _posv_operands(grid11)
+    jax.block_until_ready(st.posv(Ah, Bh))
+    assert metrics.counter_total("host.sync") == 0
+    assert not [s for s in obs.captured_spans()
+                if s["labels"].get("sync") == 1]
+    assert metrics.counter_total("matrix.relayout_bytes") == 256 * 256 * 4
+
+
+def test_materialize_without_an_op_opens_no_span(grid11, monkeypatch):
+    monkeypatch.setattr(tracing, "_profiling", lambda: True)
+    A = st.Matrix.from_dense(rand(128, 128, np.float32), nb=64,
+                             grid=grid11)
+    assert A.materialize() is A
+    assert obs.captured_spans() == []
+    At = st.transpose(A).materialize()
+    assert np.array_equal(np.asarray(At.to_dense()),
+                          np.asarray(A.to_dense()).T)
+    assert [s["name"] for s in obs.captured_spans()
+            if s["parent"] == 0] == ["matrix.materialize"]
+
+
+def test_redistribute_span_holds_its_materialize(grid11, grid22,
+                                                 monkeypatch):
+    monkeypatch.setattr(tracing, "_profiling", lambda: True)
+    A = st.Matrix.from_dense(rand(256, 256, np.float32), nb=64,
+                             grid=grid22)
+    R = st.transpose(A).redistribute(grid11)
+    assert np.array_equal(np.asarray(R.to_dense()),
+                          np.asarray(A.to_dense()).T)
+    paths = _tree(obs.captured_spans())
+    assert paths[0] == "matrix.redistribute"
+    assert "matrix.redistribute/matrix.materialize" in paths
+
+
+# ------------------------------------------------------ compile seconds
+
+def test_compile_seconds_grow_on_a_first_call_only():
+    import jax.numpy as jnp
+
+    @jax.jit
+    def probe(x):
+        return jnp.tril(x @ x.T + 3.0).sum()
+
+    before = obs.compile_seconds()
+    probe(jnp.ones((33, 17))).block_until_ready()
+    first = obs.compile_seconds()
+    for kind in ("trace", "lower", "backend_compile"):
+        assert first["counts"][kind] > before["counts"].get(kind, 0)
+        assert first["seconds"][kind] > before["seconds"].get(kind, 0.0)
+    assert any(name == "probe" for name, _ in first["top"])
+    probe(jnp.ones((33, 17))).block_until_ready()
+    assert obs.compile_seconds() == first        # nothing on a second
+
+
+def test_nested_traces_count_once():
+    import jax.numpy as jnp
+
+    @jax.jit
+    def outer(x):
+        for _ in range(20):
+            x = jnp.tril(jnp.where(x > 0, x, -x)) + 1.0   # inner jits
+        return x
+
+    t0 = time.perf_counter()
+    outer(jnp.ones((9, 9))).block_until_ready()
+    wall = time.perf_counter() - t0
+    seen = obs.compile_seconds()
+    # the inner functions' traces are inside outer's: summed they
+    # would pass the wall
+    assert seen["seconds"]["trace"] <= wall
+    assert seen["counts"]["trace"] < 20
+
+
+def test_a_compile_inside_a_captured_solve_is_marked(monkeypatch):
+    import jax.numpy as jnp
+    monkeypatch.setattr(tracing, "_profiling", lambda: True)
+
+    @jax.jit
+    def probe(x):
+        return x * 5.0 - 1.0
+
+    x = jnp.ones((3, 5))
+    with obs.span("slate.fake"):
+        probe(x).block_until_ready()
+    marks = [s for s in obs.captured_spans() if s["name"] == "compile"]
+    kinds = {s["labels"]["kind"] for s in marks}
+    assert {"lower", "backend_compile"} <= kinds
+    assert all(s["labels"]["fun"] == "probe" for s in marks
+               if s["labels"]["kind"] == "lower")
+
+
+# ------------------------------------------------------------ one clock
+
+def test_flight_ring_and_chrome_export_share_one_clock():
+    obs.trace_on()
+    try:
+        wall_before = time.time()
+        with obs.span("a"):
+            time.sleep(0.01)
+        time.sleep(0.02)
+        with obs.span("b"):
+            pass
+        obs.instant("c")
+        wall_after = time.time()
+        chrome = {e["name"]: e for e in tracing.events()}
+        ring = {e["name"]: e for e in flight.events()}
+        for x, y in (("a", "b"), ("b", "c"), ("a", "c")):
+            d_chrome = (chrome[y]["ts"] - chrome[x]["ts"]) * 1e-6
+            d_ring = ring[y]["t"] - ring[x]["t"]
+            assert abs(d_chrome - d_ring) < 5e-6
+        # the ring's start + its duration is the Chrome event's end
+        assert abs(ring["a"]["dur_s"] * 1e6 - chrome["a"]["dur"]) < 1e-3
+        # and the ring is on the wall clock, through the one anchor
+        assert wall_before - 0.05 <= ring["a"]["t"] <= wall_after + 0.05
+    finally:
+        obs.trace_off()
+
+
+# --------------------------------------------------------- named scopes
+
+def test_named_scopes_mark_the_phases_of_the_cells_programs(grid22):
+    from slate_tpu.linalg import potrf as potrf_mod
+    from slate_tpu.ops import blas
+    import jax.numpy as jnp
+    A, B = _posv_operands(grid22)
+    text = jax.jit(potrf_mod._potrf_chunk_core,
+                   static_argnames=("k0", "klen", "win_hi", "tier")).lower(
+        A, jnp.zeros((), jnp.int32), k0=0, klen=2).as_text(debug_info=True)
+    for scope in ("panel", "panel_bcast", "trailing"):
+        assert f'"{scope}/' in text, scope
+    L = st.TriangularMatrix(data=A.data, m=A.m, n=A.n, nb=A.nb,
+                            grid=A.grid, uplo=st.Uplo.Lower,
+                            diag=st.Diag.NonUnit)
+    text = jax.jit(blas._trsm_left_jit._fn,
+                   static_argnames=("lower", "unit")).lower(
+        jnp.float32(1.0), L, B, lower=True, unit=False).as_text(
+        debug_info=True)
+    for scope in ("diag_solve", "update"):
+        assert f'"{scope}/' in text, scope
