@@ -26,6 +26,7 @@ SLATE mutates C in place; here ``C = gemm(alpha, A, B, beta, C)``.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from functools import partial
 
@@ -39,6 +40,7 @@ from ..grid import AXIS_P, AXIS_Q
 from ..matrix import (Matrix, BaseTiledMatrix, BandMatrix, cdiv,
                       bc_to_tiles, bc_from_tiles)
 from ..types import Op, Uplo, Side, Diag
+from .. import obs
 from ..errors import slate_error_if
 from ..internal import comm, masks
 from ..internal.masks import tile_diag_pad_identity
@@ -530,13 +532,32 @@ def trsm(side: Side, alpha, A, B: Matrix, opts=None):
     the owner row (Left) or owner column (Right), an X panel bcast
     along the other mesh axis, and a trailing SUMMA-style update
     (exactly the reference's trsm DAG — work::trsm for Left, the
-    trsmA/trsmB right-side bodies — with collectives for listBcast;
-    no transpose materializes, src/work/work_trsm.cc).
+    trsmA/trsmB right-side bodies — with collectives for listBcast,
+    src/work/work_trsm.cc).
+
+    ``Side.Left`` reads op(A) where it lies: a ``Trans``/``ConjTrans``
+    view goes to the same program as storage plus two static flags,
+    which solves left-looking (the reference's trsmA reduce shape), so
+    no transpose materializes. ``Side.Right`` with an op still
+    resolves it by ``A.materialize()`` (a re-laid copy of A) first.
     """
-    with trace.block("trsm"):
-        Am = A.materialize()  # resolves op into storage, flips uplo
+    op = {Op.NoTrans: "N", Op.Trans: "T", Op.ConjTrans: "C"}[A.op]
+    with trace.block("trsm", op=op):
         B = B.materialize()   # resolve any lazy op on B too
-        lower = Am.uplo == Uplo.Lower
+        flags = {}
+        if side == Side.Left and op != "N":
+            # in place: A's storage, its op as static flags. The stored
+            # triangle is the one op(A) does not show (materialize()
+            # flips Lower <-> Upper the same way; no uplo reads as Upper)
+            obs.count("trsm.in_place", 1, op=op)
+            Am = dataclasses.replace(A, op=Op.NoTrans)
+            lower = A.uplo != Uplo.Upper
+            # a real Aᴴ is Aᵀ: one program for both
+            flags = {"trans": True, "conj": op == "C" and jnp.issubdtype(
+                A.dtype, jnp.complexfloating)}
+        else:
+            Am = A.materialize()  # resolves op into storage, flips uplo
+            lower = Am.uplo == Uplo.Lower
         unit = Am.diag == Diag.Unit
         if side == Side.Right:
             # X·op(A) = alpha·B — native column substitution
@@ -547,17 +568,36 @@ def trsm(side: Side, alpha, A, B: Matrix, opts=None):
             solve = _trsm_left_jit
         _check_compat(Am, B)
         with trace.block("trsm.launch"):
-            return solve(jnp.asarray(alpha, B.dtype), Am, B, lower, unit)
+            return solve(jnp.asarray(alpha, B.dtype), Am, B, lower, unit,
+                         **flags)
 
 
-@partial(cached_jit, static_argnames=("lower", "unit"))
-def _trsm_left_jit(alpha, A, B, lower, unit):
+@partial(cached_jit, static_argnames=("lower", "unit", "trans", "conj"))
+def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
+    """op(A)·X = alpha·B on A's storage: ``lower`` names the stored
+    triangle, ``trans``/``conj`` the op. NoTrans is right-looking
+    (solve block-row k, subtract its outer product from the rows
+    left); an op is left-looking, X(k,:) = op(A(k,k))⁻¹·(α·B(k,:) −
+    Σ_i A(i,k)ᴴ·X(i,:)) over the rows i already solved: tile A(i,k)
+    and tile row X(i,:) share mesh row i % p, so each device contracts
+    its own slots of column k and one reduce down the mesh column
+    stands where the right-looking form broadcasts X(k,:)."""
     g = B.grid
     p, q, nb = g.p, g.q, B.nb
     mt = cdiv(A.m, nb)
     mtl, ntl = B.data.shape[2], B.data.shape[3]
     # policy (internal/precision.py): triangular solves always bf16_6x
     pk6 = trailing_dot_kwargs("bf16_6x", B.dtype)
+
+    def diag_tile(a, k):
+        akk = lax.dynamic_slice(
+            a, (k // p, k // q, 0, 0), (1, 1, nb, nb))[0, 0]
+        akk = comm.bcast_from_owner(akk, k % p, k % q)
+        akk = tile_diag_pad_identity(akk, k, A.m, nb)
+        tri = jnp.tril(akk) if lower else jnp.triu(akk)
+        if unit:
+            tri = tri - jnp.diag(jnp.diag(tri)) + jnp.eye(nb, dtype=tri.dtype)
+        return tri
 
     def body(a, x, alpha):
         a, x = _local(a), _local(x)
@@ -568,13 +608,7 @@ def _trsm_left_jit(alpha, A, B, lower, unit):
         def step(t, x):
             k = t if lower else mt - 1 - t
             with jax.named_scope("diag_solve"):
-                akk = lax.dynamic_slice(
-                    a, (k // p, k // q, 0, 0), (1, 1, nb, nb))[0, 0]
-                akk = comm.bcast_from_owner(akk, k % p, k % q)
-                akk = tile_diag_pad_identity(akk, k, A.m, nb)
-                tri = jnp.tril(akk) if lower else jnp.triu(akk)
-                if unit:
-                    tri = tri - jnp.diag(jnp.diag(tri)) + jnp.eye(nb, dtype=tri.dtype)
+                tri = diag_tile(a, k)
                 # owner row solves its slots of block-row k
                 xrow = lax.dynamic_index_in_dim(x, k // p, axis=0, keepdims=False)
                 solved = lax.linalg.triangular_solve(
@@ -593,7 +627,32 @@ def _trsm_left_jit(alpha, A, B, lower, unit):
                 upd = jnp.einsum("aik,bkj->abij", acol, xrow_b, **pk6)
                 return x - upd
 
-        x = lax.fori_loop(0, mt, step, x)
+        def step_op(t, x):
+            # op(A) is lower iff the stored triangle is upper: a stored
+            # lower triangle solves from the last block-row up
+            k = mt - 1 - t if lower else t
+            with jax.named_scope("update"):
+                # Σ_i A(i,k)ᴴ · X(i,:) over the rows already solved
+                acol = lax.dynamic_index_in_dim(a, k // q, axis=1, keepdims=False)
+                acol = comm.bcast_from_col(acol, k % q)      # [mtl, nb, nb]
+                done = (gi > k) if lower else (gi < k)
+                acol = jnp.where(done[:, None, None], acol,
+                                 jnp.zeros_like(acol))
+                if conj:
+                    acol = jnp.conj(acol)
+                acc = jnp.einsum("aki,abkj->bij", acol, x, **pk6)
+                acc = comm.psum_rows(acc)                    # [ntl, nb, nb]
+            with jax.named_scope("diag_solve"):
+                tri = diag_tile(a, k)
+                xrow = lax.dynamic_index_in_dim(x, k // p, axis=0, keepdims=False)
+                solved = lax.linalg.triangular_solve(
+                    jnp.broadcast_to(tri, (ntl, nb, nb)), xrow - acc,
+                    left_side=True, lower=lower, unit_diagonal=unit,
+                    transpose_a=True, conjugate_a=conj)
+                xrow = jnp.where(r == k % p, solved, xrow)
+                return lax.dynamic_update_index_in_dim(x, xrow, k // p, axis=0)
+
+        x = lax.fori_loop(0, mt, step_op if trans else step, x)
         return x[None, None]
 
     data = _shard(body, g.mesh, 2, 1)(A.data, B.data, alpha)

@@ -190,11 +190,11 @@ def test_gemm_ring_complex(grid24):
                                rtol=1e-12, atol=1e-11)
 
 
-def test_trsm_right_native_no_transpose(grid24, monkeypatch):
-    """The Right-side solve must run natively (reference trsmA/trsmB,
-    src/work/work_trsm.cc) — no transpose materializes (all-to-alls)."""
+@pytest.fixture
+def materialized_ops(monkeypatch):
+    """Class names of the operands with an op that ``materialize()``
+    was called on (each one a re-laid copy: all-to-alls)."""
     from slate_tpu.matrix import BaseTiledMatrix
-    from slate_tpu.types import Op
     calls = []
     orig = BaseTiledMatrix.materialize
 
@@ -204,13 +204,91 @@ def test_trsm_right_native_no_transpose(grid24, monkeypatch):
         return orig(self)
 
     monkeypatch.setattr(BaseTiledMatrix, "materialize", counting)
+    return calls
+
+
+def test_trsm_right_native_no_transpose(grid24, materialized_ops):
+    """The Right-side solve must run natively (reference trsmA/trsmB,
+    src/work/work_trsm.cc) — no transpose materializes (all-to-alls)."""
     n, m, nb = 24, 16, 8
     a = rand(n, n, np.float64, 23) + n * np.eye(n)
     A = st.TriangularMatrix.from_dense(a, nb=nb, grid=grid24,
                                        uplo=Uplo.Lower)
     B = st.Matrix.from_dense(rand(m, n, seed=24), nb=nb, grid=grid24)
     st.trsm(Side.Right, 1.0, A, B)
-    assert calls == [], calls
+    assert materialized_ops == [], materialized_ops
+
+
+@pytest.mark.parametrize("shape", ["1x1", "2x4", "4x2", "2x2"])
+@pytest.mark.parametrize("diag", [Diag.NonUnit, Diag.Unit])
+@pytest.mark.parametrize("op", ["t", "c"])
+@pytest.mark.parametrize("uplo", [Uplo.Lower, Uplo.Upper])
+def test_trsm_left_reads_op_in_place(uplo, op, diag, shape,
+                                     materialized_ops):
+    """``trsm(Side.Left, op(A), B)`` solves on A's storage: no operand
+    with an op is materialized (a re-laid copy of A, an all-to-all),
+    on a ragged n with a B narrower than a tile."""
+    import jax
+    p, q = map(int, shape.split("x"))
+    grid = st.Grid(p, q, devices=jax.devices()[:p * q])
+    dt = np.complex128 if op == "c" else np.float64
+    n, nrhs, nb = 19, 5, 8
+    unit = diag == Diag.Unit
+    a = rand(n, n, dt, 30) * 0.3 + (0 if unit else n * np.eye(n))
+    t = tri(a, uplo == Uplo.Lower, unit=unit)
+    opt = t.T if op == "t" else np.conj(t.T)
+    b = rand(n, nrhs, dt, 31)
+    A = st.TriangularMatrix.from_dense(a, nb=nb, grid=grid, uplo=uplo,
+                                       diag=diag)
+    B = st.Matrix.from_dense(b, nb=nb, grid=grid)
+    view = st.transpose(A) if op == "t" else st.conj_transpose(A)
+    X = st.trsm(Side.Left, 1.5, view, B)
+    assert materialized_ops == [], materialized_ops
+    np.testing.assert_allclose(opt @ np.asarray(X.to_dense()), 1.5 * b,
+                               rtol=1e-10, atol=1e-10)
+
+
+def test_trsm_left_op_of_a_general_matrix_reads_its_upper_triangle(grid24):
+    """A matrix with no uplo is read as upper, op or no op: for op(A)
+    that is the stored lower triangle (what materialize() gave)."""
+    n, nrhs, nb = 19, 5, 8
+    a = rand(n, n, np.float64, 32) + n * np.eye(n)
+    b = rand(n, nrhs, seed=33)
+    A = st.Matrix.from_dense(a, nb=nb, grid=grid24)
+    B = st.Matrix.from_dense(b, nb=nb, grid=grid24)
+    X = st.trsm(Side.Left, 1.0, st.transpose(A), B)
+    np.testing.assert_allclose(np.triu(a.T) @ np.asarray(X.to_dense()), b,
+                               rtol=1e-10, atol=1e-10)
+
+
+@pytest.mark.parametrize("trans", [False, True])
+def test_trsm_left_lowering_collectives(grid22, trans):
+    """The lowered 2x2 program: an op adds no all-gather and no
+    all-to-all (the re-layout materialize() paid), one reduce down the
+    mesh column stands for the row bcast, and the NoTrans program's
+    collectives are what they were."""
+    import jax
+    import jax.numpy as jnp
+    from slate_tpu.internal import comm
+    from slate_tpu.ops import blas
+    n, nb = 64, 8
+    A = st.TriangularMatrix.from_dense(
+        rand(n, n, np.float32, 34), nb=nb, grid=grid22, uplo=Uplo.Lower)
+    B = st.Matrix.from_dense(rand(n, 4, np.float32, 35), nb=nb,
+                             grid=grid22)
+    lowered = jax.jit(
+        blas._trsm_left_jit._fn,
+        static_argnames=("lower", "unit", "trans", "conj")).lower(
+        jnp.float32(1.0), A, B, lower=True, unit=False, trans=trans)
+    stats = comm.collective_footprint(lowered.compile())
+    assert "all-gather" not in stats and "all-to-all" not in stats
+    # a step moves the diagonal tile over both axes, column k over q,
+    # and over p the solved row (NoTrans) or the partial sums (op);
+    # XLA sends two of the four together: three all-reduces, as before
+    assert set(stats) == {"all-reduce"}
+    assert stats["all-reduce"]["count"] == 3
+    if not trans:
+        assert stats["all-reduce"]["bytes"] == 768.0
 
 
 def test_gbmm(grid24):

@@ -83,6 +83,7 @@ def test_posv_span_tree_has_parents_and_one_solve_id(grid_name, request,
     A, B = _posv_operands(grid)
     jax.block_until_ready(st.posv(A, B))         # compile outside
     obs.reset()
+    obs.metrics_on()
     for _ in range(2):
         jax.block_until_ready(st.posv(A, B))
     profiler()
@@ -100,12 +101,11 @@ def test_posv_span_tree_has_parents_and_one_solve_id(grid_name, request,
         assert "slate.posv/potrf/potrf.chunk" in paths
         assert paths.count("slate.posv/potrs/trsm") == 2
         assert paths.count("slate.posv/potrs/trsm/trsm.launch") == 2
-        # only the second solve, with conj_transpose(L), re-lays storage
-        assert paths.count(
-            "slate.posv/potrs/trsm/matrix.materialize") == 1
-        for leaf in ("to_tiles", "transpose", "device_put"):
-            assert ("slate.posv/potrs/trsm/matrix.materialize/"
-                    f"materialize.{leaf}") in paths
+        # the second solve reads conj_transpose(L) where it lies:
+        # nothing under trsm re-lays storage
+        assert not [x for x in paths if "matrix.materialize" in x]
+        trsms = [s for s in mine if s["name"] == "trsm"]
+        assert [s["labels"]["op"] for s in trsms] == ["N", "C"]
         # children lie inside their parents, on one clock
         by_id = {s["id"]: s for s in mine}
         for s in mine:
@@ -116,9 +116,8 @@ def test_posv_span_tree_has_parents_and_one_solve_id(grid_name, request,
     chunks = [s for s in spans if s["name"] == "potrf.chunk"
               and s["solve"] == roots[0]["solve"]]
     assert len(chunks) == (1 if grid.size == 1 else 2)
-    (mat,) = [s for s in spans if s["name"] == "matrix.materialize"
-              and s["solve"] == roots[0]["solve"]]
-    assert mat["labels"]["bytes"] == 256 * 256 * 4
+    assert metrics.counter_total("trsm.in_place") == len(roots)
+    assert metrics.counter_total("matrix.relayout_bytes") == 0
 
 
 def test_capture_follows_the_profiler(grid11, profiler):
@@ -141,9 +140,10 @@ def test_captured_inside_a_session_and_on_the_host_plane(grid11, profiler):
     with jax.profiler.TraceAnnotation("bench.solve"):
         jax.block_until_ready(st.posv(A, B))
     assert obs.captured_spans()
+    st.transpose(B).materialize()                # a re-layout of its own
     path = profiler()
     spans = obs.captured_spans()
-    (root,) = [s for s in spans if s["parent"] == 0]
+    (root,) = [s for s in spans if s["name"] == "slate.posv"]
     found, bench = {}, None
     for plane in jax.profiler.ProfileData.from_file(path).planes:
         if plane.name != "/host:CPU":
@@ -257,7 +257,8 @@ def test_host_sync_once_for_gesv_fast_path_never_for_posv(grid11,
     assert metrics.counter_total("host.sync") == 0
     assert not [s for s in obs.captured_spans()
                 if s["labels"].get("sync") == 1]
-    assert metrics.counter_total("matrix.relayout_bytes") == 256 * 256 * 4
+    assert metrics.counter_total("matrix.relayout_bytes") == 0
+    assert metrics.counter_total("trsm.in_place") == 1
 
 
 def test_materialize_without_an_op_opens_no_span(grid11, monkeypatch):
@@ -269,8 +270,13 @@ def test_materialize_without_an_op_opens_no_span(grid11, monkeypatch):
     At = st.transpose(A).materialize()
     assert np.array_equal(np.asarray(At.to_dense()),
                           np.asarray(A.to_dense()).T)
-    assert [s["name"] for s in obs.captured_spans()
-            if s["parent"] == 0] == ["matrix.materialize"]
+    (mat,) = [s for s in obs.captured_spans() if s["parent"] == 0]
+    assert mat["name"] == "matrix.materialize"
+    assert mat["labels"]["bytes"] == 128 * 128 * 4
+    leaves = [s["name"] for s in obs.captured_spans()
+              if s["parent"] == mat["id"]]
+    assert leaves == ["materialize.to_tiles", "materialize.transpose",
+                      "materialize.device_put"]
 
 
 def test_redistribute_span_holds_its_materialize(grid11, grid22,
@@ -385,9 +391,10 @@ def test_named_scopes_mark_the_phases_of_the_cells_programs(grid22):
     L = st.TriangularMatrix(data=A.data, m=A.m, n=A.n, nb=A.nb,
                             grid=A.grid, uplo=st.Uplo.Lower,
                             diag=st.Diag.NonUnit)
-    text = jax.jit(blas._trsm_left_jit._fn,
-                   static_argnames=("lower", "unit")).lower(
-        jnp.float32(1.0), L, B, lower=True, unit=False).as_text(
-        debug_info=True)
-    for scope in ("diag_solve", "update"):
-        assert f'"{scope}/' in text, scope
+    for trans in (False, True):
+        text = jax.jit(blas._trsm_left_jit._fn,
+                       static_argnames=("lower", "unit", "trans")).lower(
+            jnp.float32(1.0), L, B, lower=True, unit=False,
+            trans=trans).as_text(debug_info=True)
+        for scope in ("diag_solve", "update"):
+            assert f'"{scope}/' in text, (scope, trans)

@@ -156,6 +156,33 @@ def test_getrf_chunk_2x2_compiles_sharded(tpu_grid22):
     _assert_sharded_with_collectives(c, H * H * 4)
 
 
+# -- potrs' second solve: trsm on conj_transpose(L) where it lies ----------
+
+@pytest.mark.parametrize("shape", ["1x1", "2x2"])
+def test_trsm_left_op_in_place_compiles_with_no_relayout(topo, tpu_grid22,
+                                                         shape):
+    from slate_tpu.ops import blas
+    grid = (tpu_grid22 if shape == "2x2"
+            else slate.Grid(1, 1, devices=[topo.devices[0]]))
+    L = slate.TriangularMatrix(data=_tiles(grid), m=H, n=H, nb=NB,
+                               grid=grid, uplo=slate.Uplo.Lower)
+    b = jax.ShapeDtypeStruct(
+        (grid.p, grid.q, H // NB // grid.p, 1, NB, NB), F32,
+        sharding=grid.sharding())
+    B = slate.Matrix(data=b, m=H, n=8, nb=NB, grid=grid)
+    c = blas._trsm_left_jit.lower(jax.ShapeDtypeStruct((), F32), L, B,
+                                  True, False, trans=True).compile()
+    text = c.as_text()
+    assert "all-gather" not in text and "all-to-all" not in text
+    mem = c.memory_analysis()
+    assert abs(mem.argument_size_in_bytes
+               - (L.data.size + b.size) * 4 // grid.size) < 2 ** 20
+    if shape == "2x2":
+        # no copy of the factor: the left-looking step needs tiles only
+        assert "all-reduce" in text
+        assert mem.temp_size_in_bytes < 2 ** 24, mem.temp_size_in_bytes
+
+
 # -- one served executable ---------------------------------------------------
 
 def test_served_posv_bucket_compiles(one_chip):
