@@ -137,7 +137,10 @@ def tile_trsm_right_lower_t(l, b, unit: bool = False, conj: bool = False):
 
 # XLA's LuDecompositionBlock runs out of scoped vmem above roughly
 # 11k panel rows on a v5e; panels taller than this go through the
-# chunked tournament (CALU) path below.
+# chunked tournament (CALU) path below. Within 2.4 % of the cap a
+# [10000, 384] f32 panel (the first of n=10000, nb=384 through
+# slate.gesv) compiled and ran on a v5e, 35 ms of LuDecompositionBlock
+# a factorization for 10,000 columns (2026-09-27, jax 0.9.0, PR 27).
 LU_PANEL_MAX_ROWS = 10240
 
 

@@ -95,17 +95,28 @@ def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
     (use :func:`getrf_resume`).
     """
     from ..robust import faults as _faults
-    A = _faults.maybe_corrupt("getrf", A)
-    A = A.materialize()
-    Anorm = _norm_one(A, opts) if health else None
-    g = A.grid
-    kt = min(A.mt, A.nt)
-    lcm_pq = g.p * g.q // math.gcd(g.p, g.q)
-    from .. import tune
-    tier, depth = tune.driver_config("getrf", A.n, opts)
+    from ..robust import abft as _abft
     with trace.block("getrf", routine="getrf", m=A.m, n=A.n, nb=A.nb,
-                     precision=tier):
-        if g.size > 1 and kt >= 2 * lcm_pq:
+                     mt=A.mt, pad_rows=A.mt * A.nb - A.m) as top:
+        # what the host does before the first launch, with the device
+        # idle unless a caller queued work ahead
+        with trace.block("getrf.prepare"):
+            A = _faults.maybe_corrupt("getrf", A)
+            A = A.materialize()
+            g = A.grid
+            kt = min(A.mt, A.nt)
+            lcm_pq = g.p * g.q // math.gcd(g.p, g.q)
+            from .. import tune
+            tier, depth = tune.driver_config("getrf", A.n, opts)
+            chunked = g.size > 1 and kt >= 2 * lcm_pq
+            ck = None
+            if chunked:
+                from ..robust import ckpt as _ckpt
+                ck = _ckpt.plan("getrf", A, opts, checkpoint=checkpoint)
+            ab = _abft.monitor("getrf", A, opts)
+        top.label(precision=tier)
+        Anorm = _norm_one(A, opts) if health else None
+        if chunked:
             # chunked super-steps (same scheme as potrf): trailing
             # updates on a statically shrinking window; swaps still
             # span the full row (back-pivoting the stored L).
@@ -114,10 +125,7 @@ def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
             # body (panel k+1 gather in flight under step-k trailing
             # gemm) vs the strictly sequential one.
             S = superstep_chunk(kt, lcm_pq, opts)
-            from ..robust import ckpt as _ckpt
-            from ..robust import abft as _abft
-            ck = _ckpt.plan("getrf", A, opts, checkpoint=checkpoint)
-            ab = _abft.monitor("getrf", A, opts)
+            obs.count("getrf.path", 1, phase="spmd_chunk")
             data = A.data
             piv0 = (jnp.arange(kt, dtype=jnp.int32)[:, None] * A.nb
                     + jnp.arange(A.nb, dtype=jnp.int32)[None, :])
@@ -198,12 +206,12 @@ def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
             if ab is not None:
                 ab.note()
         else:
-            from ..robust import abft as _abft
-            ab = _abft.monitor("getrf", A, opts)
             if ab is not None:
                 ab.init(A.data)
             fm = (_fast_path_mode(A, "partial")
                   if (g.size == 1 and kt <= 64) else None)
+            obs.count("getrf.path", 1, phase=(
+                "one_program" if fm is None else "fast_path"))
             with _abft.armed_scope(ab is not None):
                 while True:
                     if fm is not None:
@@ -1658,7 +1666,8 @@ def getrs(LU: Matrix, piv, B: Matrix, trans: Op = Op.NoTrans, opts=None):
                          grid=LU.grid, uplo=Uplo.Upper, diag=Diag.NonUnit)
     with trace.block("getrs"):
         if trans == Op.NoTrans:
-            with trace.block("getrs.apply_pivots"):
+            with trace.block("getrs.apply_pivots",
+                             kind=_apply_pivots_kind(B, piv)):
                 Bp = _apply_pivots_matrix(B, piv, forward=True)
             Y = trsm(Side.Left, 1.0, L, Bp, opts)
             X = trsm(Side.Left, 1.0, U, Y, opts)
@@ -1666,7 +1675,8 @@ def getrs(LU: Matrix, piv, B: Matrix, trans: Op = Op.NoTrans, opts=None):
         opA = transpose if trans == Op.Trans else conj_transpose
         Y = trsm(Side.Left, 1.0, opA(U), B, opts)
         Z = trsm(Side.Left, 1.0, opA(L), Y, opts)
-        with trace.block("getrs.apply_pivots"):
+        with trace.block("getrs.apply_pivots",
+                         kind=_apply_pivots_kind(Z, piv)):
             return _apply_pivots_matrix(Z, piv, forward=False)
 
 
@@ -1704,6 +1714,7 @@ def _gesv(A, B, opts):
         # neither side runs an O(n) sequential swap simulation; the
         # LAPACK ipiv of the return contract is derived on host while
         # the device runs the solve
+        obs.count("getrf.path", 1, phase="fast_path")
         with trace.block("getrf.chunk", phase="fast_path", k0=0,
                          klen=min(Am.mt, Am.nt)):
             data, order, info = _getrf_fast_jit(
@@ -1747,28 +1758,42 @@ def gesv_batched(a, b, opts=None, *, nb: int | None = None):
 #   row permutes stay within a chip's local share).
 # ---------------------------------------------------------------------------
 
-def _apply_pivots_matrix(B: Matrix, piv, forward: bool) -> Matrix:
+def _apply_pivots_kind(B: Matrix, piv) -> str:
+    """Which program applies ``piv`` to B's rows: ``order_gather`` (an
+    elimination order, one gather), ``swap_sim`` (LAPACK pivots
+    replayed swap by swap into a permutation, then one gather of the
+    replicated B) or ``dist`` (the same replay, rows exchanged tile row
+    by tile row with no replicated B)."""
     if isinstance(piv, PivotOrder):
-        # elimination order: the permutation IS the pivot data — no
-        # swap simulation. Single-device only (the fast path's gate).
-        slate_error_if(B.grid.size != 1,
-                       "PivotOrder pivots require a single-device B")
-        return _apply_order_jit(B, piv.order, forward)
+        return "order_gather"
     if B.grid.size == 1:
-        return _apply_piv_jit(B, piv, forward)
+        return "swap_sim"
     # narrow B (getrs RHS sizes): one replicated gather+take beats
     # mt_p sequential psum rounds; wide B (getri scale): the
     # distributed pass avoids materializing a replicated dense array
     repl_bytes = (B.data.shape[2] * B.grid.p * B.data.shape[3]
                   * B.grid.q * B.nb * B.nb * B.data.dtype.itemsize)
     if B.n <= 4 * B.nb or repl_bytes < 32 * 2**20:
-        return _apply_piv_jit(B, piv, forward)
+        return "swap_sim"
     # latency guard: the dist pass runs mt_p sequential psum rounds
     # (one ICI collective each); with many tile rows the one-shot
     # replicated gather wins unless the replicated array itself is
     # prohibitive (≳1 GB/chip)
     mt_p = B.data.shape[2] * B.grid.p
     if mt_p > 256 and repl_bytes < 2**30:
+        return "swap_sim"
+    return "dist"
+
+
+def _apply_pivots_matrix(B: Matrix, piv, forward: bool) -> Matrix:
+    kind = _apply_pivots_kind(B, piv)
+    if kind == "order_gather":
+        # elimination order: the permutation IS the pivot data — no
+        # swap simulation. Single-device only (the fast path's gate).
+        slate_error_if(B.grid.size != 1,
+                       "PivotOrder pivots require a single-device B")
+        return _apply_order_jit(B, piv.order, forward)
+    if kind == "swap_sim":
         return _apply_piv_jit(B, piv, forward)
     return _apply_piv_dist(B, piv, forward)
 

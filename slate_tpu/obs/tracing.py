@@ -101,6 +101,9 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def label(self, **more):
+        pass
+
 
 _NOOP = _NoopSpan()
 
@@ -170,6 +173,11 @@ class _Span:
         _CUR.set(self)
         self._start = time.perf_counter_ns()
         return self
+
+    def label(self, **more):
+        """Labels known only after the span opened (all are read at
+        its end)."""
+        self.labels.update(more)
 
     def __exit__(self, *exc):
         end = time.perf_counter_ns()

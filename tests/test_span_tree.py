@@ -21,13 +21,18 @@ from tests.conftest import rand, spd
 
 @pytest.fixture(autouse=True)
 def _fresh_obs():
-    """Everything empty; the flight ring on (the default), tracing and
-    metrics as the session had them."""
+    """Everything empty; the flight ring on (the default: switched on
+    here in case an earlier test of this worker left it off), tracing
+    and metrics as the session had them."""
     was_metrics = obs.metrics_enabled()
+    was_flight = flight.enabled()
+    flight.enable()
     obs.reset()
     yield
     if not was_metrics:
         obs.metrics_off()
+    if not was_flight:
+        flight.disable()
     obs.reset()
 
 

@@ -177,6 +177,7 @@ def wl_flight() -> None:
     histograms, flight ring + auto-dump gate, correlation inflight."""
     from slate_tpu.obs import correlation, flight, metrics
     from slate_tpu.runtime import sync
+    was_metrics, was_flight = metrics.enabled(), flight.enabled()
     metrics.enable()
     flight.enable()
     try:
@@ -201,10 +202,14 @@ def wl_flight() -> None:
             t.join()
         assert metrics.counter_total("race.test") == 200
     finally:
+        # leave both as they were found: the flight ring is on by
+        # default, and a process that runs this suite goes on using it
         metrics.reset()
-        metrics.disable()
         flight.reset()
-        flight.disable()
+        if not was_metrics:
+            metrics.disable()
+        if not was_flight:
+            flight.disable()
 
 
 SUITES = {
