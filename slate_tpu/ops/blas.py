@@ -542,8 +542,18 @@ def trsm(side: Side, alpha, A, B: Matrix, opts=None):
     resolves it by ``A.materialize()`` (a re-laid copy of A) first.
     """
     op = {Op.NoTrans: "N", Op.Trans: "T", Op.ConjTrans: "C"}[A.op]
-    with trace.block("trsm", op=op):
+    with trace.block("trsm", op=op) as span:
         B = B.materialize()   # resolve any lazy op on B too
+        if side == Side.Left:
+            # the columns a device carries through the solve: a B
+            # narrower than its storage is solved at its own width
+            ntl = B.data.shape[3]
+            w = _carried_cols(B.n, B.nb, B.grid.q, ntl)
+            span.label(nrhs=B.n, w=w)
+            if w < ntl * B.nb:
+                obs.count("trsm.narrow", 1, op=op)
+        else:
+            span.label(nrhs=B.m, w=B.data.shape[2] * B.nb)
         flags = {}
         if side == Side.Left and op != "N":
             # in place: A's storage, its op as static flags. The stored
@@ -572,6 +582,20 @@ def trsm(side: Side, alpha, A, B: Matrix, opts=None):
                          **flags)
 
 
+LANES = 128     # the TPU's lane width: the last dim's tiling
+
+
+def _carried_cols(n: int, nb: int, q: int, ntl: int) -> int:
+    """Leading local columns of an ``n``-column matrix's ``[·, ntl, ·,
+    nb]`` storage that hold a real column on some device column (device
+    column 0 holds the most), rounded up to whole lanes and capped at
+    the stored ``ntl·nb``; the columns past them are zero padding on
+    every device."""
+    slot = max(n - 1, 0) // (q * nb)          # last local tile slot in use
+    real = slot * nb + min(nb, n - slot * q * nb)
+    return min(cdiv(max(real, 1), LANES) * LANES, ntl * nb)
+
+
 @partial(cached_jit, static_argnames=("lower", "unit", "trans", "conj"))
 def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
     """op(A)·X = alpha·B on A's storage: ``lower`` names the stored
@@ -586,6 +610,10 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
     p, q, nb = g.p, g.q, B.nb
     mt = cdiv(A.m, nb)
     mtl, ntl = B.data.shape[2], B.data.shape[3]
+    # X rides the loop as [mtl, nb, w]: each local block-row's tiles
+    # side by side, cut to the columns that are real on some device.
+    # The rest is B's zero padding, whose solution is zero.
+    w = _carried_cols(B.n, nb, q, ntl)
     # policy (internal/precision.py): triangular solves always bf16_6x
     pk6 = trailing_dot_kwargs("bf16_6x", B.dtype)
 
@@ -602,6 +630,7 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
     def body(a, x, alpha):
         a, x = _local(a), _local(x)
         r, c = comm.coords()
+        x = x.transpose(0, 2, 1, 3).reshape(mtl, nb, ntl * nb)[:, :, :w]
         x = x * alpha
         gi = masks.local_tile_rows(mtl, p)               # [mtl]
 
@@ -609,22 +638,22 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
             k = t if lower else mt - 1 - t
             with jax.named_scope("diag_solve"):
                 tri = diag_tile(a, k)
-                # owner row solves its slots of block-row k
+                # owner row solves its block-row k
                 xrow = lax.dynamic_index_in_dim(x, k // p, axis=0, keepdims=False)
                 solved = lax.linalg.triangular_solve(
-                    jnp.broadcast_to(tri, (ntl, nb, nb)), xrow,
-                    left_side=True, lower=lower, unit_diagonal=unit)
+                    tri, xrow, left_side=True, lower=lower,
+                    unit_diagonal=unit)
                 xrow = jnp.where(r == k % p, solved, xrow)
                 x = lax.dynamic_update_index_in_dim(x, xrow, k // p, axis=0)
             with jax.named_scope("update"):
-                xrow_b = comm.bcast_from_row(xrow, k % p)    # [ntl, nb, nb]
+                xrow_b = comm.bcast_from_row(xrow, k % p)    # [nb, w]
                 # trailing update: B(i,:) -= A(i,k) · X(k,:) for remaining i
                 acol = lax.dynamic_index_in_dim(a, k // q, axis=1, keepdims=False)
                 acol = comm.bcast_from_col(acol, k % q)      # [mtl, nb, nb]
                 rem = (gi > k) if lower else (gi < k)
                 acol = jnp.where(rem[:, None, None], acol,
                                  jnp.zeros_like(acol))
-                upd = jnp.einsum("aik,bkj->abij", acol, xrow_b, **pk6)
+                upd = jnp.einsum("aik,kj->aij", acol, xrow_b, **pk6)
                 return x - upd
 
         def step_op(t, x):
@@ -640,19 +669,20 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
                                  jnp.zeros_like(acol))
                 if conj:
                     acol = jnp.conj(acol)
-                acc = jnp.einsum("aki,abkj->bij", acol, x, **pk6)
-                acc = comm.psum_rows(acc)                    # [ntl, nb, nb]
+                acc = jnp.einsum("aki,akj->ij", acol, x, **pk6)
+                acc = comm.psum_rows(acc)                    # [nb, w]
             with jax.named_scope("diag_solve"):
                 tri = diag_tile(a, k)
                 xrow = lax.dynamic_index_in_dim(x, k // p, axis=0, keepdims=False)
                 solved = lax.linalg.triangular_solve(
-                    jnp.broadcast_to(tri, (ntl, nb, nb)), xrow - acc,
-                    left_side=True, lower=lower, unit_diagonal=unit,
-                    transpose_a=True, conjugate_a=conj)
+                    tri, xrow - acc, left_side=True, lower=lower,
+                    unit_diagonal=unit, transpose_a=True, conjugate_a=conj)
                 xrow = jnp.where(r == k % p, solved, xrow)
                 return lax.dynamic_update_index_in_dim(x, xrow, k // p, axis=0)
 
         x = lax.fori_loop(0, mt, step_op if trans else step, x)
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, ntl * nb - w)))
+        x = x.reshape(mtl, nb, ntl, nb).transpose(0, 2, 1, 3)
         return x[None, None]
 
     data = _shard(body, g.mesh, 2, 1)(A.data, B.data, alpha)

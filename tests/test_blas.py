@@ -261,26 +261,48 @@ def test_trsm_left_op_of_a_general_matrix_reads_its_upper_triangle(grid24):
                                rtol=1e-10, atol=1e-10)
 
 
+def _all_reduce_bytes(hlo_text):
+    """Bytes of every result of every all-reduce in an optimized HLO
+    text, the operands XLA combined into one tuple included."""
+    import re
+    total = 0
+    for line in hlo_text.splitlines():
+        head, found, _ = line.partition(" all-reduce(")
+        if not found:
+            continue
+        for dt, dims in re.findall(r"\b([fcsu]\d+)\[([\d,]*)\]",
+                                   head.split("=", 1)[1]):
+            n = int(dt[1:]) // 8
+            for d in filter(None, dims.split(",")):
+                n *= int(d)
+            total += n
+    return total
+
+
+def _lower_trsm_left(grid, n, nb, nrhs, trans):
+    import jax
+    import jax.numpy as jnp
+    from slate_tpu.ops import blas
+    A = st.TriangularMatrix.from_dense(
+        rand(n, n, np.float32, 42), nb=nb, grid=grid, uplo=Uplo.Lower)
+    B = st.Matrix.from_dense(rand(n, nrhs, np.float32, 43), nb=nb,
+                             grid=grid)
+    return jax.jit(
+        blas._trsm_left_jit._fn,
+        static_argnames=("lower", "unit", "trans", "conj")).lower(
+        jnp.float32(1.0), A, B, lower=True, unit=False,
+        trans=trans).compile()
+
+
 @pytest.mark.parametrize("trans", [False, True])
 def test_trsm_left_lowering_collectives(grid22, trans):
     """The lowered 2x2 program: an op adds no all-gather and no
     all-to-all (the re-layout materialize() paid), one reduce down the
     mesh column stands for the row bcast, and the NoTrans program's
     collectives are what they were."""
-    import jax
-    import jax.numpy as jnp
     from slate_tpu.internal import comm
-    from slate_tpu.ops import blas
-    n, nb = 64, 8
-    A = st.TriangularMatrix.from_dense(
-        rand(n, n, np.float32, 34), nb=nb, grid=grid22, uplo=Uplo.Lower)
-    B = st.Matrix.from_dense(rand(n, 4, np.float32, 35), nb=nb,
-                             grid=grid22)
-    lowered = jax.jit(
-        blas._trsm_left_jit._fn,
-        static_argnames=("lower", "unit", "trans", "conj")).lower(
-        jnp.float32(1.0), A, B, lower=True, unit=False, trans=trans)
-    stats = comm.collective_footprint(lowered.compile())
+    compiled = _lower_trsm_left(grid22, 64, 8, 4, trans)
+    stats = comm.collective_footprint(compiled)
     assert "all-gather" not in stats and "all-to-all" not in stats
     # a step moves the diagonal tile over both axes, column k over q,
     # and over p the solved row (NoTrans) or the partial sums (op);
@@ -288,7 +310,106 @@ def test_trsm_left_lowering_collectives(grid22, trans):
     assert set(stats) == {"all-reduce"}
     assert stats["all-reduce"]["count"] == 3
     if not trans:
-        assert stats["all-reduce"]["bytes"] == 768.0
+        # [8,8] + ([8,8], [4,8,8]) + [8,8]; collective_footprint's own
+        # ``bytes`` reads only the first shape of a combined all-reduce,
+        # whose order XLA picks (768 or 1536 for these same collectives)
+        assert _all_reduce_bytes(compiled.as_text()) == 1792
+
+
+# -- a B narrower than its storage rides the solve at its own width --------
+# nb=8 never crops (the carried width is whole lanes of 128, capped at
+# the stored ntl*nb): these run the narrow shape at nb=256.
+
+@pytest.mark.parametrize("n,nb,q,ntl,w", [
+    (8, 1024, 1, 1, 128),       # the benchmark's cells: one chip,
+    (8, 1024, 2, 1, 128),       # the 2x2 (device column 1: padding only)
+    (8, 384, 1, 1, 128),        # and the tile of 384
+    (1, 256, 1, 1, 128), (128, 256, 4, 1, 128), (129, 256, 1, 1, 256),
+    (256, 256, 2, 1, 256),      # every stored column real
+    (300, 256, 1, 2, 384),      # one whole tile and 44 columns
+    (300, 256, 2, 1, 256),      # ... one tile a device column
+    (1300, 256, 2, 3, 768),     # slot 2 of device column 0 ends at 276
+    (5, 8, 4, 1, 8), (19, 8, 1, 3, 24), (130, 8, 1, 17, 136),
+])
+def test_trsm_carried_cols(n, nb, q, ntl, w):
+    from slate_tpu.ops import blas
+    assert blas._carried_cols(n, nb, q, ntl) == w
+
+
+def _padded_dense(M):
+    from slate_tpu.matrix import bc_to_tiles, tiles_to_dense
+    tiles = bc_to_tiles(M.data)
+    return np.asarray(tiles_to_dense(tiles, tiles.shape[0] * M.nb,
+                                     tiles.shape[1] * M.nb))
+
+
+@pytest.mark.parametrize("nrhs", [1, 5, 130, 256, 300])
+@pytest.mark.parametrize("uplo", [Uplo.Lower, Uplo.Upper])
+@pytest.mark.parametrize("op", ["n", "t", "c"])
+@pytest.mark.parametrize("shape", ["1x1", "2x2", "2x4"])
+def test_trsm_left_narrow_b(shape, op, uplo, nrhs):
+    """8 right-hand sides in a 256-wide tile: the answer, the stored
+    padding, and the same columns out of the full-width program."""
+    import jax
+    p, q = map(int, shape.split("x"))
+    grid = st.Grid(p, q, devices=jax.devices()[:p * q])
+    dt = np.complex64 if op == "c" else np.float32
+    n, nb = 600, 256
+    a = rand(n, n, dt, 40) * 0.3 + n * np.eye(n, dtype=dt)
+    t = tri(a, uplo == Uplo.Lower)
+    opt = {"n": t, "t": t.T, "c": np.conj(t.T)}[op]
+    view = {"n": lambda x: x, "t": st.transpose,
+            "c": st.conj_transpose}[op]
+    b = rand(n, nrhs, dt, 41)
+    A = st.TriangularMatrix.from_dense(a, nb=nb, grid=grid, uplo=uplo)
+    X = st.trsm(Side.Left, 1.5, view(A),
+                st.Matrix.from_dense(b, nb=nb, grid=grid))
+    x = np.asarray(X.to_dense())
+    ref = np.linalg.solve(opt.astype(np.complex128), 1.5 * b)
+    assert np.abs(x - ref).max() <= 2e-6 * np.abs(ref).max()
+    # the padding of X is stored as exact zeros
+    stored = _padded_dense(X)
+    assert stored.shape[1] >= nrhs and stored.shape[0] >= n
+    assert not stored[:, nrhs:].any() and not stored[n:].any()
+    np.testing.assert_array_equal(stored[:n, :nrhs], x)
+    # B zero-extended to whole tiles of real columns: nothing to crop
+    wide = -(-nrhs // nb) * nb
+    bw = np.zeros((n, wide), dt)
+    bw[:, :nrhs] = b
+    Xw = st.trsm(Side.Left, 1.5, view(A),
+                 st.Matrix.from_dense(bw, nb=nb, grid=grid))
+    xw = np.asarray(Xw.to_dense())
+    assert not xw[:, nrhs:].any()
+    assert np.abs(x - xw[:, :nrhs]).max() <= 1e-6 * np.abs(xw).max()
+
+
+@pytest.mark.parametrize("trans", [False, True])
+@pytest.mark.parametrize("n,nb", [(600, 256), (1200, 1024)])
+def test_trsm_left_narrow_b_pays_for_its_lanes(grid22, trans, n, nb):
+    """8 right-hand sides cost 128 lanes of the tile's nb: the lowered
+    step's flops follow the carried width (a half at nb=256, an eighth
+    at nb=1024, and the diagonal block's inversion, which has no width)."""
+    narrow = _lower_trsm_left(grid22, n, nb, 8, trans).cost_analysis()
+    full = _lower_trsm_left(grid22, n, nb, nb, trans).cost_analysis()
+    share = 128 / nb
+    assert share * 0.98 <= narrow["flops"] / full["flops"] <= share * 1.06
+
+
+@pytest.mark.parametrize("trans,flops", [(False, 68813928.0),
+                                         (True, 68617312.0)])
+def test_trsm_left_full_width_program_is_the_one_it_was(grid22, trans, flops):
+    """Every stored column real (nrhs = nb): the crop and the pad are the
+    identity, and the 2x2 program does what it did when it carried
+    tiles (numbers read off the parent of PR 28)."""
+    from slate_tpu.internal import comm
+    compiled = _lower_trsm_left(grid22, 600, 256, 256, trans)
+    assert compiled.cost_analysis()["flops"] == flops
+    stats = comm.collective_footprint(compiled)
+    assert set(stats) == {"all-reduce"}
+    assert stats["all-reduce"]["count"] == 3
+    # the diagonal tile over both axes, two tiles of column k over q,
+    # one tile row of X (or of partial sums) over p
+    assert _all_reduce_bytes(compiled.as_text()) == 5 * 256 * 256 * 4
 
 
 def test_gbmm(grid24):

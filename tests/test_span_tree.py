@@ -125,6 +125,29 @@ def test_posv_span_tree_has_parents_and_one_solve_id(grid_name, request,
     assert metrics.counter_total("matrix.relayout_bytes") == 0
 
 
+@pytest.mark.parametrize("nrhs,w,narrow", [(8, 128, 2), (256, 256, 0)])
+def test_trsm_span_says_the_width_it_carried(grid11, profiler, nrhs, w,
+                                             narrow):
+    """8 right-hand sides in a 256-wide tile ride both solves of a posv
+    at 128 columns; a B of whole tiles is carried as it is stored."""
+    n, nb = 512, 256
+    A = st.HermitianMatrix.from_dense(spd(n, np.float32, seed=5), nb=nb,
+                                      grid=grid11, uplo=st.Uplo.Lower)
+    B = st.Matrix.from_dense(rand(n, nrhs, np.float32, seed=6), nb=nb,
+                             grid=grid11)
+    jax.block_until_ready(st.posv(A, B))         # compile outside
+    obs.reset()
+    obs.metrics_on()
+    jax.block_until_ready(st.posv(A, B))
+    profiler()
+    trsms = [s["labels"] for s in obs.captured_spans()
+             if s["name"] == "trsm"]
+    assert [(t["op"], t["nrhs"], t["w"]) for t in trsms] == [
+        ("N", nrhs, w), ("C", nrhs, w)]
+    assert metrics.counter_total("trsm.narrow") == narrow
+    assert metrics.counter_total("trsm.in_place") == 1
+
+
 def test_capture_follows_the_profiler(grid11, profiler):
     A, B = _posv_operands(grid11)
     with obs.span("inside"):

@@ -158,9 +158,10 @@ def test_getrf_chunk_2x2_compiles_sharded(tpu_grid22):
 
 # -- potrs' second solve: trsm on conj_transpose(L) where it lies ----------
 
-@pytest.mark.parametrize("shape", ["1x1", "2x2"])
-def test_trsm_left_op_in_place_compiles_with_no_relayout(topo, tpu_grid22,
-                                                         shape):
+def _trsm_h_by_8(topo, tpu_grid22, shape, trans):
+    """The cells' solve, L (H x H) against 8 right-hand sides in one
+    1024-wide tile column a device column, compiled; the grid it is
+    for; and the bytes of its two stored operands."""
     from slate_tpu.ops import blas
     grid = (tpu_grid22 if shape == "2x2"
             else slate.Grid(1, 1, devices=[topo.devices[0]]))
@@ -171,16 +172,49 @@ def test_trsm_left_op_in_place_compiles_with_no_relayout(topo, tpu_grid22,
         sharding=grid.sharding())
     B = slate.Matrix(data=b, m=H, n=8, nb=NB, grid=grid)
     c = blas._trsm_left_jit.lower(jax.ShapeDtypeStruct((), F32), L, B,
-                                  True, False, trans=True).compile()
+                                  True, False, trans=trans).compile()
+    return c, grid, (L.data.size + b.size) * 4
+
+
+@pytest.mark.parametrize("shape", ["1x1", "2x2"])
+def test_trsm_left_op_in_place_compiles_with_no_relayout(topo, tpu_grid22,
+                                                         shape):
+    c, grid, stored = _trsm_h_by_8(topo, tpu_grid22, shape, trans=True)
     text = c.as_text()
     assert "all-gather" not in text and "all-to-all" not in text
     mem = c.memory_analysis()
     assert abs(mem.argument_size_in_bytes
-               - (L.data.size + b.size) * 4 // grid.size) < 2 ** 20
+               - stored // grid.size) < 2 ** 20
     if shape == "2x2":
         # no copy of the factor: the left-looking step needs tiles only
         assert "all-reduce" in text
         assert mem.temp_size_in_bytes < 2 ** 24, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("trans", [False, True], ids=["N", "C"])
+@pytest.mark.parametrize("shape", ["1x1", "2x2"])
+def test_trsm_left_8_rhs_multiplies_128_lanes(topo, tpu_grid22, shape,
+                                              trans):
+    """X rides the loop 128 columns wide, so no product of the program
+    is [.., 1024, 1024] by [.., 1024, 1024]."""
+    import math
+    import re
+    c, grid, _ = _trsm_h_by_8(topo, tpu_grid22, shape, trans)
+    mtl = H // NB // grid.p
+    text = c.as_text()
+    products = re.findall(
+        r"= f32\[([\d,]+)\]\S* (?:convolution|dot)\(", text)
+    assert products
+    # the widest is column k of A by X(k,:), [mtl, 1024, 128] (summed
+    # over the mtl tiles under an op); the padded tile's was 8x that
+    widest = max(math.prod(map(int, dims.split(","))) for dims in products)
+    assert widest in (mtl * NB * W, NB * W), widest
+    # a step's flops: that product and the diagonal block's solve (the
+    # padded tile cost 8x: 35.6e9 on one chip, 18.5e9 on the 2x2)
+    assert c.cost_analysis()["flops"] < 1.2 * 2 * mtl * NB * NB * W
+    if shape == "2x2":
+        assert "all-gather" not in text and "all-to-all" not in text
+        assert c.memory_analysis().temp_size_in_bytes < 2 ** 24
 
 
 # -- one served executable ---------------------------------------------------
