@@ -7,7 +7,7 @@ nothing is set in code. Where it is not, the cache goes to
 (git-ignored), never a temporary name, pid or time — a directory that
 moves is never found again.
 Entry points call this once before their first compile
-(``chip_smoke.py``, ``bench.py``, the serve/cache/tune CLIs).
+(``benchmarks/run.py``, the serve/cache/tune CLIs).
 """
 
 from __future__ import annotations

@@ -63,6 +63,7 @@ from .. import obs
 from ..obs import timeline as tl
 from ..runtime import dag
 from ..utils import trace
+from . import _superstep
 
 
 # ---------------------------------------------------------------------------
@@ -95,7 +96,6 @@ def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
     (use :func:`getrf_resume`).
     """
     from ..robust import faults as _faults
-    from ..robust import abft as _abft
     with trace.block("getrf", routine="getrf", m=A.m, n=A.n, nb=A.nb,
                      mt=A.mt, pad_rows=A.mt * A.nb - A.m) as top:
         # what the host does before the first launch, with the device
@@ -109,13 +109,9 @@ def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
             from .. import tune
             tier, depth = tune.driver_config("getrf", A.n, opts)
             chunked = g.size > 1 and kt >= 2 * lcm_pq
-            ck = None
-            if chunked:
-                from ..robust import ckpt as _ckpt
-                ck = _ckpt.plan("getrf", A, opts, checkpoint=checkpoint)
-            ab = _abft.monitor("getrf", A, opts)
+            guard = _superstep.arm("getrf", A, opts, checkpoint, chunked)
         top.label(precision=tier)
-        Anorm = _norm_one(A, opts) if health else None
+        Anorm = _superstep.norm_one(A, opts) if health else None
         if chunked:
             # chunked super-steps (same scheme as potrf): trailing
             # updates on a statically shrinking window; swaps still
@@ -126,171 +122,63 @@ def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
             # gemm) vs the strictly sequential one.
             S = superstep_chunk(kt, lcm_pq, opts)
             obs.count("getrf.path", 1, phase="spmd_chunk")
-            data = A.data
             piv0 = (jnp.arange(kt, dtype=jnp.int32)[:, None] * A.nb
                     + jnp.arange(A.nb, dtype=jnp.int32)[None, :])
-            piv = piv0
-            info = jnp.zeros((), jnp.int32)
-            k_start = 0
-            if _resume is not None:
-                # re-enter the step loop at the checkpointed chunk
-                # boundary with exactly the uninterrupted run's state:
-                # the remaining chunks run the same per-k0 executables
-                # and reproduce the uninterrupted result bitwise,
-                # pivots included
-                arrs = _resume["arrays"]
-                data = jax.device_put(arrs["data"], A.data.sharding)
-                piv = jnp.asarray(arrs["piv"])
-                info = jnp.asarray(arrs["info"])
-                k_start = int(_resume["k_next"])
-            chunk_starts = list(range(k_start, kt, S))
-            if ab is not None:
-                ab.init(A.data)
-            ci = 0
-            with _abft.armed_scope(ab is not None):
-                while ci < len(chunk_starts):
-                    k0 = chunk_starts[ci]
-                    if ck is not None:
-                        ck.check_preempt(k0)
-                    # donation guard: a buffer an async save still
-                    # reads must not be donated to the next chunk
-                    # executable — and abft never donates at all: the
-                    # chunk-entry buffer is the rollback state a
-                    # detected SDC re-runs from
-                    donate = ab is None and (overwrite_a or k0 > 0) and (
-                        ck is None or ck.donation_safe(data))
-                    if depth > 0:
-                        fn = (_getrf_pipe_chunk_jit_overwrite if donate
-                              else _getrf_pipe_chunk_jit)
-                    else:
-                        fn = (_getrf_chunk_jit_overwrite if donate
-                              else _getrf_chunk_jit)
-                    klen = min(S, kt - k0)
-                    with trace.block("getrf.chunk", phase="spmd_chunk",
-                                     k0=k0, klen=klen):
-                        if depth > 0:
-                            new_data, new_piv, new_info = fn(
-                                A._replace(data=data), piv, info, k0,
-                                klen, depth=depth, tier=tier)
-                        else:
-                            new_data, new_piv, new_info = fn(
-                                A._replace(data=data), piv, info, k0,
-                                klen, tier=tier)
-                    new_data = _faults.maybe_bitflip_chunk(
-                        "getrf", new_data, chunk_idx=ci,
-                        n_chunks=len(chunk_starts), nb=A.nb, p=g.p,
-                        q=g.q, mt=A.mt, k0t=k0, k1t=k0 + klen)
-                    if ab is not None and obs.sync_read(
-                            "getrf.info", int, new_info) == 0:
-                        v = ab.verify(new_data, k0 + klen)
-                        if not v.ok:
-                            act = ab.strike(k0)
-                            if act == "retry":
-                                continue   # re-run from chunk entry
-                            if act == "scratch":
-                                chunk_starts = list(range(0, kt, S))
-                                data, piv = A.data, piv0
-                                info = jnp.zeros((), jnp.int32)
-                                ci = 0
-                                continue
-                            raise _abft.SdcDetected(
-                                "getrf", tile_col=v.tile_col,
-                                resid=v.resid)
-                    data, piv, info = new_data, new_piv, new_info
-                    # save only states that passed verification — a
-                    # corrupted chunk must never become a checkpoint
-                    if ck is not None and ck.due(k0, klen):
-                        ck.save_async(k0 + klen, data=data, piv=piv,
-                                      info=info)
-                    ci += 1
-            if ab is not None:
-                ab.note()
+
+            def step(data, carried, k0, klen, donate):
+                Ak = A._replace(data=data)
+                if depth > 0:
+                    fn = (_getrf_pipe_chunk_jit_overwrite if donate
+                          else _getrf_pipe_chunk_jit)
+                    return fn(Ak, *carried, k0, klen, depth=depth,
+                              tier=tier)
+                fn = (_getrf_chunk_jit_overwrite if donate
+                      else _getrf_chunk_jit)
+                return fn(Ak, *carried, k0, klen, tier=tier)
+
+            # a resumed run reproduces the uninterrupted result
+            # bitwise, pivots included
+            data, piv, info = _superstep.run_chunks(
+                guard, A, step, (piv0, jnp.zeros((), jnp.int32)),
+                ("piv", "info"), kt, S, overwrite_a, _resume)
         else:
-            if ab is not None:
-                ab.init(A.data)
             fm = (_fast_path_mode(A, "partial")
                   if (g.size == 1 and kt <= 64) else None)
             obs.count("getrf.path", 1, phase=(
                 "one_program" if fm is None else "fast_path"))
-            with _abft.armed_scope(ab is not None):
-                while True:
-                    if fm is not None:
-                        fj = (_getrf_fast_jit_overwrite
-                              if overwrite_a and ab is None
-                              else _getrf_fast_jit)
-                        with trace.block("getrf.chunk",
-                                         phase="fast_path",
-                                         k0=0, klen=kt):
-                            data, order, info = fj(
-                                A, interpret=(fm == "interpret"),
-                                want_ipiv=False, fold=_fold_now(),
-                                tier=tier)
-                        # LAPACK ipiv derived on host (off the device
-                        # program)
-                        piv = pivot_order_to_ipiv(order)
-                    else:
-                        jit_fn = (_getrf_jit_overwrite
-                                  if overwrite_a and ab is None
-                                  else _getrf_jit)
-                        with trace.block("getrf.chunk",
-                                         phase="one_program",
-                                         k0=0, klen=kt):
-                            data, piv, info = jit_fn(
-                                A, piv_mode="partial", tier=tier,
-                                depth=depth)
-                    data = _faults.maybe_bitflip_chunk(
-                        "getrf", data, chunk_idx=0, n_chunks=1,
-                        nb=A.nb, p=g.p, q=g.q, mt=A.mt, k0t=0,
-                        k1t=kt)
-                    if ab is None or obs.sync_read(
-                            "getrf.info", int, info) != 0:
-                        break
-                    v = ab.verify(data, kt, phase="final")
-                    if v.ok:
-                        break
-                    if ab.strike(0) == "fail":
-                        raise _abft.SdcDetected(
-                            "getrf", phase="final",
-                            tile_col=v.tile_col, resid=v.resid)
-            if ab is not None:
-                ab.note()
+
+            def launch(donate):
+                if fm is not None:
+                    fj = (_getrf_fast_jit_overwrite if donate
+                          else _getrf_fast_jit)
+                    with trace.block("getrf.chunk", phase="fast_path",
+                                     k0=0, klen=kt):
+                        data, order, info = fj(
+                            A, interpret=(fm == "interpret"),
+                            want_ipiv=False, fold=_fold_now(),
+                            tier=tier)
+                    # LAPACK ipiv derived on host (off the device
+                    # program)
+                    return data, pivot_order_to_ipiv(order), info
+                jit_fn = (_getrf_jit_overwrite if donate
+                          else _getrf_jit)
+                with trace.block("getrf.chunk", phase="one_program",
+                                 k0=0, klen=kt):
+                    return jit_fn(A, piv_mode="partial", tier=tier,
+                                  depth=depth)
+
+            data, piv, info = _superstep.run_one_program(
+                guard, A, launch, kt, overwrite_a)
     LU = A._replace(data=data)
     if health:
-        return LU, piv, _getrf_health(LU, piv, info, Anorm, opts)
-    return LU, piv, info
-
-
-def _norm_one(A, opts):
-    """Host-synced ‖A‖₁ for the health path (None on failure — the
-    report then omits the growth estimate)."""
-    from ..ops.norms import norm as _mat_norm
-    from ..types import Norm
-    try:
-        return float(_mat_norm(Norm.One, A, opts=opts))
-    except Exception:
-        return None
-
-
-def _getrf_health(LU, piv, info, Anorm, opts):
-    """HealthReport for a finished getrf: info counts zero pivots
-    (no single bad-tile coordinate); rcond via gecondest when the
-    factor is nonsingular and ‖A‖₁ was available; abft verification
-    outcome when ``Option.Abft`` was armed."""
-    from ..robust import abft as _abft
-    from ..robust.guards import health_report
-    i = int(info)
-    growth = None
-    if i == 0 and Anorm:
+        # info counts zero pivots (no single bad-tile coordinate);
+        # rcond via gecondest
         from ..types import Norm
         from .condest import gecondest
-        try:
-            growth = float(gecondest(Norm.One, LU, piv, Anorm, opts))
-        except Exception:
-            growth = None
-    verified, resid = (_abft.take_result("getrf")
-                       if _abft.armed(opts) else (None, None))
-    return health_report("getrf", i, convention="count", growth=growth,
-                         verified=verified, checksum_resid=resid)
+        return LU, piv, _superstep.health(
+            "getrf", info, Anorm, opts, "count",
+            lambda anorm: gecondest(Norm.One, LU, piv, anorm, opts))
+    return LU, piv, info
 
 
 def getrf_resume(A: Matrix, opts=None, overwrite_a: bool = False,
@@ -306,14 +194,9 @@ def getrf_resume(A: Matrix, opts=None, overwrite_a: bool = False,
     fingerprint, different options) the call demotes to a from-scratch
     :func:`getrf` and the demotion lands in
     ``robust.ladder.demotion_log()``."""
-    from ..robust import ckpt as _ckpt
-    state = _ckpt.load_for("getrf", A, opts)
-    if state is None:
-        _ckpt.record_scratch_demotion("getrf")
-        return getrf(A, opts, overwrite_a=overwrite_a, health=health,
-                     checkpoint=checkpoint)
-    return getrf(A, opts, overwrite_a=overwrite_a, health=health,
-                 checkpoint=checkpoint, _resume=state)
+    return _superstep.resume("getrf", getrf, A, opts,
+                             overwrite_a=overwrite_a, health=health,
+                             checkpoint=checkpoint)
 
 
 def getrf_nopiv(A: Matrix, opts=None):

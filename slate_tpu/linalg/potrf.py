@@ -48,10 +48,10 @@ from ..internal import comm, masks
 from ..internal.tile_kernels import tile_potrf, _factor_dtype
 from ..internal.masks import tile_diag_pad_identity
 from ..internal.precision import resolve_tier, trailing_dot_kwargs
-from .. import obs
 from ..obs import timeline as tl
 from ..runtime import dag
 from ..utils import trace
+from . import _superstep
 
 
 def potrf(A: HermitianMatrix, opts=None, overwrite_a: bool = False,
@@ -85,7 +85,7 @@ def potrf(A: HermitianMatrix, opts=None, overwrite_a: bool = False,
     slate_error_if(A.m != A.n, "potrf needs a square matrix")
     from ..robust import faults as _faults
     A = _faults.maybe_corrupt("potrf", A)
-    Anorm = _norm_one(A, opts) if health else None
+    Anorm = _superstep.norm_one(A, opts) if health else None
     if A.uplo == Uplo.Upper:
         # Factor the mirrored lower problem; return upper view.
         Alow = HermitianMatrix(data=_conj_transpose_data(A), m=A.m, n=A.n,
@@ -105,7 +105,9 @@ def potrf(A: HermitianMatrix, opts=None, overwrite_a: bool = False,
         g = A.grid
         lcm_pq = g.p * g.q // math.gcd(g.p, g.q)
         nt = A.nt
-        if g.size > 1 and nt >= 2 * lcm_pq:
+        chunked = g.size > 1 and nt >= 2 * lcm_pq
+        guard = _superstep.arm("potrf", A, opts, checkpoint, chunked)
+        if chunked:
             # chunked super-steps: re-jit on a statically shrinking
             # trailing window every lcm(p,q)-aligned chunk — the
             # uniform one-program fori pays ~3x the flops (every step
@@ -117,111 +119,30 @@ def potrf(A: HermitianMatrix, opts=None, overwrite_a: bool = False,
             # flight under step-k trailing update) vs the sequential
             # one — distinct routines, never a shared executable.
             S = superstep_chunk(nt, lcm_pq, opts)
-            from ..robust import ckpt as _ckpt
-            from ..robust import abft as _abft
-            ck = _ckpt.plan("potrf", A, opts, checkpoint=checkpoint)
-            ab = _abft.monitor("potrf", A, opts)
-            data = A.data
-            info = jnp.zeros((), jnp.int32)
-            k_start = 0
-            if _resume is not None:
-                # re-enter the step loop at the checkpointed chunk
-                # boundary with exactly the uninterrupted run's state:
-                # the remaining chunks run the same per-k0 executables
-                # and reproduce the uninterrupted result bitwise
-                arrs = _resume["arrays"]
-                data = jax.device_put(arrs["data"], A.data.sharding)
-                info = jnp.asarray(arrs["info"])
-                k_start = int(_resume["k_next"])
-            chunk_starts = list(range(k_start, nt, S))
-            if ab is not None:
-                ab.init(A.data)
-            ci = 0
-            with _abft.armed_scope(ab is not None):
-                while ci < len(chunk_starts):
-                    k0 = chunk_starts[ci]
-                    if ck is not None:
-                        ck.check_preempt(k0)
-                    # later chunks always donate their (intermediate)
-                    # input; the first donates the caller's A only when
-                    # overwrite_a was requested; a buffer an async save
-                    # still reads is never donated — and abft never
-                    # donates at all: the chunk-entry buffer is the
-                    # rollback state a detected SDC re-runs from
-                    donate = ab is None and (overwrite_a or k0 > 0) and (
-                        ck is None or ck.donation_safe(data))
-                    if depth > 0:
-                        fn = (_potrf_pipe_chunk_jit_overwrite if donate
-                              else _potrf_pipe_chunk_jit)
-                    else:
-                        fn = (_potrf_chunk_jit_overwrite if donate
-                              else _potrf_chunk_jit)
-                    klen = min(S, nt - k0)
-                    with trace.block("potrf.chunk", phase="spmd_chunk",
-                                     k0=k0, klen=klen):
-                        if depth > 0:
-                            new_data, new_info = fn(
-                                A._replace(data=data), info, k0,
-                                klen, depth=depth, tier=tier)
-                        else:
-                            new_data, new_info = fn(
-                                A._replace(data=data), info, k0,
-                                klen, tier=tier)
-                    new_data = _faults.maybe_bitflip_chunk(
-                        "potrf", new_data, chunk_idx=ci,
-                        n_chunks=len(chunk_starts), nb=A.nb, p=g.p,
-                        q=g.q, mt=A.mt, k0t=k0, k1t=k0 + klen)
-                    if ab is not None and obs.sync_read(
-                            "potrf.info", int, new_info) == 0:
-                        v = ab.verify(new_data, k0 + klen)
-                        if not v.ok:
-                            act = ab.strike(k0)
-                            if act == "retry":
-                                continue      # re-run from chunk entry
-                            if act == "scratch":
-                                chunk_starts = list(range(0, nt, S))
-                                data = A.data
-                                info = jnp.zeros((), jnp.int32)
-                                ci = 0
-                                continue
-                            raise _abft.SdcDetected(
-                                "potrf", tile_col=v.tile_col,
-                                resid=v.resid)
-                    data, info = new_data, new_info
-                    # save only states that passed verification — a
-                    # corrupted chunk must never become a checkpoint
-                    if ck is not None and ck.due(k0, klen):
-                        ck.save_async(k0 + klen, data=data, info=info)
-                    ci += 1
-            if ab is not None:
-                ab.note()
+
+            def step(data, carried, k0, klen, donate):
+                Ak = A._replace(data=data)
+                if depth > 0:
+                    fn = (_potrf_pipe_chunk_jit_overwrite if donate
+                          else _potrf_pipe_chunk_jit)
+                    return fn(Ak, *carried, k0, klen, depth=depth,
+                              tier=tier)
+                fn = (_potrf_chunk_jit_overwrite if donate
+                      else _potrf_chunk_jit)
+                return fn(Ak, *carried, k0, klen, tier=tier)
+
+            data, info = _superstep.run_chunks(
+                guard, A, step, (jnp.zeros((), jnp.int32),), ("info",),
+                nt, S, overwrite_a, _resume)
         else:
-            from ..robust import abft as _abft
-            ab = _abft.monitor("potrf", A, opts)
-            if ab is not None:
-                ab.init(A.data)
+            def launch(donate):
+                return (_potrf_jit_overwrite if donate
+                        else _potrf_jit)(A, tier, depth=depth)
+
             with trace.block("potrf.chunk", phase="one_program",
-                             k0=0, klen=nt), \
-                    _abft.armed_scope(ab is not None):
-                while True:
-                    donate = overwrite_a and ab is None
-                    data, info = (_potrf_jit_overwrite if donate
-                                  else _potrf_jit)(A, tier, depth=depth)
-                    data = _faults.maybe_bitflip_chunk(
-                        "potrf", data, chunk_idx=0, n_chunks=1,
-                        nb=A.nb, p=g.p, q=g.q, mt=A.mt, k0t=0, k1t=nt)
-                    if ab is None or obs.sync_read(
-                            "potrf.info", int, info) != 0:
-                        break
-                    v = ab.verify(data, nt, phase="final")
-                    if v.ok:
-                        break
-                    if ab.strike(0) == "fail":
-                        raise _abft.SdcDetected(
-                            "potrf", phase="final",
-                            tile_col=v.tile_col, resid=v.resid)
-            if ab is not None:
-                ab.note()
+                             k0=0, klen=nt):
+                data, info = _superstep.run_one_program(
+                    guard, A, launch, nt, overwrite_a)
     L = TriangularMatrix(data=data, m=A.m, n=A.n, nb=A.nb, grid=A.grid,
                          uplo=Uplo.Lower, diag=Diag.NonUnit)
     if health:
@@ -229,40 +150,14 @@ def potrf(A: HermitianMatrix, opts=None, overwrite_a: bool = False,
     return L, info
 
 
-def _norm_one(A, opts):
-    """Host-synced ‖A‖₁ for the health path (None on failure — the
-    report then simply omits the growth estimate)."""
-    from ..ops.norms import norm as _mat_norm
-    from ..types import Norm
-    try:
-        return float(_mat_norm(Norm.One, A, opts=opts))
-    except Exception:
-        return None
-
-
 def _potrf_health(L, info, Anorm, opts):
     """HealthReport for a finished potrf: first-bad tile from the
-    first-failure info convention; rcond via pocondest when the factor
-    succeeded and ‖A‖₁ was available; abft verification outcome when
-    ``Option.Abft`` was armed (the driver notes it per-thread, which
-    also covers the Upper-mirror path where the monitor lives in the
-    inner lower call)."""
-    from ..robust import abft as _abft
-    from ..robust.guards import health_report
-    i = int(info)
-    growth = None
-    if i == 0 and Anorm:
-        from ..types import Norm
-        from .condest import pocondest
-        try:
-            growth = float(pocondest(Norm.One, L, Anorm, opts))
-        except Exception:
-            growth = None
-    verified, resid = (_abft.take_result("potrf")
-                       if _abft.armed(opts) else (None, None))
-    return health_report("potrf", i, convention="first_block",
-                         growth=growth, verified=verified,
-                         checksum_resid=resid)
+    first-failure info convention; rcond via pocondest."""
+    from ..types import Norm
+    from .condest import pocondest
+    return _superstep.health(
+        "potrf", info, Anorm, opts, "first_block",
+        lambda anorm: pocondest(Norm.One, L, anorm, opts))
 
 
 def potrf_resume(A: HermitianMatrix, opts=None,
@@ -282,14 +177,9 @@ def potrf_resume(A: HermitianMatrix, opts=None,
     lower problem exactly as :func:`potrf` does — the checkpoint job
     identity is geometry-only, so the state saved by the inner lower
     loop is found either way."""
-    from ..robust import ckpt as _ckpt
-    state = _ckpt.load_for("potrf", A, opts)
-    if state is None:
-        _ckpt.record_scratch_demotion("potrf")
-        return potrf(A, opts, overwrite_a=overwrite_a, health=health,
-                     checkpoint=checkpoint)
-    return potrf(A, opts, overwrite_a=overwrite_a, health=health,
-                 checkpoint=checkpoint, _resume=state)
+    return _superstep.resume("potrf", potrf, A, opts,
+                             overwrite_a=overwrite_a, health=health,
+                             checkpoint=checkpoint)
 
 
 def _conj_transpose_data(A):

@@ -16,8 +16,8 @@ tile-runtime performance work):
   GFLOP/s (and %-of-peak where the platform peak is known) in
   :func:`dump`;
 * **timing** (:mod:`.timing`) — the round-trip-subtracting timing
-  discipline the bench uses (single source of truth; slatelint SL008
-  bans raw ``perf_counter`` timing elsewhere).
+  discipline the tuning sweep uses (single source of truth; slatelint
+  SL008 bans raw ``perf_counter`` timing elsewhere).
 
 Activation (no code changes needed):
 
@@ -127,8 +127,7 @@ def reset() -> None:
 def dump() -> dict:
     """Machine-readable snapshot: span aggregates (flop-enriched —
     achieved GFLOP/s per routine-labeled span), counters, gauges,
-    histograms.  JSON-ready; ``bench.py`` embeds it as
-    ``detail.obs``."""
+    histograms.  JSON-ready; ``/vars`` serves it live."""
     snap = metrics.snapshot()
     snap["spans"] = [enrich_span(s) for s in snap["spans"]]
     costs = costmodel.snapshot()
@@ -223,7 +222,7 @@ class link_window:
     the window ÷ window ÷ nominal link bandwidth
     (:func:`roofline.link_bw_gbs`, SLATE_TPU_ICI_GBS/_DCN_GBS
     overridable).  An occupancy near 1.0 says the link — not the MXU —
-    owns the window; bench sections run inside one.
+    owns the window.
 
     Caveat: trace-time byte counters against a runtime window means a
     window that triggers compilation attributes the whole program's

@@ -18,7 +18,7 @@ it from a stdlib ``http.server`` daemon thread:
   ladder's demotion state — HTTP 503 once a ladder has demoted to its
   terminal ``<none>`` rung (the instance lost a capability class);
 * ``GET /vars``     — the flop-enriched ``obs.dump()`` snapshot as
-  JSON (same shape ``bench.py`` embeds as ``detail.obs``).
+  JSON.
 
 Arming: ``SLATE_TPU_METRICS_PORT=<port>`` at startup (also enables
 the metrics registry — a live exporter over a dead registry scrapes

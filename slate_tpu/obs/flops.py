@@ -1,8 +1,8 @@
 """Flop accounting: closed-form operation counts per routine.
 
 The table follows the LAPACK Users' Guide / LAWN 41 conventions the
-repo's bench has always used (bench.py potrf n³/3, gemm 2n³, getrf
-2n³/3, geqrf 2mn² − 2n³/3), generalized to rectangular shapes, so a
+repo has always used (potrf n³/3, gemm 2n³, getrf 2n³/3, geqrf
+2mn² − 2n³/3), generalized to rectangular shapes, so a
 span labeled ``routine=…`` plus its dims can report achieved GFLOP/s
 without the call site hand-computing a formula.
 
@@ -130,10 +130,10 @@ def flop_count(routine: str, **dims) -> float | None:
 
 
 # Per-(platform, dtype) peak GFLOP/s for %-of-peak. Only entries the
-# repo has measured/stated are listed (bench.py pins the v5e bf16
-# peak); everything else reports no pct_peak rather than a guess.
+# repo has measured/stated are listed (the v5e bf16 peak is Google's
+# published one); everything else reports no pct_peak rather than a guess.
 PEAK_GFLOPS = {
-    ("tpu", "bfloat16"): 197e3,       # v5e bf16 (bench.py)
+    ("tpu", "bfloat16"): 197e3,       # v5e bf16
 }
 
 

@@ -11,8 +11,7 @@ them around interesting regions:
   live bytes at entry and exit plus the allocator peak, and when the
   region exits holding more live bytes than it entered with, counts
   the growth as ``hbm.leak_bytes{section=…}`` and drops an instant —
-  the ~4.5 GB section-leak class ``bench.py``'s cleanup hooks exist
-  to contain becomes a number instead of an OOM three sections later.
+  a leak becomes a number instead of an OOM three regions later.
 
 Degradation contract: a platform without ``memory_stats`` (CPU) makes
 every entry point a cheap no-op returning ``None`` — telemetry must
@@ -77,8 +76,7 @@ class watch:
 
     After exit, ``self.stats`` is ``{"pre_live_bytes",
     "post_live_bytes", "peak_bytes", "delta_bytes"}`` (or ``None`` on
-    a statless platform) — ``bench.py`` attaches it to the section
-    row.
+    a statless platform).
     """
 
     __slots__ = ("name", "device", "stats", "_pre")

@@ -1,8 +1,8 @@
 """slatepulse SLO attainment: ``python -m slate_tpu.obs slo``.
 
 Renders a per-(tenant, slo_class) attainment table from an
-``obs.dump()`` metrics snapshot (the same document ``bench.py`` embeds
-as ``detail.obs`` and ``/vars`` serves live):
+``obs.dump()`` metrics snapshot (the same document ``/vars`` serves
+live):
 
 * goodput verdict counts from the ``serve.goodput`` counters
   (in_slo | late | shed — the scheduler attributes every terminal

@@ -1,15 +1,14 @@
-"""Host-side timing discipline of bench.py.
+"""Host-side timing discipline of the tuning sweep.
 
-THE single source of truth for the subtract-a-round-trip logic that
-used to live as hand-rolled ``perf_counter`` code in bench.py: every
+THE single source of truth for the subtract-a-round-trip logic: every
 timed program reduces its output to a scalar materialized to the host
 (``float(...)``), and a measured dispatch round trip (the host wall of
-a trivial jitted program) is subtracted from each sample.  Whether
-that subtraction survives is the benchmark's call (ROADMAP S2).
+a trivial jitted program) is subtracted from each sample.  PR 24 judged
+the method wrong for a chip (PERF.md §6); ``tune/sweep.py`` is its one
+caller left, and it goes with that (ROADMAP D6).
 slatelint rule SL008 bans raw
-``time.perf_counter`` timing outside ``slate_tpu/obs``,
-``robust/watchdog.py``, and ``bench.py`` so this discipline cannot
-fork again.
+``time.perf_counter`` timing outside ``slate_tpu/obs`` and
+``robust/watchdog.py`` so this discipline cannot fork again.
 
 All helpers optionally record an obs span (``name=``/``labels=``) so
 a timed region lands in the trace + metrics table automatically.

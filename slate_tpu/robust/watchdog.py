@@ -19,8 +19,7 @@ host-side section the same structured contract:
   rerunning, demoting to from-scratch (a logged ladder demotion) only
   when no valid checkpoint exists;
 * :func:`run_watched` — deadline + retry + cleanup in one call,
-  returning a :class:`SectionRecord` instead of leaking exceptions
-  (the shape bench.py's cumulative JSON needs);
+  returning a :class:`SectionRecord` instead of leaking exceptions;
 * :func:`checked_run` — the subprocess.run wrapper used by every
   native-compile call site (``runtime/__init__.py``,
   ``c_api/__init__.py``, ``internal/band_bulge_native.py``): honours

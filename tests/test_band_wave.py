@@ -74,9 +74,8 @@ def test_wave_band1_falls_back():
 
 # ---------------------------------------------------------------------------
 # VMEM-resident Pallas chaser (internal/band_wave_vmem.py) — interpret
-# mode on the CPU test mesh; the compiled path is exercised on TPU by
-# bench.py's heev2_split/gesvd2_split rows (which select the vmem
-# backend whenever vmem_applies holds) and the hb2st/tb2bd dispatches
+# mode on the CPU test mesh; no benchmark cell runs the compiled path
+# on a TPU yet (ROADMAP R7)
 # ---------------------------------------------------------------------------
 
 from slate_tpu.internal.band_wave_vmem import (hb2st_wave_vmem,

@@ -466,3 +466,28 @@ def test_cli_stats_and_clear(tmp_path):
     out = _run([sys.executable, "-m", "slate_tpu.cache", "stats",
                 "--json"], env)
     assert json.loads(out)["entries"] == 0
+
+
+# ---------------------------------------------------------------------------
+# where jax's own persistent compile cache goes (cache/xla_cache.py)
+# ---------------------------------------------------------------------------
+
+def test_cache_placement_env_set_sets_nothing_in_code(monkeypatch):
+    from slate_tpu.cache import xla_cache
+    monkeypatch.setenv(xla_cache.ENV, "/some/dir")
+    prev = getattr(jax.config, xla_cache.OPTION)
+    assert xla_cache.place_jax_compile_cache() == "/some/dir"
+    assert getattr(jax.config, xla_cache.OPTION) == prev
+
+
+def test_cache_placement_env_unset_uses_the_checkout(monkeypatch):
+    from slate_tpu.cache import xla_cache
+    monkeypatch.delenv(xla_cache.ENV, raising=False)
+    prev = getattr(jax.config, xla_cache.OPTION)
+    want = os.path.join(xla_cache.CHECKOUT, ".jax_cache")
+    try:
+        assert xla_cache.place_jax_compile_cache() == want
+        assert getattr(jax.config, xla_cache.OPTION) == want
+    finally:
+        jax.config.update(xla_cache.OPTION, prev)
+    assert Path(xla_cache.CHECKOUT) == Path(__file__).resolve().parents[1]

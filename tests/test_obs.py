@@ -413,35 +413,6 @@ def test_jit_events_counted_via_monitoring_hooks():
     assert obs.jit_event_total() > 0
 
 
-def test_bench_embeds_obs_snapshot_in_detail(capsys):
-    """bench's cumulative JSON line carries detail.obs when metrics
-    are armed — per-phase spans flop-enriched (the PR-4 acceptance:
-    potrf and getrf rows each report achieved GFLOP/s)."""
-    import bench
-    obs.metrics_on()
-    d = bench.RESULT["detail"]
-    try:
-        obs.record_span("bench.potrf", 0.25, routine="potrf",
-                        n=16384, nb=512)
-        obs.record_span("bench.getrf", 0.5, routine="getrf",
-                        n=16384, nb=512)
-        bench.run_section("obs_unit", lambda: None, cap_s=30)
-        line = capsys.readouterr().out.strip().splitlines()[-1]
-        snap = json.loads(line)["detail"]["obs"]
-        assert snap["metrics_enabled"]
-        spans = {s["name"]: s for s in snap["spans"]}
-        assert spans["bench.potrf"]["gflops"] == pytest.approx(
-            (16384 ** 3 / 3) / 0.25 / 1e9)
-        assert spans["bench.getrf"]["gflops"] == pytest.approx(
-            (16384 ** 3 - 16384 ** 3 / 3) / 0.5 / 1e9)
-        assert "bench.obs_unit" in spans       # run_section's own span
-    finally:
-        d.pop("obs", None)
-        d.pop("obs_unit_wall_s", None)
-        if "obs_unit" in d["sections"]:
-            d["sections"].remove("obs_unit")
-
-
 # ---------------------------------------------------------------------------
 # the chaos contract: every injected fault is visible in obs
 # ---------------------------------------------------------------------------

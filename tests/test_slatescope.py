@@ -412,40 +412,3 @@ def test_disk_hit_restores_cost_attribution(tmp_path):
         jitcache.clear_in_process()
         cstore.reset_cache_dir()
 
-
-# ---------------------------------------------------------------------------
-# bench integration: every section row carries a roofline class
-# ---------------------------------------------------------------------------
-
-def test_run_section_emits_roofline_and_host_rows(capsys):
-    import bench
-    obs.metrics_on()
-    d = bench.RESULT["detail"]
-    try:
-        bench.run_section(
-            "scope_unit",
-            lambda: bench.record_routine_span(
-                "bench.gemm", 0.05, routine="gemm", m=1024, n=1024,
-                k=1024, platform="cpu", dtype="float32"),
-            cap_s=30)
-        (row,) = d["scope_unit_roofline"]
-        assert row["bound"] == "compute"
-        assert row["ai"] > 0 and row["bytes"] > 0
-        assert row["span"] == "bench.gemm"
-        # a section with no routine span still gets a classified row
-        bench.run_section("scope_host", lambda: None, cap_s=30)
-        (host,) = d["scope_host_roofline"]
-        assert host["bound"] == "host"
-        # the cumulative JSON line is still parseable with the rows in
-        line = capsys.readouterr().out.strip().splitlines()[-1]
-        parsed = json.loads(line)
-        assert parsed["detail"]["scope_unit_roofline"][0][
-            "bound"] == "compute"
-    finally:
-        for k in ("scope_unit_roofline", "scope_unit_wall_s",
-                  "scope_host_roofline", "scope_host_wall_s",
-                  "scope_unit_hbm", "scope_host_hbm", "obs"):
-            d.pop(k, None)
-        for name in ("scope_unit", "scope_host"):
-            if name in d["sections"]:
-                d["sections"].remove(name)

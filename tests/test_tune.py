@@ -3,15 +3,12 @@ stale-fingerprint invalidation → corrupt quarantine), driver_config
 pinning semantics, the cached_jit key token, the two-process pinning
 proof (process A sweeps and persists; a fresh process B resolves the
 tuned config with ``tune.pinned`` ≥ 1 and zero sweeps, and its
-persisted executable keys carry the table token), and the bench
-admission gate satellite (evaluated BEFORE the watchdog arms)."""
+persisted executable keys carry the table token)."""
 
-import contextlib
 import json
 import os
 import subprocess
 import sys
-import time
 from pathlib import Path
 
 import numpy as np
@@ -280,92 +277,3 @@ def test_two_process_sweep_then_pinned(tmp_path):
                       and k.startswith("tune:"))
     assert got["TOKEN"] in tokens, (got["TOKEN"], tokens)
 
-
-# ---------------------------------------------------------------------------
-# bench admission gate (satellite 1)
-# ---------------------------------------------------------------------------
-
-@pytest.fixture
-def bench_mod():
-    import bench
-    d = bench.RESULT["detail"]
-    keys_before = set(d)
-    sections_before = list(d["sections"])
-    yield bench
-    for k in set(d) - keys_before:
-        d.pop(k, None)
-    d["sections"][:] = sections_before
-
-
-def test_run_section_admission_skips_before_watchdog(bench_mod,
-                                                     monkeypatch,
-                                                     capsys):
-    bench = bench_mod
-    metrics.enable()
-    armed_deadlines = []
-
-    @contextlib.contextmanager
-    def recording_deadline(name, cap, **kw):
-        armed_deadlines.append((name, cap))
-        yield
-
-    monkeypatch.setattr(bench._watchdog, "deadline", recording_deadline)
-    ran = []
-    bench.run_section(
-        "adm_unit", lambda: ran.append(1), cap_s=30,
-        admission=lambda: {"reason_code": "below_warm_wall",
-                           "need_s": 150.0})
-    capsys.readouterr()
-    d = bench.RESULT["detail"]
-    assert ran == []                       # fn never started
-    assert armed_deadlines == []           # watchdog never armed
-    assert d["adm_unit_skipped"]["reason_code"] == "below_warm_wall"
-    assert "adm_unit" not in d["sections"]
-    assert metrics.counter_total("bench.admission_skip") >= 1
-
-
-def test_run_section_admission_admits_when_none(bench_mod, capsys):
-    bench = bench_mod
-    ran = []
-    bench.run_section("adm_ok", lambda: ran.append(1), cap_s=30,
-                      admission=lambda: None)
-    capsys.readouterr()
-    assert ran == [1]
-    assert "adm_ok" in bench.RESULT["detail"]["sections"]
-
-
-def test_run_section_admission_gate_error_skips(bench_mod, capsys):
-    bench = bench_mod
-    ran = []
-
-    def broken():
-        raise RuntimeError("boom")
-
-    bench.run_section("adm_err", lambda: ran.append(1), cap_s=30,
-                      admission=broken)
-    capsys.readouterr()
-    d = bench.RESULT["detail"]
-    assert ran == []
-    assert d["adm_err_skipped"]["reason_code"] == "admission_error"
-
-
-def test_getrf_45056_admission_reason_codes(bench_mod, monkeypatch,
-                                            tmp_path):
-    bench = bench_mod
-    b = bench.Bench()
-    marker = tmp_path / ".getrf45056_compiled"
-    monkeypatch.setattr(bench.Bench, "_GETRF45056_MARKER", str(marker))
-    monkeypatch.setattr(bench, "T_START", time.time())
-    # cold cache, tiny budget → the cold wall refuses admission
-    monkeypatch.setattr(bench, "BUDGET_S", 200.0)
-    v = b.getrf_45056_admission()
-    assert v["reason_code"] == "cold_compile_exceeds_budget"
-    assert v["need_s"] == 750.0
-    # warm marker drops the wall to 150 s
-    marker.touch()
-    assert b.getrf_45056_admission() is None     # 200 s fits warm
-    monkeypatch.setattr(bench, "BUDGET_S", 100.0)
-    v = b.getrf_45056_admission()
-    assert v["reason_code"] == "below_warm_wall"
-    monkeypatch.setattr(bench, "BUDGET_S", 1000.0)
-    assert b.getrf_45056_admission() is None

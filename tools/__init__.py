@@ -1,2 +1,2 @@
-# Repo tooling namespace (slatelint lives here; benchscripts and
-# c_api are plain script directories).
+# Repo tooling namespace (slatelint lives here; c_api is a plain
+# script directory).
