@@ -17,8 +17,12 @@ gbmm.cc, hbmm.cc, tbsm.cc) as functional JAX programs:
 * Ops with transposed/shaped operands are normalized first
   (materialize transposes, mirror Hermitian halves, zero triangles) —
   the analog of SLATE's gemmA/gemmC/hemmA… Method variants collapses
-  to data normalization + one SUMMA core, because XLA re-shards
-  automatically where SLATE had to pick a stationary operand.
+  to data normalization + one SUMMA core. XLA does not pick the
+  stationary operand, though: a collective written into a
+  ``shard_map`` body moves what it is given. ``trsm(Side.Left)``
+  chooses as the reference's trsmA / trsmB do, from the shape of B
+  alone (``_moves_x``): a B of one tile column leaves A where it is
+  stored and moves block-rows of X.
 
 All routines return the updated output matrix (functional style) —
 SLATE mutates C in place; here ``C = gemm(alpha, A, B, beta, C)``.
@@ -540,6 +544,14 @@ def trsm(side: Side, alpha, A, B: Matrix, opts=None):
     which solves left-looking (the reference's trsmA reduce shape), so
     no transpose materializes. ``Side.Right`` with an op still
     resolves it by ``A.materialize()`` (a re-laid copy of A) first.
+
+    ``Side.Left`` on a grid with q > 1 picks its stationary operand by
+    the width of B (``_moves_x``; the span's ``form`` label and the
+    counter ``trsm.move_x`` say which): a B of at most one tile column
+    (``B.n <= nb``: ``potrs``/``getrs`` with a few right-hand sides)
+    leaves A in place and moves ``[nb, w]`` block-rows of X over q
+    (work::trsmA); a wider B, whose columns are spread over q, has the
+    tile column k of A broadcast over q each step (work::trsm).
     """
     op = {Op.NoTrans: "N", Op.Trans: "T", Op.ConjTrans: "C"}[A.op]
     with trace.block("trsm", op=op) as span:
@@ -549,9 +561,12 @@ def trsm(side: Side, alpha, A, B: Matrix, opts=None):
             # narrower than its storage is solved at its own width
             ntl = B.data.shape[3]
             w = _carried_cols(B.n, B.nb, B.grid.q, ntl)
-            span.label(nrhs=B.n, w=w)
+            move_x = _moves_x(B.n, B.nb, B.grid.q)
+            span.label(nrhs=B.n, w=w, form="move_x" if move_x else "move_a")
             if w < ntl * B.nb:
                 obs.count("trsm.narrow", 1, op=op)
+            if move_x:
+                obs.count("trsm.move_x", 1, op=op)
         else:
             span.label(nrhs=B.m, w=B.data.shape[2] * B.nb)
         flags = {}
@@ -596,6 +611,20 @@ def _carried_cols(n: int, nb: int, q: int, ntl: int) -> int:
     return min(cdiv(max(real, 1), LANES) * LANES, ntl * nb)
 
 
+def _moves_x(n: int, nb: int, q: int) -> bool:
+    """Whether a left solve against an ``n``-column B on ``q`` device
+    columns keeps A where it is stored and moves X over q instead.
+
+    True when there is an axis to move over and B lies in one tile
+    column: tile column 0 sits on device column 0 (``Matrix.sub``
+    returns a re-laid copy, so no view shifts it), the other device
+    columns hold only padding, and a block-row of X (``[nb, w]``) is
+    smaller than the column of A (``[mtl, nb, nb]``) it meets. A wider
+    B spreads X's columns over q: every device column then has work
+    for the A it receives, and X whole would cost q times its memory."""
+    return q > 1 and n <= nb
+
+
 @partial(cached_jit, static_argnames=("lower", "unit", "trans", "conj"))
 def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
     """op(A)·X = alpha·B on A's storage: ``lower`` names the stored
@@ -605,7 +634,21 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
     Σ_i A(i,k)ᴴ·X(i,:)) over the rows i already solved: tile A(i,k)
     and tile row X(i,:) share mesh row i % p, so each device contracts
     its own slots of column k and one reduce down the mesh column
-    stands where the right-looking form broadcasts X(k,:)."""
+    stands where the right-looking form broadcasts X(k,:).
+
+    Over q one operand has to travel each step. By default it is tile
+    column k of A (``bcast_from_col``, ``[mtl, nb, nb]``), which meets
+    the columns of X that each device column stores. When B is one
+    tile column (``_moves_x``) A stays and X travels: device column
+    k % q alone reads column k from its own storage, and what crosses
+    q is ``[nb, w]``. NoTrans then keeps on each device column its own
+    share of the running right-hand side (device column 0 starts from
+    alpha·B, the others from zero; their sum is alpha·B(i,:) −
+    Σ_j A(i,j)·X(j,:) over the rows j solved) and sums block-row k over
+    q before solving it; an op keeps X whole on every device column,
+    contracts column k tile by tile in the order A is stored in, and
+    reduces the partial sums over q as well as over p. Either way X
+    leaves as it is stored: on device column 0, exact zeros beside."""
     g = B.grid
     p, q, nb = g.p, g.q, B.nb
     mt = cdiv(A.m, nb)
@@ -614,6 +657,7 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
     # side by side, cut to the columns that are real on some device.
     # The rest is B's zero padding, whose solution is zero.
     w = _carried_cols(B.n, nb, q, ntl)
+    move_x = _moves_x(B.n, nb, q)
     # policy (internal/precision.py): triangular solves always bf16_6x
     pk6 = trailing_dot_kwargs("bf16_6x", B.dtype)
 
@@ -633,6 +677,51 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
         x = x.transpose(0, 2, 1, 3).reshape(mtl, nb, ntl * nb)[:, :, :w]
         x = x * alpha
         gi = masks.local_tile_rows(mtl, p)               # [mtl]
+        if move_x:
+            # alpha·B is device column 0's; the others bring nothing
+            x = jnp.where(c == 0, x, jnp.zeros_like(x))
+            if trans:
+                x = comm.psum_cols(x)        # X whole on every device column
+
+        def past_diag(k):
+            """Which local slots of tile column k of A lie past the
+            diagonal tile: the rows left to update (NoTrans), the rows
+            already solved (op). With A stationary, none but on the
+            device column that stores column k."""
+            rows = (gi > k) if lower else (gi < k)
+            return rows & (c == k % q) if move_x else rows
+
+        def column(k):
+            """Those slots of tile column k, the rest zero."""
+            acol = lax.dynamic_index_in_dim(a, k // q, axis=1, keepdims=False)
+            if not move_x:
+                acol = comm.bcast_from_col(acol, k % q)      # [mtl, nb, nb]
+            return jnp.where(past_diag(k)[:, None, None], acol,
+                             jnp.zeros_like(acol))
+
+        def column_h_times(k, x):
+            """Σ_i A(i,k)ᴴ · X(i,:) over this device's slots of tile
+            column k past the diagonal tile."""
+            if not move_x:
+                acol = column(k)
+                if conj:
+                    acol = jnp.conj(acol)
+                return jnp.einsum("aki,akj->ij", acol, x, **pk6)
+            # A stationary: read tile by tile, in the order A is stored
+            # in. One product over all the slots wants tile column k
+            # contiguous, and XLA then re-orders a copy of all the
+            # local A before the loop (PERF 7, fault 3a)
+            rows = past_diag(k)
+
+            def slot(s, acc):
+                tile = lax.dynamic_slice(
+                    a, (s, k // q, 0, 0), (1, 1, nb, nb))[0, 0]
+                tile = jnp.where(rows[s], tile, jnp.zeros_like(tile))
+                if conj:
+                    tile = jnp.conj(tile)
+                return acc + jnp.einsum("ki,kj->ij", tile, x[s], **pk6)
+
+            return lax.fori_loop(0, mtl, slot, jnp.zeros((nb, w), x.dtype))
 
         def step(t, x):
             k = t if lower else mt - 1 - t
@@ -640,20 +729,18 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
                 tri = diag_tile(a, k)
                 # owner row solves its block-row k
                 xrow = lax.dynamic_index_in_dim(x, k // p, axis=0, keepdims=False)
+                # A stationary: x is this device column's share of the
+                # running right-hand side, block-row k the sum of them
+                rhs = comm.psum_cols(xrow) if move_x else xrow   # [nb, w]
                 solved = lax.linalg.triangular_solve(
-                    tri, xrow, left_side=True, lower=lower,
+                    tri, rhs, left_side=True, lower=lower,
                     unit_diagonal=unit)
                 xrow = jnp.where(r == k % p, solved, xrow)
                 x = lax.dynamic_update_index_in_dim(x, xrow, k // p, axis=0)
             with jax.named_scope("update"):
                 xrow_b = comm.bcast_from_row(xrow, k % p)    # [nb, w]
                 # trailing update: B(i,:) -= A(i,k) · X(k,:) for remaining i
-                acol = lax.dynamic_index_in_dim(a, k // q, axis=1, keepdims=False)
-                acol = comm.bcast_from_col(acol, k % q)      # [mtl, nb, nb]
-                rem = (gi > k) if lower else (gi < k)
-                acol = jnp.where(rem[:, None, None], acol,
-                                 jnp.zeros_like(acol))
-                upd = jnp.einsum("aik,kj->aij", acol, xrow_b, **pk6)
+                upd = jnp.einsum("aik,kj->aij", column(k), xrow_b, **pk6)
                 return x - upd
 
         def step_op(t, x):
@@ -662,15 +749,10 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
             k = mt - 1 - t if lower else t
             with jax.named_scope("update"):
                 # Σ_i A(i,k)ᴴ · X(i,:) over the rows already solved
-                acol = lax.dynamic_index_in_dim(a, k // q, axis=1, keepdims=False)
-                acol = comm.bcast_from_col(acol, k % q)      # [mtl, nb, nb]
-                done = (gi > k) if lower else (gi < k)
-                acol = jnp.where(done[:, None, None], acol,
-                                 jnp.zeros_like(acol))
-                if conj:
-                    acol = jnp.conj(acol)
-                acc = jnp.einsum("aki,akj->ij", acol, x, **pk6)
+                acc = column_h_times(k, x)
                 acc = comm.psum_rows(acc)                    # [nb, w]
+                if move_x:
+                    acc = comm.psum_cols(acc)
             with jax.named_scope("diag_solve"):
                 tri = diag_tile(a, k)
                 xrow = lax.dynamic_index_in_dim(x, k // p, axis=0, keepdims=False)
@@ -681,6 +763,10 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
                 return lax.dynamic_update_index_in_dim(x, xrow, k // p, axis=0)
 
         x = lax.fori_loop(0, mt, step_op if trans else step, x)
+        if move_x:
+            # the solved rows sit on every device column: X is stored
+            # on device column 0, exact zeros beside it
+            x = jnp.where(c == 0, x, jnp.zeros_like(x))
         x = jnp.pad(x, ((0, 0), (0, 0), (0, ntl * nb - w)))
         x = x.reshape(mtl, nb, ntl, nb).transpose(0, 2, 1, 3)
         return x[None, None]

@@ -53,6 +53,24 @@ def spd(n, dtype=np.float64, seed=0):
     return (g @ np.conj(g.T) / n + np.eye(n)).astype(dtype)
 
 
+def all_reduce_shapes(hlo_text):
+    """(bytes an element, dims) of every result of every all-reduce in
+    an optimized HLO text, the operands XLA combined into one tuple
+    included."""
+    import re
+    shapes = []
+    for line in hlo_text.splitlines():
+        head, found, _ = line.partition(" all-reduce(")
+        if not found:
+            continue
+        for dt, dims in re.findall(r"\b([fcsu]\d+)\[([\d,]*)\]",
+                                   head.split("=", 1)[1]):
+            shapes.append((int(dt[1:]) // 8,
+                           tuple(int(d) for d in filter(None,
+                                                        dims.split(",")))))
+    return shapes
+
+
 @pytest.fixture
 def nprand():
     return rand

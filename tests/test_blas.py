@@ -7,7 +7,7 @@ import pytest
 
 import slate_tpu as st
 from slate_tpu.types import Side, Uplo, Diag, Op
-from tests.conftest import rand
+from tests.conftest import all_reduce_shapes, rand
 
 
 def tri(a, lower, unit=False):
@@ -263,20 +263,10 @@ def test_trsm_left_op_of_a_general_matrix_reads_its_upper_triangle(grid24):
 
 def _all_reduce_bytes(hlo_text):
     """Bytes of every result of every all-reduce in an optimized HLO
-    text, the operands XLA combined into one tuple included."""
-    import re
-    total = 0
-    for line in hlo_text.splitlines():
-        head, found, _ = line.partition(" all-reduce(")
-        if not found:
-            continue
-        for dt, dims in re.findall(r"\b([fcsu]\d+)\[([\d,]*)\]",
-                                   head.split("=", 1)[1]):
-            n = int(dt[1:]) // 8
-            for d in filter(None, dims.split(",")):
-                n *= int(d)
-            total += n
-    return total
+    text."""
+    import math
+    return sum(size * math.prod(dims)
+               for size, dims in all_reduce_shapes(hlo_text))
 
 
 def _lower_trsm_left(grid, n, nb, nrhs, trans):
@@ -296,24 +286,32 @@ def _lower_trsm_left(grid, n, nb, nrhs, trans):
 
 @pytest.mark.parametrize("trans", [False, True])
 def test_trsm_left_lowering_collectives(grid22, trans):
-    """The lowered 2x2 program: an op adds no all-gather and no
-    all-to-all (the re-layout materialize() paid), one reduce down the
-    mesh column stands for the row bcast, and the NoTrans program's
-    collectives are what they were."""
+    """The lowered 2x2 program for a B of one tile column (A stays, X
+    moves): an op adds no all-gather and no all-to-all (the re-layout
+    materialize() paid), and no all-reduce carries the local slots of a
+    tile column of A."""
+    import math
     from slate_tpu.internal import comm
-    compiled = _lower_trsm_left(grid22, 64, 8, 4, trans)
+    n, nb, nrhs, mtl, w = 600, 256, 8, 2, 128
+    compiled = _lower_trsm_left(grid22, n, nb, nrhs, trans)
     stats = comm.collective_footprint(compiled)
     assert "all-gather" not in stats and "all-to-all" not in stats
-    # a step moves the diagonal tile over both axes, column k over q,
-    # and over p the solved row (NoTrans) or the partial sums (op);
-    # XLA sends two of the four together: three all-reduces, as before
     assert set(stats) == {"all-reduce"}
+    text = compiled.as_text()
+    assert max(math.prod(dims) for _, dims in all_reduce_shapes(text)) \
+        < mtl * nb * nb
+    # a step moves the diagonal tile over both axes and two [nb, w]
+    # terms: the sum of block-row k's shares over q and the solved row
+    # over p (NoTrans), the partial sums over p and over q (op); XLA
+    # sends two of the four together: three all-reduces a step. An op
+    # first makes B whole on every device column, [mtl, nb, w] once.
+    # (collective_footprint's own ``bytes`` reads only the first shape
+    # of a combined all-reduce, whose order XLA picks: PERF 7, fault 11.)
     assert stats["all-reduce"]["count"] == 3
-    if not trans:
-        # [8,8] + ([8,8], [4,8,8]) + [8,8]; collective_footprint's own
-        # ``bytes`` reads only the first shape of a combined all-reduce,
-        # whose order XLA picks (768 or 1536 for these same collectives)
-        assert _all_reduce_bytes(compiled.as_text()) == 1792
+    step = 2 * nb * nb + 2 * nb * w
+    once = mtl * nb * w if trans else 0
+    # (the program that moved A: 2 nb nb + mtl nb nb + nb w a step)
+    assert _all_reduce_bytes(text) == 4 * (step + once)
 
 
 # -- a B narrower than its storage rides the solve at its own width --------
@@ -336,6 +334,20 @@ def test_trsm_carried_cols(n, nb, q, ntl, w):
     assert blas._carried_cols(n, nb, q, ntl) == w
 
 
+@pytest.mark.parametrize("n,nb,q,moves", [
+    (8, 1024, 2, True),         # the benchmark's 2x2: A stays, X moves
+    (8, 1024, 1, False),        # its one-chip cells: no axis to move over
+    (8, 384, 1, False),
+    (256, 256, 2, True),        # n = nb: still one tile column
+    (257, 256, 2, False),       # n = nb + 1: X's columns spread over q
+    (8, 256, 4, True), (256, 256, 4, True), (512, 256, 4, False),
+    (1024, 256, 4, False), (1, 8, 2, True), (9, 8, 2, False),
+])
+def test_trsm_moves_x(n, nb, q, moves):
+    from slate_tpu.ops import blas
+    assert blas._moves_x(n, nb, q) is moves
+
+
 def _padded_dense(M):
     from slate_tpu.matrix import bc_to_tiles, tiles_to_dense
     tiles = bc_to_tiles(M.data)
@@ -343,37 +355,56 @@ def _padded_dense(M):
                                      tiles.shape[1] * M.nb))
 
 
-@pytest.mark.parametrize("nrhs", [1, 5, 130, 256, 300])
+def _narrow_b_cases():
+    """Every width on the three older shapes; on 1x2 and 4x2, which are
+    here for the form that moves X, the widths that take it (nrhs <= nb,
+    its edge nrhs = nb included)."""
+    widths = [(1, Diag.NonUnit), (5, Diag.NonUnit), (5, Diag.Unit),
+              (130, Diag.NonUnit), (256, Diag.NonUnit), (300, Diag.NonUnit)]
+    for shape in ["1x1", "2x2", "2x4", "1x2", "4x2"]:
+        for nrhs, diag in widths:
+            if shape in ("1x2", "4x2") and nrhs not in (5, 256):
+                continue
+            yield pytest.param(shape, nrhs, diag,
+                               id=f"{shape}-{nrhs}-{diag.name}")
+
+
 @pytest.mark.parametrize("uplo", [Uplo.Lower, Uplo.Upper])
 @pytest.mark.parametrize("op", ["n", "t", "c"])
-@pytest.mark.parametrize("shape", ["1x1", "2x2", "2x4"])
-def test_trsm_left_narrow_b(shape, op, uplo, nrhs):
+@pytest.mark.parametrize("shape,nrhs,diag", _narrow_b_cases())
+def test_trsm_left_narrow_b(shape, op, uplo, nrhs, diag):
     """8 right-hand sides in a 256-wide tile: the answer, the stored
-    padding, and the same columns out of the full-width program."""
+    padding, and the same columns out of a B of two or more tile
+    columns, which on a grid is the other form (A moves, not X)."""
     import jax
     p, q = map(int, shape.split("x"))
     grid = st.Grid(p, q, devices=jax.devices()[:p * q])
     dt = np.complex64 if op == "c" else np.float32
     n, nb = 600, 256
+    unit = diag == Diag.Unit
     a = rand(n, n, dt, 40) * 0.3 + n * np.eye(n, dtype=dt)
-    t = tri(a, uplo == Uplo.Lower)
+    if unit:
+        a = a / n               # off-diagonal small beside the implied 1
+    t = tri(a, uplo == Uplo.Lower, unit)
     opt = {"n": t, "t": t.T, "c": np.conj(t.T)}[op]
     view = {"n": lambda x: x, "t": st.transpose,
             "c": st.conj_transpose}[op]
     b = rand(n, nrhs, dt, 41)
-    A = st.TriangularMatrix.from_dense(a, nb=nb, grid=grid, uplo=uplo)
+    A = st.TriangularMatrix.from_dense(a, nb=nb, grid=grid, uplo=uplo,
+                                       diag=diag)
     X = st.trsm(Side.Left, 1.5, view(A),
                 st.Matrix.from_dense(b, nb=nb, grid=grid))
     x = np.asarray(X.to_dense())
     ref = np.linalg.solve(opt.astype(np.complex128), 1.5 * b)
     assert np.abs(x - ref).max() <= 2e-6 * np.abs(ref).max()
-    # the padding of X is stored as exact zeros
+    # the padding of X is stored as exact zeros, on every device column
     stored = _padded_dense(X)
     assert stored.shape[1] >= nrhs and stored.shape[0] >= n
     assert not stored[:, nrhs:].any() and not stored[n:].any()
     np.testing.assert_array_equal(stored[:n, :nrhs], x)
-    # B zero-extended to whole tiles of real columns: nothing to crop
-    wide = -(-nrhs // nb) * nb
+    # B zero-extended to whole tiles of real columns, two at least:
+    # nothing to crop, and no tile column that holds all of X
+    wide = max(-(-nrhs // nb), 2) * nb
     bw = np.zeros((n, wide), dt)
     bw[:, :nrhs] = b
     Xw = st.trsm(Side.Left, 1.5, view(A),
@@ -398,11 +429,12 @@ def test_trsm_left_narrow_b_pays_for_its_lanes(grid22, trans, n, nb):
 @pytest.mark.parametrize("trans,flops", [(False, 68813928.0),
                                          (True, 68617312.0)])
 def test_trsm_left_full_width_program_is_the_one_it_was(grid22, trans, flops):
-    """Every stored column real (nrhs = nb): the crop and the pad are the
-    identity, and the 2x2 program does what it did when it carried
-    tiles (numbers read off the parent of PR 28)."""
+    """A B of q whole tile columns (nrhs = 2 nb on the 2x2): every stored
+    column real, the crop and the pad the identity, X's columns spread
+    over q. The program moves A as it did (numbers read off the parent
+    of PR 30, which are those of the parent of PR 28)."""
     from slate_tpu.internal import comm
-    compiled = _lower_trsm_left(grid22, 600, 256, 256, trans)
+    compiled = _lower_trsm_left(grid22, 600, 256, 512, trans)
     assert compiled.cost_analysis()["flops"] == flops
     stats = comm.collective_footprint(compiled)
     assert set(stats) == {"all-reduce"}

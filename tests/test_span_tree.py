@@ -66,6 +66,12 @@ def _posv_operands(grid, n=256, nb=64):
     return A, B
 
 
+def _moved_x():
+    """``trsm.move_x`` by its ``op`` label."""
+    return {dict(labels)["op"]: v for labels, v
+            in metrics.counters_named("trsm.move_x").items()}
+
+
 def _tree(spans):
     by_id = {s["id"]: s for s in spans}
 
@@ -111,6 +117,9 @@ def test_posv_span_tree_has_parents_and_one_solve_id(grid_name, request,
         assert not [x for x in paths if "matrix.materialize" in x]
         trsms = [s for s in mine if s["name"] == "trsm"]
         assert [s["labels"]["op"] for s in trsms] == ["N", "C"]
+        # B is one tile column: on a grid A stays and X moves over q
+        assert [s["labels"]["form"] for s in trsms] == [
+            "move_a" if grid.q == 1 else "move_x"] * 2
         # children lie inside their parents, on one clock
         by_id = {s["id"]: s for s in mine}
         for s in mine:
@@ -123,18 +132,28 @@ def test_posv_span_tree_has_parents_and_one_solve_id(grid_name, request,
     assert len(chunks) == (1 if grid.size == 1 else 2)
     assert metrics.counter_total("trsm.in_place") == len(roots)
     assert metrics.counter_total("matrix.relayout_bytes") == 0
+    assert _moved_x() == ({} if grid.q == 1 else {"N": len(roots),
+                                                  "C": len(roots)})
 
 
-@pytest.mark.parametrize("nrhs,w,narrow", [(8, 128, 2), (256, 256, 0)])
-def test_trsm_span_says_the_width_it_carried(grid11, profiler, nrhs, w,
-                                             narrow):
+@pytest.mark.parametrize("grid_name,nrhs,w,narrow,form", [
+    ("grid11", 8, 128, 2, "move_a"), ("grid11", 256, 256, 0, "move_a"),
+    ("grid22", 8, 128, 2, "move_x"), ("grid22", 256, 256, 0, "move_x"),
+    ("grid22", 512, 256, 0, "move_a"),      # two tile columns: X spread over q
+])
+def test_trsm_span_says_the_width_it_carried(request, profiler, grid_name,
+                                             nrhs, w, narrow, form):
     """8 right-hand sides in a 256-wide tile ride both solves of a posv
-    at 128 columns; a B of whole tiles is carried as it is stored."""
+    at 128 columns; a B of whole tiles is carried as it is stored. On a
+    grid a B of one tile column stays put while X moves (``form``,
+    ``trsm.move_x`` once a solve and op); on one chip, or with a wider
+    B, never."""
+    grid = request.getfixturevalue(grid_name)
     n, nb = 512, 256
     A = st.HermitianMatrix.from_dense(spd(n, np.float32, seed=5), nb=nb,
-                                      grid=grid11, uplo=st.Uplo.Lower)
+                                      grid=grid, uplo=st.Uplo.Lower)
     B = st.Matrix.from_dense(rand(n, nrhs, np.float32, seed=6), nb=nb,
-                             grid=grid11)
+                             grid=grid)
     jax.block_until_ready(st.posv(A, B))         # compile outside
     obs.reset()
     obs.metrics_on()
@@ -142,10 +161,11 @@ def test_trsm_span_says_the_width_it_carried(grid11, profiler, nrhs, w,
     profiler()
     trsms = [s["labels"] for s in obs.captured_spans()
              if s["name"] == "trsm"]
-    assert [(t["op"], t["nrhs"], t["w"]) for t in trsms] == [
-        ("N", nrhs, w), ("C", nrhs, w)]
+    assert [(t["op"], t["nrhs"], t["w"], t["form"]) for t in trsms] == [
+        ("N", nrhs, w, form), ("C", nrhs, w, form)]
     assert metrics.counter_total("trsm.narrow") == narrow
     assert metrics.counter_total("trsm.in_place") == 1
+    assert _moved_x() == ({"N": 1, "C": 1} if form == "move_x" else {})
 
 
 def test_capture_follows_the_profiler(grid11, profiler):

@@ -25,6 +25,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import slate_tpu as slate
+from tests.conftest import all_reduce_shapes
 
 H, W, NB = 16384, 128, 1024
 F32 = jnp.float32
@@ -158,21 +159,22 @@ def test_getrf_chunk_2x2_compiles_sharded(tpu_grid22):
 
 # -- potrs' second solve: trsm on conj_transpose(L) where it lies ----------
 
-def _trsm_h_by_8(topo, tpu_grid22, shape, trans):
+def _trsm_h_by_8(topo, tpu_grid22, shape, trans, lower=True, unit=False):
     """The cells' solve, L (H x H) against 8 right-hand sides in one
     1024-wide tile column a device column, compiled; the grid it is
     for; and the bytes of its two stored operands."""
     from slate_tpu.ops import blas
     grid = (tpu_grid22 if shape == "2x2"
             else slate.Grid(1, 1, devices=[topo.devices[0]]))
-    L = slate.TriangularMatrix(data=_tiles(grid), m=H, n=H, nb=NB,
-                               grid=grid, uplo=slate.Uplo.Lower)
+    L = slate.TriangularMatrix(
+        data=_tiles(grid), m=H, n=H, nb=NB, grid=grid,
+        uplo=slate.Uplo.Lower if lower else slate.Uplo.Upper)
     b = jax.ShapeDtypeStruct(
         (grid.p, grid.q, H // NB // grid.p, 1, NB, NB), F32,
         sharding=grid.sharding())
     B = slate.Matrix(data=b, m=H, n=8, nb=NB, grid=grid)
     c = blas._trsm_left_jit.lower(jax.ShapeDtypeStruct((), F32), L, B,
-                                  True, False, trans=trans).compile()
+                                  lower, unit, trans=trans).compile()
     return c, grid, (L.data.size + b.size) * 4
 
 
@@ -191,6 +193,19 @@ def test_trsm_left_op_in_place_compiles_with_no_relayout(topo, tpu_grid22,
         assert mem.temp_size_in_bytes < 2 ** 24, mem.temp_size_in_bytes
 
 
+def _assert_a_stays(c, mtl):
+    """The 2x2 program for a B of one tile column: A stays where it is
+    stored, in the order it is stored in. No all-reduce carries the
+    local slots of a tile column of it, nothing gathers it, and the
+    program holds no copy of it."""
+    import math
+    text = c.as_text()
+    assert "all-gather" not in text and "all-to-all" not in text
+    sent = [math.prod(dims) for _, dims in all_reduce_shapes(text)]
+    assert sent and max(sent) <= mtl * NB * W, sent
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 24
+
+
 @pytest.mark.parametrize("trans", [False, True], ids=["N", "C"])
 @pytest.mark.parametrize("shape", ["1x1", "2x2"])
 def test_trsm_left_8_rhs_multiplies_128_lanes(topo, tpu_grid22, shape,
@@ -205,16 +220,29 @@ def test_trsm_left_8_rhs_multiplies_128_lanes(topo, tpu_grid22, shape,
     products = re.findall(
         r"= f32\[([\d,]+)\]\S* (?:convolution|dot)\(", text)
     assert products
-    # the widest is column k of A by X(k,:), [mtl, 1024, 128] (summed
-    # over the mtl tiles under an op); the padded tile's was 8x that
+    # the widest is column k of A by X(k,:), [mtl, 1024, 128] (one tile
+    # of it by its rows of X, [1024, 128], summed, under an op); the
+    # padded tile's was 8x that
     widest = max(math.prod(map(int, dims.split(","))) for dims in products)
     assert widest in (mtl * NB * W, NB * W), widest
     # a step's flops: that product and the diagonal block's solve (the
     # padded tile cost 8x: 35.6e9 on one chip, 18.5e9 on the 2x2)
     assert c.cost_analysis()["flops"] < 1.2 * 2 * mtl * NB * NB * W
     if shape == "2x2":
-        assert "all-gather" not in text and "all-to-all" not in text
-        assert c.memory_analysis().temp_size_in_bytes < 2 ** 24
+        _assert_a_stays(c, mtl)
+
+
+@pytest.mark.parametrize("trans", [False, True], ids=["N", "C"])
+@pytest.mark.parametrize("lower,unit", [(True, True), (False, False),
+                                        (False, True)],
+                         ids=["unit_lower", "upper", "unit_upper"])
+def test_trsm_left_moves_x_for_getrs_too(topo, tpu_grid22, lower, unit,
+                                         trans):
+    """``getrs`` on a grid takes the same form with L unit-lower and U
+    upper, and their op'd twins (first cell to measure it:
+    ``gesv_16k_2x2``, PERF 7)."""
+    c, grid, _ = _trsm_h_by_8(topo, tpu_grid22, "2x2", trans, lower, unit)
+    _assert_a_stays(c, H // NB // grid.p)
 
 
 # -- one served executable ---------------------------------------------------
