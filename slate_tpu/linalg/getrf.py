@@ -110,7 +110,7 @@ def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
             tier, depth = tune.driver_config("getrf", A.n, opts)
             chunked = g.size > 1 and kt >= 2 * lcm_pq
             guard = _superstep.arm("getrf", A, opts, checkpoint, chunked)
-        top.label(precision=tier)
+        top.label(precision=tier, **_panel_labels(A, chunked))
         Anorm = _superstep.norm_one(A, opts) if health else None
         if chunked:
             # chunked super-steps (same scheme as potrf): trailing
@@ -614,6 +614,34 @@ def _fold_now() -> bool:
     return _fold_enabled()
 
 
+def _panel_max_rows(platform: str) -> int | None:
+    """Rows one single-shot ``lax.linalg.lu`` panel may have on
+    ``platform``: a TPU's scoped vmem caps it (tile_kernels.
+    LU_PANEL_MAX_ROWS), nothing else does. A taller panel takes the
+    tournament (CALU) form of ``panel_lu_factor``."""
+    return _LU_PANEL_MAX_ROWS if platform == "tpu" else None
+
+
+def _panel_labels(A, chunked: bool) -> dict:
+    """Labels of a chunked factorization's ``getrf`` span, none
+    otherwise: which pivoting the row cap chose for the gathered
+    panel's height, that height, and the bytes of panels each device
+    receives (``comm.allgather_panel_rows``: every step the whole
+    [M, nb] panel on every device); the bytes also go to the
+    ``getrf.panel_gather_bytes`` counter."""
+    if not chunked:
+        return {}
+    g = A.grid
+    M = A.data.shape[2] * g.p * A.nb
+    cap = _panel_max_rows(g.devices[0].platform)
+    gathered = (min(A.mt, A.nt) * M * A.nb
+                * jnp.dtype(A.dtype).itemsize)
+    obs.count("getrf.panel_gather_bytes", gathered)
+    return {"pivoting": ("tournament" if cap is not None and M > cap
+                         else "partial"),
+            "panel_rows": M, "panel_gather_bytes": gathered}
+
+
 class PivotOrder(NamedTuple):
     """Pivots as an ELIMINATION ORDER instead of a LAPACK swap list:
     ``order[k, j]`` = original row eliminated at step k·nb+j. The LU
@@ -665,19 +693,18 @@ def _getrf_dense_1dev(A, piv_mode, tier=None):
         # the luxury of the unrolled path), so padding never enters the
         # pivot search. The SPMD path must instead scrub+identity-pad
         # uniform full tiles every step (masks.tile_diag_pad_identity).
-        on_tpu = A.grid.devices[0].platform == "tpu"
+        cap = _panel_max_rows(A.grid.devices[0].platform)
         for k in range(kt):
             r0 = k * nb
             w = min(nb, n - r0)          # real panel width
             h = m - r0                   # real panel height
             kw = min(h, w)               # pivots this panel
             pan = a[r0:m, r0:r0 + w]
-            if on_tpu and h > _LU_PANEL_MAX_ROWS:
+            if cap is not None and h > cap:
                 # taller than XLA's single-shot lu row cap: chunked
                 # CALU tournament panel (same kernel the SPMD path
                 # uses), pivots resolved to a permutation locally.
-                lu, piv_l, _ = panel_lu_factor(
-                    pan, 0, h, max_rows=_LU_PANEL_MAX_ROWS)
+                lu, piv_l, _ = panel_lu_factor(pan, 0, h, max_rows=cap)
                 perm0 = jnp.arange(h, dtype=jnp.int32)
 
                 def _sim(j, prm, piv_l=piv_l):
@@ -884,8 +911,7 @@ def _getrf_chunk_core(A, pivots0, info0, k0, klen, win_hi=None,
     mtl, ntl = A.data.shape[2], A.data.shape[3]
     mt_p = mtl * p
     M = mt_p * nb
-    on_tpu = g.devices[0].platform == "tpu"
-    panel_max_rows = _LU_PANEL_MAX_ROWS if on_tpu else None
+    panel_max_rows = _panel_max_rows(g.devices[0].platform)
     windowed = win_hi is not None
     whi = nt if win_hi is None else win_hi
     r0s, c0s = k0 // p, k0 // q
@@ -913,69 +939,78 @@ def _getrf_chunk_core(A, pivots0, info0, k0, klen, win_hi=None,
                         kind=tl.KIND_STEP, edge="b", routine="getrf",
                         ndev=ndev)
             # ---- panel: gather column k, factor redundantly --------
-            pcol = lax.dynamic_index_in_dim(a, k // q, axis=1,
-                                            keepdims=False)
-            diag_slot = k // p
-            fixed = tile_diag_pad_identity(
-                lax.dynamic_index_in_dim(pcol, diag_slot, axis=0,
-                                         keepdims=False), k, m, nb, n)
-            pcol = jnp.where(
-                (gi == k)[:, None, None],
-                lax.dynamic_update_index_in_dim(pcol, fixed, diag_slot,
-                                                axis=0), pcol)
+            # (named scopes as in _potrf_chunk_core: benchmarks/
+            # span_report.py reads the device trace by them)
+            with jax.named_scope("panel_bcast"):
+                pcol = lax.dynamic_index_in_dim(a, k // q, axis=1,
+                                                keepdims=False)
+                diag_slot = k // p
+                fixed = tile_diag_pad_identity(
+                    lax.dynamic_index_in_dim(pcol, diag_slot, axis=0,
+                                             keepdims=False), k, m, nb, n)
+                pcol = jnp.where(
+                    (gi == k)[:, None, None],
+                    lax.dynamic_update_index_in_dim(pcol, fixed, diag_slot,
+                                                    axis=0), pcol)
             pcol = tl.mark(pcol, "panel_bcast", step=k, device=dev,
                            kind=tl.KIND_COLLECTIVE, edge="b",
                            routine="getrf", ndev=ndev)
-            full = comm.allgather_panel_rows(pcol, p, k % q)
+            with jax.named_scope("panel_bcast"):
+                full = comm.allgather_panel_rows(pcol, p, k % q)
             full = tl.mark(full, "panel_bcast", step=k, device=dev,
                            kind=tl.KIND_COLLECTIVE, edge="e",
                            routine="getrf", ndev=ndev)
-            panel2d = full.reshape(M, nb)
-            panel2d, piv_k, info_k = panel_lu_factor(
-                panel2d, k * nb, m, max_rows=panel_max_rows)
-            info = info + info_k
-            pivots = pivots.at[k].set(piv_k)
-            ptiles = panel2d.reshape(mt_p, nb, nb)
+            with jax.named_scope("panel"):
+                panel2d = full.reshape(M, nb)
+                panel2d, piv_k, info_k = panel_lu_factor(
+                    panel2d, k * nb, m, max_rows=panel_max_rows)
+                info = info + info_k
+                pivots = pivots.at[k].set(piv_k)
+                ptiles = panel2d.reshape(mt_p, nb, nb)
 
-            newcol = jnp.take(ptiles, gi, axis=0)
-            a = jnp.where(
-                c == k % q,
-                lax.dynamic_update_index_in_dim(a, newcol, k // q,
-                                                axis=1), a)
-            a = _swap_rows_local(a, piv_k, k * nb, t_local, nb, p, q,
-                                 exclude_col=k,
-                                 min_col=swap_min if windowed else 0,
-                                 max_col=win_hi)
+                newcol = jnp.take(ptiles, gi, axis=0)
+                a = jnp.where(
+                    c == k % q,
+                    lax.dynamic_update_index_in_dim(a, newcol, k // q,
+                                                    axis=1), a)
+            with jax.named_scope("row_swap"):
+                a = _swap_rows_local(a, piv_k, k * nb, t_local, nb, p, q,
+                                     exclude_col=k,
+                                     min_col=swap_min if windowed else 0,
+                                     max_col=win_hi)
 
             # ---- U block-row solve, window columns only ------------
-            lkk = lax.dynamic_slice(panel2d, (k * nb, 0), (nb, nb))
-            arow = lax.dynamic_index_in_dim(a, k // p, axis=0,
-                                            keepdims=False)[c0s:c1s]
-            solved = lax.linalg.triangular_solve(
-                jnp.broadcast_to(lkk, (nsub, nb, nb)), arow,
-                left_side=True, lower=True, unit_diagonal=True)
-            right = (gjs > k) & (gjs < min(nt, whi))
-            urow = jnp.where(right[:, None, None], solved, arow)
-            a = jnp.where(
-                r == k % p,
-                lax.dynamic_update_index_in_dim(
-                    a, a[k // p].at[c0s:c1s].set(urow), k // p,
-                    axis=0), a)
-            urow_b = comm.bcast_from_row(
-                jnp.where(right[:, None, None], urow,
-                          jnp.zeros_like(urow)), k % p)
+            with jax.named_scope("diag_solve"):
+                lkk = lax.dynamic_slice(panel2d, (k * nb, 0), (nb, nb))
+                arow = lax.dynamic_index_in_dim(a, k // p, axis=0,
+                                                keepdims=False)[c0s:c1s]
+                solved = lax.linalg.triangular_solve(
+                    jnp.broadcast_to(lkk, (nsub, nb, nb)), arow,
+                    left_side=True, lower=True, unit_diagonal=True)
+                right = (gjs > k) & (gjs < min(nt, whi))
+                urow = jnp.where(right[:, None, None], solved, arow)
+                a = jnp.where(
+                    r == k % p,
+                    lax.dynamic_update_index_in_dim(
+                        a, a[k // p].at[c0s:c1s].set(urow), k // p,
+                        axis=0), a)
+                urow_b = comm.bcast_from_row(
+                    jnp.where(right[:, None, None], urow,
+                              jnp.zeros_like(urow)), k % p)
 
             # ---- trailing gemm on the window -----------------------
-            lrows = jnp.take(ptiles, gis, axis=0)
-            below = (gis > k) & (gis < mt)
-            lrows = jnp.where(below[:, None, None], lrows,
-                              jnp.zeros_like(lrows))
+            with jax.named_scope("trailing"):
+                lrows = jnp.take(ptiles, gis, axis=0)
+                below = (gis > k) & (gis < mt)
+                lrows = jnp.where(below[:, None, None], lrows,
+                                  jnp.zeros_like(lrows))
             lrows = tl.mark(lrows, "trailing", step=k, device=dev,
                             kind=tl.KIND_COMPUTE, edge="b",
                             routine="getrf", ndev=ndev)
-            upd = jnp.einsum("aik,bkj->abij", lrows, urow_b, **pk)
-            sub = a[r0s:, c0s:c1s] - upd
-            a = a.at[r0s:, c0s:c1s].set(sub)
+            with jax.named_scope("trailing"):
+                upd = jnp.einsum("aik,bkj->abij", lrows, urow_b, **pk)
+                sub = a[r0s:, c0s:c1s] - upd
+                a = a.at[r0s:, c0s:c1s].set(sub)
             a = tl.mark(a, "trailing", step=k, device=dev,
                         kind=tl.KIND_COMPUTE, edge="e", routine="getrf",
                         ndev=ndev)
@@ -1045,8 +1080,7 @@ def _getrf_pipe_chunk_core(A, pivots0, info0, k0, klen, depth=1,
     mtl, ntl = A.data.shape[2], A.data.shape[3]
     mt_p = mtl * p
     M = mt_p * nb
-    on_tpu = g.devices[0].platform == "tpu"
-    panel_max_rows = _LU_PANEL_MAX_ROWS if on_tpu else None
+    panel_max_rows = _panel_max_rows(g.devices[0].platform)
     r0s, c0s = k0 // p, k0 // q
     nsub = ntl - c0s
     pk = trailing_dot_kwargs(tier, A.dtype)
@@ -1550,7 +1584,7 @@ def getrs(LU: Matrix, piv, B: Matrix, trans: Op = Op.NoTrans, opts=None):
     with trace.block("getrs"):
         if trans == Op.NoTrans:
             with trace.block("getrs.apply_pivots",
-                             kind=_apply_pivots_kind(B, piv)):
+                             **_apply_pivots_labels(B, piv)):
                 Bp = _apply_pivots_matrix(B, piv, forward=True)
             Y = trsm(Side.Left, 1.0, L, Bp, opts)
             X = trsm(Side.Left, 1.0, U, Y, opts)
@@ -1559,7 +1593,7 @@ def getrs(LU: Matrix, piv, B: Matrix, trans: Op = Op.NoTrans, opts=None):
         Y = trsm(Side.Left, 1.0, opA(U), B, opts)
         Z = trsm(Side.Left, 1.0, opA(L), Y, opts)
         with trace.block("getrs.apply_pivots",
-                         kind=_apply_pivots_kind(Z, piv)):
+                         **_apply_pivots_labels(Z, piv)):
             return _apply_pivots_matrix(Z, piv, forward=False)
 
 
@@ -1666,6 +1700,16 @@ def _apply_pivots_kind(B: Matrix, piv) -> str:
     if mt_p > 256 and repl_bytes < 2**30:
         return "swap_sim"
     return "dist"
+
+
+def _apply_pivots_labels(B: Matrix, piv) -> dict:
+    """Labels of a ``getrs.apply_pivots`` span: the ``kind`` and, where
+    LAPACK pivots are replayed, the ``steps`` of that replay (one
+    dependent swap each: ``_sim_perm``)."""
+    kind = _apply_pivots_kind(B, piv)
+    if kind == "order_gather":
+        return {"kind": kind}
+    return {"kind": kind, "steps": int(piv.size)}
 
 
 def _apply_pivots_matrix(B: Matrix, piv, forward: bool) -> Matrix:

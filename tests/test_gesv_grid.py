@@ -1,0 +1,233 @@
+"""``slate.gesv`` on ``Grid(2,2)``: the LU that ``gesv_16k_2x2`` times on
+four chips (``getrf()`` → eight ``_getrf_chunk_core`` programs of two
+block columns → ``getrs``: LAPACK pivots replayed swap by swap, two
+X-moving ``trsm``), against the benchmark's plain numpy solver
+(``benchmarks/harness/plain_solver.gesv``) and the residual of
+``benchmarks/harness/check.py``.
+
+Both forms of the gathered panel run. ``partial``: the row cap as the
+CPU has it (none), one ``lax.linalg.lu`` of the whole panel, LAPACK's
+pivots. ``tournament``: the cap lowered to 256 rows, so that the 512-row
+panel goes through ``_panel_lu_tournament`` with two row chunks, as the
+chip's 16,384-row panel does over its cap of 10,240 (the rule itself,
+``getrf._panel_max_rows``, reads the platform and is not changed).
+
+Geometries: kt = 16 block columns of 32, so eight chunks as on the
+chip; n = 512 on the tile grid and n = 500 with 20 real rows in the
+last tile. One right-hand side (HPL's, the cell's) and eight.
+
+CPU only (every tier is f32 here): what the chip adds is in PERF.md.
+"""
+
+import jax
+import numpy as np
+import pytest
+import scipy.linalg as sla
+
+import slate_tpu as st
+from slate_tpu import obs
+from slate_tpu.cache import jitcache
+from slate_tpu.linalg import getrf as getrf_mod
+from slate_tpu.obs import metrics, tracing
+from benchmarks.harness import check, plain_solver
+from tests.test_gesv_ragged import _paths, errors_in_eps
+
+NB, KT = 32, 16
+TOURNAMENT_CAP = 256            # two row chunks of the 512-row panel
+GEOMETRIES = [512, 500]
+FORMS = ["partial", "tournament"]
+CASES = [(n, nrhs, form) for form in FORMS for n in GEOMETRIES
+         for nrhs in (1, 8)]
+IDS = [f"n{n}-nrhs{nrhs}-{form}" for n, nrhs, form in CASES]
+TIER = {st.Option.TrailingPrecision: "bf16_6x"}
+
+# Backward errors in units of eps = 2^-24, evaluated in float64. Over
+# these cases the program reads 1.4-2.9 (inf) and 1.6-2.1 (Frobenius)
+# with either panel (3.5 and 2.4 at most through Op.Trans), the plain
+# f32 solver 1.8-3.0 and 1.8-3.3; the plain solver with its trailing
+# products at bf16_3x reads 69-85 and 52-56 (sixteen trailing updates of
+# depth 32). The limit 12 is 3.4x over the largest sound reading and
+# 4.4x under the smallest lowered one.
+TOL_EPS = 12.0
+# As tests/test_gesv_ragged.py: two answers that each solve a nearby
+# system lie within (the sum of their backward errors) x cond of each
+# other; measured 0.004-0.05 cond*eps here, tournament pivots included
+# (another choice of pivots, the same system); cond is 2.5e5-3.6e5.
+TOL_COND_EPS = 1.0
+# ||P A - L U||_F / ||A||_F in eps: 22.6-22.8 with LAPACK's pivots,
+# 25.6-25.8 with the tournament's (n rounding errors of random sign an
+# entry: sqrt(n) = 22.6). 100 leaves 4x; a wrong permutation or a row
+# of L out of place reads 10^6 and more.
+TOL_FACTOR_EPS = 100.0
+
+
+@pytest.fixture(scope="module")
+def solved(grid22):
+    """``{(n, nrhs, form): operands, outputs, records}``: every case
+    solved once, the spans of the solve captured and its counters read.
+    The chunk programs are keyed by shapes, not by the cap, so the
+    in-process executables are dropped where the form changes and again
+    at the end (a later test of this worker must not be handed a
+    tournament panel)."""
+    out = {}
+    patch = pytest.MonkeyPatch()
+    was_metrics = obs.metrics_enabled()
+    patch.setattr(tracing, "_profiling", lambda: True)
+    try:
+        for form in FORMS:
+            jitcache.clear_in_process("getrf.chunk")
+            if form == "tournament":
+                patch.setattr(getrf_mod, "_panel_max_rows",
+                              lambda platform: TOURNAMENT_CAP)
+            for i, n in enumerate(GEOMETRIES):
+                A = st.random_matrix(n, n, NB, grid22, np.float32,
+                                     seed=6001 + i)
+                a = np.asarray(A.to_dense())
+                for nrhs in (1, 8):
+                    B = st.random_matrix(n, nrhs, NB, grid22, np.float32,
+                                         seed=7001 + i)
+                    obs.reset()
+                    obs.metrics_on()
+                    X, LU, piv, info = jax.block_until_ready(
+                        st.gesv(A, B, TIER))
+                    b = np.asarray(B.to_dense())
+                    out[n, nrhs, form] = {
+                        "A": A, "B": B, "X": X, "LU": LU, "piv": piv,
+                        "a": a, "b": b, "info": int(info),
+                        "x": np.asarray(X.to_dense()),
+                        "x_ref": plain_solver.gesv(a, b, NB, "f32"),
+                        "spans": _paths(obs.captured_spans()),
+                        "chunked": metrics.counter_value(
+                            "getrf.path", phase="spmd_chunk"),
+                        "paths": metrics.counter_total("getrf.path"),
+                        "gathered": metrics.counter_total(
+                            "getrf.panel_gather_bytes"),
+                        "moved_x": metrics.counter_total("trsm.move_x")}
+        yield out
+    finally:
+        patch.undo()
+        jitcache.clear_in_process("getrf.chunk")
+        if not was_metrics:
+            obs.metrics_off()
+        obs.reset()
+
+
+def _perm_of(piv, rows):
+    """LAPACK's swap list replayed: row i of P·A is row ``perm[i]`` of A."""
+    perm = np.arange(rows)
+    for j, t in enumerate(np.asarray(piv).reshape(-1)):
+        perm[j], perm[t] = perm[t], perm[j]
+    return perm
+
+
+@pytest.mark.parametrize("n,nrhs,form", CASES, ids=IDS)
+def test_info_zero_on_the_chunked_path(solved, n, nrhs, form):
+    s = solved[n, nrhs, form]
+    assert s["info"] == 0
+    assert s["x"].shape == (n, nrhs) and np.isfinite(s["x"]).all()
+    assert s["chunked"] == 1 and s["paths"] == 1
+    # each device receives every one of the kt panels whole
+    assert s["gathered"] == KT * (KT * NB) * NB * 4
+
+
+@pytest.mark.parametrize("n,nrhs,form", CASES, ids=IDS)
+def test_x_agrees_with_the_plain_solver(solved, n, nrhs, form):
+    s = solved[n, nrhs, form]
+    cond = np.linalg.cond(s["a"].astype(np.float64), np.inf)
+    apart = (np.linalg.norm(s["x"] - s["x_ref"], np.inf)
+             / np.linalg.norm(s["x_ref"], np.inf))
+    assert apart <= TOL_COND_EPS * cond * check.EPS, (apart, cond)
+
+
+@pytest.mark.parametrize("n,nrhs,form", CASES, ids=IDS)
+def test_backward_errors_within_the_limit(solved, n, nrhs, form):
+    s = solved[n, nrhs, form]
+    got = errors_in_eps(s["a"], s["x"], s["b"])
+    ref = errors_in_eps(s["a"], s["x_ref"], s["b"])
+    assert all(v <= TOL_EPS for v in got.values()), got
+    assert all(v <= TOL_EPS for v in ref.values()), ref
+
+
+@pytest.mark.parametrize("n,nrhs,form", CASES, ids=IDS)
+def test_a_tier_down_fails_the_limit_the_sound_tier_passes(solved, n,
+                                                           nrhs, form):
+    s = solved[n, nrhs, form]
+    lowered = plain_solver.gesv(s["a"], s["b"], NB, "bf16_3x")
+    low = errors_in_eps(s["a"], lowered, s["b"])
+    assert all(v > TOL_EPS for v in low.values()), low
+    assert low["fro"] > 4 * errors_in_eps(s["a"], s["x"], s["b"])["fro"]
+
+
+@pytest.mark.parametrize("n,nrhs,form", CASES, ids=IDS)
+def test_the_pivots_and_the_factor(solved, n, nrhs, form):
+    """``partial``: LAPACK's rows, row for row. ``tournament``: another
+    choice of rows (CALU), still a swap list, and P·A = L·U with unit
+    lower L either way."""
+    s = solved[n, nrhs, form]
+    piv = np.asarray(s["piv"])
+    assert piv.shape == (KT, NB) and piv.dtype == np.int32
+    rows = np.arange(KT * NB)
+    flat = piv.reshape(-1)
+    assert (flat[n:] == rows[n:]).all()          # padded slots self-swap
+    assert (flat[:n] >= rows[:n]).all() and (flat[:n] < n).all()
+    lapack = sla.lu_factor(s["a"])[1]
+    if form == "partial":
+        assert (flat[:n] == lapack).all()
+    else:
+        assert (flat[:n] != lapack).any()
+    perm = _perm_of(piv, KT * NB)
+    assert sorted(perm[:n]) == list(range(n))
+    lu = np.asarray(s["LU"].to_dense(), np.float64)
+    L = np.tril(lu, -1) + np.eye(n)
+    apart = np.linalg.norm(s["a"].astype(np.float64)[perm[:n]]
+                           - L @ np.triu(lu))
+    assert apart <= TOL_FACTOR_EPS * check.EPS * np.linalg.norm(s["a"])
+    if form == "partial":
+        assert np.abs(L).max() <= 1.0            # what row pivoting bounds
+
+
+@pytest.mark.parametrize("n,nrhs,form", CASES, ids=IDS)
+def test_four_equal_shards(solved, n, nrhs, form):
+    s = solved[n, nrhs, form]
+    for M in (s["A"], s["LU"], s["X"]):
+        where = check.equal_shards(M.data, 4)
+        assert where["ok"], where
+
+
+@pytest.mark.parametrize("n,nrhs,form", CASES, ids=IDS)
+def test_getrs_trans_on_the_same_factor(solved, n, nrhs, form):
+    s = solved[n, nrhs, form]
+    Xt = st.getrs(s["LU"], s["piv"], s["B"], st.Op.Trans, TIER)
+    got = errors_in_eps(s["a"].T, np.asarray(Xt.to_dense()), s["b"])
+    assert all(v <= TOL_EPS for v in got.values()), got
+
+
+@pytest.mark.parametrize("n,nrhs,form", CASES, ids=IDS)
+def test_the_span_tree_of_one_solve(solved, n, nrhs, form):
+    s = solved[n, nrhs, form]
+    tree = s["spans"]
+    names = [p for p, _ in tree]
+    assert names[0] == "slate.gesv"
+    assert tree[0][1]["labels"]["grid"] == "2x2"
+    assert tree[0][1]["labels"]["nrhs"] == nrhs
+    top = dict(tree)["slate.gesv/getrf"]["labels"]
+    assert top["pivoting"] == form
+    assert top["panel_rows"] == KT * NB
+    assert top["panel_gather_bytes"] == KT * (KT * NB) * NB * 4
+    assert top["precision"] == "bf16_6x"
+    assert top["mt"] == KT and top["pad_rows"] == KT * NB - n
+    prepare = names.index("slate.gesv/getrf/getrf.prepare")
+    chunks = [(i, span) for i, (p, span) in enumerate(tree)
+              if p == "slate.gesv/getrf/getrf.chunk"]
+    assert prepare < chunks[0][0]
+    assert [(c["labels"]["phase"], c["labels"]["k0"], c["labels"]["klen"])
+            for _, c in chunks] == [("spmd_chunk", k0, 2)
+                                    for k0 in range(0, KT, 2)]
+    pivots = dict(tree)["slate.gesv/getrs/getrs.apply_pivots"]["labels"]
+    assert pivots["kind"] == "swap_sim" and pivots["steps"] == KT * NB
+    solves = [span for p, span in tree if p == "slate.gesv/getrs/trsm"]
+    assert [t["labels"]["form"] for t in solves] == ["move_x", "move_x"]
+    assert [t["labels"]["op"] for t in solves] == ["N", "N"]
+    assert s["moved_x"] == 2
+    assert names.index("slate.gesv/getrf") < names.index(
+        "slate.gesv/getrs")

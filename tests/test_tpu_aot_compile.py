@@ -146,15 +146,72 @@ def test_potrf_chunk_2x2_compiles_sharded(tpu_grid22):
     _assert_sharded_with_collectives(c, H * H * 4)
 
 
-def test_getrf_chunk_2x2_compiles_sharded(tpu_grid22):
+def _getrf_chunk(grid, k0):
+    """The chunk program of block columns [k0, k0 + 2), compiled."""
     from slate_tpu.linalg import getrf
-    data = _tiles(tpu_grid22)
-    A = slate.Matrix(data=data, m=H, n=H, nb=NB, grid=tpu_grid22)
+    A = slate.Matrix(data=_tiles(grid), m=H, n=H, nb=NB, grid=grid)
     piv0 = jax.ShapeDtypeStruct((H // NB, NB), jnp.int32)
     info0 = jax.ShapeDtypeStruct((), jnp.int32)
-    c = getrf._getrf_chunk_jit.lower(A, piv0, info0, 0, 2,
-                                     tier="bf16_6x").compile()
+    return getrf._getrf_chunk_jit.lower(A, piv0, info0, k0, 2,
+                                        tier="bf16_6x").compile()
+
+
+def _all_gathers(text) -> int:
+    return text.count(" all-gather(") + text.count(" all-gather-start(")
+
+
+def test_getrf_chunk_2x2_compiles_sharded(tpu_grid22):
+    _assert_sharded_with_collectives(_getrf_chunk(tpu_grid22, 0),
+                                     H * H * 4)
+
+
+def test_getrf_last_chunk_2x2_collectives_and_temp(tpu_grid22):
+    """The last of the eight chunk programs ``gesv_16k_2x2`` runs
+    (k0 = 14, two block columns). What crosses chips in a step, and
+    nothing else: column k's local slots over q (mtl*nb*nb elements),
+    that panel gathered over p to every device, the row swaps'
+    candidate rows over p (2*nb rows of the local stack: as many bytes
+    as the panel), the U block-row of the window over p. No all-to-all.
+    A distributed pivot search has these numbers to beat."""
+    import math
+    mtl = ntl = H // NB // 2
+    c = _getrf_chunk(tpu_grid22, H // NB - 2)
     _assert_sharded_with_collectives(c, H * H * 4)
+    text = c.as_text()
+    assert "all-to-all" not in text and "collective-permute" not in text
+    reduced = sorted(math.prod(dims) for _, dims in all_reduce_shapes(text))
+    assert reduced == [NB * NB,                 # U(k, last tile column)
+                       mtl * NB * NB,           # column k over q
+                       2 * NB * ntl * NB], reduced      # swapped rows
+    assert _all_gathers(text) == 1
+    assert f"f32[2,{mtl},{NB},{NB}]" in text    # the [M, nb] panel, whole
+    # 330 MiB compiled here (2026-09-28, jax 0.9.0): the 64 MiB panel in
+    # its several forms and the swaps' rows; the first chunk holds 860
+    temp_mib = c.memory_analysis().temp_size_in_bytes / 2 ** 20
+    assert temp_mib < 400, temp_mib
+
+
+def test_apply_piv_2x2_one_rhs_compiles(tpu_grid22):
+    """``getrs``'s pivots on the cell's B, [16384, 1] in one tile
+    column a device column: the stored 2 x 64 MiB (one real column) are
+    gathered to every device, the 16,384 swaps replayed in one ``while``
+    and the rows taken: one all-gather, nothing else crosses."""
+    from slate_tpu.linalg import getrf
+    b = jax.ShapeDtypeStruct((2, 2, H // NB // 2, 1, NB, NB), F32,
+                             sharding=tpu_grid22.sharding())
+    B = slate.Matrix(data=b, m=H, n=1, nb=NB, grid=tpu_grid22)
+    piv = jax.ShapeDtypeStruct((H // NB, NB), jnp.int32)
+    c = getrf._apply_piv_jit.lower(B, piv, forward=True).compile()
+    text = c.as_text()
+    assert "all-reduce" not in text and "all-to-all" not in text
+    assert _all_gathers(text) == 1
+    assert " while(" in text
+    mem = c.memory_analysis()
+    assert abs(mem.argument_size_in_bytes
+               - b.size * 4 // tpu_grid22.size) < 2 ** 20
+    # 64 MiB compiled here: the gathered B; a reader of one column
+    # would hold 64 KiB
+    assert mem.temp_size_in_bytes / 2 ** 20 < 80
 
 
 # -- potrs' second solve: trsm on conj_transpose(L) where it lies ----------
