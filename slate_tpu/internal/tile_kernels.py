@@ -207,45 +207,31 @@ def panel_lu_factor(panel: jax.Array, start: jax.Array | int, m: int,
     return out, piv, info
 
 
-def _panel_lu_tournament(panel: jax.Array, start, m: int, max_rows: int):
-    """Tournament-pivot LU of a tall panel (CALU — reference
-    src/getrf_tntpiv.cc / internal_getrf_tntpiv.cc:334's binary
-    tournament, here a ``max_rows``-ary reduction).
-
-    Round structure: split the candidate rows into chunks of
-    ``max_rows``, run XLA's pivoted ``lu`` on each chunk (vmapped — one
-    batched call per round), keep each chunk's nb winner rows, repeat
-    until one chunk remains; a final pivoted ``lu`` of the survivors
-    fixes the nb pivot rows *and* their elimination order. The panel is
-    then permuted with the LAPACK-equivalent sequential-swap
-    permutation and factored in place: the winners' LU is already the
-    top block's factorization, and the remaining rows get
-    L21 = A21·U11⁻¹ in one MXU triangular solve.
-
-    Same contract as :func:`panel_lu_factor`; pivot *choices* are
-    CALU's (backward stable, tighter comm profile) rather than classic
-    partial pivoting's.
-    """
-    M, nb = panel.shape
-    fd = _factor_dtype(panel.dtype)
-    rows = jnp.arange(M)
-    hi = jnp.maximum(m, start + nb)
-    keep = (rows >= start) & (rows < hi)
-    masked = jnp.where(keep[:, None], panel, jnp.zeros_like(panel))
-    rolled = jnp.roll(masked, -start, axis=0)   # active window at row 0
-
-    # --- phase A: tournament pivot selection -------------------------
-    cand = rolled.astype(fd)                    # [R, nb] candidates
-    cand_idx = rows.astype(jnp.int32)           # rolled-space index
-    R = M
+def _tournament_select(cand: jax.Array, cand_idx: jax.Array,
+                       max_rows: int, sentinel: int):
+    """The tournament's rounds on ``cand`` [R, nb] (zero rows lose every
+    round) with row ids ``cand_idx`` [R]: while more than ``max_rows``
+    candidates are left, split them into chunks of ``max_rows``, run
+    XLA's pivoted ``lu`` on each chunk (vmapped: one batched call a
+    round) and keep each chunk's nb winner rows; then one pivoted
+    ``lu`` of the survivors. Returns that ``lu``, the positions of its
+    first nb pivot rows among the survivors (elimination order), the
+    survivors and their ids. Rows padded onto the last chunk are zero
+    and carry the id ``sentinel``."""
+    nb = cand.shape[1]
+    R = cand.shape[0]
+    if R > max_rows and max_rows < 2 * nb:
+        # a round of c chunks leaves c·nb rows: no fewer under 2·nb
+        raise ValueError(f"tournament chunks of {max_rows} rows cannot "
+                         f"reduce {R} candidates of width {nb}")
     while R > max_rows:
         c = -(-R // max_rows)
         pad = c * max_rows - R
         cand = jnp.pad(cand, ((0, pad), (0, 0)))
-        # pad rows are zero (they lose every real tournament); sentinel
-        # index M marks them so a degenerate win (all-zero column)
-        # resolves to a self-swap below.
-        cand_idx = jnp.pad(cand_idx, (0, pad), constant_values=M)
+        # pad rows are zero (they lose every real tournament); the
+        # sentinel id marks them so a degenerate win (all-zero column)
+        # resolves to a self-swap in _tournament_swap_list.
+        cand_idx = jnp.pad(cand_idx, (0, pad), constant_values=sentinel)
         chunks = cand.reshape(c, max_rows, nb)
         _, _, perm_c = jax.vmap(lax.linalg.lu)(chunks)
         sel = perm_c[:, :nb]                    # [c, nb] winners
@@ -255,41 +241,91 @@ def _panel_lu_tournament(panel: jax.Array, start, m: int, max_rows: int):
             cand_idx.reshape(c, max_rows), sel, axis=1).reshape(c * nb)
         R = c * nb
     lu_f, _, perm_f = lax.linalg.lu(cand)
-    win = jnp.take(cand_idx, perm_f[:nb])       # winners, elim. order
+    return lu_f, perm_f[:nb], cand, cand_idx
+
+
+def _tournament_swap_list(win: jax.Array, first, M: int):
+    """LAPACK's sequential swaps that bring winner j (row id ``win[j]``
+    of an M-row space, ids >= M are sentinels) to position ``first+j``,
+    j = 0..nb-1 in order. Returns (content, locof, piv): ``piv[j]`` =
+    position of winner j when swaps 0..j-1 have been applied;
+    ``content[i]`` = the row whose data ends at position i and
+    ``locof`` its inverse."""
+    nb = win.shape[0]
+    rows = jnp.arange(M, dtype=jnp.int32)
+
+    def sim(j, carry):
+        content, locof, piv = carry
+        at = first + j
+        t = win[j]
+        # sentinel winner (all-zero column, singular) → self-swap
+        t = jnp.where(t < M, t, content[at])
+        loc = locof[t]
+        piv = piv.at[j].set(loc)
+        cj = content[at]
+        content = content.at[at].set(t).at[loc].set(cj)
+        locof = locof.at[t].set(at).at[cj].set(loc)
+        return content, locof, piv
+
+    return lax.fori_loop(0, nb, sim,
+                         (rows, rows, jnp.zeros(nb, jnp.int32)))
+
+
+def _safe_upper(lu_top: jax.Array) -> jax.Array:
+    """U of a factored diagonal block with 1 in place of a zero pivot,
+    so that L21 = A21·U⁻¹ stays finite where ``info`` counts it."""
+    nb = lu_top.shape[0]
+    u11 = jnp.triu(lu_top)
+    return u11 + jnp.diag(jnp.where(jnp.diagonal(u11) == 0,
+                                    jnp.ones(nb, u11.dtype),
+                                    jnp.zeros(nb, u11.dtype)))
+
+
+def _panel_lu_tournament(panel: jax.Array, start, m: int, max_rows: int):
+    """Tournament-pivot LU of a tall panel (CALU — reference
+    src/getrf_tntpiv.cc / internal_getrf_tntpiv.cc:334's binary
+    tournament, here a ``max_rows``-ary reduction).
+
+    Round structure (:func:`_tournament_select`): split the candidate
+    rows into chunks of ``max_rows``, run XLA's pivoted ``lu`` on each
+    chunk, keep each chunk's nb winner rows, repeat until one chunk
+    remains; a final pivoted ``lu`` of the survivors fixes the nb pivot
+    rows *and* their elimination order. The panel is then permuted with
+    the LAPACK-equivalent sequential-swap permutation and factored in
+    place: the winners' LU is already the top block's factorization,
+    and the remaining rows get L21 = A21·U11⁻¹ in one MXU triangular
+    solve.
+
+    Same contract as :func:`panel_lu_factor`; pivot *choices* are
+    CALU's (backward stable, tighter comm profile) rather than classic
+    partial pivoting's. On a grid the chunk driver runs the first round
+    on the rows each device stores and never assembles this panel
+    (:func:`tournament_winners`, :func:`tournament_pivots`).
+    """
+    M, nb = panel.shape
+    fd = _factor_dtype(panel.dtype)
+    rows = jnp.arange(M)
+    hi = jnp.maximum(m, start + nb)
+    keep = (rows >= start) & (rows < hi)
+    masked = jnp.where(keep[:, None], panel, jnp.zeros_like(panel))
+    rolled = jnp.roll(masked, -start, axis=0)   # active window at row 0
+
+    # --- phase A: tournament pivot selection (rolled-space ids) ------
+    lu_f, sel, _, cand_idx = _tournament_select(
+        rolled.astype(fd), rows.astype(jnp.int32), max_rows, M)
+    win = jnp.take(cand_idx, sel)               # winners, elim. order
     lu_top = lu_f[:nb].astype(panel.dtype)      # LU of permuted top blk
     diag = jnp.diagonal(lu_f)[:nb]
     info = jnp.sum(diag == 0).astype(jnp.int32)
 
     # --- phase B: LAPACK-style sequential-swap permutation -----------
-    # piv[j] = slot of winner j when swaps 0..j-1 have been applied;
-    # content[i] = original rolled row whose data sits at slot i.
-    def sim(j, carry):
-        content, locof, piv = carry
-        t = win[j]
-        # sentinel winner (all-zero column, singular) → self-swap
-        t = jnp.where(t < M, t, content[j])
-        loc = locof[t]
-        piv = piv.at[j].set(loc)
-        cj = content[j]
-        content = content.at[j].set(t).at[loc].set(cj)
-        locof = locof.at[t].set(j).at[cj].set(loc)
-        return content, locof, piv
-
-    content, _, piv_r = lax.fori_loop(
-        0, nb, sim,
-        (rows.astype(jnp.int32), rows.astype(jnp.int32),
-         jnp.zeros(nb, jnp.int32)))
-
+    content, _, piv_r = _tournament_swap_list(win, 0, M)
     permuted = jnp.take(rolled, content, axis=0)
 
     # --- factor in place: top block is done; rows below get L21 ------
-    u11 = jnp.triu(lu_top)
-    safe_u = u11 + jnp.diag(jnp.where(jnp.diagonal(u11) == 0,
-                                      jnp.ones(nb, u11.dtype),
-                                      jnp.zeros(nb, u11.dtype)))
     l21 = lax.linalg.triangular_solve(
-        safe_u.astype(fd), permuted[nb:].astype(fd), left_side=False,
-        lower=False).astype(panel.dtype)
+        _safe_upper(lu_top).astype(fd), permuted[nb:].astype(fd),
+        left_side=False, lower=False).astype(panel.dtype)
     out_rolled = jnp.concatenate([lu_top, l21], axis=0)
     # rows outside the active window were zeroed before the permutation
     # and no swap touches them (winners are active rows), so the keep
@@ -298,6 +334,44 @@ def _panel_lu_tournament(panel: jax.Array, start, m: int, max_rows: int):
     out = jnp.where(keep[:, None], back, panel)
     piv = jnp.int32(start) + piv_r
     return out, piv, info
+
+
+def tournament_winners(rows: jax.Array, ids: jax.Array, max_rows: int,
+                       sentinel: int):
+    """The tournament's first round where the rows are stored (the
+    reference's getrf_tntpiv runs it on each rank's own rows too):
+    ``rows`` [L, nb] are one device's rows of the panel, the active
+    ones first and the rest zero, ``ids`` [L] their global row ids
+    (``sentinel`` on the zeroed ones). Returns this device's nb winner
+    rows (their original values, elimination order) and their ids — all
+    that has to cross the mesh; further rounds only where L exceeds
+    ``max_rows``. With the active rows first a tie (a column of zeros)
+    falls to an active row while one is left, so the winners are real
+    rows first, sentinels after."""
+    _, sel, cand, cand_idx = _tournament_select(
+        rows.astype(_factor_dtype(rows.dtype)), ids, max_rows, sentinel)
+    return jnp.take(cand, sel, axis=0), jnp.take(cand_idx, sel)
+
+
+def tournament_pivots(cand: jax.Array, cand_idx: jax.Array, first, M: int,
+                      max_rows: int, dtype):
+    """The tournament's last rounds on the gathered winners ``cand``
+    [R, nb] with global row ids ``cand_idx`` (ids >= M are sentinels:
+    zero rows of a device with fewer than nb active rows), the same on
+    every device. The real rows are put first, so that a tie never
+    falls to a sentinel while a real row is left. Returns (lu_top, piv,
+    locof, info): the winners' LU (the factored diagonal block), the
+    LAPACK swap list that brings winner j to global row ``first+j``,
+    the position every global row ends at
+    (:func:`_tournament_swap_list`), and the number of zero pivots."""
+    nb = cand.shape[1]
+    real_first = jnp.argsort(cand_idx >= M, stable=True)
+    lu_f, sel, _, cand_idx = _tournament_select(
+        jnp.take(cand, real_first, axis=0), jnp.take(cand_idx, real_first),
+        max_rows, M)
+    info = jnp.sum(jnp.diagonal(lu_f)[:nb] == 0).astype(jnp.int32)
+    _, locof, piv = _tournament_swap_list(jnp.take(cand_idx, sel), first, M)
+    return lu_f[:nb].astype(dtype), piv, locof, info
 
 
 def lu_nopiv_block(a: jax.Array, ib: int = 32):
@@ -359,10 +433,7 @@ def panel_lu_nopiv(panel: jax.Array, start, m: int):
     d = lax.dynamic_slice(panel, (start, 0), (nb, nb))
     d_f, info = lu_nopiv_block(d)
     panel = lax.dynamic_update_slice(panel, d_f, (start, 0))
-    u11 = jnp.triu(d_f)
-    safe_u = u11 + jnp.diag(jnp.where(jnp.diagonal(u11) == 0,
-                                      jnp.ones(nb, u11.dtype),
-                                      jnp.zeros(nb, u11.dtype)))
+    safe_u = _safe_upper(d_f)
     below = (rows >= start + nb) & (rows < m)
     a21 = jnp.where(below[:, None], panel, jnp.zeros_like(panel))
     # L21 = A21·U11⁻¹  (right-side upper solve)

@@ -8,13 +8,13 @@ src/getrf_tntpiv.cc (CALU tournament), src/getrs.cc, src/gesv.cc.
 
 TPU redesign — one jitted ``shard_map`` program per driver:
 
-* **Panel**: the tile column is all-gathered (one ICI all-gather down
-  mesh rows — replacing the panel sub-communicator of
-  internal_getrf.cc:56-67) and *every chip factors the panel
-  redundantly* with a masked column loop
-  (internal/tile_kernels.panel_lu_factor). Redundant compute replaces
-  SLATE's ThreadBarrier + cross-rank argmax/bcast per column — on TPU
-  the panel flops are cheap compared to one ICI latency per column.
+* **Panel**: under the row cap of one ``lu`` the tile column is
+  all-gathered down mesh rows (internal_getrf.cc:56-67's panel
+  sub-communicator) and *every chip factors it redundantly*
+  (internal/tile_kernels.panel_lu_factor): no ThreadBarrier, no
+  cross-rank argmax/bcast per column. Over the cap it is factored
+  where its rows are stored (``_panel_stored_rows``): CALU's first
+  round on each chip's own rows, only nb winner rows a chip cross p.
 
 * **Row swaps**: LAPACK-style sequential swaps touch at most 2·nb rows
   per panel. Those candidate rows are gathered with a masked ``psum``
@@ -27,11 +27,11 @@ TPU redesign — one jitted ``shard_map`` program per driver:
 * **Trailing update**: batched triangular solve on the U block-row +
   one einsum over local trailing tiles, exactly like potrf.
 
-``getrf_tntpiv`` (CALU): v1 maps to the same panel algorithm — the
+``getrf_tntpiv`` (CALU): the same driver — under the cap the
 replicated panel *is* a degenerate tournament (every chip holds all
 candidate rows already), so the plain partial-pivot panel gives
-CALU's communication profile; a blocked tournament for panels too tall
-to replicate is a planned optimization.
+CALU's communication profile; over it the tournament runs for real,
+on a grid without ever assembling the panel.
 
 Pivots are returned as an int32 array ``piv[kt, nb]`` of global row
 indices (LAPACK ipiv semantics, 0-based): at panel k, step j, row
@@ -110,7 +110,7 @@ def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
             tier, depth = tune.driver_config("getrf", A.n, opts)
             chunked = g.size > 1 and kt >= 2 * lcm_pq
             guard = _superstep.arm("getrf", A, opts, checkpoint, chunked)
-        top.label(precision=tier, **_panel_labels(A, chunked))
+        top.label(precision=tier, **_panel_labels(A, chunked, depth))
         Anorm = _superstep.norm_one(A, opts) if health else None
         if chunked:
             # chunked super-steps (same scheme as potrf): trailing
@@ -212,8 +212,8 @@ def getrf_tntpiv(A: Matrix, opts=None):
     """CALU tournament-pivot LU (reference src/getrf_tntpiv.cc). The
     replicated panel is a collapsed tournament (all candidate rows are
     already on every chip); panels taller than the single-shot row cap
-    run the real chunked tournament
-    (internal.tile_kernels._panel_lu_tournament)."""
+    run the real one: on a grid where the rows are stored, winners
+    only crossing p (_panel_stored_rows), else _panel_lu_tournament."""
     return getrf(A, opts)
 
 
@@ -622,24 +622,37 @@ def _panel_max_rows(platform: str) -> int | None:
     return _LU_PANEL_MAX_ROWS if platform == "tpu" else None
 
 
-def _panel_labels(A, chunked: bool) -> dict:
+def _panel_form(M: int, cap: int | None, depth: int = 0) -> str:
+    """``stored``: the panel is over the row cap, so it takes the
+    tournament, and the sequential chunk core runs it on the rows each
+    device stores (``_panel_stored_rows``); ``gathered``: the [M, nb]
+    panel is assembled on every device (under the cap, or the
+    pipelined core, whose ring buffer holds it)."""
+    return ("stored" if cap is not None and M > cap and depth == 0
+            else "gathered")
+
+
+def _panel_labels(A, chunked: bool, depth: int) -> dict:
     """Labels of a chunked factorization's ``getrf`` span, none
-    otherwise: which pivoting the row cap chose for the gathered
-    panel's height, that height, and the bytes of panels each device
-    receives (``comm.allgather_panel_rows``: every step the whole
-    [M, nb] panel on every device); the bytes also go to the
+    otherwise: which pivoting the row cap chose for the panel's height,
+    that height, where the panel is factored (``_panel_form``) and the
+    bytes of panel rows each device receives over p in the
+    factorization: every step p·nb winner rows on the stored form, the
+    whole [M, nb] panel on the gathered one; the bytes also go to the
     ``getrf.panel_gather_bytes`` counter."""
     if not chunked:
         return {}
     g = A.grid
     M = A.data.shape[2] * g.p * A.nb
     cap = _panel_max_rows(g.devices[0].platform)
-    gathered = (min(A.mt, A.nt) * M * A.nb
-                * jnp.dtype(A.dtype).itemsize)
-    obs.count("getrf.panel_gather_bytes", gathered)
+    form = _panel_form(M, cap, depth)
+    received = (min(A.mt, A.nt) * (g.p * A.nb if form == "stored" else M)
+                * A.nb * jnp.dtype(A.dtype).itemsize)
+    obs.count("getrf.panel_gather_bytes", received)
     return {"pivoting": ("tournament" if cap is not None and M > cap
                          else "partial"),
-            "panel_rows": M, "panel_gather_bytes": gathered}
+            "panel_rows": M, "panel_form": form,
+            "panel_gather_bytes": received}
 
 
 class PivotOrder(NamedTuple):
@@ -903,7 +916,16 @@ def _getrf_chunk_core(A, pivots0, info0, k0, klen, win_hi=None,
     superstep DAG instead passes win_hi=k0+klen, swap_min=k0 so the
     factor task touches ONLY its own chunk columns and the tailLA /
     tailRest / backpivot tasks own the rest (runtime/hosttask.py
-    getrf_superstep_dag). ``k0`` must be a multiple of lcm(p, q)."""
+    getrf_superstep_dag). ``k0`` must be a multiple of lcm(p, q).
+
+    The panel's static height M chooses its form (``_panel_form``).
+    Under the row cap, ``gathered``: column k crosses q and is
+    all-gathered over p (scope ``panel_bcast``), every device factors
+    the [M, nb] panel (``panel``). Over it, ``stored``: column k
+    crosses q, the tournament's first round runs on each device's own
+    rows, and ``panel_bcast`` carries over p only the p·nb winner rows,
+    their ids and the diagonal tile (``_panel_stored_rows``); ``panel``
+    is that round, the last one, and the local rows' L21."""
     g = A.grid
     p, q, nb = g.p, g.q, A.nb
     m, n = A.m, A.n
@@ -912,6 +934,7 @@ def _getrf_chunk_core(A, pivots0, info0, k0, klen, win_hi=None,
     mt_p = mtl * p
     M = mt_p * nb
     panel_max_rows = _panel_max_rows(g.devices[0].platform)
+    stored = _panel_form(M, panel_max_rows) == "stored"
     windowed = win_hi is not None
     whi = nt if win_hi is None else win_hi
     r0s, c0s = k0 // p, k0 // q
@@ -938,7 +961,7 @@ def _getrf_chunk_core(A, pivots0, info0, k0, klen, win_hi=None,
             a = tl.mark(a, "step", step=k, device=dev,
                         kind=tl.KIND_STEP, edge="b", routine="getrf",
                         ndev=ndev)
-            # ---- panel: gather column k, factor redundantly --------
+            # ---- panel: column k, gathered or where it is stored ---
             # (named scopes as in _potrf_chunk_core: benchmarks/
             # span_report.py reads the device trace by them)
             with jax.named_scope("panel_bcast"):
@@ -955,20 +978,37 @@ def _getrf_chunk_core(A, pivots0, info0, k0, klen, win_hi=None,
             pcol = tl.mark(pcol, "panel_bcast", step=k, device=dev,
                            kind=tl.KIND_COLLECTIVE, edge="b",
                            routine="getrf", ndev=ndev)
-            with jax.named_scope("panel_bcast"):
-                full = comm.allgather_panel_rows(pcol, p, k % q)
-            full = tl.mark(full, "panel_bcast", step=k, device=dev,
-                           kind=tl.KIND_COLLECTIVE, edge="e",
-                           routine="getrf", ndev=ndev)
+            if stored:
+                # column k crosses q, then the tournament runs on the
+                # rows each device stores: only winners cross p
+                with jax.named_scope("panel_bcast"):
+                    pcol = comm.bcast_from_col(pcol, k % q)
+                pcol = tl.mark(pcol, "panel_bcast", step=k, device=dev,
+                               kind=tl.KIND_COLLECTIVE, edge="e",
+                               routine="getrf", ndev=ndev)
+                # lkk: the factored diagonal block, on every device;
+                # the local slots are the L rows `trailing` multiplies
+                newcol, lkk, piv_k, info_k = _panel_stored_rows(
+                    pcol, k, t_local, m, p, panel_max_rows)
+                lrows = newcol[r0s:]
+            else:
+                # the gathered panel, op for op as it was before the
+                # stored form (tests/test_getrf.py pins its text)
+                with jax.named_scope("panel_bcast"):
+                    full = comm.allgather_panel_rows(pcol, p, k % q)
+                full = tl.mark(full, "panel_bcast", step=k, device=dev,
+                               kind=tl.KIND_COLLECTIVE, edge="e",
+                               routine="getrf", ndev=ndev)
+                with jax.named_scope("panel"):
+                    panel2d, piv_k, info_k = panel_lu_factor(
+                        full.reshape(M, nb), k * nb, m,
+                        max_rows=panel_max_rows)
             with jax.named_scope("panel"):
-                panel2d = full.reshape(M, nb)
-                panel2d, piv_k, info_k = panel_lu_factor(
-                    panel2d, k * nb, m, max_rows=panel_max_rows)
                 info = info + info_k
                 pivots = pivots.at[k].set(piv_k)
-                ptiles = panel2d.reshape(mt_p, nb, nb)
-
-                newcol = jnp.take(ptiles, gi, axis=0)
+                if not stored:
+                    ptiles = panel2d.reshape(mt_p, nb, nb)
+                    newcol = jnp.take(ptiles, gi, axis=0)
                 a = jnp.where(
                     c == k % q,
                     lax.dynamic_update_index_in_dim(a, newcol, k // q,
@@ -981,7 +1021,8 @@ def _getrf_chunk_core(A, pivots0, info0, k0, klen, win_hi=None,
 
             # ---- U block-row solve, window columns only ------------
             with jax.named_scope("diag_solve"):
-                lkk = lax.dynamic_slice(panel2d, (k * nb, 0), (nb, nb))
+                if not stored:
+                    lkk = lax.dynamic_slice(panel2d, (k * nb, 0), (nb, nb))
                 arow = lax.dynamic_index_in_dim(a, k // p, axis=0,
                                                 keepdims=False)[c0s:c1s]
                 solved = lax.linalg.triangular_solve(
@@ -1000,7 +1041,8 @@ def _getrf_chunk_core(A, pivots0, info0, k0, klen, win_hi=None,
 
             # ---- trailing gemm on the window -----------------------
             with jax.named_scope("trailing"):
-                lrows = jnp.take(ptiles, gis, axis=0)
+                if not stored:
+                    lrows = jnp.take(ptiles, gis, axis=0)
                 below = (gis > k) & (gis < mt)
                 lrows = jnp.where(below[:, None, None], lrows,
                                   jnp.zeros_like(lrows))
@@ -1070,7 +1112,13 @@ def _getrf_pipe_chunk_core(A, pivots0, info0, k0, klen, depth=1,
     exclusion windows are empty and the advance is the single
     fresh-U-row gemm). ``depth`` is static and part of the
     executable-cache key. No windowed (``win_hi``/``swap_min``)
-    variant — the superstep DAG keeps the sequential cores."""
+    variant — the superstep DAG keeps the sequential cores.
+
+    This core keeps the gathered [M, nb] panel whatever its height (its
+    ring buffer holds ``panel2d``): over the row cap it still assembles
+    the panel on every device and runs ``_panel_lu_tournament`` there,
+    where ``_getrf_chunk_core`` factors it where its rows are stored
+    (``_panel_form`` says ``gathered`` for any depth > 0)."""
     plan = dag.chunk_plan("getrf", k0, klen, depth)
     d = plan.d_eff
     g = A.grid
@@ -1439,6 +1487,76 @@ def _getrf_backpiv_core(A, pivots, k0, klen, hi):
 _getrf_backpiv_jit = cached_jit(_getrf_backpiv_core,
                                 routine="getrf.backpiv",
                                 static_argnames=("k0", "klen", "hi"))
+
+
+def _panel_stored_rows(pcol, k, t_local, m, p, max_rows):
+    """Step k's tournament panel factored where its rows are stored
+    (CALU as the reference's getrf_tntpiv runs it: the first round on
+    each rank's own rows). The [M, nb] panel is never assembled.
+
+    pcol: [mtl, nb, nb] this device's slots of tile column k, padded
+    diagonal fixed, the same on every device of a mesh row (the caller
+    sent it over q); t_local: [mtl, nb] their global row ids.
+
+    Round one picks nb winners among the local rows of the active
+    window [k·nb, max(m, k·nb+nb)), the rest zeroed; the p·nb winners
+    and the diagonal tile cross p (scope ``panel_bcast``), the last
+    round and LAPACK's swap list run on every device. The column's own
+    rows are then swapped — outside the diagonal block a swap can only
+    bring in one of that block's rows — and the rows below the block
+    take L21 = A21·U11⁻¹ in one triangular solve over local rows.
+
+    Returns (newcol, lu_top, piv_k, info_k): the factored local slots
+    of the column, the factored diagonal block (replicated), and the
+    step's swap list and zero-pivot count as ``panel_lu_factor`` gives
+    them."""
+    from ..internal.tile_kernels import (tournament_winners,
+                                         tournament_pivots, _safe_upper,
+                                         _factor_dtype)
+    mtl, nb, _ = pcol.shape
+    L, M = mtl * nb, mtl * p * nb
+    fd = _factor_dtype(pcol.dtype)
+    r = lax.axis_index(AXIS_P)
+    start = k * nb
+    t_flat = t_local.reshape(L).astype(jnp.int32)
+    with jax.named_scope("panel"):
+        rows = pcol.reshape(L, nb)
+        active = (t_flat >= start) & (t_flat < jnp.maximum(m, start + nb))
+        # round one sees the slots from the first active one on (whole
+        # tiles move, no row does): active rows first, so a tie falls
+        # to one of them
+        order = (jnp.sum(t_local[:, 0] < start) + jnp.arange(mtl)) % mtl
+        masked = jnp.where(active.reshape(mtl, nb, 1), pcol,
+                           jnp.zeros_like(pcol))
+        ids = jnp.where(active, t_flat, M).reshape(mtl, nb)
+        win_rows, win_ids = tournament_winners(
+            jnp.take(masked, order, axis=0).reshape(L, nb),
+            jnp.take(ids, order, axis=0).reshape(L), max_rows, M)
+    with jax.named_scope("panel_bcast"):
+        cand = comm.allgather_tiled(win_rows, AXIS_P, p)
+        cand_idx = comm.allgather_tiled(win_ids, AXIS_P, p)
+        diag = comm.bcast_from_row(
+            lax.dynamic_index_in_dim(pcol, k // p, axis=0, keepdims=False),
+            k % p)
+    with jax.named_scope("panel"):
+        lu_top, piv_k, locof, info_k = tournament_pivots(
+            cand, cand_idx, start, M, max_rows, pcol.dtype)
+        # where the diagonal block's rows went: local rows outside the
+        # block that received one (anything else is dropped)
+        pos = lax.dynamic_slice(locof, (start,), (nb,))
+        tile = pos // nb
+        here = (tile % p == r) & (tile != k)
+        lidx = jnp.where(here, (tile // p) * nb + pos % nb,
+                         L + jnp.arange(nb, dtype=jnp.int32))
+        swapped = rows.at[lidx].set(diag, mode="drop", unique_indices=True)
+        l21 = lax.linalg.triangular_solve(
+            _safe_upper(lu_top).astype(fd), swapped.astype(fd),
+            left_side=False, lower=False).astype(pcol.dtype)
+        below = active & (t_flat >= start + nb)
+        out = jnp.where(below[:, None], l21, rows).reshape(mtl, nb, nb)
+        on_diag = (t_local[:, 0] == start)[:, None, None]
+        newcol = jnp.where(on_diag, lu_top[None], out)
+    return newcol, lu_top, piv_k, info_k
 
 
 def _swap_rows_local(a, piv_k, start, t_local, nb, p, q, exclude_col,

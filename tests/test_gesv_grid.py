@@ -5,12 +5,17 @@ X-moving ``trsm``), against the benchmark's plain numpy solver
 (``benchmarks/harness/plain_solver.gesv``) and the residual of
 ``benchmarks/harness/check.py``.
 
-Both forms of the gathered panel run. ``partial``: the row cap as the
-CPU has it (none), one ``lax.linalg.lu`` of the whole panel, LAPACK's
-pivots. ``tournament``: the cap lowered to 256 rows, so that the 512-row
-panel goes through ``_panel_lu_tournament`` with two row chunks, as the
-chip's 16,384-row panel does over its cap of 10,240 (the rule itself,
-``getrf._panel_max_rows``, reads the platform and is not changed).
+Both forms of the panel run. ``partial``: the row cap as the CPU has it
+(none), the [M, nb] panel gathered to every device, one
+``lax.linalg.lu`` of it, LAPACK's pivots. ``tournament``: the cap
+lowered to 256 rows, so that the 512-row panel is over it, as the
+chip's 16,384-row panel is over its cap of 10,240 (the rule itself,
+``getrf._panel_max_rows``, reads the platform and is not changed); the
+panel is then factored where its rows are stored
+(``getrf._panel_stored_rows``): one ``lu`` of each device's 256 rows,
+the 2 x 32 winners gathered over p, a last ``lu`` of them.
+``tournament-cap128``: the cap under the local height, so a device's
+rows go through two chunks and a second round before anything crosses.
 
 Geometries: kt = 16 block columns of 32, so eight chunks as on the
 chip; n = 512 on the tile grid and n = 500 with 20 real rows in the
@@ -33,21 +38,24 @@ from benchmarks.harness import check, plain_solver
 from tests.test_gesv_ragged import _paths, errors_in_eps
 
 NB, KT = 32, 16
-TOURNAMENT_CAP = 256            # two row chunks of the 512-row panel
+# the row cap of each form: None as the CPU has it; 256 = a device's
+# rows of the 512-row panel; 128 = two local chunks, a second round
+CAPS = {"partial": None, "tournament": 256, "tournament-cap128": 128}
 GEOMETRIES = [512, 500]
-FORMS = ["partial", "tournament"]
+FORMS = list(CAPS)
 CASES = [(n, nrhs, form) for form in FORMS for n in GEOMETRIES
          for nrhs in (1, 8)]
 IDS = [f"n{n}-nrhs{nrhs}-{form}" for n, nrhs, form in CASES]
 TIER = {st.Option.TrailingPrecision: "bf16_6x"}
 
 # Backward errors in units of eps = 2^-24, evaluated in float64. Over
-# these cases the program reads 1.4-2.9 (inf) and 1.6-2.1 (Frobenius)
-# with either panel (3.5 and 2.4 at most through Op.Trans), the plain
-# f32 solver 1.8-3.0 and 1.8-3.3; the plain solver with its trailing
-# products at bf16_3x reads 69-85 and 52-56 (sixteen trailing updates of
-# depth 32). The limit 12 is 3.4x over the largest sound reading and
-# 4.4x under the smallest lowered one.
+# these cases the program reads 1.4-3.5 (inf) and 1.6-2.2 (Frobenius)
+# with any of the three panels (5.2 and 3.0 at most through Op.Trans,
+# on the stored tournament), the plain f32 solver 1.8-3.0 and 1.8-3.3;
+# the plain solver with its trailing products at bf16_3x reads 69-85
+# and 52-56 (sixteen trailing updates of depth 32). The limit 12 is
+# 2.3x over the largest sound reading and 4.4x under the smallest
+# lowered one.
 TOL_EPS = 12.0
 # As tests/test_gesv_ragged.py: two answers that each solve a nearby
 # system lie within (the sum of their backward errors) x cond of each
@@ -55,9 +63,9 @@ TOL_EPS = 12.0
 # (another choice of pivots, the same system); cond is 2.5e5-3.6e5.
 TOL_COND_EPS = 1.0
 # ||P A - L U||_F / ||A||_F in eps: 22.6-22.8 with LAPACK's pivots,
-# 25.6-25.8 with the tournament's (n rounding errors of random sign an
-# entry: sqrt(n) = 22.6). 100 leaves 4x; a wrong permutation or a row
-# of L out of place reads 10^6 and more.
+# 26.4-27.7 with the stored tournament's, max |L| 2.0-2.2 (n rounding
+# errors of random sign an entry: sqrt(n) = 22.6). 100 leaves 3.6x; a
+# wrong permutation or a row of L out of place reads 10^6 and more.
 TOL_FACTOR_EPS = 100.0
 
 
@@ -76,9 +84,9 @@ def solved(grid22):
     try:
         for form in FORMS:
             jitcache.clear_in_process("getrf.chunk")
-            if form == "tournament":
+            if CAPS[form] is not None:
                 patch.setattr(getrf_mod, "_panel_max_rows",
-                              lambda platform: TOURNAMENT_CAP)
+                              lambda platform, cap=CAPS[form]: cap)
             for i, n in enumerate(GEOMETRIES):
                 A = st.random_matrix(n, n, NB, grid22, np.float32,
                                      seed=6001 + i)
@@ -112,6 +120,14 @@ def solved(grid22):
         obs.reset()
 
 
+def _panel_bytes(form):
+    """What a device receives of panel rows over p in one factorization:
+    gathered, every one of the kt panels whole; stored, the p x nb
+    winner rows of each."""
+    rows = KT * NB if form == "partial" else 2 * NB
+    return KT * rows * NB * 4
+
+
 def _perm_of(piv, rows):
     """LAPACK's swap list replayed: row i of P·A is row ``perm[i]`` of A."""
     perm = np.arange(rows)
@@ -126,8 +142,7 @@ def test_info_zero_on_the_chunked_path(solved, n, nrhs, form):
     assert s["info"] == 0
     assert s["x"].shape == (n, nrhs) and np.isfinite(s["x"]).all()
     assert s["chunked"] == 1 and s["paths"] == 1
-    # each device receives every one of the kt panels whole
-    assert s["gathered"] == KT * (KT * NB) * NB * 4
+    assert s["gathered"] == _panel_bytes(form)
 
 
 @pytest.mark.parametrize("n,nrhs,form", CASES, ids=IDS)
@@ -211,9 +226,11 @@ def test_the_span_tree_of_one_solve(solved, n, nrhs, form):
     assert tree[0][1]["labels"]["grid"] == "2x2"
     assert tree[0][1]["labels"]["nrhs"] == nrhs
     top = dict(tree)["slate.gesv/getrf"]["labels"]
-    assert top["pivoting"] == form
+    assert top["pivoting"] == form.split("-")[0]
+    assert top["panel_form"] == ("gathered" if form == "partial"
+                                 else "stored")
     assert top["panel_rows"] == KT * NB
-    assert top["panel_gather_bytes"] == KT * (KT * NB) * NB * 4
+    assert top["panel_gather_bytes"] == _panel_bytes(form)
     assert top["precision"] == "bf16_6x"
     assert top["mt"] == KT and top["pad_rows"] == KT * NB - n
     prepare = names.index("slate.gesv/getrf/getrf.prepare")

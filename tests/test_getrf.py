@@ -227,6 +227,174 @@ def test_panel_lu_tournament():
         assert np.abs(L).max() < 8.0
 
 
+def _stored_rows_panel(grid, panel, start, m, nb, cap):
+    """``getrf._panel_stored_rows`` on ``panel`` [M, nb] laid out
+    block-cyclically over the grid's p (tile t on mesh row t % p, the
+    same on every mesh column, as the chunk core hands it over after
+    column k has crossed q), the padded diagonal fixed as the core
+    fixes it. Returns the factored panel in global row order, the
+    replicated diagonal block, the swap list and info."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from slate_tpu.grid import AXIS_P
+    from slate_tpu.internal import masks
+    from slate_tpu.linalg import getrf
+    p = grid.p
+    M = panel.shape[0]
+    mtl, k = M // nb // p, start // nb
+    tiles = jnp.asarray(panel).reshape(M // nb, nb, nb)
+    tiles = tiles.at[k].set(masks.tile_diag_pad_identity(tiles[k], k, m, nb))
+    local = tiles.reshape(mtl, p, nb, nb).transpose(1, 0, 2, 3)
+
+    def body(x):
+        gi = masks.local_tile_rows(mtl, p)
+        t_local = gi[:, None] * nb + jnp.arange(nb)[None, :]
+        newcol, lu_top, piv, info = getrf._panel_stored_rows(
+            x[0], jnp.int32(k), t_local, m, p, cap)
+        return newcol[None], lu_top, piv, info
+
+    newcol, lu_top, piv, info = jax.jit(jax.shard_map(
+        body, mesh=grid.mesh, in_specs=P(AXIS_P),
+        out_specs=(P(AXIS_P), P(), P(), P()), check_vma=False))(local)
+    out = np.asarray(newcol).transpose(1, 0, 2, 3).reshape(M, nb)
+    fixed = np.asarray(tiles).reshape(M, nb)
+    return out, fixed, np.asarray(lu_top), np.asarray(piv), int(info)
+
+
+def _assert_swap_list(piv, start, hi):
+    """LAPACK's contract of a step's swap list: row start+j is swapped
+    with a row at or below it, inside the active window."""
+    at = start + np.arange(piv.size)
+    assert (piv >= at).all() and (piv < hi).all(), piv
+
+
+@pytest.fixture(scope="module")
+def grids():
+    import jax
+    d = jax.devices()
+    return {"2x2": st.Grid(2, 2, devices=d[:4]),
+            "4x2": st.Grid(4, 2, devices=d[:8]),
+            "1x4": st.Grid(1, 4, devices=d[:4])}
+
+
+# on the 2x2 a device stores 48 of the 96 rows. cap 48: one lu each;
+# 24: two local chunks and a second local round. On a 4x2 (24 rows a
+# device) cap 16: two local chunks, and the 4 x 8 gathered winners are
+# over the cap too, so the rounds after the gather run as well. On a
+# 1x4 every device stores all 96 rows and nothing crosses p
+@pytest.mark.parametrize("shape,cap", [("2x2", 48), ("2x2", 24),
+                                       ("4x2", 16), ("1x4", 48)])
+@pytest.mark.parametrize("start,m", [(0, 96), (16, 90), (88, 90), (88, 96)],
+                         ids=["first", "ragged", "ragged-last", "last"])
+def test_panel_stored_rows_contract(grids, shape, cap, start, m):
+    """The tournament run where the rows are stored, against
+    ``_panel_lu_tournament``'s contract: P*panel = L*U on the active
+    window, a LAPACK swap list, rows outside the window untouched, the
+    replicated diagonal block = the top of the factor. ``last``: one
+    mesh row stores the active rows, the others send sentinels only."""
+    M, nb = 96, 8
+    rng = np.random.default_rng(11 + start)
+    panel = rng.standard_normal((M, nb))
+    panel[m:] = 0.0
+    out, ref, lu_top, piv, info = _stored_rows_panel(
+        grids[shape], panel, start, m, nb, cap)
+    hi = max(m, start + nb)
+    assert info == 0
+    _assert_swap_list(piv, start, hi)
+    np.testing.assert_array_equal(out[:start], ref[:start])
+    np.testing.assert_array_equal(out[hi:], ref[hi:])
+    np.testing.assert_array_equal(out[start:start + nb], lu_top)
+    perm = np.arange(M)
+    for j, pv in enumerate(piv):
+        perm[[start + j, pv]] = perm[[pv, start + j]]
+    pa = ref[perm][start:hi]
+    lw = out[start:hi]
+    L = np.tril(lw, -1)
+    L[:nb] += np.eye(nb)
+    err = np.linalg.norm(pa - L @ np.triu(lw[:nb])) / np.linalg.norm(pa)
+    assert err < 1e-12, err
+    assert np.abs(L).max() < 8.0            # CALU's growth stays modest
+
+
+@pytest.mark.parametrize("cap", [48, 24])
+def test_panel_stored_rows_singular_column(grid22, cap):
+    """A zero column counts into info and leaves a finite factor with
+    P*panel = L*U; an all-zero window counts nb zero pivots and moves
+    nothing it should not."""
+    M, nb, start, m = 96, 8, 16, 96
+    rng = np.random.default_rng(5)
+    panel = rng.standard_normal((M, nb))
+    panel[:, 3] = 0.0
+    out, ref, _, piv, info = _stored_rows_panel(grid22, panel, start, m,
+                                                nb, cap)
+    assert info == 1 and np.isfinite(out).all()
+    _assert_swap_list(piv, start, m)
+    perm = np.arange(M)
+    for j, pv in enumerate(piv):
+        perm[[start + j, pv]] = perm[[pv, start + j]]
+    lw = out[start:]
+    L = np.tril(lw, -1)
+    L[:nb] += np.eye(nb)
+    assert np.abs(ref[perm][start:] - L @ np.triu(lw[:nb])).max() < 1e-12
+    panel[start:] = 0.0
+    out, ref, _, piv, info = _stored_rows_panel(grid22, panel, start, m,
+                                                nb, cap)
+    assert info == nb
+    _assert_swap_list(piv, start, m)
+    np.testing.assert_array_equal(out[:start], ref[:start])
+    assert (out[start:] == 0).all()
+
+
+@pytest.mark.parametrize("M,cap,depth,form", [
+    (16384, 10240, 0, "stored"),        # the chip's 2x2 cell
+    (10240, 10240, 0, "gathered"),      # at the cap: one lu of it
+    (16384, None, 0, "gathered"),       # no cap off the TPU
+    (16384, 10240, 1, "gathered"),      # the pipelined core's ring
+])
+def test_panel_form_is_read_from_the_shape(M, cap, depth, form):
+    from slate_tpu.linalg import getrf
+    assert getrf._panel_form(M, cap, depth) == form
+
+
+# sha256 of _getrf_chunk_jit's lowered StableHLO text under the cap
+# (the gathered panel) on the CPU 2x2 at nb = 32, as the commit before
+# the stored form (8d50beb) lowers it: (n, k0, window) -> digest;
+# jax 0.9.0, x64 on as tests/conftest.py sets it
+_GATHERED_CHUNK_TEXT = {
+    (512, 0, None): "42f58bf7225b8662fcfa404ab3d980e0"
+                    "a532c1c58998a1d75bfac20079e14b84",
+    (500, 14, None): "02dc781a385c1db18cdcb30f95a38e6e"
+                     "efa18220c7855bbc45959c6fc36c7d12",
+    (512, 4, (6, 4)): "1206e6a9136cf62d24273fbdb1955963"
+                      "7eda6142b29f369ba73c94b8bd7d113f",
+}
+
+
+@pytest.mark.parametrize("n,k0,window", list(_GATHERED_CHUNK_TEXT),
+                         ids=["first", "ragged-last", "windowed"])
+def test_chunk_core_under_the_cap_lowers_to_the_text_it_had(grid22, n, k0,
+                                                            window):
+    """Under the row cap the shape chooses the gathered panel, and the
+    chunk program is the one it was: the same StableHLO text."""
+    import hashlib
+    import jax
+    import jax.numpy as jnp
+    from slate_tpu.linalg import getrf
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests were taken with jax 0.9.0")
+    nb = 32
+    A = st.random_matrix(n, n, nb, grid22, jnp.float32, seed=1)
+    kw = {} if window is None else {"win_hi": window[0],
+                                    "swap_min": window[1]}
+    low = getrf._getrf_chunk_jit.lower(
+        A, jnp.zeros((A.mt, nb), jnp.int32), jnp.zeros((), jnp.int32),
+        k0, 2, tier="bf16_6x", **kw)
+    assert "all_gather" in low.as_text()
+    assert (hashlib.sha256(low.as_text().encode()).hexdigest()
+            == _GATHERED_CHUNK_TEXT[n, k0, window])
+
+
 def test_getrf_chunked_spmd_path(grid24):
     # kt=12 >= 2*lcm(2,4): exercises the chunked super-step programs,
     # with a matrix that genuinely pivots

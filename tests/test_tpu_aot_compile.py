@@ -160,19 +160,36 @@ def _all_gathers(text) -> int:
     return text.count(" all-gather(") + text.count(" all-gather-start(")
 
 
+def _all_gather_shapes(text) -> set:
+    """(dtype, dims) of every all-gather's result in an optimized HLO
+    text (an async one appears once in each fusion it is split over)."""
+    import re
+    return {(dt, tuple(int(d) for d in dims.split(",")))
+            for dt, dims in re.findall(
+                r"= (\w+)\[([\d,]+)\]\S* all-gather(?:-start)?\(", text)}
+
+
 def test_getrf_chunk_2x2_compiles_sharded(tpu_grid22):
-    _assert_sharded_with_collectives(_getrf_chunk(tpu_grid22, 0),
-                                     H * H * 4)
+    c = _getrf_chunk(tpu_grid22, 0)
+    _assert_sharded_with_collectives(c, H * H * 4)
+    # the first chunk's temp decides the cell's peak_hbm_gib: 396 MiB
+    # compiled here (2026-09-28, jax 0.9.0) with the panel factored where
+    # its rows are stored; 860 while the [M, nb] panel was gathered
+    temp_mib = c.memory_analysis().temp_size_in_bytes / 2 ** 20
+    assert temp_mib < 450, temp_mib
 
 
 def test_getrf_last_chunk_2x2_collectives_and_temp(tpu_grid22):
     """The last of the eight chunk programs ``gesv_16k_2x2`` runs
     (k0 = 14, two block columns). What crosses chips in a step, and
-    nothing else: column k's local slots over q (mtl*nb*nb elements),
-    that panel gathered over p to every device, the row swaps'
-    candidate rows over p (2*nb rows of the local stack: as many bytes
-    as the panel), the U block-row of the window over p. No all-to-all.
-    A distributed pivot search has these numbers to beat."""
+    nothing else: column k's local slots over q (mtl*nb*nb elements);
+    over p the tournament's p*nb winner rows and their row ids (the
+    16,384-row panel is over the cap of one ``lu``, so it is factored
+    where its rows are stored and never gathered), the diagonal tile,
+    the row swaps' candidate rows (2*nb rows of the local stack: twice
+    column k's bytes, the largest thing that moves), the U block-row
+    of the window. No all-to-all. The next change to the panel or the
+    swaps has these numbers to beat."""
     import math
     mtl = ntl = H // NB // 2
     c = _getrf_chunk(tpu_grid22, H // NB - 2)
@@ -181,12 +198,15 @@ def test_getrf_last_chunk_2x2_collectives_and_temp(tpu_grid22):
     assert "all-to-all" not in text and "collective-permute" not in text
     reduced = sorted(math.prod(dims) for _, dims in all_reduce_shapes(text))
     assert reduced == [NB * NB,                 # U(k, last tile column)
+                       NB * NB,                 # the diagonal tile over p
                        mtl * NB * NB,           # column k over q
                        2 * NB * ntl * NB], reduced      # swapped rows
-    assert _all_gathers(text) == 1
-    assert f"f32[2,{mtl},{NB},{NB}]" in text    # the [M, nb] panel, whole
-    # 330 MiB compiled here (2026-09-28, jax 0.9.0): the 64 MiB panel in
-    # its several forms and the swaps' rows; the first chunk holds 860
+    assert _all_gather_shapes(text) == {("f32", (2 * NB, NB)),
+                                        ("s32", (2 * NB,))}
+    assert f"f32[2,{mtl},{NB},{NB}]" not in text    # no [M, nb] panel
+    # 362 MiB compiled here (2026-09-28, jax 0.9.0; 330 with the
+    # gathered panel, whose first chunk held 860): the swaps' rows
+    # (2 x 64 MiB of candidates, 256 MiB of replacements) lead it
     temp_mib = c.memory_analysis().temp_size_in_bytes / 2 ** 20
     assert temp_mib < 400, temp_mib
 
