@@ -70,30 +70,30 @@ from . import _superstep
 # getrf — partial pivoting
 # ---------------------------------------------------------------------------
 
-def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
-          health: bool = False, checkpoint=None, _resume=None):
-    """LU with partial pivoting: P·A = L·U (reference src/getrf.cc).
+def _getrf_native(A: Matrix, opts=None, overwrite_a: bool = False,
+                  health: bool = False, checkpoint=None, _resume=None):
+    """:func:`getrf` with the pivots in the form the factor produced
+    them (ROADMAP D2a): ``(LU, piv, info)`` where ``piv`` is a
+    :class:`PivotOrder` from the one-chip fast path (pivoting by index
+    never makes a swap list) and the [kt, nb] int32 LAPACK pivots from
+    every other program.  :func:`getrs` takes either, so a caller that
+    only solves with the factors (``linalg/mixed.py``'s refinement,
+    ``gecondest`` on the health path) never pays the host conversion
+    nor, in every solve, the serial replay of n swaps (``_sim_perm``):
+    an order is applied as one gather (``_apply_order_jit``).
 
-    Returns ``(LU, piv, info)``: LU holds unit-lower L below the
-    diagonal and U on/above (LAPACK layout); piv is [kt, nb] int32
-    global-row pivots; info = number of zero pivots (0 ⇒ nonsingular).
+    The public :func:`getrf`, further down beside
+    :func:`pivot_order_to_ipiv`, is this function followed by that
+    conversion, for the caller who asks for LAPACK pivots; its
+    docstring describes the arguments, which are passed through as
+    they are.
 
-    ``overwrite_a=True`` donates A's device buffer to the factors
-    (reference in-place semantics); A must not be used afterwards.
-
-    ``health=True`` swaps the info scalar for a
-    :class:`~slate_tpu.robust.guards.HealthReport` — same info value
-    plus an rcond estimate via ``gecondest`` (host-synced; opt-in).
-
-    ``checkpoint`` controls factorization-state checkpointing on the
-    chunked multi-device path (robust.ckpt, docs/robustness.md
-    "Checkpoint & resume"): ``None``/``True`` follow the
-    ``SLATE_TPU_CKPT_DIR`` arming (off-by-default passthrough),
-    ``False`` disables for this call, an int sets the save stride in
-    chunks.  Saves offload asynchronously and never block the next
-    trailing update; :func:`getrf_resume` picks a killed run back up
-    bitwise-identically.  ``_resume`` is the internal restart state
-    (use :func:`getrf_resume`).
+    Kept at this place and at this length on purpose: the fast core's
+    persistent-cache key holds the line numbers of the kernel calls
+    below (PERF.md section 7, fault 5), so a line added or taken away
+    above them costs every checkout a cold compile of the 16k LU
+    (two minutes on a v5e).  ``_gesv`` still chooses the fast path a
+    second time by itself (fault 1, D2a's first half).
     """
     from ..robust import faults as _faults
     with trace.block("getrf", routine="getrf", m=A.m, n=A.n, nb=A.nb,
@@ -157,9 +157,9 @@ def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
                             A, interpret=(fm == "interpret"),
                             want_ipiv=False, fold=_fold_now(),
                             tier=tier)
-                    # LAPACK ipiv derived on host (off the device
-                    # program)
-                    return data, pivot_order_to_ipiv(order), info
+                    # the order as the factor made it; LAPACK ipiv
+                    # only in getrf(), for the caller who asks
+                    return data, PivotOrder(order), info
                 jit_fn = (_getrf_jit_overwrite if donate
                           else _getrf_jit)
                 with trace.block("getrf.chunk", phase="one_program",
@@ -678,6 +678,43 @@ def pivot_order_to_ipiv(order) -> jnp.ndarray:
     ipiv = obs.sync_read("gesv.order_to_ipiv",
                          lambda o: _rt.order_to_ipiv(_np.asarray(o)), arr)
     return jnp.asarray(ipiv, jnp.int32).reshape(kt, nb)
+
+
+def getrf(A: Matrix, opts=None, overwrite_a: bool = False,
+          health: bool = False, checkpoint=None, _resume=None):
+    """LU with partial pivoting: P·A = L·U (reference src/getrf.cc).
+
+    Returns ``(LU, piv, info)``: LU holds unit-lower L below the
+    diagonal and U on/above (LAPACK layout); piv is [kt, nb] int32
+    global-row pivots; info = number of zero pivots (0 ⇒ nonsingular).
+
+    ``overwrite_a=True`` donates A's device buffer to the factors
+    (reference in-place semantics); A must not be used afterwards.
+
+    ``health=True`` swaps the info scalar for a
+    :class:`~slate_tpu.robust.guards.HealthReport` — same info value
+    plus an rcond estimate via ``gecondest`` (host-synced; opt-in).
+
+    ``checkpoint`` controls factorization-state checkpointing on the
+    chunked multi-device path (robust.ckpt, docs/robustness.md
+    "Checkpoint & resume"): ``None``/``True`` follow the
+    ``SLATE_TPU_CKPT_DIR`` arming (off-by-default passthrough),
+    ``False`` disables for this call, an int sets the save stride in
+    chunks.  Saves offload asynchronously and never block the next
+    trailing update; :func:`getrf_resume` picks a killed run back up
+    bitwise-identically.  ``_resume`` is the internal restart state
+    (use :func:`getrf_resume`).
+
+    The one-chip fast path factors by index and has no swap list: its
+    elimination order is turned into LAPACK pivots here, on the host
+    (one blocking read).  A caller that only hands the pivots back to
+    :func:`getrs` takes :func:`_getrf_native` and skips that.
+    """
+    LU, piv, info = _getrf_native(A, opts, overwrite_a, health,
+                                  checkpoint, _resume)
+    if isinstance(piv, PivotOrder):
+        piv = pivot_order_to_ipiv(piv)
+    return LU, piv, info
 
 
 def _getrf_dense_1dev(A, piv_mode, tier=None):
