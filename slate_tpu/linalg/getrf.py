@@ -79,7 +79,7 @@ def _getrf_native(A: Matrix, opts=None, overwrite_a: bool = False,
     every other program.  :func:`getrs` takes either, so a caller that
     only solves with the factors (``linalg/mixed.py``'s refinement,
     ``gecondest`` on the health path) never pays the host conversion
-    nor, in every solve, the serial replay of n swaps (``_sim_perm``):
+    nor, in every solve, the replay of its swap list (``_sim_perm``):
     an order is applied as one gather (``_apply_order_jit``).
 
     The public :func:`getrf`, further down beside
@@ -1819,7 +1819,8 @@ def gesv_batched(a, b, opts=None, *, nb: int | None = None):
 # ---------------------------------------------------------------------------
 # pivot application to a full matrix (reference internal_swap.cc —
 # the reference swaps rows one MPI_Sendrecv at a time; here the swap
-# sequence is composed into one global permutation (O(M) ints, cheap)
+# list is composed into one global permutation (``_sim_perm``: every
+# panel's swaps replayed at once, nb + kt dependent steps, O(M) ints)
 # and applied in one pass):
 #
 # * single device: local dense take (fastest, no comm);
@@ -1833,9 +1834,9 @@ def gesv_batched(a, b, opts=None, *, nb: int | None = None):
 def _apply_pivots_kind(B: Matrix, piv) -> str:
     """Which program applies ``piv`` to B's rows: ``order_gather`` (an
     elimination order, one gather), ``swap_sim`` (LAPACK pivots
-    replayed swap by swap into a permutation, then one gather of the
-    replicated B) or ``dist`` (the same replay, rows exchanged tile row
-    by tile row with no replicated B)."""
+    replayed panel by panel into a permutation, ``_sim_perm``, then one
+    gather of the replicated B) or ``dist`` (the same replay, rows
+    exchanged tile row by tile row with no replicated B)."""
     if isinstance(piv, PivotOrder):
         return "order_gather"
     if B.grid.size == 1:
@@ -1858,13 +1859,17 @@ def _apply_pivots_kind(B: Matrix, piv) -> str:
 
 
 def _apply_pivots_labels(B: Matrix, piv) -> dict:
-    """Labels of a ``getrs.apply_pivots`` span: the ``kind`` and, where
-    LAPACK pivots are replayed, the ``steps`` of that replay (one
-    dependent swap each: ``_sim_perm``)."""
+    """Labels of a ``getrs.apply_pivots`` span: the ``kind`` (also
+    counted, ``getrs.apply_pivots{kind}``) and, where LAPACK pivots are
+    replayed, the ``steps`` of that replay (kt·nb swaps) beside its
+    ``serial_steps`` (nb + kt: what of ``_sim_perm`` waits on the step
+    before it)."""
     kind = _apply_pivots_kind(B, piv)
+    obs.count("getrs.apply_pivots", 1, kind=kind)
     if kind == "order_gather":
         return {"kind": kind}
-    return {"kind": kind, "steps": int(piv.size)}
+    kt, nb = piv.shape
+    return {"kind": kind, "steps": kt * nb, "serial_steps": nb + kt}
 
 
 def _apply_pivots_matrix(B: Matrix, piv, forward: bool) -> Matrix:
@@ -1881,19 +1886,48 @@ def _apply_pivots_matrix(B: Matrix, piv, forward: bool) -> Matrix:
 
 
 def _sim_perm(piv, Mrows, forward):
-    """Compose the pivot swap sequence into out_row[i] = in_row[perm[i]]."""
-    kt, nbp = piv.shape
-    perm0 = jnp.arange(Mrows, dtype=jnp.int32)
+    """Compose the LAPACK swap list ``piv`` [kt, nb] (swap t exchanges
+    rows t and ``piv.reshape(-1)[t]``, t ascending) into the row
+    permutation it amounts to, out_row[i] = in_row[perm[i]], entry for
+    entry what replaying the kt·nb swaps one after another gives, for
+    any swap list, in nb + kt dependent steps.
 
-    def sim(t, perm):
-        j = t if forward else kt * nbp - 1 - t
-        kk, jj = j // nbp, j % nbp
-        aj = kk * nbp + jj
-        bj = piv[kk, jj]
-        pa, pb = perm[aj], perm[bj]
-        return perm.at[aj].set(pb).at[bj].set(pa)
+    The swaps of panel k, started from the identity, are a permutation
+    P_k that no other panel has a say in, and it moves at most the
+    2·nb rows ``pos[k]`` it names: k·nb + j and ``piv[k, j]``.  So all
+    kt panels are replayed at once on a [kt, 2·nb] state (the row held
+    in each named place), nb steps of two masked passes; a row named
+    more than once is followed in the slot of its first mention.  Then
+    perm = P_0 ∘ P_1 ∘ … is kt gathers and scatters of 2·nb entries,
+    and ``forward=False`` (the swaps undone, last first) is its
+    inverse."""
+    kt, nb = piv.shape
+    iw = jnp.arange(2 * nb, dtype=jnp.int32)
+    pos = jnp.concatenate(
+        [jnp.arange(kt * nb, dtype=jnp.int32).reshape(kt, nb),
+         piv.astype(jnp.int32)], axis=1)
+    slot = jnp.min(jnp.where(pos[:, :, None] == pos[:, None, :], iw,
+                             2 * nb), axis=2)       # slot[:, :nb] == iw[:nb]
 
-    return lax.fori_loop(0, kt * nbp, sim, perm0)
+    def swap(j, held):
+        sb = lax.dynamic_slice_in_dim(slot, nb + j, 1, axis=1)
+        at_b = iw == sb
+        ha = lax.dynamic_slice_in_dim(held, j, 1, axis=1)
+        hb = jnp.sum(jnp.where(at_b, held, 0), axis=1, keepdims=True,
+                     dtype=jnp.int32)
+        return jnp.where(at_b, ha, jnp.where(iw == j, hb, held))
+
+    held = lax.fori_loop(0, nb, swap, pos)
+    held = jnp.take_along_axis(held, slot, axis=1)      # P_k[pos[k]]
+
+    def compose(k, perm):
+        return perm.at[pos[k]].set(jnp.take(perm, held[k], mode="clip"))
+
+    perm = lax.fori_loop(0, kt, compose, jnp.arange(Mrows, dtype=jnp.int32))
+    if forward:
+        return perm
+    return jnp.zeros(Mrows, jnp.int32).at[perm].set(
+        jnp.arange(Mrows, dtype=jnp.int32))
 
 
 @partial(cached_jit, routine="getrs.apply_piv_dist",

@@ -110,7 +110,9 @@ def solved(grid22):
                         "paths": metrics.counter_total("getrf.path"),
                         "gathered": metrics.counter_total(
                             "getrf.panel_gather_bytes"),
-                        "moved_x": metrics.counter_total("trsm.move_x")}
+                        "moved_x": metrics.counter_total("trsm.move_x"),
+                        "replays": metrics.counter_value(
+                            "getrs.apply_pivots", kind="swap_sim")}
         yield out
     finally:
         patch.undo()
@@ -242,6 +244,7 @@ def test_the_span_tree_of_one_solve(solved, n, nrhs, form):
                                     for k0 in range(0, KT, 2)]
     pivots = dict(tree)["slate.gesv/getrs/getrs.apply_pivots"]["labels"]
     assert pivots["kind"] == "swap_sim" and pivots["steps"] == KT * NB
+    assert pivots["serial_steps"] == NB + KT and s["replays"] == 1
     solves = [span for p, span in tree if p == "slate.gesv/getrs/trsm"]
     assert [t["labels"]["form"] for t in solves] == ["move_x", "move_x"]
     assert [t["labels"]["op"] for t in solves] == ["N", "N"]

@@ -197,6 +197,10 @@ def test_the_span_tree_names_the_path_taken(grid11, monkeypatch):
         assert tree[prepare][1]["end_ns"] <= tree[chunk][1]["start_ns"]
         pivots = dict(tree)["slate.gesv/getrs/getrs.apply_pivots"]
         assert pivots["labels"]["kind"] == "swap_sim"
+        assert pivots["labels"]["steps"] == 3 * 96
+        assert pivots["labels"]["serial_steps"] == 96 + 3
+        assert metrics.counter_value("getrs.apply_pivots",
+                                     kind="swap_sim") == 1
         assert metrics.counter_value("getrf.path",
                                      phase="one_program") == 1
 
@@ -214,7 +218,10 @@ def test_the_span_tree_names_the_path_taken(grid11, monkeypatch):
         chunk = dict(tree)["slate.gesv/getrf.chunk"]
         assert chunk["labels"]["phase"] == "fast_path"
         pivots = dict(tree)["slate.gesv/getrs/getrs.apply_pivots"]
-        assert pivots["labels"]["kind"] == "order_gather"
+        assert pivots["labels"] == {"kind": "order_gather"}
+        assert metrics.counter_value("getrs.apply_pivots",
+                                     kind="order_gather") == 1
+        assert metrics.counter_total("getrs.apply_pivots") == 1
         assert metrics.counter_value("getrf.path",
                                      phase="fast_path") == 1
         assert metrics.counter_total("getrf.path") == 1

@@ -445,6 +445,74 @@ def test_apply_pivots_distributed_matches_dense(grid24):
         assert np.array_equal(ref, got)
 
 
+def _serial_perm(piv, Mrows, forward):
+    """The swap list replayed one swap after another, in numpy: the
+    permutation ``_sim_perm`` must give entry for entry."""
+    piv = np.asarray(piv).reshape(-1)
+    perm = np.arange(Mrows)
+    for t in (range(piv.size) if forward else reversed(range(piv.size))):
+        perm[[t, piv[t]]] = perm[[piv[t], t]]
+    return perm
+
+
+def _swap_list(kind, kt, nb, Mrows, rng):
+    own = np.arange(kt * nb).reshape(kt, nb)
+    if kind == "lapack":            # getrf's: piv[t] >= t
+        return rng.integers(own, Mrows)
+    if kind == "arbitrary":         # Aasen's (hetrs): rows above t too
+        return rng.integers(0, Mrows, (kt, nb))
+    if kind == "self":
+        return own
+    if kind == "repeated":          # a few rows named again and again
+        return rng.integers(0, min(3, Mrows), (kt, nb))
+    assert kind == "padded"         # a ragged order: the tail swaps itself
+    real = max(1, kt * nb - max(1, nb // 3))
+    return np.where(own < real, rng.integers(np.minimum(own, real - 1),
+                                             real), own)
+
+
+@pytest.mark.parametrize("forward", [True, False], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("kind", ["lapack", "arbitrary", "self", "repeated",
+                                  "padded"])
+@pytest.mark.parametrize("kt,nb,Mrows", [
+    (4, 8, 32), (1, 16, 16), (6, 1, 6), (3, 8, 40), (5, 1, 9), (7, 16, 130)],
+    ids=["4x8", "kt1", "nb1", "ragged", "nb1-ragged", "7x16-ragged"])
+def test_sim_perm_is_the_serial_replay(kt, nb, Mrows, kind, forward):
+    """``_sim_perm`` (all panels replayed at once, then composed) gives
+    bit for bit the permutation of the swap-by-swap replay, for any swap
+    list, both directions."""
+    import jax.numpy as jnp
+    from slate_tpu.linalg.getrf import _sim_perm
+    rng = np.random.default_rng(kt * 1000 + nb * 10 + forward)
+    piv = _swap_list(kind, kt, nb, Mrows, rng).astype(np.int32)
+    got = np.asarray(_sim_perm(jnp.asarray(piv), Mrows, forward))
+    assert got.dtype == np.int32
+    assert np.array_equal(got, _serial_perm(piv, Mrows, forward))
+
+
+@pytest.mark.parametrize("cell,p,q,kt,nb", [
+    ("gesv_10000_nb384_1x1", 1, 1, 27, 384), ("gesv_16k_2x2", 2, 2, 16, 1024)])
+@pytest.mark.parametrize("forward", [True, False], ids=["fwd", "bwd"])
+def test_apply_piv_program_has_no_loop_of_kt_nb_trips(cell, p, q, kt, nb,
+                                                      forward):
+    """``_apply_piv_jit`` compiled for the two cells' B: its longest
+    loop is the nb steps in which all panels are replayed at once, with
+    the kt compositions beside it; nothing runs kt·nb trips."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from slate_tpu.linalg.getrf import _apply_piv_jit
+    grid = st.Grid(p, q, devices=jax.devices()[:p * q])
+    b = jax.ShapeDtypeStruct((p, q, kt // p, 1, nb, nb), jnp.float32,
+                             sharding=grid.sharding())
+    B = st.Matrix(data=b, m=kt * nb, n=1, nb=nb, grid=grid)
+    piv = jax.ShapeDtypeStruct((kt, nb), jnp.int32)
+    text = _apply_piv_jit.lower(B, piv, forward=forward).compile().as_text()
+    trips = sorted(int(n) for n in re.findall(
+        r'"known_trip_count":\{"n":"(\d+)"\}', text))
+    assert trips == sorted([kt, nb]), trips
+
+
 def test_getrf_fast_path(grid24, monkeypatch):
     """The no-row-movement fast LU (Pallas panel kernel, pivoting by
     index — internal/panel_plu.py) through the public API on CPU via

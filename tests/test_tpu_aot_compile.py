@@ -17,6 +17,7 @@ Mosaic has no 64-bit integers.
 """
 
 import os
+import re
 from functools import partial
 
 import jax
@@ -211,11 +212,25 @@ def test_getrf_last_chunk_2x2_collectives_and_temp(tpu_grid22):
     assert temp_mib < 400, temp_mib
 
 
+def _while_trips(text):
+    """Trip counts of the compiled text's ``while`` loops, each read
+    off the constant its condition compares the counter with."""
+    trips = []
+    for cond in re.findall(r" while\(.*?condition=%([\w.\-]+)", text):
+        body = text[text.index(f"\n%{cond} ("):]
+        body = body[:body.index("\n}")]
+        assert "direction=LT" in body, body
+        trips += [int(n) for n in re.findall(r"constant\((\d+)\)", body)]
+    return sorted(trips)
+
+
 def test_apply_piv_2x2_one_rhs_compiles(tpu_grid22):
     """``getrs``'s pivots on the cell's B, [16384, 1] in one tile
     column a device column: the stored 2 x 64 MiB (one real column) are
-    gathered to every device, the 16,384 swaps replayed in one ``while``
-    and the rows taken: one all-gather, nothing else crosses."""
+    gathered to every device, the 16 panels' swaps replayed at once in
+    one ``while`` of 1,024 trips and composed in one of 16
+    (``_sim_perm``), and the rows taken: one all-gather, nothing else
+    crosses."""
     from slate_tpu.linalg import getrf
     b = jax.ShapeDtypeStruct((2, 2, H // NB // 2, 1, NB, NB), F32,
                              sharding=tpu_grid22.sharding())
@@ -225,7 +240,7 @@ def test_apply_piv_2x2_one_rhs_compiles(tpu_grid22):
     text = c.as_text()
     assert "all-reduce" not in text and "all-to-all" not in text
     assert _all_gathers(text) == 1
-    assert " while(" in text
+    assert _while_trips(text) == [H // NB, NB]
     mem = c.memory_analysis()
     assert abs(mem.argument_size_in_bytes
                - b.size * 4 // tpu_grid22.size) < 2 ** 20
