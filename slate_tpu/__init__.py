@@ -33,8 +33,13 @@ eigensolvers — expressed TPU-first:
 # bf16_6x, Option.TrailingPrecision) out-ranks this global default —
 # see docs/performance.md and internal/precision.py.
 import os as _os
+import sys as _sys
+import time as _time
 
-import jax as _jax
+_import_start_ns = _time.perf_counter_ns()
+_jax_preloaded = "jax" in _sys.modules      # else jax's import is in ours
+
+import jax as _jax  # noqa: E402
 
 if "SLATE_TPU_MATMUL_PRECISION" in _os.environ:
     _jax.config.update("jax_default_matmul_precision",
@@ -106,3 +111,8 @@ from .simplified import (
 from .utils.generator import generate_matrix, random_matrix, random_spd
 from .utils.printing import print_matrix
 from .utils import trace
+
+# the package's own import on the program's clock, for whoever asks
+# what a process's set-up went on (obs.compile_ledger())
+from .obs import tracing as _tracing  # noqa: E402
+_tracing.import_record(_import_start_ns, jax_preloaded=_jax_preloaded)

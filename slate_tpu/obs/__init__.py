@@ -58,8 +58,8 @@ from .metrics import counter_value
 from .report import enrich_span
 from .timing import (roundtrip_latency, timed_regen_median,
                      timed_scalar_median)
-from .tracing import (captured_spans, instant, record_span, span,
-                      sync_read)
+from .tracing import (captured_spans, compile_ledger, instant,
+                      record_span, span, sync_read)
 
 # verb-named metric entry points
 count = metrics.inc
@@ -115,9 +115,6 @@ def reset() -> None:
     timeline.reset()
     flight.reset()
     correlation.reset()
-    with _compile_lock:
-        _compile.clear()
-        _compile_by_fun.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -271,18 +268,43 @@ class link_window:
 # jit retrace / compile accounting (jax.monitoring listeners)
 # ---------------------------------------------------------------------------
 
-_COMPILE_KINDS = {
-    "/jax/core/compile/jaxpr_trace_duration": "trace",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
-    "/jax/core/compile/backend_compile_duration": "backend_compile",
-    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
-}
 _TRACE = "/jax/core/compile/jaxpr_trace_duration"
-_compile_lock = sync.Lock(name="obs.compile_seconds")
-_compile: dict[str, list] = {}          # kind -> [seconds, count]
-_compile_by_fun: dict[str, float] = {}  # program -> trace + lower seconds
-_trace_depth: dict[int, int] = {}       # thread -> open jaxpr traces
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_COMPILE_EVENTS = {     # jax's event -> (kind, record name)
+    _TRACE: ("trace", "compile.trace"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("lower", "compile.lower"),
+    _BACKEND: ("backend_compile", "compile.backend"),
+}
+# what the persistent cache says inside an open backend compile: it
+# served the executable or it stored the fresh one; ``off`` when it did
+# neither (none placed, or the program under the cache's thresholds)
+_CACHE_ANSWERS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 _jax_hooks_installed = False
+
+
+class _Compiling:
+    """What one thread has open of jax's compile events. Plain counters,
+    so the tens of thousands of nested traces of one large program cost
+    two integer updates each and allocate nothing."""
+
+    __slots__ = ("open", "traces", "inner_traces", "cache", "retrieval_s",
+                 "ns")
+
+    def __init__(self):
+        self.open = 0               # events of any kind not closed yet
+        self.traces = 0             # of them, traces
+        self.inner_traces = 0       # traces closed inside the outermost
+        self.cache = "off"          # the open backend compile's answer
+        self.retrieval_s = None
+        self.ns = 0                 # spent in the listeners, not yet booked
+
+
+_open: dict[int, _Compiling] = {}   # thread -> what it has open
 
 
 def compile_seconds() -> dict:
@@ -292,18 +314,29 @@ def compile_seconds() -> dict:
     (Python → jaxpr; only the outermost trace of a nest, so the sum is
     wall time), ``lower`` (jaxpr → MLIR, Mosaic kernels included),
     ``backend_compile`` (XLA, or the persistent cache's load) and
-    ``cache_retrieval``."""
-    with _compile_lock:
-        top = sorted(_compile_by_fun.items(), key=lambda kv: -kv[1])[:8]
-        return {"seconds": {k: v[0] for k, v in _compile.items()},
-                "counts": {k: v[1] for k, v in _compile.items()},
-                "top": [list(kv) for kv in top]}
+    ``cache_retrieval``: the sums of :func:`compile_ledger`'s
+    per-program totals."""
+    seconds: dict = {}
+    counts: dict = {}
+    by_fun: dict = {}
+    for program, totals in tracing.program_totals().items():
+        for kind, total in totals.items():
+            if kind == "cache":
+                continue
+            seconds[kind] = seconds.get(kind, 0.0) + total[0]
+            counts[kind] = counts.get(kind, 0) + total[1]
+            if kind in ("trace", "lower"):
+                by_fun[program] = by_fun.get(program, 0.0) + total[0]
+    top = sorted(by_fun.items(), key=lambda kv: -kv[1])[:8]
+    return {"seconds": seconds, "counts": counts,
+            "top": [list(kv) for kv in top]}
 
 
 def install_jax_hooks() -> bool:
-    """Register the ``jax.monitoring`` listeners (at import): compile
-    seconds by kind are always kept (:func:`compile_seconds`); the
-    ``jax.events{event=…}`` counters (+ duration histograms) only
+    """Register the ``jax.monitoring`` listeners (at import): every
+    trace, lowering and backend compile becomes a record of the compile
+    ledger (:func:`compile_ledger`, :func:`compile_seconds`), always;
+    the ``jax.events{event=…}`` counters (+ duration histograms) only
     while metrics are on. Idempotent (jax only offers a global
     clear)."""
     global _jax_hooks_installed
@@ -311,54 +344,99 @@ def install_jax_hooks() -> bool:
         return True
     try:
         from jax import monitoring as _mon
-
-        def _on_event(event, **kw):
-            if metrics.enabled():
-                metrics.inc("jax.events", event=event)
-
-        def _on_scalar(event, value, **kw):
-            # jax records a trace's start time as it opens: inner
-            # jitted functions are traced inside the outer one's span
-            if event == _TRACE:
-                tid = sync.get_ident()
-                _trace_depth[tid] = _trace_depth.get(tid, 0) + 1
-
-        def _on_duration(event, duration, fun_name="", **kw):
-            kind = _COMPILE_KINDS.get(event)
-            if kind is not None:
-                _compile_second(kind, duration, str(fun_name))
-            if metrics.enabled():
-                metrics.inc("jax.events", event=event)
-                metrics.observe("jax.event_duration_s", duration,
-                                event=event)
-
         _mon.register_event_listener(_on_event)
         _mon.register_scalar_listener(_on_scalar)
         _mon.register_event_duration_secs_listener(_on_duration)
+        _mon.register_event_time_span_listener(_on_compile_span)
         _jax_hooks_installed = True
         return True
     except Exception:  # noqa: BLE001 — observability must never crash
         return False
 
 
-def _compile_second(kind: str, duration: float, fun: str) -> None:
-    if kind == "trace":
+def _on_event(event, **kw):
+    answer = _CACHE_ANSWERS.get(event)
+    if answer is not None:
+        _in_open_backend("cache", answer)
+    if metrics.enabled():
+        metrics.inc("jax.events", event=event)
+
+
+def _on_scalar(event, value, **kw):
+    # jax announces a trace, lowering or backend compile as it opens:
+    # what happens on this thread until the event's time span arrives
+    # happens inside it
+    if event in _COMPILE_EVENTS:
+        t0 = _time.perf_counter_ns()
         tid = sync.get_ident()
-        depth = _trace_depth.get(tid, 1) - 1
-        if depth > 0:
-            _trace_depth[tid] = depth
-            return              # inside an enclosing trace's seconds
-        _trace_depth.pop(tid, None)
-    with _compile_lock:
-        rec = _compile.setdefault(kind, [0.0, 0])
-        rec[0] += duration
-        rec[1] += 1
-        if kind in ("trace", "lower"):
-            fun = fun[4:-1] if fun.startswith("jit(") else fun
-            _compile_by_fun[fun] = _compile_by_fun.get(fun, 0.0) + duration
-    if kind != "trace" and tracing.capturing():
-        # a recompile inside a traced solve shows in its span tree
-        tracing.instant("compile", kind=kind, seconds=duration, fun=fun)
+        mine = _open.get(tid)
+        if mine is None:
+            mine = _open[tid] = _Compiling()
+        mine.open += 1
+        if event == _TRACE:
+            mine.traces += 1
+        elif event == _BACKEND:
+            mine.cache, mine.retrieval_s = "off", None
+        mine.ns += _time.perf_counter_ns() - t0
+
+
+def _on_duration(event, duration, **kw):
+    if event == _RETRIEVAL:
+        _in_open_backend("retrieval_s", duration)
+    if metrics.enabled():
+        metrics.inc("jax.events", event=event)
+        metrics.observe("jax.event_duration_s", duration, event=event)
+
+
+def _in_open_backend(field: str, value) -> None:
+    """``compile_or_get_cached`` runs inside the backend compile's
+    extent and names no program: its events belong to the one this
+    thread has open."""
+    mine = _open.get(sync.get_ident())
+    if mine is not None:
+        setattr(mine, field, value)
+
+
+def _on_compile_span(event, start_time, end_time, fun_name="", **kw):
+    """A trace, lowering or backend compile has ended: one record of
+    the ledger, on the spans' clock through the one anchor. A trace
+    inside another trace of its thread is a count on the outer one."""
+    names = _COMPILE_EVENTS.get(event)
+    if names is None:
+        return
+    t0 = _time.perf_counter_ns()
+    tid = sync.get_ident()
+    mine = _open.get(tid)
+    if mine is None:                # opened before the listeners were
+        mine = _Compiling()
+    mine.open -= 1
+    if mine.open <= 0:
+        _open.pop(tid, None)
+    if event == _TRACE:
+        mine.traces -= 1
+        if mine.traces > 0:
+            mine.inner_traces += 1
+            mine.ns += _time.perf_counter_ns() - t0
+            return
+    labels = {"program": _program(str(fun_name))}
+    if event == _TRACE:
+        labels["inner_traces"], mine.inner_traces = mine.inner_traces, 0
+    elif event == _BACKEND:
+        labels["cache"] = mine.cache
+        if mine.retrieval_s is not None:
+            labels["retrieval_s"] = mine.retrieval_s
+    kind, name = names
+    spent, mine.ns = mine.ns, 0
+    tracing.compile_record(
+        name, kind, int((start_time - tracing._WALL0) * 1e9),
+        int((end_time - tracing._WALL0) * 1e9), labels,
+        spent + _time.perf_counter_ns() - t0)
+
+
+def _program(fun_name: str) -> str:
+    """jax names a trace ``f`` and its lowering and compile
+    ``jit(f)``: one program."""
+    return fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
 
 
 def jit_event_total() -> float:

@@ -21,7 +21,11 @@ Extensions over the old stub:
 * every span knows its parent and its solve, and a solve that runs
   inside a ``jax.profiler`` session puts its tree on the profiler's
   host plane and into :func:`captured_spans` (docs/observability.md
-  "Under ``jax.profiler``"). No switch: capture follows the profiler.
+  "Under ``jax.profiler``"). No switch: capture follows the profiler;
+* the cold path is kept with no session at all: every trace, lowering
+  and backend compile is a record under the span that paid for it, and
+  a process's first root spans and every root that compiled are kept
+  at their end (:func:`compile_ledger`).
 
 slateflight additions: every span exit / instant also lands in the
 always-on flight-recorder ring (:mod:`slate_tpu.obs.flight`) so a
@@ -74,6 +78,21 @@ ANNOTATION_PREFIX = "slate."    # names on the profiler's host plane
 CAPTURE_CAP = 65_536
 _captured: collections.deque = collections.deque()
 _captured_n = 0
+
+# the cold path, kept with or without a session (``compile_ledger``):
+# one record a trace, lowering or backend compile (``compile_record``,
+# fed by the ``jax.monitoring`` listeners of ``obs/__init__.py``), the
+# process's first ``COLD_ROOTS`` root spans and every later root that a
+# record landed in. Records and roots are each bounded by
+# ``CAPTURE_CAP``; the per-program totals are exact past it.
+COLD_ROOTS = 16     # a cell's set-up is two generators and two warm-ups
+_records: list[dict] = []
+_roots: list[dict] = []
+_cold_left = COLD_ROOTS
+_by_program: dict[str, dict] = {}   # program -> {kind: [seconds, count],
+#                                     "cache": {answer: count}}
+_dropped = 0
+_listener_ns = 0
 
 
 def on() -> None:
@@ -148,7 +167,7 @@ class _Span:
     :func:`captured_spans`."""
 
     __slots__ = ("name", "labels", "id", "parent", "solve", "_bag",
-                 "_ann", "_up", "_start")
+                 "_ann", "_up", "_start", "_compiled")
 
     def __init__(self, name: str, labels: dict):
         self.name = name
@@ -161,6 +180,7 @@ class _Span:
             self.parent = 0
             self.solve = _correlation.current() or next(_solves)
             self._bag = [] if _profiling() else None
+            self._compiled = False      # a compile record landed here
         else:
             self.parent, self.solve, self._bag = up.id, up.solve, up._bag
         self._ann = None
@@ -185,8 +205,11 @@ class _Span:
         if self._ann is not None:
             self._ann.__exit__(*exc)
         _finish(self.name, self._start, end, self.labels, self)
-        if self._bag is not None and self.parent == 0:
-            _keep(self._bag)
+        if self.parent == 0:
+            if self._bag is not None:
+                _keep(self._bag)
+            if self._compiled or _cold_left > 0:
+                _keep_root(self, end)
         return False
 
 
@@ -199,6 +222,112 @@ def _keep(bag: list) -> None:
             _captured_n -= len(_captured.popleft())
 
 
+def _keep_root(root: _Span, end_ns: int) -> None:
+    global _cold_left
+    kept = _kept(root.name, root._start, end_ns, root.id, 0, root.solve,
+                 root.labels)
+    kept["compiled"] = root._compiled
+    with _lock:
+        _cold_left = max(0, _cold_left - 1)
+        if len(_roots) < CAPTURE_CAP:
+            _roots.append(kept)
+
+
+def compile_record(name: str, kind: str, start_ns: int, end_ns: int,
+                   labels: dict, listener_ns: int = 0) -> None:
+    """One trace, lowering or backend compile as a span record
+    ``name`` (``compile.trace`` / ``.lower`` / ``.backend``) with its
+    ``program`` among the ``labels``, under the innermost open span of
+    this thread (parent and solve 0 outside every root). It goes to the
+    ledger, marks its root as one that compiled and, inside a captured
+    tree, is kept there too; never to the flight ring. ``kind`` keys
+    the program's totals, which stay exact past the ledger's bound.
+    ``listener_ns`` is what the listeners spent on this event before
+    the call; the call adds its own time."""
+    global _dropped, _listener_ns
+    entered = time.perf_counter_ns()
+    up = _CUR.get()
+    parent = solve = 0
+    bag = None
+    if up is not None:
+        parent, solve, bag = up.id, up.solve, up._bag
+        root = up
+        while root._up is not None:
+            root = root._up
+        root._compiled = True
+    rec = _kept(name, start_ns, end_ns, next(_ids), parent, solve, labels)
+    if bag is not None:
+        bag.append(rec)
+    with _lock:
+        totals = _by_program.setdefault(labels["program"], {})
+        _add(totals, kind, (end_ns - start_ns) * 1e-9)
+        if "cache" in labels:
+            answers = totals.setdefault("cache", {})
+            answers[labels["cache"]] = answers.get(labels["cache"], 0) + 1
+        if "retrieval_s" in labels:
+            _add(totals, "cache_retrieval", labels["retrieval_s"])
+        if len(_records) < CAPTURE_CAP:
+            _records.append(rec)
+        else:
+            _dropped += 1
+        _listener_ns += listener_ns + time.perf_counter_ns() - entered
+
+
+def _add(totals: dict, kind: str, seconds: float) -> None:
+    total = totals.setdefault(kind, [0.0, 0])
+    total[0] += seconds
+    total[1] += 1
+
+
+def import_record(start_ns: int, **labels) -> None:
+    """``slate_tpu/__init__.py``'s own extent, from ``start_ns`` to
+    now, as the ledger's record ``slate.import``."""
+    rec = _kept("slate.import", start_ns, time.perf_counter_ns(),
+                next(_ids), 0, 0, labels)
+    with _lock:
+        _records.append(rec)
+
+
+def program_totals() -> dict:
+    """``{program: {kind: [seconds, count], "cache": {answer: n}}}`` of
+    every compile record made, kept or dropped."""
+    with _lock:
+        return _copy_totals()
+
+
+def _copy_totals() -> dict:
+    return {program: {k: (dict(v) if k == "cache" else list(v))
+                      for k, v in totals.items()}
+            for program, totals in _by_program.items()}
+
+
+def compile_ledger() -> dict:
+    """What this process spent getting programs ready, call by call,
+    with or without a profiler session: ``{"records": [...], "roots":
+    [...], "by_program": {...}, "dropped": n, "listener_s": s}``.
+
+    ``records`` are spans in :func:`captured_spans`' form, oldest
+    first: ``compile.trace`` (Python → jaxpr; the outermost trace of a
+    nest, the ones inside it counted in its ``inner_traces`` label),
+    ``compile.lower`` (jaxpr → MLIR), ``compile.backend`` (XLA, or the
+    persistent cache's load: labels ``cache`` = ``hit`` / ``miss`` /
+    ``off`` and, on a hit, ``retrieval_s``), each with its ``program``
+    and, as ``parent`` / ``solve``, the innermost span that was open on
+    the compiling thread (0 outside every root); and one
+    ``slate.import`` for the package's own import. ``roots`` are the
+    process's first ``COLD_ROOTS`` root spans and every later root in
+    which a record landed, with ``compiled``. ``by_program`` is
+    :func:`program_totals`. Past ``CAPTURE_CAP`` records a new one only
+    adds to its program's totals and to ``dropped``. ``listener_s`` is
+    what keeping all this has cost: the seconds spent inside the
+    ``jax.monitoring`` listeners."""
+    with _lock:
+        return {"records": [dict(r) for r in _records],
+                "roots": [dict(r) for r in _roots],
+                "by_program": _copy_totals(), "dropped": _dropped,
+                "listener_s": _listener_ns * 1e-9}
+
+
 def captured_spans() -> list[dict]:
     """The span trees of the solves that ran inside a ``jax.profiler``
     session, oldest first: ``{"name", "start_ns", "end_ns"
@@ -206,12 +335,6 @@ def captured_spans() -> list[dict]:
     "labels"}``. Empty when no session was on."""
     with _lock:
         return [dict(s) for bag in _captured for s in bag]
-
-
-def capturing() -> bool:
-    """Inside a span tree that is being captured?"""
-    up = _CUR.get()
-    return up is not None and up._bag is not None
 
 
 def span(name: str, **labels):
@@ -309,11 +432,15 @@ def finish(path: str = "trace.json") -> str | None:
 
 
 def reset() -> None:
-    """Drop buffered events and captured spans and restart the session
-    clock (tests)."""
-    global _t0_ns, _captured_n
+    """Drop buffered events, captured spans and the compile ledger and
+    restart the session clock (tests)."""
+    global _t0_ns, _captured_n, _cold_left, _dropped, _listener_ns
     with _lock:
         _events.clear()
         _captured.clear()
         _captured_n = 0
+        _records.clear()
+        _roots.clear()
+        _by_program.clear()
+        _cold_left, _dropped, _listener_ns = COLD_ROOTS, 0, 0
         _t0_ns = time.perf_counter_ns()

@@ -32,6 +32,7 @@ from ..grid import Grid, default_grid, AXIS_P, AXIS_Q
 from ..matrix import Matrix, HermitianMatrix, cdiv
 from ..internal import masks
 from ..errors import SlateError
+from .. import obs
 
 
 def random_matrix(m: int, n: int, nb: int | None = None,
@@ -41,11 +42,14 @@ def random_matrix(m: int, n: int, nb: int | None = None,
     grid = grid or default_grid()
     if nb is None:
         nb = min(256, max(8, m // max(grid.p, grid.q)))
-    mtl = cdiv(cdiv(m, nb), grid.p)
-    ntl = cdiv(cdiv(n, nb), grid.q)
-    data = _random_bc(grid, mtl, ntl, nb, m, n, seed, kind,
-                      jnp.dtype(dtype).name)
-    return Matrix(data=data, m=m, n=n, nb=nb, grid=grid)
+    # the host side only: the device fills the tiles after this returns
+    with obs.span("slate.random_matrix", m=m, n=n, nb=nb,
+                  grid=f"{grid.p}x{grid.q}", dtype=jnp.dtype(dtype).name):
+        mtl = cdiv(cdiv(m, nb), grid.p)
+        ntl = cdiv(cdiv(n, nb), grid.q)
+        data = _random_bc(grid, mtl, ntl, nb, m, n, seed, kind,
+                          jnp.dtype(dtype).name)
+        return Matrix(data=data, m=m, n=n, nb=nb, grid=grid)
 
 
 @partial(jax.jit, static_argnames=("grid", "mtl", "ntl", "nb", "m", "n",
@@ -286,8 +290,11 @@ def random_spd(n: int, nb: int | None = None, grid: Grid | None = None,
     from ..ops.blas import syrk
     from ..ops.elementwise import _add_scaled_identity
     grid = grid or default_grid()
-    G = random_matrix(n, n, nb, grid, dtype, seed, "randn")
-    C = HermitianMatrix.zeros(n, n, G.nb, grid, dtype=dtype)
-    C = syrk(1.0 / n, G, 0.0, C)
-    C = _add_scaled_identity(C, 1.0)
-    return HermitianMatrix(data=C.data, m=n, n=n, nb=G.nb, grid=grid)
+    with obs.span("slate.random_spd", m=n, n=n, grid=f"{grid.p}x{grid.q}",
+                  dtype=jnp.dtype(dtype).name) as root:
+        G = random_matrix(n, n, nb, grid, dtype, seed, "randn")
+        root.label(nb=G.nb)
+        C = HermitianMatrix.zeros(n, n, G.nb, grid, dtype=dtype)
+        C = syrk(1.0 / n, G, 0.0, C)
+        C = _add_scaled_identity(C, 1.0)
+        return HermitianMatrix(data=C.data, m=n, n=n, nb=G.nb, grid=grid)

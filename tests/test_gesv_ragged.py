@@ -176,12 +176,12 @@ def test_the_span_tree_names_the_path_taken(grid11, monkeypatch):
     ``getrf.path{phase}`` counts both."""
     monkeypatch.setattr(tracing, "_profiling", lambda: True)
     was_metrics = obs.metrics_enabled()
-    obs.reset()
-    obs.metrics_on()
     try:
         n, nb = 250, 96
         A = st.random_matrix(n, n, nb, grid11, np.float32, seed=4001)
         B = st.random_matrix(n, NRHS, nb, grid11, np.float32, seed=5001)
+        obs.reset()             # the generators' roots are not the solve's
+        obs.metrics_on()
         jax.block_until_ready(st.gesv(A, B, TIER))
         tree = _paths(obs.captured_spans())
         names = [p for p, _ in tree]
@@ -204,12 +204,12 @@ def test_the_span_tree_names_the_path_taken(grid11, monkeypatch):
         assert metrics.counter_value("getrf.path",
                                      phase="one_program") == 1
 
-        obs.reset()
-        obs.metrics_on()
         monkeypatch.setenv("SLATE_LU_FAST", "1")
         n, nb = 384, 128
         A = st.random_matrix(n, n, nb, grid11, np.float32, seed=4002)
         B = st.random_matrix(n, NRHS, nb, grid11, np.float32, seed=5002)
+        obs.reset()
+        obs.metrics_on()
         X, LU, piv, info = st.gesv(A, B, TIER)
         assert int(info) == 0
         tree = _paths(obs.captured_spans())

@@ -1,6 +1,8 @@
 """The span record of PR 25: every span knows its parent and its solve,
 capture follows ``jax.profiler`` (no switch), the host's spans land on
-the profiler's host plane, and compile seconds are always counted.
+the profiler's host plane, and every trace, lowering and backend compile
+is a span record under the call that paid for it, kept with no session
+(``obs.compile_ledger()``: PR 39).
 
 CPU only: what a chip run adds is in PERF.md section 6.
 """
@@ -340,7 +342,23 @@ def test_redistribute_span_holds_its_materialize(grid11, grid22,
     assert "matrix.redistribute/matrix.materialize" in paths
 
 
-# ------------------------------------------------------ compile seconds
+# ------------------------------------------------------ compile records
+
+def _compile_records(program=None):
+    return [r for r in obs.compile_ledger()["records"]
+            if r["name"].startswith("compile.")
+            and program in (None, r["labels"]["program"])]
+
+
+def _seconds_by_kind(by_program):
+    out = {}
+    for totals in by_program.values():
+        for kind, total in totals.items():
+            if kind != "cache":
+                seconds, count = out.get(kind, (0.0, 0))
+                out[kind] = (seconds + total[0], count + total[1])
+    return out
+
 
 def test_compile_seconds_grow_on_a_first_call_only():
     import jax.numpy as jnp
@@ -356,8 +374,19 @@ def test_compile_seconds_grow_on_a_first_call_only():
         assert first["counts"][kind] > before["counts"].get(kind, 0)
         assert first["seconds"][kind] > before["seconds"].get(kind, 0.0)
     assert any(name == "probe" for name, _ in first["top"])
+    # one record a kind for the program, with a start and an end, and
+    # nothing of it in the flight ring
+    mine = _compile_records("probe")
+    assert sorted(r["name"] for r in mine) == [
+        "compile.backend", "compile.lower", "compile.trace"]
+    assert all(r["end_ns"] > r["start_ns"] for r in mine)
+    assert all(r["parent"] == 0 and r["solve"] == 0 for r in mine)
+    assert not [e for e in flight.events()
+                if e["name"].startswith("compile")]
+    size = len(obs.compile_ledger()["records"])
     probe(jnp.ones((33, 17))).block_until_ready()
     assert obs.compile_seconds() == first        # nothing on a second
+    assert len(obs.compile_ledger()["records"]) == size
 
 
 def test_nested_traces_count_once():
@@ -377,9 +406,17 @@ def test_nested_traces_count_once():
     # would pass the wall
     assert seen["seconds"]["trace"] <= wall
     assert seen["counts"]["trace"] < 20
+    # ... and are a count on the outermost trace's record
+    (trace,) = [r for r in _compile_records("outer")
+                if r["name"] == "compile.trace"]
+    assert trace["labels"]["inner_traces"] >= 20
+    assert not [r for r in _compile_records()
+                if r["name"] == "compile.trace"
+                and r["start_ns"] > trace["start_ns"]
+                and r["end_ns"] < trace["end_ns"]]
 
 
-def test_a_compile_inside_a_captured_solve_is_marked(monkeypatch):
+def test_a_compile_inside_a_captured_solve_is_a_span(monkeypatch):
     import jax.numpy as jnp
     monkeypatch.setattr(tracing, "_profiling", lambda: True)
 
@@ -389,12 +426,201 @@ def test_a_compile_inside_a_captured_solve_is_marked(monkeypatch):
 
     x = jnp.ones((3, 5))
     with obs.span("slate.fake"):
-        probe(x).block_until_ready()
-    marks = [s for s in obs.captured_spans() if s["name"] == "compile"]
-    kinds = {s["labels"]["kind"] for s in marks}
-    assert {"lower", "backend_compile"} <= kinds
-    assert all(s["labels"]["fun"] == "probe" for s in marks
-               if s["labels"]["kind"] == "lower")
+        with obs.span("child"):
+            probe(x).block_until_ready()
+    spans = obs.captured_spans()
+    (child,) = [s for s in spans if s["name"] == "child"]
+    mine = [s for s in spans if s["name"].startswith("compile.")
+            and s["labels"]["program"] == "probe"]
+    assert {s["name"] for s in mine} == {"compile.trace", "compile.lower",
+                                         "compile.backend"}
+    for s in mine:       # a span with a duration, where the instant was
+        assert s["end_ns"] > s["start_ns"]
+        assert s["parent"] == child["id"] and s["solve"] == child["solve"]
+    assert not [s for s in spans if s["name"] == "compile"]
+    # the same records are the ledger's, with no session needed
+    assert {s["id"] for s in mine} <= {r["id"] for r in _compile_records()}
+
+
+@pytest.mark.parametrize("depth", [1, 3])
+def test_a_compile_record_lies_inside_the_span_that_paid(depth):
+    """No profiler session: the record's parent is the innermost open
+    span, on the spans' clock (``time.time`` through the one anchor)."""
+    import jax.numpy as jnp
+
+    @jax.jit
+    def probe(x):
+        return jnp.cos(x).sum() * float(depth)
+
+    opened = []
+    with obs.span("slate.fake") as up:
+        opened.append(up)
+        for level in range(1, depth):
+            up = obs.span(f"level{level}")
+            up.__enter__()
+            opened.append(up)
+        time.sleep(0.002)
+        t0 = time.perf_counter_ns()
+        probe(jnp.ones((7, 3))).block_until_ready()
+        t1 = time.perf_counter_ns()
+        time.sleep(0.002)
+        for up in reversed(opened[1:]):
+            up.__exit__(None, None, None)
+    mine = _compile_records("probe")
+    assert len(mine) == 3
+    for r in mine:
+        assert r["parent"] == opened[-1].id
+        assert r["solve"] == opened[0].solve
+        assert t0 - 1e6 <= r["start_ns"] <= r["end_ns"] <= t1 + 1e6
+    (root,) = obs.compile_ledger()["roots"]
+    assert root["name"] == "slate.fake" and root["compiled"] is True
+    assert root["start_ns"] <= mine[0]["start_ns"] + 1e6
+    assert mine[-1]["end_ns"] <= root["end_ns"] + 1e6
+
+
+def test_the_cold_path_keeps_its_roots():
+    """The first sixteen roots whatever they did, then only a root in
+    which something compiled; ten thousand warm calls add nothing."""
+    import jax.numpy as jnp
+    for i in range(tracing.COLD_ROOTS + 4):
+        with obs.span("slate.fake", i=i):
+            with obs.span("child"):
+                pass
+    roots = obs.compile_ledger()["roots"]
+    assert [r["labels"]["i"] for r in roots] == list(
+        range(tracing.COLD_ROOTS))
+    assert not any(r["compiled"] for r in roots)
+    assert all(r["parent"] == 0 and r["end_ns"] >= r["start_ns"]
+               for r in roots)
+
+    @jax.jit
+    def probe(x):
+        return jnp.sin(x) + 2.0
+
+    with obs.span("slate.late", n=5) as late:
+        with obs.span("child"):
+            probe(jnp.ones((5,))).block_until_ready()
+    with obs.span("slate.late", n=5):
+        probe(jnp.ones((5,))).block_until_ready()    # warm: not kept
+    ledger = obs.compile_ledger()
+    assert len(ledger["roots"]) == tracing.COLD_ROOTS + 1
+    kept = ledger["roots"][-1]
+    assert kept["name"] == "slate.late" and kept["compiled"] is True
+    assert kept["id"] == late.id and kept["solve"] == late.solve
+    assert kept["labels"] == {"n": 5}
+    assert {r["solve"] for r in _compile_records("probe")} == {late.solve}
+    sizes = (len(ledger["roots"]), len(ledger["records"]))
+    for _ in range(10_000):
+        with obs.span("slate.warm"):
+            pass
+    ledger = obs.compile_ledger()
+    assert (len(ledger["roots"]), len(ledger["records"])) == sizes
+    assert ledger["listener_s"] > 0.0 and ledger["dropped"] == 0
+
+
+def test_the_persistent_caches_answer_is_on_the_record(tmp_path):
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    was = [getattr(jax.config, name) for name in names]
+
+    @jax.jit
+    def probe(x):
+        return jnp.tanh(x @ x.T).sum() - 7.0
+
+    x = jnp.ones((19, 5))
+    try:
+        probe(x).block_until_ready()             # no cache placed: off
+        for name, value in zip(names, (str(tmp_path), 0.0, -1)):
+            jax.config.update(name, value)
+        cc.reset_cache()
+        for _ in range(2):
+            jax.clear_caches()
+            probe(x).block_until_ready()
+    finally:
+        for name, value in zip(names, was):
+            jax.config.update(name, value)
+        cc.reset_cache()
+    backends = [r for r in _compile_records("probe")
+                if r["name"] == "compile.backend"]
+    assert [r["labels"]["cache"] for r in backends] == ["off", "miss",
+                                                        "hit"]
+    assert 0.0 < backends[2]["labels"]["retrieval_s"] <= (
+        backends[2]["end_ns"] - backends[2]["start_ns"]) * 1e-9
+    assert "retrieval_s" not in backends[1]["labels"]
+    totals = obs.compile_ledger()["by_program"]["probe"]
+    assert totals["cache"] == {"off": 1, "miss": 1, "hit": 1}
+    assert totals["cache_retrieval"] == [
+        backends[2]["labels"]["retrieval_s"], 1]
+    assert obs.compile_seconds()["counts"]["cache_retrieval"] >= 1
+
+
+@pytest.mark.parametrize("cap", [4, 65_536])
+def test_compile_seconds_is_the_ledgers_sum_whatever_the_bound(
+        monkeypatch, cap):
+    import jax.numpy as jnp
+    monkeypatch.setattr(tracing, "CAPTURE_CAP", cap)
+    for k in range(1, 5):
+        jax.jit(lambda x, k=k: jnp.exp(x) * k)(jnp.ones((k,)))
+    ledger = obs.compile_ledger()
+    made = sum(count for _, count in _seconds_by_kind(
+        ledger["by_program"]).values())
+    assert made >= 12
+    compiles = [r for r in ledger["records"]
+                if r["name"].startswith("compile.")]
+    assert len(compiles) == min(cap, made)
+    assert ledger["dropped"] == made - len(compiles)
+    seen = obs.compile_seconds()
+    by_kind = _seconds_by_kind(ledger["by_program"])
+    assert set(by_kind) == set(seen["seconds"]) == set(seen["counts"])
+    for kind, (seconds, count) in by_kind.items():
+        assert seen["counts"][kind] == count
+        assert seen["seconds"][kind] == pytest.approx(seconds)
+    if not ledger["dropped"]:
+        # kind by kind the kept records are the totals
+        kinds = {"compile.trace": "trace", "compile.lower": "lower",
+                 "compile.backend": "backend_compile"}
+        for name, kind in kinds.items():
+            mine = [r for r in compiles if r["name"] == name]
+            assert len(mine) == seen["counts"][kind]
+            assert sum(r["end_ns"] - r["start_ns"]
+                       for r in mine) * 1e-9 == pytest.approx(
+                           seen["seconds"][kind])
+
+
+@pytest.mark.parametrize("make,labels", [
+    (lambda g: st.random_matrix(96, 40, 32, g, np.float32, seed=1),
+     {"m": 96, "n": 40, "nb": 32, "grid": "1x1", "dtype": "float32"}),
+    (lambda g: st.random_spd(96, nb=32, grid=g, dtype=np.float32, seed=1),
+     {"m": 96, "n": 96, "nb": 32, "grid": "1x1", "dtype": "float32"}),
+], ids=["random_matrix", "random_spd"])
+def test_a_generator_opens_a_root_with_its_labels(grid11, make, labels):
+    M = make(grid11)
+    name = ("slate.random_spd" if isinstance(M, st.HermitianMatrix)
+            else "slate.random_matrix")
+    ledger = obs.compile_ledger()
+    (root,) = ledger["roots"]
+    assert root["name"] == name and root["labels"] == labels
+    assert root["parent"] == 0 and root["end_ns"] > root["start_ns"]
+    # whatever it compiled is its own, and a generator inside a
+    # generator is a child, not a second root
+    for r in _compile_records():
+        assert r["solve"] == root["solve"]
+    flown = [e["name"] for e in flight.events()]
+    assert name in flown
+    assert ("slate.random_matrix" in flown) or name == "slate.random_spd"
+
+
+def test_the_import_is_a_record_of_the_ledger():
+    t0 = time.perf_counter_ns()
+    tracing.import_record(t0 - 5_000_000, jax_preloaded=True)
+    (rec,) = [r for r in obs.compile_ledger()["records"]
+              if r["name"] == "slate.import"]
+    assert rec["labels"] == {"jax_preloaded": True}
+    assert rec["parent"] == 0 and rec["solve"] == 0
+    assert 5_000_000 <= rec["end_ns"] - rec["start_ns"] < 1_000_000_000
 
 
 # ------------------------------------------------------------ one clock
