@@ -24,6 +24,11 @@ gbmm.cc, hbmm.cc, tbsm.cc) as functional JAX programs:
   alone (``_moves_x``): a B of one tile column leaves A where it is
   stored and moves block-rows of X.
 
+* A B narrower than its storage (a few right-hand sides in an
+  nb-wide tile column, the rest stored zeros) is multiplied by
+  ``gemm``, and solved against by ``trsm(Side.Left)``, at the whole
+  lanes it holds (``_carried_cols``), not at the stored width.
+
 All routines return the updated output matrix (functional style) —
 SLATE mutates C in place; here ``C = gemm(alpha, A, B, beta, C)``.
 """
@@ -95,6 +100,16 @@ def gemm(alpha, A: Matrix, B: Matrix, beta, C: Matrix,
     Method dispatch: bcast-SUMMA (default) or the ring-systolic
     Cannon variant (``Option.MethodGemm: MethodGemm.Ring`` —
     nearest-neighbor ICI hops instead of bcasts, see _gemm_ring_jit).
+
+    The default method multiplies B and accumulates C at the width
+    they hold, not the width they are stored at: ``w`` whole lanes of
+    real columns a device (``_carried_cols``; the span's ``nrhs`` and
+    ``w`` labels and the counter ``gemm.narrow`` say when that is under
+    the stored ``ntl·nb``). A one-column operand of the refining
+    solvers is a 1,024-wide tile column whose other columns are stored
+    zeros: at six passes their product with A is 8× the work of the
+    128 lanes that hold the column. The tier and the accumulator are
+    the same at either width, and the result's padding is exact zeros.
     """
     from ..types import Option, MethodGemm, get_option
     A = A.materialize()
@@ -109,14 +124,26 @@ def gemm(alpha, A: Matrix, B: Matrix, beta, C: Matrix,
     # single-buffered one, so unlike the factorization lookahead it
     # stays on unless the caller pins PipelineDepth: 0
     double_buffer = bool(get_option(opts, Option.PipelineDepth, 1))
-    with trace.block("gemm", precision=tier):
+    on_grid = C.grid.size > 1
+    ring = method == MethodGemm.Ring and on_grid
+    gemm_a = method == MethodGemm.GemmA and on_grid
+    with trace.block("gemm", precision=tier) as span:
+        # the columns of B a device multiplies: the two explicit
+        # methods take B as it is stored
+        stored = B.data.shape[3] * B.nb
+        w = stored if ring or gemm_a else _carried_cols(
+            B.n, B.nb, B.grid.q, B.data.shape[3])
+        span.label(nrhs=B.n, w=w)
+        if w < stored:
+            obs.count("gemm.narrow", 1)
+
         def _run():
-            if method == MethodGemm.Ring and C.grid.size > 1:
+            if ring:
                 return _gemm_ring_jit(jnp.asarray(alpha, C.dtype), A,
                                       B, jnp.asarray(beta, C.dtype),
                                       C, tier,
                                       double_buffer=double_buffer)
-            if method == MethodGemm.GemmA and C.grid.size > 1:
+            if gemm_a:
                 return _gemm_a_jit(jnp.asarray(alpha, C.dtype), A, B,
                                    jnp.asarray(beta, C.dtype), C,
                                    tier)
@@ -140,33 +167,60 @@ def _gemm_jit(alpha, A, B, beta, C, tier=None):
     kt = cdiv(A.n, nb)
     acc = _acc_dtype(C.dtype)
     pk = trailing_dot_kwargs(tier, A.dtype)
+    # B and C ride the product as [·, nb, w]: each local block-row's
+    # tiles side by side, cut to the columns that are real on some
+    # device. The rest is zero padding in both, whose product is zero.
+    ntl = B.data.shape[3]
+    w = _carried_cols(B.n, nb, q, ntl)
+    narrow = w < ntl * nb
 
     if g.size == 1:
         # Single-device fast path: no communication, so the SUMMA
-        # k-loop collapses into ONE tiled-einsum contraction that XLA
-        # tiles onto the MXU in a single fused pass (~1.5x the looped
-        # rate on a v5e; the loop pays one dispatch per block step).
+        # k-loop collapses into ONE contraction over A's tiles where
+        # they lie (no re-laid copy of A in either form). At the
+        # stored width XLA tiles it onto the MXU in a single fused
+        # pass (~1.5x the looped rate on a v5e; the loop pays one
+        # dispatch per block step); at a carried width under it, a
+        # convolution whose window runs over the tile columns (one
+        # column at n = 16,384, nb = 1,024 on a v5e: 2.29 ms, the
+        # program 2.85, where the stored width takes 19.98; a loop
+        # over tile columns 2.37, over block-rows 2.62: PERF 6, PR 40).
         a, b, c = A.data[0, 0], B.data[0, 0], C.data[0, 0]
-        upd = jnp.einsum("acik,cbkj->abij", a, b,
-                         preferred_element_type=acc, **pk)
+        if narrow:
+            b, c = _side_by_side(b, w), _side_by_side(c, w)
+        upd = jnp.einsum("acik,ckj->aij" if narrow else "acik,cbkj->abij",
+                         a, b, preferred_element_type=acc, **pk)
         out = (beta * c).astype(acc) + alpha.astype(acc) * upd
-        return C._replace(data=out.astype(c.dtype)[None, None])
+        out = out.astype(c.dtype)
+        if narrow:
+            out = _as_tiles(out, ntl)
+        return C._replace(data=out[None, None])
 
     def body(a, b, c, alpha, beta):
         a, b, c = _local(a), _local(b), _local(c)
+        if narrow:
+            b, c = _side_by_side(b, w), _side_by_side(c, w)
         c_acc = (beta * c).astype(acc)
 
         def step(k, c_acc):
-            acol = lax.dynamic_index_in_dim(a, k // q, axis=1, keepdims=False)
+            if narrow:
+                acol = _tile_column(a, k // q)
+            else:
+                acol = lax.dynamic_index_in_dim(a, k // q, axis=1,
+                                                keepdims=False)
             acol = comm.bcast_from_col(acol, k % q)      # [mtl, nb, nb]
             brow = lax.dynamic_index_in_dim(b, k // p, axis=0, keepdims=False)
             brow = comm.bcast_from_row(brow, k % p)      # [ntl, nb, nb]
-            upd = jnp.einsum("aik,bkj->abij", acol, brow,
+            upd = jnp.einsum("aik,kj->aij" if narrow else "aik,bkj->abij",
+                             acol, brow,                 # ... or [nb, w]
                              preferred_element_type=acc, **pk)
             return c_acc + alpha.astype(acc) * upd
 
         c_acc = lax.fori_loop(0, kt, step, c_acc)
-        return c_acc.astype(c.dtype)[None, None]
+        out = c_acc.astype(c.dtype)
+        if narrow:
+            out = _as_tiles(out, ntl)
+        return out[None, None]
 
     data = _shard(body, g.mesh, 3, 2)(A.data, B.data, C.data, alpha, beta)
     return C._replace(data=data)
@@ -605,10 +659,40 @@ def _carried_cols(n: int, nb: int, q: int, ntl: int) -> int:
     nb]`` storage that hold a real column on some device column (device
     column 0 holds the most), rounded up to whole lanes and capped at
     the stored ``ntl·nb``; the columns past them are zero padding on
-    every device."""
+    every device. What ``trsm(Side.Left)`` carries of B through a
+    solve and what ``gemm`` multiplies of B and accumulates of C."""
     slot = max(n - 1, 0) // (q * nb)          # last local tile slot in use
     real = slot * nb + min(nb, n - slot * q * nb)
     return min(cdiv(max(real, 1), LANES) * LANES, ntl * nb)
+
+
+def _side_by_side(t: jax.Array, w: int) -> jax.Array:
+    """Local tiles ``[mtl, ntl, nb, nb]`` as ``[mtl, nb, w]``: each
+    block-row's tiles side by side, cut to the leading ``w`` columns."""
+    mtl, ntl, nb, _ = t.shape
+    return t.transpose(0, 2, 1, 3).reshape(mtl, nb, ntl * nb)[:, :, :w]
+
+
+def _tile_column(a: jax.Array, s) -> jax.Array:
+    """Local tile column ``a[:, s]`` of ``[mtl, ktl, nb, nb]`` read tile
+    by tile, in the order A is stored in. One slice of the column inside
+    a loop wants it contiguous, and XLA then re-orders a copy of all the
+    local A before the loop (PERF 7, fault 3a)."""
+    mtl, _, nb, _ = a.shape
+
+    def slot(r, col):
+        tile = lax.dynamic_slice(a, (r, s, 0, 0), (1, 1, nb, nb))[0]
+        return lax.dynamic_update_slice(col, tile, (r, 0, 0))
+
+    return lax.fori_loop(0, mtl, slot, jnp.zeros((mtl, nb, nb), a.dtype))
+
+
+def _as_tiles(x: jax.Array, ntl: int) -> jax.Array:
+    """``_side_by_side``'s inverse: ``[mtl, nb, w]`` back into
+    ``[mtl, ntl, nb, nb]``, exact zeros past column ``w``."""
+    mtl, nb, w = x.shape
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, ntl * nb - w)))
+    return x.reshape(mtl, nb, ntl, nb).transpose(0, 2, 1, 3)
 
 
 def _moves_x(n: int, nb: int, q: int) -> bool:
@@ -674,8 +758,7 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
     def body(a, x, alpha):
         a, x = _local(a), _local(x)
         r, c = comm.coords()
-        x = x.transpose(0, 2, 1, 3).reshape(mtl, nb, ntl * nb)[:, :, :w]
-        x = x * alpha
+        x = _side_by_side(x, w) * alpha
         gi = masks.local_tile_rows(mtl, p)               # [mtl]
         if move_x:
             # alpha·B is device column 0's; the others bring nothing
@@ -767,9 +850,7 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
             # the solved rows sit on every device column: X is stored
             # on device column 0, exact zeros beside it
             x = jnp.where(c == 0, x, jnp.zeros_like(x))
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, ntl * nb - w)))
-        x = x.reshape(mtl, nb, ntl, nb).transpose(0, 2, 1, 3)
-        return x[None, None]
+        return _as_tiles(x, ntl)[None, None]
 
     data = _shard(body, g.mesh, 2, 1)(A.data, B.data, alpha)
     return B._replace(data=data)

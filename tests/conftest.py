@@ -53,6 +53,15 @@ def spd(n, dtype=np.float64, seed=0):
     return (g @ np.conj(g.T) / n + np.eye(n)).astype(dtype)
 
 
+def padded_dense(M):
+    """A matrix as it is stored: every tile of every device, padding
+    included, as one dense array."""
+    from slate_tpu.matrix import bc_to_tiles, tiles_to_dense
+    tiles = bc_to_tiles(M.data)
+    return np.asarray(tiles_to_dense(tiles, tiles.shape[0] * M.nb,
+                                     tiles.shape[1] * M.nb))
+
+
 def all_reduce_shapes(hlo_text):
     """(bytes an element, dims) of every result of every all-reduce in
     an optimized HLO text, the operands XLA combined into one tuple

@@ -7,7 +7,7 @@ import pytest
 
 import slate_tpu as st
 from slate_tpu.types import Side, Uplo, Diag, Op
-from tests.conftest import all_reduce_shapes, rand
+from tests.conftest import all_reduce_shapes, padded_dense, rand
 
 
 def tri(a, lower, unit=False):
@@ -348,13 +348,6 @@ def test_trsm_moves_x(n, nb, q, moves):
     assert blas._moves_x(n, nb, q) is moves
 
 
-def _padded_dense(M):
-    from slate_tpu.matrix import bc_to_tiles, tiles_to_dense
-    tiles = bc_to_tiles(M.data)
-    return np.asarray(tiles_to_dense(tiles, tiles.shape[0] * M.nb,
-                                     tiles.shape[1] * M.nb))
-
-
 def _narrow_b_cases():
     """Every width on the three older shapes; on 1x2 and 4x2, which are
     here for the form that moves X, the widths that take it (nrhs <= nb,
@@ -398,7 +391,7 @@ def test_trsm_left_narrow_b(shape, op, uplo, nrhs, diag):
     ref = np.linalg.solve(opt.astype(np.complex128), 1.5 * b)
     assert np.abs(x - ref).max() <= 2e-6 * np.abs(ref).max()
     # the padding of X is stored as exact zeros, on every device column
-    stored = _padded_dense(X)
+    stored = padded_dense(X)
     assert stored.shape[1] >= nrhs and stored.shape[0] >= n
     assert not stored[:, nrhs:].any() and not stored[n:].any()
     np.testing.assert_array_equal(stored[:n, :nrhs], x)

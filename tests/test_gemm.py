@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 import slate_tpu as st
-from tests.conftest import rand
+from tests.conftest import padded_dense, rand
 
 
 @pytest.mark.parametrize("m,n,k,nb", [(32, 32, 32, 8), (24, 40, 16, 8),
@@ -83,3 +83,92 @@ def test_gemm_bf16_accumulates_f32(grid22):
     got = np.asarray(C2.to_dense()).astype(np.float32)
     # bf16 inputs, f32 accumulation: relative error ~1e-2
     assert np.abs(got - ref).max() / np.abs(ref).max() < 5e-2
+
+
+# -- a B narrower than its storage is multiplied at its own width ------------
+# nb=8 never crops (the carried width is whole lanes of 128, capped at the
+# stored ntl*nb): these run the narrow shapes at nb=256.
+
+@pytest.mark.parametrize("dt", [np.float32, np.complex64],
+                         ids=["f32", "c64"])
+@pytest.mark.parametrize("op", ["n", "c"])
+@pytest.mark.parametrize("beta", [0.0, 0.5])
+@pytest.mark.parametrize("n", [1, 8, 130, 256, 257, 1024])
+@pytest.mark.parametrize("shape", ["1x1", "2x2", "2x4"])
+def test_gemm_narrow_b(shape, n, beta, op, dt):
+    """One column in a 256-wide tile (``mixed.matvec``: beta = 0 into a
+    zeroed C; ``mixed._residual``: a filled C), eight, one over a lane
+    boundary, a whole tile, one column over it, and a B that fills its
+    storage on every grid: the product, and the stored padding of the
+    result, which is exact zeros."""
+    import jax
+    import jax.numpy as jnp
+    p, q = map(int, shape.split("x"))
+    grid = st.Grid(p, q, devices=jax.devices()[:p * q])
+    m, k, nb = 520, 300, 256
+    a = rand(*((m, k) if op == "n" else (k, m)), dt, 50)
+    b = rand(k, n, dt, 51)
+    c = rand(m, n, dt, 52) if beta else np.zeros((m, n), dt)
+    A = st.Matrix.from_dense(a, nb=nb, grid=grid)
+    if op == "c":
+        A = st.conj_transpose(A)
+        a = np.conj(a.T)
+    out = st.gemm(-1.5, A, st.Matrix.from_dense(b, nb=nb, grid=grid), beta,
+                  st.Matrix.from_dense(c, nb=nb, grid=grid))
+    ref = np.asarray(-1.5 * jnp.matmul(a, b, precision="highest") + beta * c)
+    got = np.asarray(out.to_dense())
+    assert got.dtype == dt
+    assert np.abs(got - ref).max() <= 1e-5 * np.abs(ref).max()
+    stored = padded_dense(out)
+    assert stored.shape[0] >= m and stored.shape[1] >= n
+    assert not stored[:, n:].any() and not stored[m:].any()
+    np.testing.assert_array_equal(stored[:m, :n], got)
+
+
+def _lower_gemm(grid, n, nb=256, m=520, k=300):
+    import jax.numpy as jnp
+    from slate_tpu.ops import blas
+    A = st.Matrix.from_dense(rand(m, k, np.float32, 53), nb=nb, grid=grid)
+    B = st.Matrix.from_dense(rand(k, n, np.float32, 54), nb=nb, grid=grid)
+    C = st.Matrix.from_dense(rand(m, n, np.float32, 55), nb=nb, grid=grid)
+    return blas._gemm_jit.lower(jnp.float32(2.0), A, B, jnp.float32(0.5), C,
+                                tier="bf16_6x")
+
+
+# sha256 of _gemm_jit's lowered StableHLO text for a B that fills its
+# storage, taken from the parent of PR 40 (8c997dc) with jax 0.9.0
+_WIDE_GEMM_TEXT = {
+    "1x1": "07c4c4a17d13a527be0f13a04a359d18"
+           "0a6ccb699139c375508e33debdbecd4a",
+    "2x2": "da88191465779116124442b1d5b6eade"
+           "0ea1ad184aef235bb7efe84410c7c8f2",
+}
+
+
+@pytest.mark.parametrize("shape", list(_WIDE_GEMM_TEXT))
+def test_gemm_full_width_program_is_the_one_it_was(grid11, grid22, shape):
+    """A B of whole tile columns on every device column: nothing to
+    cut, and the program is the parent's, text for text."""
+    import hashlib
+    import jax
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests were taken with jax 0.9.0")
+    grid = {"1x1": grid11, "2x2": grid22}[shape]
+    text = _lower_gemm(grid, 512).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == _WIDE_GEMM_TEXT[shape]
+
+
+@pytest.mark.parametrize("n,nb", [(1, 256), (130, 256), (8, 1024)])
+@pytest.mark.parametrize("shape", ["1x1", "2x2"])
+def test_gemm_narrow_b_pays_for_its_lanes(grid11, grid22, shape, n, nb):
+    """A one-column B costs 128 lanes of the tile's nb: the compiled
+    program's flops follow the carried width (a half at nb=256, an
+    eighth at nb=1024; 130 columns carry 256, which is the whole tile,
+    and cost what nb columns cost)."""
+    from slate_tpu.ops import blas
+    grid = {"1x1": grid11, "2x2": grid22}[shape]
+    m = k = 2 * nb + 40
+    narrow = _lower_gemm(grid, n, nb, m, k).compile().cost_analysis()
+    full = _lower_gemm(grid, nb, nb, m, k).compile().cost_analysis()
+    share = blas._carried_cols(n, nb, grid.q, 1) / nb
+    assert share * 0.98 <= narrow["flops"] / full["flops"] <= share * 1.06

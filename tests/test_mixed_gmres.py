@@ -267,6 +267,38 @@ def test_root_span_and_convergence_of_each_routine(routine, grid22):
     assert backward_error_eps(a, np.asarray(X.to_dense())[:, 0], b) < 20.0
 
 
+def test_every_product_with_a_is_one_lane_group_wide(grid, monkeypatch):
+    """The residuals and the Arnoldi products are ``gemm`` of A against
+    one column in a 256-wide tile: each ``gemm`` span under
+    ``mixed.residual`` / ``mixed.matvec`` says ``nrhs`` = 1 and ``w`` =
+    128, ``gemm.narrow`` counts them, and they are the root's steps
+    (``refine_steps_per_solve``: inner + outer + 1). A product of two
+    square matrices counts none."""
+    never_satisfied(monkeypatch)
+    n, nb = 512, 256
+    a, b = operands(31, n=n)
+    A, B = on_grid(a + 4 * np.eye(n, dtype=np.float32), b, grid, nb)
+    st.gesv_mixed_gmres(
+        A, B, {st.Option.MaxIterations: 3, st.Option.UseFallbackSolver: False})
+    labels = root_of("gesv_mixed_gmres")["labels"]
+    steps = labels["inner"] + labels["outer"] + 1
+    assert labels["inner"] == 3 and steps >= 5
+    by_id = {s["id"]: s for s in obs.captured_spans()}
+    gemms = spans_named("gemm")
+    assert len(gemms) == steps
+    assert {by_id[s["parent"]]["name"] for s in gemms} == {
+        "mixed.residual", "mixed.matvec"}
+    assert {(s["labels"]["nrhs"], s["labels"]["w"]) for s in gemms} == {
+        (1, 128)}
+    assert metrics.counter_total("gemm.narrow") == steps
+    C = st.Matrix.zeros(n, n, nb, grid, dtype=np.float32)
+    st.multiply(1.0, A, A, 0.0, C)
+    (wide,) = spans_named("gemm")[steps:]
+    assert (wide["labels"]["nrhs"], wide["labels"]["w"]) == (
+        n, n // grid.q)
+    assert metrics.counter_total("gemm.narrow") == steps
+
+
 @pytest.mark.parametrize("routine", ROUTINES)
 def test_fallback_runs_at_the_working_tier_and_returns_its_info(
         routine, grid22, monkeypatch):

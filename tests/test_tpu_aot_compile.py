@@ -337,6 +337,46 @@ def test_trsm_left_moves_x_for_getrs_too(topo, tpu_grid22, lower, unit,
     _assert_a_stays(c, H // NB // grid.p)
 
 
+# -- the refining solvers' products with A: gemm at the width B holds --------
+
+@pytest.mark.parametrize("shape", ["1x1", "2x2"])
+def test_gemm_one_column_multiplies_128_lanes(topo, tpu_grid22, shape):
+    """``mixed._residual`` / ``matvec`` at the cell's size: A (H x H)
+    times one column stored as a 1024-wide tile column. B and C ride
+    the product 128 columns wide, A is read where it lies (no copy of
+    it among the temporaries), and on the grid what crosses p is a
+    ``[nb, w]`` block-row of B beside column k of A over q."""
+    import math
+    from slate_tpu.ops import blas
+    grid = (tpu_grid22 if shape == "2x2"
+            else slate.Grid(1, 1, devices=[topo.devices[0]]))
+    mtl = H // NB // grid.p
+    col = jax.ShapeDtypeStruct((grid.p, grid.q, mtl, 1, NB, NB), F32,
+                               sharding=grid.sharding())
+    A = slate.Matrix(data=_tiles(grid), m=H, n=H, nb=NB, grid=grid)
+    B = slate.Matrix(data=col, m=H, n=1, nb=NB, grid=grid)
+    C = slate.Matrix(data=col, m=H, n=1, nb=NB, grid=grid)
+    s = jax.ShapeDtypeStruct((), F32)
+    c = blas._gemm_jit.lower(s, A, B, s, C, tier="bf16_6x").compile()
+    text = c.as_text()
+    products = re.findall(
+        r"= f32\[([\d,]+)\]\S* (?:convolution|dot)\(", text)
+    assert products
+    widest = max(math.prod(map(int, dims.split(","))) for dims in products)
+    assert widest <= mtl * NB * W, products
+    # one chip: the whole product, 2 H H w; the grid: a step's, whose
+    # loop cost_analysis counts once (the 1024-wide tile cost 8x)
+    assert c.cost_analysis()["flops"] < 1.2 * 2 * H * H * W / grid.size
+    mem = c.memory_analysis()
+    assert abs(mem.argument_size_in_bytes
+               - (A.data.size + 2 * col.size) * 4 // grid.size) < 2 ** 20
+    # a copy of the local A would be 2^30 / chips bytes
+    assert mem.temp_size_in_bytes < 2 ** 24, mem.temp_size_in_bytes
+    assert "all-gather" not in text and "all-to-all" not in text
+    sent = sorted(math.prod(dims) for _, dims in all_reduce_shapes(text))
+    assert sent == ([] if shape == "1x1" else [NB * W, mtl * NB * NB]), sent
+
+
 # -- one served executable ---------------------------------------------------
 
 def test_served_posv_bucket_compiles(one_chip):
