@@ -1,0 +1,15 @@
+"""Blocking device-to-host reads the program makes inside one
+``slate.heev``: its spans labelled ``sync=1`` under the root
+(``band.gather``, ``hb2st.tridiagonal``, two a merge: ``stedc.zrow``
+and ``stedc.roots``), median over the traced calls. Each is a round
+trip during which the device has nothing queued."""
+
+from __future__ import annotations
+
+from benchmarks.layer_metrics import host_syncs_per_solve
+
+HEADER = {"name": "eig_host_syncs_per_solve", "unit": "count",
+          "better": "lower", "source": "program_counter",
+          "layer": "eigen", "moves": "solve_s"}
+# the accepted reader: this cell's roots are slate.<routine> as it is
+compute = host_syncs_per_solve.compute

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .band_bulge import max_chase
+from .. import obs
 
 
 def _masked_larfg(x, L, cplx):
@@ -303,5 +304,5 @@ def hb2st_wave(ab):
         from .band_bulge import hb2st as _host
         return _host(ab)
     d, e, V, tau = _hb2st_wave_jit(jnp.asarray(ab), band, n)
-    return (np.asarray(d), np.asarray(e), np.asarray(V),
-            np.asarray(tau))
+    return obs.sync_read("hb2st.tridiagonal", jax.device_get,
+                         (d, e, V, tau))

@@ -71,6 +71,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .band_bulge import max_chase
+from .. import obs
 
 TAUP = 128     # tau slots padded to one lane tile
 U_SLOTS = 8    # wave slots unrolled per chunk body (the compile-time
@@ -635,4 +636,5 @@ def hb2st_wave_vmem(ab, interpret=None):
     # pack to the host costs ~0.6 GB at n=12288/b=128 (the
     # vectors path feeds them straight back into device einsums via
     # apply_bulge_reflectors' jnp.asarray)
-    return np.asarray(d), np.asarray(e), V, tau
+    d, e = obs.sync_read("hb2st.tridiagonal", jax.device_get, (d, e))
+    return d, e, V, tau

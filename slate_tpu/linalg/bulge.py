@@ -36,6 +36,7 @@ from ..cache.jitcache import cached_jit
 from ..grid import AXIS_P, AXIS_Q
 from ..matrix import cdiv
 from ..utils import trace
+from .. import obs
 
 
 # ---------------------------------------------------------------------------
@@ -65,7 +66,8 @@ def _band_tiles(A, super_diag: bool):
     else:
         off = tuple(_tile_flat_index(k + 1, k, g, mtl, ntl)
                     for k in range(nt - 1))
-    tiles = np.asarray(_gather_tiles_jit(A.data, diag + off))
+    tiles = obs.sync_read("band.gather", np.asarray,
+                          _gather_tiles_jit(A.data, diag + off))
     return tiles[:nt], tiles[nt:], nt
 
 
@@ -112,27 +114,75 @@ def gather_band_upper(A) -> np.ndarray:
 # Device-side packed-reflector application
 # ---------------------------------------------------------------------------
 
+def _skew(x):
+    """[..., K, w] → [..., K, w + K - 1], row i shifted right by i
+    (zeros elsewhere): a pad and two reshapes, no gather."""
+    *lead, K, w = x.shape
+    x = jnp.pad(x, [(0, 0)] * (len(lead) + 1) + [(0, K)])
+    flat = x.reshape(*lead, K * (w + K))[..., :K * (w + K - 1)]
+    return flat.reshape(*lead, K, w + K - 1)
+
+
 @partial(cached_jit, static_argnames=("band", "forward", "conj_tau"))
 def _apply_bulge_jit(V, tau, Z, band, forward, conj_tau):
+    """The reflector family applied in blocks (the reference's
+    unmtr_hb2st applies its reflectors in blocks with T factors for the
+    same reason).  K = band consecutive sweeps at one task t are K
+    reflectors on the band + K rows from (g·K + t·band): their product
+    is I − U·Θ·Uᴴ (compact WY: Θ⁻¹ = diag(1/θ) + the strict triangle
+    of UᴴU on the side of the ones applied first), three products on
+    the MXU and one aligned window of Z a block.  Reflector (s, t)
+    meets (s', t') only for t' = t or t + 1 at s' < s, so a group of K
+    sweeps goes task by task (ascending t when the later sweeps apply
+    first), group after group; a task wholly past row n is not read.
+    One sweep at a time, this was 4.4 ms a sweep at n=8192 (four
+    passes over a 256 MiB window off the sublane grid): PERF.md
+    section 6, PR 41."""
     S, T = tau.shape
     n, m = Z.shape
-    n_pad = S + T * band + 1
-    Zp = jnp.zeros((n_pad, m), Z.dtype)
-    Zp = Zp.at[:n].set(Z)
-    Vc = jnp.conj(V)
-    taus = jnp.conj(tau) if conj_tau else tau
+    K = band
+    G = cdiv(S, K)
+    Lw = band + K
+    high = lax.Precision.HIGHEST
+    theta = jnp.conj(tau) if conj_tau else tau
+    # a reflector with tau = 0 is the identity: u = 0 under theta = 1
+    dead = theta == 0
+    Vz = jnp.where(dead[:, :, None], 0, V)
+    Vz = jnp.pad(Vz, ((0, G * K - S), (0, 0), (0, 0)))
+    theta = jnp.pad(jnp.where(dead, 1, theta), ((0, G * K - S), (0, 0)),
+                    constant_values=1)
+    Zp = jnp.zeros((G * K + T * band, m), Z.dtype).at[:n].set(Z)
+    eye = jnp.eye(K, dtype=Z.dtype)
 
-    def body(i, Zp):
-        s = i if forward else S - 1 - i
-        Zw = lax.dynamic_slice(Zp, (s + 1, 0), (T * band, m))
-        Zw = Zw.reshape(T, band, m)
-        w = jnp.einsum("tb,tbm->tm", Vc[s], Zw)
-        Zw = Zw - taus[s][:, None, None] * V[s][:, :, None] * w[:, None, :]
-        return lax.dynamic_update_slice(Zp, Zw.reshape(T * band, m),
-                                        (s + 1, 0))
+    def group(i, Zp):
+        g = i if forward else G - 1 - i
+        Vg = lax.dynamic_slice(Vz, (g * K, 0, 0), (K, T, band))
+        th = lax.dynamic_slice(theta, (g * K, 0), (K, T)).T     # [T, K]
+        # row i of U[t] is sweep g·K + i: rows (g·K + t·band) + i + 1 on
+        U = _skew(jnp.pad(Vg.transpose(1, 0, 2),
+                          ((0, 0), (0, 0), (1, 0))))            # [T, K, Lw]
+        gram = jnp.einsum("til,tjl->tij", jnp.conj(U), U, precision=high)
+        first = jnp.tril(gram, -1) if forward else jnp.triu(gram, 1)
+        theta_inv = first + eye / th[:, :, None]
+        Theta = lax.linalg.triangular_solve(
+            theta_inv, jnp.broadcast_to(eye, theta_inv.shape),
+            left_side=True, lower=forward)
+        live = jnp.clip((n - 1 - g * K + band - 1) // band, 0, T)
 
-    Zp = lax.fori_loop(0, S, body, Zp)
-    return Zp[:n]
+        def task(j, Zp):
+            t = live - 1 - j if forward else j
+            r0 = g * K + t * band
+            Zw = lax.dynamic_slice(Zp, (r0, 0), (Lw, m))
+            Ut = lax.dynamic_index_in_dim(U, t, 0, keepdims=False)
+            Tt = lax.dynamic_index_in_dim(Theta, t, 0, keepdims=False)
+            W = jnp.matmul(jnp.conj(Ut), Zw, precision=high)
+            W = jnp.matmul(Tt, W, precision=high)
+            Zw = Zw - jnp.matmul(Ut.T, W, precision=high)
+            return lax.dynamic_update_slice(Zp, Zw, (r0, 0))
+
+        return lax.fori_loop(0, live, task, Zp)
+
+    return lax.fori_loop(0, G, group, Zp)[:n]
 
 
 def apply_bulge_reflectors(V, tau, Z, band, forward=False, conj_tau=True,

@@ -7,18 +7,36 @@ src/he2hb.cc) then hb2st (band→tridiagonal bulge chasing, src/hb2st.cc
 (sterf values-only / steqr2 ◆Fortran / stedc divide & conquer), then
 distributed back-transform (unmtr_hb2st / unmtr_he2hb).
 
-v1 TPU design: the dense→eigen path uses XLA's native ``eigh`` (a
-QDWH-based spectral divide-and-conquer, MXU-friendly) on a replicated
-copy, then redistributes the eigenvectors — a deliberate parity
-choice: the reference itself serializes the band stage onto one rank
-(SURVEY §3.5 "known scalability cliff"), so the crossover where a
-distributed two-stage wins is large; the distributed he2hb pipeline is
-the planned next step (tracked in ROADMAP.md). hegst (the generalized
-→ standard reduction) IS fully distributed via trsm/hemm.
+What runs here.  ``heev`` has two paths.  *Two-stage* is the
+reference's pipeline, every stage on the device but the O(k) scalar
+work of a merge: ``he2hb`` (one jitted ``shard_map`` loop over block
+columns, ``linalg/he2hb.py``), the band gathered to the host
+(2·nt tiles), ``hb2st`` by the ``robust.ladder`` rung that takes the
+problem (on a TPU in f32 the VMEM-resident Pallas chaser at band 128,
+``internal/band_wave_vmem.py``), the tridiagonal eigenproblem
+(``MethodEig.DC``: ``linalg/stedc.py`` with Z, the secular solves and
+the merge products on the device; ``MethodEig.QR``: host values +
+device inverse iteration, ``linalg/stein.py``), then the two
+back-transforms ``unmtr_hb2st`` (``linalg/bulge.py``) and
+``unmtr_he2hb``.  *Dense* is one replicated ``jnp.linalg.eigh`` (XLA's
+QDWH), a one-chip shortcut the reference does not have; ``Auto`` takes
+it below the crossover in :func:`heev`.  ``Option.TrailingPrecision``
+reaches stage 1's trailing products only: the merge products, the
+packed-reflector sweeps and ``unmtr_he2hb`` run at the package default
+(``highest``).  hegst (the generalized → standard reduction) is fully
+distributed via trsm/hemm.
 
-Tridiagonal kernels sterf/steqr/stedc are provided for API parity and
-for the two-stage path, backed by LAPACK via scipy on host (the
-reference equally runs sterf/steqr2/stedc on the host CPUs).
+What a call reports (docs/observability.md): the spans :data:`SPANS`
+(a root ``slate.heev`` with ``routine``, ``n``, ``nb``, ``grid``,
+``jobz``, ``method``, ``path``; at its end ``method`` as resolved and,
+two-stage, ``band`` and ``chase_backend``), every blocking read as an
+``obs.sync_read`` (``band.gather``, ``hb2st.tridiagonal``,
+``stedc.zrow``, ``stedc.roots``, ``heev.values``), and the counters
+:data:`COUNTERS`.
+
+The host tridiagonal kernels sterf/steqr (scipy LAPACK) are kept for
+API parity and for the values-only and QR paths (the reference equally
+runs sterf/steqr2 on the host CPUs).
 """
 
 from __future__ import annotations
@@ -28,7 +46,8 @@ import jax.numpy as jnp
 
 from ..matrix import (Matrix, HermitianMatrix, TriangularMatrix,
                       conj_transpose)
-from ..types import Norm, Uplo, Side, Op, MethodEig
+from .. import obs
+from ..types import Norm, Uplo, Side, Op, MethodEig, Option, get_option
 from ..errors import slate_error_if
 from ..ops.blas import trsm, gemm
 from ..utils import trace
@@ -46,80 +65,107 @@ def _he_to_dense(A: HermitianMatrix):
     return full
 
 
+# what an eigensolve reports: the spans (the root first, then its
+# children in the order they open; ``heev.dense`` alone on the dense
+# path) and the counters (``/metrics``): ``heev.path{path}``,
+# ``hb2st.backend{rung}`` (the rung whose answer was used),
+# ``hb2st.demotion{from,to}`` (a rung that was stepped past: the
+# ladder's own ``ladder.demotions`` by another name, so a caller of
+# heev need not know the ladder), and ``linalg/stedc.py``'s
+SPANS = ("slate.heev", "heev.stage1", "heev.gather", "heev.stage2",
+         "heev.tridiag", "heev.back.hb2st", "heev.back.he2hb",
+         "heev.dense")
+COUNTERS = ("heev.path", "hb2st.backend", "hb2st.demotion",
+            "stedc.merges", "stedc.poles", "stedc.deflated")
+
+# one chip: below this n ``Auto`` takes XLA's eigh. The number is
+# round 5's (two-stage with vectors was then slower than eigh, ~5 s at
+# n=8192, until eigh's n² replication threatens HBM near 24k f32 on
+# 16 GB) and the chip has since contradicted both halves (PERF.md
+# section 6, PR 41, n=8192 f32 on one v5e, jax 0.9.0): two-stage DC
+# with vectors is 4.14 s a call, and ``jnp.linalg.eigh`` did not
+# come back at n=4096 or n=8192: the process passed 30 GiB of host
+# memory 286 / 341 s in and was stopped (left alone it was killed at
+# the machine's 40 GiB). Not moved here: ROADMAP R7b
+DENSE_BELOW = 24576
+
+
+def _takes_two_stage(A, opts, method, want_vectors) -> bool:
+    if method != MethodEig.Auto:
+        # QR/DC name the tridiagonal stage of the two-stage pipeline
+        # (reference MethodEig semantics, src/heev.cc:139-156)
+        return method in (MethodEig.TwoStage, MethodEig.QR, MethodEig.DC)
+    # two-stage whenever the grid is parallel OR the problem is too
+    # big for a replicated dense eigh on one chip (its n² replication
+    # and workspace threaten HBM near 24k f32 on 16 GB).  The
+    # reference is ALWAYS two-stage (src/heev.cc:104-172); the dense
+    # path is a single-chip shortcut only
+    thresh = DENSE_BELOW
+    if not want_vectors:
+        try:
+            import jax as _jax
+            from ..internal.band_wave_vmem import (preferred_eig_band,
+                                                   vmem_applies)
+            # test the band the two-stage pipeline will ACTUALLY
+            # use (a user Option.EigBand override included) — the
+            # lowered threshold is only justified when the VMEM
+            # chaser takes that band. heev_two_stage re-blocks to
+            # band_nb only when A.nb > band_nb and n > 2*band_nb;
+            # otherwise the chase runs at A.nb, so gate on that
+            band_nb = get_option(opts, Option.EigBand,
+                                 preferred_eig_band(A.n, A.dtype))
+            from .he2hb import two_stage_chase_band
+            chase_nb = two_stage_chase_band(A.n, A.nb, band_nb)
+            if (_jax.default_backend() == "tpu"
+                    and vmem_applies(A.n, chase_nb,
+                                     np.dtype(A.dtype))):
+                thresh = 8192
+        except Exception:  # pragma: no cover
+            pass
+    return (A.grid.size > 1 and A.nt >= 4) or A.n >= thresh
+
+
 def heev(A: HermitianMatrix, opts=None, want_vectors: bool = True):
     """Eigendecomposition A = Z·Λ·Zᴴ (reference src/heev.cc).
 
-    Method dispatch (Option.MethodEig): TwoStage = distributed he2hb
-    band reduction + host banded solver + distributed back-transform
-    (the reference's pipeline, src/heev.cc:104-172); Dense = replicated
-    XLA eigh (QDWH). Auto: two-stage on multi-chip grids with enough
-    tiles (the he2hb flops — the O(n³) term — then run distributed),
-    dense otherwise.
+    Method dispatch (Option.MethodEig): TwoStage / QR / DC = he2hb
+    band reduction + hb2st bulge chase + the tridiagonal solver named
+    (DC by default) + the two back-transforms (the reference's
+    pipeline, src/heev.cc:104-172); Dense = replicated XLA eigh
+    (QDWH). Auto: two-stage on multi-chip grids with enough tiles
+    (the he2hb flops — the O(n³) term — then run distributed) and
+    from ``DENSE_BELOW`` up, dense otherwise.
 
     Returns (Lambda [n] ascending, Z distributed Matrix or None).
     """
-    from ..types import Option, MethodEig, get_option, Uplo as _U
     slate_error_if(A.m != A.n, "heev needs square")
     method = get_option(opts, Option.MethodEig, MethodEig.Auto)
-    if method == MethodEig.Auto:
-        # two-stage whenever the grid is parallel OR the problem is
-        # too big for a replicated dense eigh on one chip. Single-chip
-        # VALUES-only crossover re-tuned in round 5: the VMEM Pallas
-        # chaser cut stage 2 at n=8192/b=128 from 5.95 s to 2.45 s
-        # (BENCH_r05 heev2_split), so two-stage (0.23 + 2.45 + sterf)
-        # beats dense eigh (~5 s) from n ≈ 8192 up — when the chaser
-        # applies (f32, ribbon fits VMEM). With VECTORS the
-        # back-transform + inverse-iteration costs keep dense ahead
-        # until its n² replication threatens HBM (~24k f32 with eigh
-        # workspace on 16 GB). The reference is ALWAYS two-stage
-        # (src/heev.cc:104-172); the dense path is a single-chip
-        # shortcut only.
-        thresh = 24576
-        if not want_vectors:
-            try:
-                import jax as _jax
-                from ..internal.band_wave_vmem import (preferred_eig_band,
-                                                       vmem_applies)
-                # test the band the two-stage pipeline will ACTUALLY
-                # use (a user Option.EigBand override included) — the
-                # lowered threshold is only justified when the VMEM
-                # chaser takes that band. heev_two_stage re-blocks to
-                # band_nb only when A.nb > band_nb and n > 2*band_nb;
-                # otherwise the chase runs at A.nb, so gate on that
-                band_nb = get_option(opts, Option.EigBand,
-                                     preferred_eig_band(A.n, A.dtype))
-                from .he2hb import two_stage_chase_band
-                chase_nb = two_stage_chase_band(A.n, A.nb, band_nb)
-                if (_jax.default_backend() == "tpu"
-                        and vmem_applies(A.n, chase_nb,
-                                         np.dtype(A.dtype))):
-                    thresh = 8192
-            except Exception:  # pragma: no cover
-                pass
-        two = (A.grid.size > 1 and A.nt >= 4) or A.n >= thresh
-    else:
-        # QR/DC name the tridiagonal stage of the two-stage pipeline
-        # (reference MethodEig semantics, src/heev.cc:139-156)
-        two = method in (MethodEig.TwoStage, MethodEig.QR, MethodEig.DC)
-    if two:
-        from .he2hb import heev_two_stage
-        if A.uplo == _U.Upper:
-            # mirror the stored Upper half into Lower storage — the
-            # same Hermitian operator, so Λ and Z are unchanged
-            # (reference he2hb handles Lower; heev.cc dispatches the
-            # conjugated problem the same way)
-            G = Matrix(data=A.data, m=A.m, n=A.n, nb=A.nb, grid=A.grid)
-            low = conj_transpose(G).materialize().data
-            A = HermitianMatrix(data=low, m=A.m, n=A.n, nb=A.nb,
-                                grid=A.grid, uplo=_U.Lower)
-        return heev_two_stage(A, opts, want_vectors)
-    with trace.block("heev"):
-        full = _he_to_dense(A)
-        lam, z = jnp.linalg.eigh(full)
-        if not want_vectors:
-            return np.asarray(lam), None
-        Z = Matrix.from_dense(z, nb=A.nb, grid=A.grid)
-    return np.asarray(lam), Z
+    two = _takes_two_stage(A, opts, method, want_vectors)
+    path = "two_stage" if two else "dense"
+    obs.count("heev.path", 1, path=path)
+    with trace.block("slate.heev", routine="heev", n=A.n, nb=A.nb,
+                     grid=f"{A.grid.p}x{A.grid.q}",
+                     jobz="V" if want_vectors else "N",
+                     method=method.name, path=path) as root:
+        if two:
+            from .he2hb import heev_two_stage
+            if A.uplo == Uplo.Upper:
+                # mirror the stored Upper half into Lower storage — the
+                # same Hermitian operator, so Λ and Z are unchanged
+                # (reference he2hb handles Lower; heev.cc dispatches
+                # the conjugated problem the same way)
+                G = Matrix(data=A.data, m=A.m, n=A.n, nb=A.nb,
+                           grid=A.grid)
+                low = conj_transpose(G).materialize().data
+                A = HermitianMatrix(data=low, m=A.m, n=A.n, nb=A.nb,
+                                    grid=A.grid, uplo=Uplo.Lower)
+            return heev_two_stage(A, opts, want_vectors, root=root)
+        root.label(method=MethodEig.Dense.name)
+        with trace.block("heev.dense", n=A.n):
+            lam, z = jnp.linalg.eigh(_he_to_dense(A))
+            Z = (Matrix.from_dense(z, nb=A.nb, grid=A.grid)
+                 if want_vectors else None)
+        return obs.sync_read("heev.values", np.asarray, lam), Z
 
 
 def hegst(itype: int, A: HermitianMatrix, L: TriangularMatrix, opts=None):

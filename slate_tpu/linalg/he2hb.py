@@ -24,10 +24,14 @@ After the loop the storage holds the band (diagonal tiles + upper-
 triangular sub-diagonal tiles) with the Householder V blocks below —
 exactly the reference's in-place layout — plus the T stack.
 
-Stage 2+3 (band → tridiagonal → eigenpairs) run on the host via
-LAPACK's banded solvers (scipy ?hbevd), matching the reference, which
-gathers the band to rank 0 and bulge-chases serially
-(src/heev.cc:108-131). The back-transform is distributed.
+Stage 2 (band → tridiagonal) is ``hb2st``: the band is gathered to
+the host (2·nt tiles; the reference gathers it to rank 0 and chases
+there, src/heev.cc:108-131) and chased by the ``robust.ladder`` rung
+that takes it, on a TPU in f32 the VMEM-resident Pallas kernel. Stage 3
+is ``linalg/stedc.py`` / ``stein.py``; the two back-transforms
+(``unmtr_hb2st``, ``unmtr_he2hb``) run on the device, the second
+distributed. ``heev_two_stage`` strings them together under
+``eig.heev``'s root span.
 """
 
 from __future__ import annotations
@@ -49,6 +53,7 @@ from ..internal import comm, masks
 from ..internal.precision import resolve_tier, trailing_dot_kwargs
 from ..internal.tile_kernels import panel_qr_factor, extract_v, larft
 from ..utils import trace
+from .. import obs
 
 
 def he2hb(A: HermitianMatrix, opts=None):
@@ -268,14 +273,26 @@ def hb2st(band: np.ndarray):
     demotion logged in ``robust.ladder.demotion_log()``.
     """
     import os
-    from ..robust.ladder import hb2st_ladder
+    from ..robust.ladder import demotion_log, hb2st_ladder
     band = np.asarray(band)
     choice = os.environ.get("SLATE_HB2ST", "")
     start = (choice if choice in ("vmem", "wave", "native", "numpy")
              else None)
+    ladder = hb2st_ladder()
+    logged = len(demotion_log())
     with trace.block("hb2st", routine="hb2st",
-                     n=band.shape[1], b=band.shape[0] - 1):
-        return hb2st_ladder().run(band, start=start)
+                     n=band.shape[1], b=band.shape[0] - 1) as span:
+        out = ladder.run(band, start=start)
+        span.label(rung=ladder.last_rung)
+    # which rung answered, and every rung stepped past on the way: a
+    # demotion is silent to the caller (vmem -> wave is 2.4x on this
+    # stage), so it is counted where a caller of heev can read it
+    obs.count("hb2st.backend", 1, rung=ladder.last_rung)
+    for d in demotion_log()[logged:]:
+        if d.ladder == ladder.name:
+            obs.count("hb2st.demotion", 1, to=d.to_rung,
+                      **{"from": d.from_rung})
+    return out
 
 
 def unmtr_hb2st(V, tau, C, band, trans: Op = Op.NoTrans, grid=None):
@@ -302,13 +319,18 @@ def two_stage_chase_band(n: int, nb: int, band_nb: int) -> int:
     return band_nb if (nb > band_nb and n > 2 * band_nb) else nb
 
 
-def heev_two_stage(A: HermitianMatrix, opts=None, want_vectors=True):
+def heev_two_stage(A: HermitianMatrix, opts=None, want_vectors=True,
+                   root=None):
     """Full two-stage pipeline (reference src/heev.cc:104-172):
     he2hb (distributed) → band gather (2·nt tiles) → hb2st bulge
-    chasing (host, band-limited) → sterf/steqr on the tridiagonal →
-    back-transforms unmtr_hb2st (device, column-sharded) and
-    unmtr_he2hb (distributed)."""
+    chasing (the ``robust.ladder`` rung that takes the band) →
+    sterf/steqr/stedc on the tridiagonal → back-transforms
+    unmtr_hb2st (device, column-sharded) and unmtr_he2hb
+    (distributed).  ``root`` is ``slate.heev``'s span (``eig.heev``),
+    labelled here with what the pipeline chose: ``method`` (the
+    tridiagonal solver), ``band``, ``chase_backend``."""
     from .eig import sterf, steqr, stedc
+    from ..robust.ladder import hb2st_ladder
     from ..types import Option, MethodEig, get_option
     method = get_option(opts, Option.MethodEig, MethodEig.Auto)
     # Re-block to the two-stage band width: stage 2's bulge chase and
@@ -317,10 +339,9 @@ def heev_two_stage(A: HermitianMatrix, opts=None, want_vectors=True):
     # stage-1 MXU batches against chase volume (reference keeps a
     # separate inner band for the same reason, src/he2hb.cc). When the
     # VMEM Pallas chaser can take the problem at band 128 (TPU, f32,
-    # ribbon fits VMEM), prefer that: the chase is the pipeline's
-    # dominant cost and the VMEM kernel at 128 beats the XLA wave at
-    # 256 by a wide margin (r5 measurements: 2.45 s vs 5.95 s at
-    # n=8192 — and the wave's cost grows with band).
+    # ribbon fits VMEM), prefer that: the VMEM kernel at 128 beats the
+    # XLA wave at 256 by a wide margin (PERF.md section 6, PR 41; the
+    # wave's cost grows with band).
     from ..internal.band_wave_vmem import preferred_eig_band
     band_nb = get_option(opts, Option.EigBand,
                          preferred_eig_band(A.n, A.dtype))
@@ -333,43 +354,42 @@ def heev_two_stage(A: HermitianMatrix, opts=None, want_vectors=True):
         else:
             A = HermitianMatrix.from_dense(A.to_dense(), nb=band_nb,
                                            grid=A.grid, uplo=A.uplo)
-    with trace.block("heev_2stage", n=A.n, nb=A.nb):
-        with trace.block("heev.stage1", phase="he2hb", n=A.n):
-            Aband, T = he2hb(A, opts)
-        with trace.block("heev.gather", phase="band_gather", n=A.n):
-            band = he2hb_gather(Aband)
-        with trace.block("heev.stage2", phase="hb2st", n=A.n):
-            d, e, V2, tau2 = hb2st(band)
-        rdt = np.zeros(1, A.dtype).real.dtype
-        if not want_vectors:
-            with trace.block("heev.tridiag", phase="sterf", n=A.n):
-                return np.asarray(sterf(d, e)).astype(rdt), None
-        with trace.block("heev.tridiag", phase="eig_solve", n=A.n):
-            if method == MethodEig.QR or (method not in (MethodEig.DC,)
-                                          and A.n <= 128):
-                if A.n > 512:
-                    # device-Z steqr: values by host QR iteration,
-                    # vectors by batched device inverse iteration
-                    # (stein.py) — the QR-with-vectors path never holds
-                    # dense Z on host (VERDICT r3 #9, reference
-                    # dsteqr2.f semantics)
-                    rdt0 = np.zeros(1, A.dtype).real.dtype
-                    lam, ztri = steqr(d, e, grid=A.grid, dtype=rdt0)
-                else:
-                    lam, ztri = steqr(d, e)     # host QR (tiny n)
-                    ztri = np.ascontiguousarray(ztri)
-            else:
-                # D&C with device-accumulated, row-sharded Z — host
-                # memory stays O(n) (reference stedc + steqr2
-                # semantics)
-                lam, ztri = stedc(d, e, grid=A.grid, dtype=rdt)
-        import jax.numpy as jnp
-        with trace.block("heev.back", phase="back_transform", n=A.n):
-            zb = unmtr_hb2st(V2, tau2,
-                             jnp.asarray(ztri).astype(A.dtype),
-                             A.nb, Op.NoTrans, A.grid)
-            Zb = Matrix.from_dense(zb, nb=A.nb, grid=A.grid)
-            Z = unmtr_he2hb(Op.NoTrans, Aband, T, Zb, opts)
+    qr = method == MethodEig.QR or (method != MethodEig.DC
+                                    and A.n <= 128)
+    rdt = np.zeros(1, A.dtype).real.dtype
+    with trace.block("heev.stage1", phase="he2hb", n=A.n):
+        Aband, T = he2hb(A, opts)
+    with trace.block("heev.gather", phase="band_gather", n=A.n):
+        band = he2hb_gather(Aband)
+    with trace.block("heev.stage2", phase="hb2st", n=A.n):
+        d, e, V2, tau2 = hb2st(band)
+    if root is not None:
+        root.label(method=(MethodEig.QR if qr else MethodEig.DC).name,
+                   band=A.nb, chase_backend=hb2st_ladder().last_rung)
+    if not want_vectors:
+        with trace.block("heev.tridiag", phase="sterf", n=A.n):
+            return np.asarray(sterf(d, e)).astype(rdt), None
+    with trace.block("heev.tridiag", phase="eig_solve", n=A.n):
+        if qr and A.n > 512:
+            # device-Z steqr: values by host QR iteration, vectors by
+            # batched device inverse iteration (stein.py) — the
+            # QR-with-vectors path never holds dense Z on host
+            # (VERDICT r3 #9, reference dsteqr2.f semantics)
+            lam, ztri = steqr(d, e, grid=A.grid, dtype=rdt)
+        elif qr:
+            lam, ztri = steqr(d, e)     # host QR (tiny n)
+            ztri = np.ascontiguousarray(ztri)
+        else:
+            # D&C with device-accumulated, row-sharded Z and the
+            # merges solved on the device — host memory stays
+            # O(n·nmin) (reference stedc + steqr2 semantics)
+            lam, ztri = stedc(d, e, grid=A.grid, dtype=rdt)
+    with trace.block("heev.back.hb2st", phase="unmtr_hb2st", n=A.n):
+        zb = unmtr_hb2st(V2, tau2, jnp.asarray(ztri).astype(A.dtype),
+                         A.nb, Op.NoTrans, A.grid)
+    with trace.block("heev.back.he2hb", phase="unmtr_he2hb", n=A.n):
+        Zb = Matrix.from_dense(zb, nb=A.nb, grid=A.grid)
+        Z = unmtr_he2hb(Op.NoTrans, Aband, T, Zb, opts)
     return np.asarray(lam).astype(rdt), Z
 
 

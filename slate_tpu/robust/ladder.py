@@ -151,6 +151,7 @@ class BackendLadder:
         self.rungs = list(rungs)
         self.validate = validate          # result -> bool (healthy?)
         self._names = [r.name for r in self.rungs]
+        self.last_rung: str | None = None  # whose answer run() returned
 
     def select(self, *args) -> str:
         """Auto-selection: the first rung whose policy prefers the
@@ -210,6 +211,7 @@ class BackendLadder:
                         continue
                     self._demote(i, "non-finite output")
                     break
+                self.last_rung = rung.name
                 return out
         raise SlateError(
             f"backend ladder {self.name!r} exhausted "

@@ -62,7 +62,7 @@ def test_merge_rank_one_direct():
     rho = 0.7
     A = np.diag(D) + rho * np.outer(z, z)
     spec = _merge_spec(D, z, rho)
-    G = _assemble_g(spec, k, np)
+    G = _assemble_g(spec, k)
     assert np.abs(G.T @ G - np.eye(k)).max() < 1e-13
     assert np.abs(G.T @ A @ G - np.diag(spec.vals)).max() < 1e-12
     assert np.abs(spec.vals - np.linalg.eigvalsh(A)).max() < 1e-12

@@ -389,3 +389,48 @@ def test_served_posv_bucket_compiles(one_chip):
     c = batched._posv_jit.lower(a, b, nb=buckets.default_nb(bucket),
                                 tier="bf16_6x").compile()
     assert c.memory_analysis().argument_size_in_bytes >= a.size * 4
+
+
+# -- heev with vectors: the tridiagonal stage's merges and the blocked
+#    back-transform at the benchmark cell's size (PR 41) ------------------
+
+N_EIG, BAND_EIG = 8192, 128
+
+
+def _shape(one_chip, *dims, dtype=F32):
+    return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+
+def test_stedc_top_merge_compiles(one_chip):
+    """The secular solve and the merge at k = n = 8192: everything
+    k x k fuses (no 256 MiB temporary in the solve), G and the product
+    are the merge's only ones."""
+    from slate_tpu.linalg import stedc
+    k, i32 = N_EIG, jnp.int32
+    poles = _shape(one_chip, 3, k)
+    solve = stedc._secular_jit._jit.lower(
+        poles, _shape(one_chip), _shape(one_chip, dtype=i32),
+        iters=35).compile()
+    assert solve.memory_analysis().temp_size_in_bytes < 2 ** 24
+    merge = stedc._merge_jit._jit.lower(
+        _shape(one_chip, k, k), _shape(one_chip, dtype=i32), poles,
+        _shape(one_chip, k, dtype=i32), _shape(one_chip, k),
+        _shape(one_chip, k), _shape(one_chip, 5, k, dtype=i32),
+        _shape(one_chip, 2, k), _shape(one_chip, dtype=i32)).compile()
+    assert merge.memory_analysis().temp_size_in_bytes <= 3 * 4 * k * k
+    assert _kernels(solve) == _kernels(merge) == 0
+
+
+def test_blocked_unmtr_hb2st_compiles(one_chip):
+    """The blocked back-transform on the VMEM chaser's pack at n=8192,
+    band 128: its windows of Z start on multiples of the band."""
+    from slate_tpu.linalg import bulge
+    S, T = N_EIG - 1, N_EIG // BAND_EIG
+    back = bulge._apply_bulge_jit._jit.lower(
+        _shape(one_chip, S, T, BAND_EIG), _shape(one_chip, S, T),
+        _shape(one_chip, N_EIG, N_EIG), band=BAND_EIG, forward=False,
+        conj_tau=True).compile()
+    # the padded copy of Z and one more of its size, no window's worth
+    # a sweep any more
+    assert back.memory_analysis().temp_size_in_bytes < 4 * 4 * N_EIG ** 2
+    assert "dot" in back.as_text() or "convolution" in back.as_text()
