@@ -15,7 +15,7 @@ columns, ``linalg/he2hb.py``), the band gathered to the host
 problem (on a TPU in f32 the VMEM-resident Pallas chaser at band 128,
 ``internal/band_wave_vmem.py``), the tridiagonal eigenproblem
 (``MethodEig.DC``: ``linalg/stedc.py`` with Z, the secular solves and
-the merge products on the device; ``MethodEig.QR``: host values +
+the merge products on the device, a level of the tree a batch; ``MethodEig.QR``: host values +
 device inverse iteration, ``linalg/stein.py``), then the two
 back-transforms ``unmtr_hb2st`` (``linalg/bulge.py``) and
 ``unmtr_he2hb``.  *Dense* is one replicated ``jnp.linalg.eigh`` (XLA's
@@ -31,7 +31,8 @@ What a call reports (docs/observability.md): the spans :data:`SPANS`
 ``jobz``, ``method``, ``path``; at its end ``method`` as resolved and,
 two-stage, ``band`` and ``chase_backend``), every blocking read as an
 ``obs.sync_read`` (``band.gather``, ``hb2st.tridiagonal``,
-``stedc.zrow``, ``stedc.roots``, ``heev.values``), and the counters
+``stedc.zrow`` and ``stedc.roots``: one each a level of the D&C tree,
+with ``level``, ``k``, ``m``; ``heev.values``), and the counters
 :data:`COUNTERS`.
 
 The host tridiagonal kernels sterf/steqr (scipy LAPACK) are kept for
@@ -71,12 +72,14 @@ def _he_to_dense(A: HermitianMatrix):
 # ``hb2st.backend{rung}`` (the rung whose answer was used),
 # ``hb2st.demotion{from,to}`` (a rung that was stepped past: the
 # ladder's own ``ladder.demotions`` by another name, so a caller of
-# heev need not know the ladder), and ``linalg/stedc.py``'s
+# heev need not know the ladder), and ``linalg/stedc.py``'s (merges,
+# poles, deflated poles, and the levels those merges ran in)
 SPANS = ("slate.heev", "heev.stage1", "heev.gather", "heev.stage2",
          "heev.tridiag", "heev.back.hb2st", "heev.back.he2hb",
          "heev.dense")
 COUNTERS = ("heev.path", "hb2st.backend", "hb2st.demotion",
-            "stedc.merges", "stedc.poles", "stedc.deflated")
+            "stedc.merges", "stedc.poles", "stedc.deflated",
+            "stedc.levels")
 
 # one chip: below this n ``Auto`` takes XLA's eigh. The number is
 # round 5's (two-stage with vectors was then slower than eigh, ~5 s at

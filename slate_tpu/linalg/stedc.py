@@ -8,17 +8,24 @@ distributed-Z variant (src/dsteqr2.f:19-25).
 TPU redesign — with a ``grid`` the host does only the O(k) scalar
 work of a merge (sort, deflation walk), while everything O(k²) and up
 lives on the device: the secular solve, the Gu-Eisenstat z-vector, the
-merge factor G and the product with Z.  A merge is three programs
-(``_zrows_jit``, ``_secular_jit``, ``_merge_jit``), compiled once per
-merge size k, and two blocking reads (``stedc.zrow``: the two rows of Z
-that make z; ``stedc.roots``: the roots, as a pole index and an offset).
-Without a grid (``grid=None``: rank-0 semantics, Z a host array) the
-same steps run in numpy float64:
+merge factor G and the product with Z.  The merges run a level of the
+tree at a time, from the leaves up: those of one level touch disjoint
+diagonal blocks of Z, so a level is three programs (``_zrows_jit``,
+``_secular_jit``, ``_merge_jit``) over its m merges, each widened to
+the level's widest k (the children of a level differ by at most a row),
+compiled once per (m, k), and two blocking reads (``stedc.zrow``: the
+two rows of Z that make each z; ``stedc.roots``: the roots, as a pole
+index and an offset).  A merge across an exactly zero off-diagonal
+rides its level with no pole to solve for.  At n = 8192 with 256 leaves
+that is 8 levels and 16 reads for 255 merges.  Without a grid
+(``grid=None``: rank-0 semantics, Z a host array) the same steps run
+merge by merge in numpy float64:
 
 * Z is accumulated on device, **row-sharded** over the mesh — each
-  merge is ``Z[lo:hi, lo:hi] @ G`` with G replicated, so the gemm
-  needs zero communication (the reference redistributes Z 2D→1D for
-  the same reason, heev.cc:163-170).
+  merge is ``Z[lo:hi, lo:hi] @ G``, the m of a level one batched
+  product (the reference redistributes Z 2D→1D to keep that product
+  local, heev.cc:163-170; here a block lies where its rows are stored
+  while k ≤ n/grid.size and crosses devices above that).
 * The merge orthogonal factor G is *assembled on device*, entry by
   entry in its final row and column order from O(k) vectors: secular
   columns ẑ/(dᵢ-λⱼ) by broadcast, deflated unit columns, then the
@@ -62,8 +69,11 @@ _EPS = np.finfo(np.float64).eps
 # what a device D&C counts (``/metrics``): merges that solved a secular
 # equation, the poles they merged (Σ k) and the poles they deflated
 # (Σ deflated): deflated / poles says how much of the O(k²) work the
-# matrix let the merges skip, i.e. whether the work hangs on the seed
-COUNTERS = ("stedc.merges", "stedc.poles", "stedc.deflated")
+# matrix let the merges skip, i.e. whether the work hangs on the seed;
+# and the levels of the tree, each one batched step (three programs,
+# two reads): merges / levels says how far the batching engaged
+COUNTERS = ("stedc.merges", "stedc.poles", "stedc.deflated",
+            "stedc.levels")
 
 
 # ---------------------------------------------------------------------------
@@ -270,14 +280,26 @@ def _split(x, dt):
 
 @partial(cached_jit, static_argnames=("k",))
 def _zrows_jit(Z, mid, lo, k):
-    """Rows mid-1 and mid of Z over the columns [lo, lo+k): the last
-    row of Q1 and the first row of Q2 of the merge at ``mid``."""
+    """For each merge of a level, rows mid-1 and mid of Z over the
+    columns [lo, lo+k): the last row of Q1 and the first row of Q2 of
+    the merge at ``mid``.  [m, 2, k]; a merge narrower than ``k`` reads
+    its neighbour's first column past its own."""
+    import jax
     from jax import lax
-    return lax.dynamic_slice(Z, (mid - 1, lo), (2, k))
+    return jax.vmap(
+        lambda mid, lo: lax.dynamic_slice(Z, (mid - 1, lo), (2, k)))(mid, lo)
 
 
 @partial(cached_jit, static_argnames=("iters",))
 def _secular_jit(poles, rho, k1, iters):
+    """:func:`_secular_one` for the m merges of a level: ``poles``
+    [m, 3, kp], ``rho`` and ``k1`` [m].  Returns (base, off, zhat),
+    [m, kp] each."""
+    import jax
+    return jax.vmap(partial(_secular_one, iters=iters))(poles, rho, k1)
+
+
+def _secular_one(poles, rho, k1, iters):
     """:func:`_secular` + :func:`_z_vector` for the first ``k1`` of
     the padded ``poles`` = (dh, dl, z): poles dh + dl ascending with
     weights z, in their dtype.  Returns (base, off, zhat), zero past
@@ -402,21 +424,15 @@ _LN2_HI = 0.693145751953125
 _LN2_LO = math.log(2.0) - _LN2_HI
 
 
-@partial(cached_jit, donate_argnums=0)
-def _merge_jit(Z, lo, poles, base, off, zhat, where, rot, nrot):
-    """Z[lo:lo+k, lo:lo+k] @ G in place, G as :func:`_assemble_g`
-    makes it, built entry by entry in its final order.  ``where`` =
-    (row_u, row_c, col_j, ri, rj): row r is the undeflated pole
-    ``row_u[r]`` (-1: a deflated one, whose unit entry sits in column
-    ``row_c[r]``), column c is the root ``col_j[c]`` (-1: a deflated
-    column); then the ``nrot`` deflation rotations (cosines and sines
-    ``rot``) on the rows (``ri``, ``rj``), already in the order they
-    apply."""
+def _secular_block(poles, base, off, zhat, where):
+    """One merge's G before its rotations, entry by entry in its final
+    order.  ``where`` = (row_u, row_c, col_j): row r is the undeflated
+    pole ``row_u[r]`` (-1: a deflated one, or a row past the merge's
+    own k, whose unit entry sits in column ``row_c[r]``), column c is
+    the root ``col_j[c]`` (-1: a deflated column)."""
     import jax.numpy as jnp
-    from jax import lax
     dh, dl, _ = poles
-    row_u, row_c, col_j, ri, rj = where
-    rc, rs = rot
+    row_u, row_c, col_j = where
     k = dh.shape[0]
     u, j = jnp.maximum(row_u, 0), jnp.maximum(col_j, 0)
     bj = base[j]
@@ -426,19 +442,44 @@ def _merge_jit(Z, lo, poles, base, off, zhat, where, rot, nrot):
     cols = jnp.where(live, zhat[u][:, None] / jnp.where(live, denom, 1), 0)
     norm = jnp.sqrt(jnp.sum(cols * cols, axis=0, keepdims=True))
     G = cols / jnp.where(norm > 0, norm, 1)
-    G = G + (row_c[:, None] == jnp.arange(k)[None, :]).astype(G.dtype)
+    return G + (row_c[:, None] == jnp.arange(k)[None, :]).astype(G.dtype)
+
+
+@partial(cached_jit, donate_argnums=0)
+def _merge_jit(Z, lo, poles, base, off, zhat, where, turn, rot, nrot):
+    """Z[lo:lo+k, lo:lo+k] @ G in place for the m merges of a level
+    (``lo`` [m] ascending, the rest stacked on a leading m), each G as
+    :func:`_assemble_g` makes it: :func:`_secular_block`, then the
+    level's ``nrot`` deflation rotations, one after the other as a
+    single merge's are (a row at a time, in place): cosines and sines
+    ``rot`` on the rows ``turn`` of the m G's stacked [m·k, k], each
+    merge's already in the order they apply."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    G = jax.vmap(_secular_block)(poles, base, off, zhat, where)
+    m, k = lo.shape[0], G.shape[-1]
 
     def rotate(t, G):
-        gi = lax.dynamic_index_in_dim(G, ri[t], 0, keepdims=False)
-        gj = lax.dynamic_index_in_dim(G, rj[t], 0, keepdims=False)
-        c, s = rc[t], rs[t]
-        G = lax.dynamic_update_index_in_dim(G, c * gi - s * gj, ri[t], 0)
-        return lax.dynamic_update_index_in_dim(G, s * gi + c * gj, rj[t], 0)
+        gi = lax.dynamic_index_in_dim(G, turn[0, t], 0, keepdims=False)
+        gj = lax.dynamic_index_in_dim(G, turn[1, t], 0, keepdims=False)
+        c, s = rot[0, t], rot[1, t]
+        G = lax.dynamic_update_index_in_dim(G, c * gi - s * gj,
+                                            turn[0, t], 0)
+        return lax.dynamic_update_index_in_dim(G, s * gi + c * gj,
+                                               turn[1, t], 0)
 
-    G = lax.fori_loop(0, nrot, rotate, G)
-    blk = lax.dynamic_slice(Z, (lo, lo), (k, k))
-    blk = jnp.matmul(blk, G, precision=lax.Precision.HIGHEST)
-    return lax.dynamic_update_slice(Z, blk, (lo, lo))
+    G = lax.fori_loop(0, nrot, rotate, G.reshape(m * k, k)).reshape(m, k, k)
+    blocks = jax.vmap(lambda at: lax.dynamic_slice(Z, (at, at), (k, k)))(lo)
+    blocks = jnp.matmul(blocks, G, precision=lax.Precision.HIGHEST)
+
+    # in ascending order: a merge one short of k carries its
+    # neighbour's first row and column through untouched (G is the
+    # identity there), and the neighbour's own block then lands on them
+    def put(t, Z):
+        return lax.dynamic_update_slice(Z, blocks[t], (lo[t], lo[t]))
+
+    return lax.fori_loop(0, m, put, Z)
 
 
 @partial(cached_jit, static_argnames=("n",))
@@ -459,10 +500,14 @@ def _leaves_jit(rows, leaf, pos, n):
 # Recursion driver (reference stedc.cc / dlaed0 slot)
 # ---------------------------------------------------------------------------
 
-def _tear(d, e, lo, hi, nmin, leaves):
+def _tear(d, e, lo, hi, nmin, leaves, levels, depth=0):
     """The rank-one tears of the whole tree, into ``d`` in place
     (T = blockdiag + |rho|·v·vᵀ, v = [e_l; sgn·e_f] at every split),
-    and the leaf ranges in order."""
+    the leaf ranges in order, and the merges (lo, mid, hi) by their
+    depth in the tree, ``levels[0]`` the top one, each level in
+    ascending order.  The nodes of one depth hold ⌊n/2ᵈ⌋ or ⌈n/2ᵈ⌉ rows
+    and the last holds the most, so where it is a leaf all are: every
+    merge of a level, widened to the level's widest, stays inside n."""
     n = hi - lo
     if n <= nmin:
         leaves.append((lo, hi))
@@ -471,8 +516,11 @@ def _tear(d, e, lo, hi, nmin, leaves):
     arho = abs(e[mid - 1])
     d[mid - 1] -= arho
     d[mid] -= arho
-    _tear(d, e, lo, mid, nmin, leaves)
-    _tear(d, e, mid, hi, nmin, leaves)
+    if depth == len(levels):
+        levels.append([])
+    levels[depth].append((lo, mid, hi))
+    _tear(d, e, lo, mid, nmin, leaves, levels, depth + 1)
+    _tear(d, e, mid, hi, nmin, leaves, levels, depth + 1)
 
 
 def _stedc_rec(e, lo, hi, leaf_vals, merge_fn, nmin):
@@ -529,13 +577,13 @@ def stedc(d, e, want_vectors: bool = True, grid=None, dtype=None,
             Z = jnp.asarray(Z if dtype is None else Z.astype(dtype))
         return lam, Z
 
-    leaves = []
-    _tear(d, e, 0, n, nmin, leaves)
+    leaves, levels = [], []
+    _tear(d, e, 0, n, nmin, leaves, levels)
     solved = [eigh_tridiagonal(d[lo:hi], e[lo:hi - 1])
               for lo, hi in leaves]
-    leaf_vals = {lo: lam for (lo, _), (lam, _) in zip(leaves, solved)}
 
     if grid is None:
+        leaf_vals = {lo: lam for (lo, _), (lam, _) in zip(leaves, solved)}
         Z = np.zeros((n, n))
         for (lo, hi), (_, q) in zip(leaves, solved):
             Z[lo:hi, lo:hi] = q
@@ -551,12 +599,14 @@ def stedc(d, e, want_vectors: bool = True, grid=None, dtype=None,
             return spec.vals
 
         return _stedc_rec(e, 0, n, leaf_vals, merge_fn, nmin), Z
-    return _stedc_device(e, n, leaves, solved, leaf_vals, grid, dtype,
-                         nmin)
+    return _stedc_device(e, n, leaves, solved, levels, grid, dtype)
 
 
-def _stedc_device(e, n, leaves, solved, leaf_vals, grid, dtype, nmin):
-    """The merges of :func:`stedc` with Z on the device, row-sharded."""
+def _stedc_device(e, n, leaves, solved, levels, grid, dtype):
+    """The merges of :func:`stedc` with Z on the device, row-sharded, a
+    level of the tree at a time from the leaves up: the merges of one
+    level touch disjoint diagonal blocks of Z, so one program and one
+    read serve them all, each widened to the level's widest k."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P, NamedSharding
@@ -578,55 +628,73 @@ def _stedc_device(e, n, leaves, solved, leaf_vals, grid, dtype, nmin):
         rows[lo:hi, :hi - lo] = q
         leaf[lo:hi] = t
         pos[lo:hi] = np.arange(hi - lo)
-    Zbox = [jax.device_put(_leaves_jit(jax.device_put(rows, sh), leaf,
-                                       pos, n=n), sh)]
-    tally = {"merges": 0, "poles": 0, "deflated": 0}
+    Z = jax.device_put(_leaves_jit(jax.device_put(rows, sh), leaf, pos,
+                                   n=n), sh)
+    vals = {lo: lam for (lo, _), (lam, _) in zip(leaves, solved)}
+    tally = {"levels": len(levels), "merges": 0, "poles": 0,
+             "deflated": 0}
 
-    def merge_fn(lo, mid, hi, D, rho):
-        k = hi - lo
-        poles = np.zeros((3, k), zdt)       # dh, dl, z: what survives
-        if rho == 0.0:
-            spec = _trivial_sort_spec(D)
-            base, off, zhat = np.zeros(k, np.int32), poles[0], poles[0]
-        else:
-            two = obs.sync_read(
-                "stedc.zrow", np.asarray,
-                _zrows_jit(Zbox[0], mid, lo, k=k))
-            z = np.concatenate([two[0, :mid - lo],
-                                np.sign(rho) * two[1, mid - lo:]])
-            spec = _deflate(D, z.astype(np.float64), abs(rho), eps)
-            k1 = spec.uidx.size
-            poles[0, :k1], poles[1, :k1] = _split(spec.dd, zdt)
-            poles[2, :k1] = spec.zz
-            base, off, zhat = _secular_jit(poles, zdt.type(abs(rho)),
-                                           np.int32(k1), iters=iters)
-            roots = obs.sync_read("stedc.roots", jax.device_get,
-                                  (base, off))
-            _close(spec, np.asarray(roots[0][:k1], int),
-                   np.asarray(roots[1][:k1], np.float64))
+    for depth in range(len(levels) - 1, -1, -1):
+        level = levels[depth]
+        m, k = len(level), max(hi - lo for lo, _, hi in level)
+        at = {"level": depth, "k": k, "m": m}
+        los = np.array([lo for lo, _, _ in level], np.int32)
+        mids = np.array([mid for _, mid, _ in level], np.int32)
+        two = obs.sync_read("stedc.zrow", np.asarray,
+                            _zrows_jit(Z, mids, los, k=k), **at)
+        poles = np.zeros((m, 3, k), zdt)    # dh, dl, z: what survives
+        rho = np.ones(m, zdt)
+        k1 = np.zeros(m, np.int32)
+        specs = []
+        for t, (lo, mid, hi) in enumerate(level):
+            D = np.concatenate([vals.pop(lo), vals.pop(mid)])
+            tear = e[mid - 1]
+            if tear == 0.0:             # rides the batch with no pole
+                specs.append(_trivial_sort_spec(D))
+                continue
+            z = np.concatenate([two[t, 0, :mid - lo],
+                                np.sign(tear) * two[t, 1, mid - lo:hi - lo]])
+            rho[t] = abs(tear)
+            spec = _deflate(D, z.astype(np.float64), abs(tear), eps)
+            k1[t] = spec.uidx.size
+            poles[t, 0, :k1[t]], poles[t, 1, :k1[t]] = _split(spec.dd, zdt)
+            poles[t, 2, :k1[t]] = spec.zz
+            specs.append(spec)
             tally["merges"] += 1
-            tally["poles"] += k
-            tally["deflated"] += k - k1
-        k1 = spec.uidx.size
+            tally["poles"] += hi - lo
+            tally["deflated"] += hi - lo - k1[t]
+        base, off, zhat = _secular_jit(poles, rho, k1, iters=iters)
+        roots = obs.sync_read("stedc.roots", jax.device_get, (base, off),
+                              **at)
         # G's final order: row order[p] is sorted position p, column c
-        # is source column col_sort[c] (a root below k1, else deflated)
-        nrot = len(spec.rots)
-        where = np.full((5, k), -1, np.int32)   # row_u, row_c, col_j, ri, rj
-        where[0, spec.order[spec.uidx]] = np.arange(k1)
-        where_col = np.empty(k, np.int32)
-        where_col[spec.col_sort] = np.arange(k)
-        where[1, spec.order[spec.fidx]] = where_col[k1:]
-        where[2] = np.where(spec.col_sort < k1, spec.col_sort, -1)
-        rot = np.zeros((2, k), zdt)             # cosines, sines
-        if nrot:
-            i, j, c, s = zip(*spec.rots[::-1])  # in the order they apply
-            where[3, :nrot], where[4, :nrot] = spec.order[[i, j]]
-            rot[0, :nrot], rot[1, :nrot] = c, s
-        Zbox[0] = _merge_jit(Zbox[0], np.int32(lo), poles, base, off,
-                             zhat, where, rot, np.int32(nrot))
-        return spec.vals
+        # is source column col_sort[c] (a root below k1, else
+        # deflated); past a merge's own rows, the identity
+        where = np.full((m, 3, k), -1, np.int32)    # row_u, row_c, col_j
+        where[:, 1] = np.arange(k)
+        turn = np.zeros((2, m * k), np.int32)   # rows of the stacked G's
+        rot = np.zeros((2, m * k), zdt)         # cosines, sines
+        nrot = 0
+        for t, ((lo, _, hi), spec) in enumerate(zip(level, specs)):
+            _close(spec, np.asarray(roots[0][t, :k1[t]], int),
+                   np.asarray(roots[1][t, :k1[t]], np.float64))
+            vals[lo] = spec.vals
+            own = hi - lo
+            where[t, 0, spec.order[spec.uidx]] = np.arange(k1[t])
+            where_col = np.empty(own, np.int32)
+            where_col[spec.col_sort] = np.arange(own)
+            where[t, 1, spec.order[spec.uidx]] = -1
+            where[t, 1, spec.order[spec.fidx]] = where_col[k1[t]:]
+            where[t, 2, :own] = np.where(spec.col_sort < k1[t],
+                                         spec.col_sort, -1)
+            if spec.rots:
+                i, j, c, s = zip(*spec.rots[::-1])  # in the order they apply
+                upto = nrot + len(spec.rots)
+                turn[:, nrot:upto] = t * k + spec.order[[i, j]]
+                rot[:, nrot:upto] = c, s
+                nrot = upto
+        Z = _merge_jit(Z, los, poles, base, off, zhat, where, turn, rot,
+                       np.int32(nrot))
 
-    lam = _stedc_rec(e, 0, n, leaf_vals, merge_fn, nmin)
     for name, value in tally.items():
         obs.count("stedc." + name, value)
-    return lam, Zbox[0][:n]
+    return vals[0], Z[:n]

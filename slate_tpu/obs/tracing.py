@@ -347,11 +347,11 @@ def span(name: str, **labels):
     return _Span(name, labels)
 
 
-def sync_read(name: str, read, x):
+def sync_read(name: str, read, x, **labels):
     """``read(x)``, a blocking device→host read (``int(info)``,
-    ``np.asarray(order)``), in a span labelled ``sync=1`` and counted
-    as ``host.sync``."""
-    with span(name, sync=1):
+    ``np.asarray(order)``), in a span labelled ``sync=1`` (and
+    ``labels``) and counted as ``host.sync``."""
+    with span(name, sync=1, **labels):
         _metrics.inc("host.sync", site=name)
         return read(x)
 

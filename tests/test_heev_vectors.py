@@ -135,16 +135,17 @@ def merge_case(kind, k=160, seed=5):
 
 
 def device_merge(D, z, rho, dtype, eps):
-    """``_stedc_device``'s merge steps on one (D, z, rho): the spec the
-    host closes from the device's roots, and the device's ẑ."""
+    """``_stedc_device``'s merge steps on one (D, z, rho), a level of
+    one merge: the spec the host closes from the device's roots, and
+    the device's ẑ."""
     spec = stedc._deflate(D, z, rho, eps)
     k, k1 = len(D), spec.uidx.size
-    poles = np.zeros((3, k), dtype)
-    poles[0, :k1], poles[1, :k1] = stedc._split(spec.dd, dtype)
-    poles[2, :k1] = spec.zz
-    base, off, zhat = stedc._secular_jit(
-        poles, dtype(rho), np.int32(k1),
-        iters=int(np.finfo(dtype).nmant) + 12)
+    poles = np.zeros((1, 3, k), dtype)
+    poles[0, 0, :k1], poles[0, 1, :k1] = stedc._split(spec.dd, dtype)
+    poles[0, 2, :k1] = spec.zz
+    base, off, zhat = (x[0] for x in stedc._secular_jit(
+        poles, np.full(1, rho, dtype), np.full(1, k1, np.int32),
+        iters=int(np.finfo(dtype).nmant) + 12))
     assert np.all(np.asarray(off[k1:]) == 0)
     assert np.all(np.asarray(zhat[k1:]) == 0)
     stedc._close(spec, np.asarray(base[:k1], int),
@@ -288,10 +289,17 @@ def test_span_tree_of_a_two_stage_call(grid, observed):
     for s in spans:
         if s["labels"].get("sync") == 1:
             sites[s["name"]] = sites.get(s["name"], 0) + 1
-    merges = int(metrics.counter_total("stedc.merges"))
-    assert merges >= 7
+    # N = 384 at nmin 48: 8 leaves of 48, 7 merges in 3 levels, and no
+    # split of a random matrix has an off-diagonal of exactly zero
+    levels = int(metrics.counter_total("stedc.levels"))
+    assert levels == 3
+    assert int(metrics.counter_total("stedc.merges")) == 8 - 1
     assert sites.pop("band.gather") == 1
-    assert sites.pop("stedc.zrow") == sites.pop("stedc.roots") == merges
+    assert sites.pop("stedc.zrow") == sites.pop("stedc.roots") == levels
+    for site in ("stedc.zrow", "stedc.roots"):
+        assert [(s["labels"]["level"], s["labels"]["k"], s["labels"]["m"])
+                for s in spans if s["name"] == site] == [
+            (2, 96, 4), (1, 192, 2), (0, 384, 1)]
     assert set(sites) <= {"hb2st.tridiagonal"}, sites
     (tridiag,) = [s for s in spans if s["name"] == "heev.tridiag"]
     inside = [s for s in spans if s["name"].startswith("stedc.")]

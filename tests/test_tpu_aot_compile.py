@@ -16,6 +16,7 @@ turns on for the CPU references: the chip runs with jax's default, and
 Mosaic has no 64-bit integers.
 """
 
+import math
 import os
 import re
 from functools import partial
@@ -69,6 +70,14 @@ def _compile(fn, *shapes):
 
 def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
+
+
+def _loop_bodies(hlo: str) -> str:
+    """The text of the computations that a ``while`` of ``hlo`` runs
+    as its body."""
+    bodies = set(re.findall(r"\bbody=(%?[\w.\-]+)", hlo))
+    return "\n".join(c for c in hlo.split("\n\n")
+                     if c.lstrip().split(" ", 1)[0] in bodies)
 
 
 # -- the production LU panel kernels (internal/panel_plu.py) ---------------
@@ -401,24 +410,38 @@ def _shape(one_chip, *dims, dtype=F32):
     return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
 
-def test_stedc_top_merge_compiles(one_chip):
-    """The secular solve and the merge at k = n = 8192: everything
-    k x k fuses (no 256 MiB temporary in the solve), G and the product
-    are the merge's only ones."""
+@pytest.mark.parametrize("m, k", [(1, N_EIG), (2, N_EIG // 2), (128, 64)],
+                         ids=["top", "second", "bottom"])
+def test_stedc_level_compiles(one_chip, m, k):
+    """The three programs of a level of the cell's tree, the top merge
+    (m = 1, k = n = 8192) as the 128 bottom ones: everything k x k of
+    the secular solves fuses (no temporary of a G's size), and no level
+    holds more than the top merge does: its G, its blocks of Z and
+    their product, n·k entries each."""
     from slate_tpu.linalg import stedc
-    k, i32 = N_EIG, jnp.int32
-    poles = _shape(one_chip, 3, k)
+    n, i32 = N_EIG, jnp.int32
+    assert m * k == n
+    poles = _shape(one_chip, m, 3, k)
+    each, wide = _shape(one_chip, m, dtype=i32), _shape(one_chip, m, k)
+    rows = stedc._zrows_jit._jit.lower(
+        _shape(one_chip, n, n), each, each, k=k).compile()
     solve = stedc._secular_jit._jit.lower(
-        poles, _shape(one_chip), _shape(one_chip, dtype=i32),
-        iters=35).compile()
+        poles, _shape(one_chip, m), each, iters=35).compile()
     assert solve.memory_analysis().temp_size_in_bytes < 2 ** 24
     merge = stedc._merge_jit._jit.lower(
-        _shape(one_chip, k, k), _shape(one_chip, dtype=i32), poles,
-        _shape(one_chip, k, dtype=i32), _shape(one_chip, k),
-        _shape(one_chip, k), _shape(one_chip, 5, k, dtype=i32),
-        _shape(one_chip, 2, k), _shape(one_chip, dtype=i32)).compile()
-    assert merge.memory_analysis().temp_size_in_bytes <= 3 * 4 * k * k
-    assert _kernels(solve) == _kernels(merge) == 0
+        _shape(one_chip, n, n), each, poles,
+        _shape(one_chip, m, k, dtype=i32), wide, wide,
+        _shape(one_chip, m, 3, k, dtype=i32),
+        _shape(one_chip, 2, n, dtype=i32), _shape(one_chip, 2, n),
+        _shape(one_chip, dtype=i32)).compile()
+    assert merge.memory_analysis().temp_size_in_bytes <= 3 * 4 * n * n
+    # a rotation turns two rows of G where they lie: a loop that copied
+    # G each step was 1.02 s of a 0.10 s stage on the chip (PR 43)
+    copied = re.findall(r"= f32\[([\d,]+)\]\S* copy\(",
+                        _loop_bodies(merge.as_text()))
+    assert all(math.prod(map(int, dims.split(","))) < n * k
+               for dims in copied), copied
+    assert _kernels(rows) == _kernels(solve) == _kernels(merge) == 0
 
 
 def test_blocked_unmtr_hb2st_compiles(one_chip):
