@@ -43,29 +43,80 @@ from ..errors import slate_error_if
 from ..internal import comm, masks
 from ..internal.precision import resolve_tier, trailing_dot_kwargs
 from ..internal.tile_kernels import panel_qr_factor, extract_v, larft
+from .. import obs
 from ..obs import timeline as tl
 from ..runtime import dag
 from ..utils import trace
+
+# what a least-squares solve reports (docs/observability.md): the spans
+# (the root first, then its children in the order the QR branch opens
+# them; the existing ``geqrf`` / ``geqrf.chunk`` / ``unmqr`` / ``trsm``
+# blocks nest under them) and the counters (``/metrics``):
+# ``gels.method{method}`` (what ``MethodGels.select_algo`` resolved),
+# ``geqrf.path{program}`` (``fast``: the exact-shape one-chip program,
+# ``one_program``: the SPMD one) and ``geqrf.panel{panel}`` (``pallas``:
+# every panel through internal/panel_qr.py, ``xla``: none, ``mixed``),
+# each counted once a factorization, where the choice is made
+SPANS = ("slate.gels", "gels.factor", "gels.apply_q", "gels.solve_r")
+COUNTERS = ("gels.method", "geqrf.path", "geqrf.panel")
 
 
 def geqrf(A: Matrix, opts=None):
     """QR: A = Q·R (reference src/geqrf.cc). Returns (QR, T) with QR
     holding V below / R on-above the diagonal and T the [kt, nb, nb]
     block-reflector triangles."""
+    QR, T, _ = _geqrf_chosen(A, opts)
+    return QR, T
+
+
+def _geqrf_chosen(A: Matrix, opts=None):
+    """``geqrf`` and what answered it: (QR, T, {"program", "panel",
+    "tier"}). The choice of program and panel form is made here, once a
+    factorization, and counted here (``geqrf.path``, ``geqrf.panel``)."""
     A = A.materialize()
     from .. import tune
     tier, depth = tune.driver_config("geqrf", A.n, opts)
+    fast = _qr_fast_applies(A)
+    panel_mode = _qr_panel_mode(A) if fast else None
+    program = "fast" if fast else "one_program"
+    panel = _panel_form(A, panel_mode)
+    obs.count("geqrf.path", 1, program=program)
+    obs.count("geqrf.panel", 1, panel=panel)
     with trace.block("geqrf", routine="geqrf", m=A.m, n=A.n, nb=A.nb,
-                     precision=tier):
-        if _qr_fast_applies(A):
+                     precision=tier, program=program, panel=panel):
+        if fast:
             with trace.block("geqrf.chunk", phase="fast_path"):
-                data, T = _geqrf_fast_jit(A,
-                                          panel_mode=_qr_panel_mode(A),
+                data, T = _geqrf_fast_jit(A, panel_mode=panel_mode,
                                           tier=tier)
         else:
             with trace.block("geqrf.chunk", phase="one_program"):
                 data, T = _geqrf_jit(A, tier, depth)
-    return A._replace(data=data), T
+    return (A._replace(data=data), T,
+            {"program": program, "panel": panel, "tier": tier})
+
+
+def _panel_takes_kernel(panel_mode, fd, rows: int, w: int) -> bool:
+    """Whether one [rows, w] panel of the exact-shape program runs the
+    Pallas Householder kernel (f32, whole 128-lane subpanels, no taller
+    than the kernel's VMEM window); else XLA's geqrf factors it."""
+    from ..internal import panel_qr
+    return (panel_mode is not None and fd == jnp.float32
+            and w % panel_qr.W == 0 and rows <= panel_qr.H_MAX)
+
+
+def _panel_form(A, panel_mode) -> str:
+    """``pallas`` when every panel of the factorization takes the
+    kernel, ``xla`` when none does (the SPMD program's panels never
+    do), ``mixed`` when only the shorter ones fit."""
+    from ..internal.tile_kernels import _factor_dtype
+    if panel_mode is None:
+        return "xla"
+    fd = _factor_dtype(A.dtype)
+    kt = min(A.mt, A.nt)
+    takes = [_panel_takes_kernel(panel_mode, fd, A.m - k * A.nb,
+                                 min(A.nb, A.n - k * A.nb))
+             for k in range(kt)]
+    return "pallas" if all(takes) else "mixed" if any(takes) else "xla"
 
 
 def _qr_panel_mode(A):
@@ -83,11 +134,21 @@ def _qr_panel_mode(A):
     return "tpu" if on_tpu else None
 
 
+# one chip: from this n up an exact-shape operand with m >= n takes the
+# exact-shape program. Measured (PERF.md section 6, PR 44: one v5e, f32,
+# nb=256, nrhs=8, slate.gels by hand through both programs, median wall
+# of 20-30 calls, SPMD / exact-shape in ms): [16384, 1024] 18.89 / 12.18,
+# [16384, 512] 9.66 / 7.65, [1024, 1024] 5.91 / 5.17, [2048, 2048]
+# 12.25 / 5.42; level at [4096, 1024] 7.40 / 7.57 and [16384, 256]
+# 7.47 / 7.30 (one panel: nothing to win)
+FAST_FROM_N = 512
+
+
 def _qr_fast_applies(A) -> bool:
     """Single-device dense fast path: exact-shape unrolled panels.
     The SPMD path's uniform full-height panels + masked einsum
-    trailing cost ~2× on one chip (same trade as potrf/getrf dense
-    paths); auto-on for accelerators at useful sizes,
+    trailing cost 1.1-2.3× on one chip (same trade as potrf/getrf
+    dense paths); auto-on for accelerators from ``FAST_FROM_N``,
     SLATE_QR_FAST=1/0 forces/disables (tests force on CPU)."""
     import os
     flag = os.environ.get("SLATE_QR_FAST", "")
@@ -101,7 +162,7 @@ def _qr_fast_applies(A) -> bool:
         return False
     if flag == "1":
         return True
-    return (A.grid.devices[0].platform == "tpu" and A.n >= 2048)
+    return (A.grid.devices[0].platform == "tpu" and A.n >= FAST_FROM_N)
 
 
 def _blocked_T(G, taus, nb, base: int = 8):
@@ -177,28 +238,30 @@ def _geqrf_fast_core(A, panel_mode=None, tier=None):
     for k in range(kt):
         r0 = k * nb
         w = min(nb, n - r0)
-        pan = a[r0:, r0:r0 + w]                      # [m-r0, w] exact
-        if (panel_mode is not None and fd == jnp.float32
-                and w % panel_qr.W == 0
-                and pan.shape[0] <= panel_qr.H_MAX):
-            qr_, taus = panel_qr.qr_panel_blocked(
-                pan, interpret=(panel_mode == "interpret"))
-        else:
-            qr_, taus = _geqrf(pan)
-        a = a.at[r0:, r0:r0 + w].set(qr_)
-        rows = jnp.arange(m - r0)[:, None]
-        diag = jnp.arange(w)[None, :]
-        V = jnp.where(rows > diag, qr_, jnp.zeros_like(qr_)) \
-            + (rows == diag).astype(fd)
-        G = jnp.conj(V.T) @ V
-        # w == nb always here (the gate requires exact tile multiples)
-        T = _blocked_T(G, taus.astype(fd), w)
-        Ts.append(T)
+        with jax.named_scope("qr_panel"):
+            pan = a[r0:, r0:r0 + w]                  # [m-r0, w] exact
+            if _panel_takes_kernel(panel_mode, fd, pan.shape[0], w):
+                qr_, taus = panel_qr.qr_panel_blocked(
+                    pan, interpret=(panel_mode == "interpret"))
+            else:
+                qr_, taus = _geqrf(pan)
+            a = a.at[r0:, r0:r0 + w].set(qr_)
+        with jax.named_scope("qr_T"):
+            rows = jnp.arange(m - r0)[:, None]
+            diag = jnp.arange(w)[None, :]
+            V = jnp.where(rows > diag, qr_, jnp.zeros_like(qr_)) \
+                + (rows == diag).astype(fd)
+            G = jnp.conj(V.T) @ V
+            # w == nb always here (the gate requires exact tile
+            # multiples)
+            T = _blocked_T(G, taus.astype(fd), w)
+            Ts.append(T)
         if r0 + w < n:
-            C = a[r0:, r0 + w:]
-            W1 = jnp.matmul(jnp.conj(V.T), C, **pk)  # [w, n-r0-w]
-            W2 = jnp.conj(T).T @ W1
-            a = a.at[r0:, r0 + w:].set(C - jnp.matmul(V, W2, **pk))
+            with jax.named_scope("qr_trailing"):
+                C = a[r0:, r0 + w:]
+                W1 = jnp.matmul(jnp.conj(V.T), C, **pk)  # [w, n-r0-w]
+                W2 = jnp.conj(T).T @ W1
+                a = a.at[r0:, r0 + w:].set(C - jnp.matmul(V, W2, **pk))
     Tst = jnp.stack(Ts).astype(A.dtype)
     tiles = dense_to_tiles(a.astype(A.dtype), nb, A.data.shape[2],
                            A.data.shape[3])
@@ -244,16 +307,19 @@ def _geqrf_jit(A, tier=None, depth=0):
         def factor_panel(kk, a, Ts):
             """Gather + redundantly QR-factor panel kk, write it back,
             record T, and hand (V tiles, T) to the ring."""
-            pcol = lax.dynamic_index_in_dim(a, kk // q, axis=1,
-                                            keepdims=False)
-            pcol = dag.mark(pcol, "panel_bcast", step=kk, device=dev,
-                            edge="b", routine="geqrf", ndev=ndev)
-            full = comm.allgather_panel_rows(pcol, p, kk % q)
-            panel2d = full.reshape(M, nb)
-            panel2d, taus = panel_qr_factor(panel2d, kk * nb, m)
-            V = extract_v(panel2d, kk * nb, m)           # [M, nb]
-            T = larft(V, taus)                           # [nb, nb]
-            Ts = Ts.at[kk].set(T)
+            with jax.named_scope("qr_panel"):
+                pcol = lax.dynamic_index_in_dim(a, kk // q, axis=1,
+                                                keepdims=False)
+                pcol = dag.mark(pcol, "panel_bcast", step=kk,
+                                device=dev, edge="b", routine="geqrf",
+                                ndev=ndev)
+                full = comm.allgather_panel_rows(pcol, p, kk % q)
+                panel2d = full.reshape(M, nb)
+                panel2d, taus = panel_qr_factor(panel2d, kk * nb, m)
+            with jax.named_scope("qr_T"):
+                V = extract_v(panel2d, kk * nb, m)       # [M, nb]
+                T = larft(V, taus)                       # [nb, nb]
+                Ts = Ts.at[kk].set(T)
             ptiles = panel2d.reshape(mt_p, nb, nb)
             newcol = jnp.take(ptiles, gi, axis=0)
             a = jnp.where(
@@ -262,6 +328,7 @@ def _geqrf_jit(A, tier=None, depth=0):
                                                 axis=1), a)
             return a, Ts, (V.reshape(mt_p, nb, nb), T)
 
+        @jax.named_scope("qr_trailing")
         def col_advance(s, j, a, entry):
             """Step s's compact-WY apply on block column j only, from
             the ring buffer — element-for-element the slice of the big
@@ -282,6 +349,7 @@ def _geqrf_jit(A, tier=None, depth=0):
                 lax.dynamic_update_index_in_dim(a, acol - upd, j // q,
                                                 axis=1), a)
 
+        @jax.named_scope("qr_trailing")
         def trailing(k, a, entry, jlo):
             """Step k's big trailing apply A₂ −= V·Tᴴ·(Vᴴ·A₂) on
             columns > jlo, from the ring buffer."""
@@ -442,6 +510,7 @@ def _unmqr_jit(QR, T, C, notrans):
         gi = masks.local_tile_rows(mtl, p)
         gj = masks.local_tile_cols(ntl, q)
 
+        @jax.named_scope("unmqr_apply")
         def apply_one(k, cdat):
             pcol = lax.dynamic_index_in_dim(aq, k // q, axis=1,
                                             keepdims=False)
@@ -492,6 +561,7 @@ def _unmqr_right_jit(QR, T, C, notrans):
         gj = masks.local_tile_cols(ntl, q)
         gj_clip = jnp.clip(gj, 0, mt_p - 1)
 
+        @jax.named_scope("unmqr_apply")
         def apply_one(k, cdat):
             pcol = lax.dynamic_index_in_dim(aq, k // q, axis=1,
                                             keepdims=False)
@@ -565,27 +635,64 @@ def gels(A: Matrix, BX: Matrix, opts=None):
     gels_cholqr.cc). Overdetermined m ≥ n: min‖AX − B‖₂ via QR/CholQR.
     Underdetermined m < n: the minimum-norm solution via LQ
     (A = L·Q ⇒ X = Qᴴ·L⁻¹·B), like the reference's gels_qr LQ branch.
-    Returns the [n, nrhs] solution X."""
+    Returns the [n, nrhs] solution X.
+
+    ``Option.MethodGels``: ``Geqrf`` is LAPACK's ``gels`` (Householder
+    QR: ``geqrf``, ``unmqr`` Qᴴ·B, ``trsm`` with R; backward stable for
+    any full-rank A). ``Auto`` takes **CholQR** whenever m ≥ 2n, as the
+    reference does (``herk`` AᴴA, ``potrf``, a right ``trsm``, ``gemm``,
+    ``trsm``: none of the QR family runs). CholQR factors AᴴA, whose
+    condition is κ(A)²: in f32 ``potrf`` fails (or the answer holds no
+    digit) from κ(A) ≈ ε^-1/2 ≈ 4096 up, and below that the error grows
+    as κ(A)²·ε where Householder QR's grows as κ(A)·ε. A caller who
+    does not know κ(A) to be small passes ``MethodGels.Geqrf``.
+
+    What a call reports (docs/observability.md): the spans
+    :data:`SPANS` (a root ``slate.gels`` with ``routine``, ``m``, ``n``,
+    ``nrhs``, ``nb``, ``grid``, ``method``, ``tier``; at its end, on
+    the Householder branches, ``program`` and ``panel``, what answered
+    ``geqrf``, and ``tier`` as its driver resolved it) and the counters
+    :data:`COUNTERS`."""
     from ..ops.blas import trsm
-    if A.m < A.n:
-        with trace.block("gels_lq"):
-            LQ, T = gelqf(A, opts)          # QR factors of Aᴴ [n, m]
-            Rh = _upper_view(LQ)            # R̂ (m×m upper): A = R̂ᴴ·Q̂ᴴ
-            Y = trsm(Side.Left, 1.0, conj_transpose(Rh), BX, opts)
-            Ypad = _pad_rows(Y, A.n)        # [y; 0] in n rows
-            return unmqr(Side.Left, Op.NoTrans, LQ, T, Ypad, opts)
-    method = MethodGels.select_algo(A, BX, opts)
-    with trace.block("gels"):
+    wide = A.m < A.n
+    method = (MethodGels.Geqrf if wide
+              else MethodGels.select_algo(A, BX, opts))
+    obs.count("gels.method", 1, method=method.name)
+    with trace.block("slate.gels", routine="gels", m=A.m, n=A.n,
+                     nrhs=BX.n, nb=A.nb, grid=f"{A.grid.p}x{A.grid.q}",
+                     method=method.name, tier=resolve_tier(opts)) as root:
+        if wide:
+            with trace.block("gels_lq"):
+                with trace.block("gels.factor"):
+                    # gelqf: QR factors of Aᴴ [n, m]
+                    LQ, T, chosen = _geqrf_chosen(conj_transpose(A),
+                                                  opts)
+                    root.label(**chosen)
+                with trace.block("gels.solve_r"):
+                    Rh = _upper_view(LQ)    # R̂ (m×m upper): A = R̂ᴴ·Q̂ᴴ
+                    Y = trsm(Side.Left, 1.0, conj_transpose(Rh), BX,
+                             opts)
+                with trace.block("gels.apply_q"):
+                    Ypad = _pad_rows(Y, A.n)        # [y; 0] in n rows
+                    return unmqr(Side.Left, Op.NoTrans, LQ, T, Ypad,
+                                 opts)
         if method == MethodGels.Cholqr:
-            Q, R, info = cholqr(A, opts)
+            with trace.block("gels.factor"):
+                Q, R, info = cholqr(A, opts)
             # X = R⁻¹·(Qᴴ B)
-            QhB = _gemm_qhb(Q, BX)
-            return trsm(Side.Left, 1.0, R, QhB, opts)
-        QR, T = geqrf(A, opts)
-        QhB = unmqr(Side.Left, Op.ConjTrans, QR, T, BX, opts)
-        R = _upper_view(QR)
-        Xfull = _top_rows(QhB, A.n)
-        return trsm(Side.Left, 1.0, R, Xfull, opts)
+            with trace.block("gels.apply_q"):
+                QhB = _gemm_qhb(Q, BX)
+            with trace.block("gels.solve_r"):
+                return trsm(Side.Left, 1.0, R, QhB, opts)
+        with trace.block("gels.factor"):
+            QR, T, chosen = _geqrf_chosen(A, opts)
+            root.label(**chosen)
+        with trace.block("gels.apply_q"):
+            QhB = unmqr(Side.Left, Op.ConjTrans, QR, T, BX, opts)
+        with trace.block("gels.solve_r"):
+            R = _upper_view(QR)
+            Xfull = _top_rows(QhB, A.n)
+            return trsm(Side.Left, 1.0, R, Xfull, opts)
 
 
 def _gemm_qhb(Q: Matrix, B: Matrix) -> Matrix:

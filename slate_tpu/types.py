@@ -284,7 +284,13 @@ class MethodGels(enum.Enum):
         m = get_option(opts, Option.MethodGels, MethodGels.Auto)
         if m != MethodGels.Auto:
             return m
-        # reference gels.cc:96-110 defaults to CholQR for tall matrices.
+        # reference gels.cc:96-110 defaults to CholQR for tall matrices:
+        # at m >= 2n Auto runs herk + potrf + a right trsm + gemm + trsm
+        # and none of geqrf / unmqr. CholQR factors A^H A, so it squares
+        # kappa(A): in f32 it fails from kappa(A) ~ eps^-1/2 ~ 4096 up
+        # and loses kappa(A)^2 eps below that, where Householder QR
+        # loses kappa(A) eps. Pass MethodGels.Geqrf for LAPACK's gels
+        # (PERF.md section 4, gels_qr_f32_1x1)
         return MethodGels.Cholqr if A.m >= 2 * A.n else MethodGels.Geqrf
 
 

@@ -130,6 +130,31 @@ def test_qr_panel_compiles(one_chip):
     assert _kernels(_compile(panel_qr.qr_panel_blocked, pan)) >= NB // W
 
 
+def test_gels_cell_programs_compile_with_the_panel_kernel(topo):
+    """The two programs of ``slate.gels(MethodGels.Geqrf)`` at the
+    benchmark cell's shape (m=16384, n=1024, nb=256: PR 44): the
+    exact-shape QR with its panels in the Pallas kernel at the tallest
+    height it takes, and ``unmqr`` on the 8 right-hand sides."""
+    from slate_tpu.linalg import geqrf
+    m, n, nb, nrhs = H, 1024, 256, 8
+    g = slate.Grid(1, 1, devices=[topo.devices[0]])
+
+    def tiled(rows, cols):
+        data = jax.ShapeDtypeStruct(
+            (1, 1, rows // nb, -(-cols // nb), nb, nb), F32,
+            sharding=g.sharding())
+        return slate.Matrix(data=data, m=rows, n=cols, nb=nb, grid=g)
+
+    A, B = tiled(m, n), tiled(m, nrhs)
+    assert geqrf._panel_form(A, "tpu") == "pallas"
+    c = geqrf._geqrf_fast_jit.lower(A, panel_mode="tpu",
+                                    tier="bf16_6x").compile()
+    assert _kernels(c) == (n // nb) * (nb // W)     # a call a subpanel
+    assert c.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+    T = jax.ShapeDtypeStruct((n // nb, nb, nb), F32)
+    assert _kernels(geqrf._unmqr_jit.lower(A, T, B, False).compile()) == 0
+
+
 # -- one 2x2 super-step chunk of each factorization -------------------------
 
 def _tiles(grid, n=H, nb=NB):
