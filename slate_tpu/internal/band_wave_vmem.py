@@ -20,9 +20,16 @@ Design (f32, b a power of two, 8 <= b <= 256):
 * Tasks read/write SHEARED blocks: B[i, k] of the task at i0 lives at
   (i0 + i, off - b + k - i). All Householder applications are rank-1,
   and a sheared rank-1 factors into (column vector — broadcast, free)
-  x (row vector — sheared): the only lane shuffles are log2(b)
-  masked-roll passes building sheared row vectors; block data itself
-  is never unsheared.
+  x (row vector — sheared): the only lane shuffles build sheared row
+  vectors and rotate a block's rows for a column sum; block data
+  itself is never unsheared. On the band-128 frame layout (``_fw``)
+  each is ONE pass — a lane gather (``_shear_rowvec``) or a strided
+  rotate (``_antishear``), ``shear_form`` — elsewhere a ladder of
+  log2(b) masked rolls. On one v5e at n=8192/b=128 (PR 45,
+  ``tools/chase_probe.py``): a shear 0.16 us single-pass, 0.72 us by
+  the ladder; the kernel 1.11 s, 2.38 s with ladders, 0.79 s with no
+  shear at all — the window RMW and the rest of the body are now the
+  larger part.
 * The Hermitian mirror (upper triangle) is maintained by CONJUGATE
   rank-1s — U = conj(B)^T evolves as U -= tau * v_col x w_row with
   vectors already computed on the B side, so no in-kernel transposes.
@@ -109,14 +116,29 @@ def _geometry(n: int, b: int):
     return G, P, PP, NCH, CH, PAD, ROWS
 
 
-def _shear_rowvec(vec_row, col0, rows, W4):
-    """S[i, c] = vec[c - col0 + i] — the sheared broadcast matching a
-    block whose element (i, k) lives at column col0 + k - i.
+def shear_form(rows: int, W4: int, col0: int | None = None) -> str:
+    """How a [rows, W4] sheared vector is built, read off the shape:
+    ``"single_pass"`` on the FRAMES layout (rows a lane-tile multiple,
+    frame width 2*rows, local col0 = rows-1: the vector's lane tiles
+    line up with the frame's, so one lane gather or one strided rotate
+    does what the ladder does in log2(rows) masked rolls), ``"ladder"``
+    elsewhere (FW = 4b is under one lane tile for b < 32, and no chip
+    run has validated bands under 128)."""
+    single = (rows % 128 == 0 and W4 == 2 * rows
+              and col0 in (None, rows - 1))
+    return "single_pass" if single else "ladder"
 
-    vec_row: [1, W4] with the vector in cols [0, b), zeros elsewhere.
-    Returns [rows, W4]. Row i is vec shifted so that index k appears
-    at column col0 + k - i: log2(rows) masked-roll passes.
-    """
+
+def chase_shear_form(band: int) -> str:
+    """``shear_form`` of the task bodies of a chase at ``band``: what
+    ``hb2st.shear{form}`` counts and the ``hb2st`` span's ``shear``
+    says."""
+    return shear_form(band, _fw(band), band - 1)
+
+
+def _shear_rowvec_ladder(vec_row, col0, rows, W4):
+    """``_shear_rowvec`` by log2(rows) masked-roll passes: any shape,
+    and the reference the single-pass form is tested against."""
     s = jnp.broadcast_to(pltpu.roll(vec_row, shift=col0, axis=1),
                          (rows, W4))
     ii = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
@@ -130,20 +152,82 @@ def _shear_rowvec(vec_row, col0, rows, W4):
     return s
 
 
-def _antishear_sum(Q, rows, W4):
-    """out[0, c'] = sum_i Q[i, c' - i] — column reductions of sheared
-    blocks (v^H B, v^H D): shift row i right by i (log masked rolls),
-    then one sublane sum. Exact up to summation order — replaces the
-    Hermitian v^H D = (D v)^T shortcut, whose rounding asymmetry fed
-    back through deep chase sequences (eig error grew to O(10) by
-    n=1024; measured round 4)."""
+def _shear_lanes(rows, W4, col0):
+    """The index array of the single-pass ``_shear_rowvec``,
+    m[i, l] = l + 1 + i on one 128-lane tile (None where the form is
+    the ladder): a constant of the shape, built once a grid step
+    beside the blocks' column indices and handed to every call."""
+    if shear_form(rows, W4, col0) == "ladder":
+        return None
+    return (lax.broadcasted_iota(jnp.int32, (rows, 128), 1) + 1
+            + lax.broadcasted_iota(jnp.int32, (rows, 128), 0))
+
+
+def _shear_rowvec(vec_row, col0, rows, W4, lanes=None):
+    """S[i, c] = vec[c - col0 + i] — the sheared broadcast matching a
+    block whose element (i, k) lives at column col0 + k - i.
+
+    vec_row: [1, W4] with the vector in cols [0, b), zeros elsewhere.
+    Returns [rows, W4]; only S[i, c] with 0 <= c - col0 + i < rows is
+    defined (every call site masks the rest away).
+
+    Single pass (``shear_form``): with col0 = rows-1 the wanted index
+    on the 128-lane tile j of the frame is (128*(j - T) + m) mod rows,
+    T = rows // 128, m = l + 1 + i (``lanes``: ``_shear_lanes``) —
+    lane m mod 128 of source tile (j + m // 128) mod T, and tiles j
+    and j + T are the same array. So one lane gather of each broadcast
+    source tile (a Mosaic gather reads one source vreg along lanes)
+    and, past one tile, a select between them. Elsewhere: the ladder."""
+    m = _shear_lanes(rows, W4, col0) if lanes is None else lanes
+    if m is None:
+        return _shear_rowvec_ladder(vec_row, col0, rows, W4)
+    T = rows // 128
+    lane = m & 127
+    # source tile h is rotated down to lanes [0, 128) first: Mosaic
+    # refuses to broadcast a lane-offset slice of a one-row value
+    # ("Invalid input layout")
+    src = [jnp.take_along_axis(
+        jnp.broadcast_to(
+            (pltpu.roll(vec_row, shift=W4 - 128 * h, axis=1)
+             if h else vec_row)[:, :128], (rows, 128)),
+        lane, axis=1, mode="promise_in_bounds") for h in range(T)]
+    tiles = []
+    for j in range(T):
+        tile = src[0]
+        for h in range(1, T):
+            tile = jnp.where(((j + (m >> 7)) % T) == h, src[h], tile)
+        tiles.append(tile)
+    return jnp.concatenate(tiles + tiles, axis=1)
+
+
+def _antishear_ladder(Q, rows, W4):
+    """``_antishear`` by log2(rows) masked-roll passes."""
     ii = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
     shift = 1
     while shift < rows:
         rolled = pltpu.roll(Q, shift=shift, axis=1)
         Q = jnp.where((ii & shift) != 0, rolled, Q)
         shift *= 2
-    return jnp.sum(Q, axis=0, keepdims=True)
+    return Q
+
+
+def _antishear(Q, rows, W4):
+    """Row i of Q rotated right by i lanes: one strided rotate on the
+    FRAMES layout (``shear_form``), the ladder elsewhere. Pure data
+    movement, so both forms give the same bits."""
+    if shear_form(rows, W4) == "ladder":
+        return _antishear_ladder(Q, rows, W4)
+    return pltpu.roll(Q, 0, axis=1, stride=1, stride_axis=0)
+
+
+def _antishear_sum(Q, rows, W4):
+    """out[0, c'] = sum_i Q[i, c' - i] — column reductions of sheared
+    blocks (v^H B, v^H D): shift row i right by i, then one sublane
+    sum. Exact up to summation order — replaces the
+    Hermitian v^H D = (D v)^T shortcut, whose rounding asymmetry fed
+    back through deep chase sequences (eig error grew to O(10) by
+    n=1024; measured round 4)."""
+    return jnp.sum(_antishear(Q, rows, W4), axis=0, keepdims=True)
 
 
 def _col2row(xcol, E):
@@ -216,9 +300,10 @@ def _fw(b: int) -> int:
     multiple, every block (B at global col0 = b-1 over lanes [0, 2b),
     D at off over [b, 3b), mirror-U at off+b over [2b, 4b)) is an
     ALIGNED static [b, 2b] lane window with the SAME local col0 = b-1,
-    so shears/masks/reductions run on half-width arrays (the shear
-    ladders are the kernel's dominant VMEM traffic). Other bands keep
-    the full 4b width (unaligned static lane slices don't lower)."""
+    so shears/masks/reductions run on half-width arrays, and the
+    shears themselves take their single-pass form (``shear_form``).
+    Other bands keep the full 4b width (unaligned static lane slices
+    don't lower)."""
     return 2 * b if b % 128 == 0 else 4 * b
 
 
@@ -258,6 +343,7 @@ def _wave_kernel(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
     colD = lcF - c0D + liF
     colU = lcF - c0U + liF
     colS = lcF - c0S + liF               # seed column c = s (B frame)
+    shl = _shear_lanes(b, FW, c0B)       # c0B == c0D == c0U where not None
     E = (lcF == li1).astype(jnp.float32)    # [b, FW] one-hot
     rowPP = lax.broadcasted_iota(jnp.int32, (PP, 1), 0)
     ohu = lax.broadcasted_iota(jnp.int32, (U, PP), 0)   # slot uu
@@ -337,14 +423,14 @@ def _wave_kernel(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
             # ---------------- chase branch -----------------------
             vp_row = Vp[uu:uu + 1, :]              # [1, FW]
             tp = Tp[uu, 0]
-            VPb = jnp.where(mB, _shear_rowvec(vp_row, c0B, b, FW),
+            VPb = jnp.where(mB, _shear_rowvec(vp_row, c0B, b, FW, shl),
                             0.0)
             wv = jnp.sum(B0 * VPb, axis=1, keepdims=True)  # B0 vp [b,1]
             B1 = B0 - tp * wv * VPb
             # mirror: U1 = U0 - tp * vp_col x wv_row
             vp_col = _row2col(vp_row, E)                   # [b, 1]
             WVu = jnp.where(mU, _shear_rowvec(
-                _col2row(wv, E), c0U, b, FW), 0.0)
+                _col2row(wv, E), c0U, b, FW, shl), 0.0)
             U1 = U0 - tp * vp_col * WVu
             # larfg on B1 col k=0 (bulge column)
             e0 = (colB == 0) & mrow2
@@ -365,20 +451,20 @@ def _wave_kernel(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
             z_at0 = pltpu.roll(z_row, shift=FW - c0B, axis=1)
             z_col = _row2col(z_at0, E)
             # B2 = B1 - tau v_col x z_row ; U2 = U1 - tau z_col x v_row
-            VUs = jnp.where(mU, _shear_rowvec(v_ch, c0U, b, FW),
+            VUs = jnp.where(mU, _shear_rowvec(v_ch, c0U, b, FW, shl),
                             0.0)
             Zb = jnp.where(mB & (colB >= 1), _shear_rowvec(
-                z_at0, c0B, b, FW), 0.0)
+                z_at0, c0B, b, FW, shl), 0.0)
             B2 = B1 - tau_ch * v_col * Zb
             U2 = U1 - tau_ch * z_col * VUs
             # D two-sided: w = v^H D0 exactly (anti-shear), then
             # D1 = D0 - tau v x w ; D2 = D1 - tau (D1 v) x v^H
             D0 = jnp.where(mD, browsD, 0.0)
-            VDs = jnp.where(mD, _shear_rowvec(v_ch, c0D, b, FW), 0.0)
+            VDs = jnp.where(mD, _shear_rowvec(v_ch, c0D, b, FW, shl), 0.0)
             Qw = D0 * v_col
             w_at0 = pltpu.roll(_antishear_sum(Qw, b, FW),
                                shift=FW - c0D, axis=1)
-            Ws = jnp.where(mD, _shear_rowvec(w_at0, c0D, b, FW), 0.0)
+            Ws = jnp.where(mD, _shear_rowvec(w_at0, c0D, b, FW, shl), 0.0)
             D1 = D0 - tau_ch * v_col * Ws
             y2 = jnp.sum(D1 * VDs, axis=1, keepdims=True)
             D2 = D1 - tau_ch * y2 * VDs
@@ -406,13 +492,13 @@ def _wave_kernel(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
                     0.0)
                 # seed's diag block: the seed-column update is outside
                 # mD (c - r < 0), so D0s == D0
-                VDsd = jnp.where(mD, _shear_rowvec(v_sd, c0D, b, FW),
+                VDsd = jnp.where(mD, _shear_rowvec(v_sd, c0D, b, FW, shl),
                                  0.0)
                 vsd_col = _row2col(v_sd, E)
                 ws_at0 = pltpu.roll(
                     _antishear_sum(D0 * vsd_col, b, FW),
                     shift=FW - c0D, axis=1)
-                Wss = jnp.where(mD, _shear_rowvec(ws_at0, c0D, b, FW),
+                Wss = jnp.where(mD, _shear_rowvec(ws_at0, c0D, b, FW, shl),
                                 0.0)
                 D1s = D0 - tau_sd * vsd_col * Wss
                 y2s = jnp.sum(D1s * VDsd, axis=1, keepdims=True)
@@ -595,8 +681,10 @@ def vmem_applies(n: int, band: int, dtype) -> bool:
 def preferred_eig_band(n: int, dtype, default: int = 256) -> int:
     """Two-stage band width for heev/gesvd pipelines: the chase is
     the pipeline's dominant cost, and the VMEM chaser at band 128
-    beats the XLA wave at 256 by a wide margin (r5: 2.45 s vs 5.95 s
-    at n=8192) — so prefer 128 whenever the VMEM kernel would take
+    beats the XLA wave at 256 by a wide margin (at n=8192 on one v5e
+    1.11 s since its shears are single-pass, PR 45; 2.37 s with the
+    ladder before; the wave was 5.95 s in round 5 and has not been
+    timed since) — so prefer 128 whenever the VMEM kernel would take
     the problem ON THE COMPILED TPU PATH (f32 real only: the gate
     must see the ACTUAL dtype — complex inputs fall back to the XLA
     wave, where the tuned 256 default stands)."""
@@ -629,6 +717,7 @@ def hb2st_wave_vmem(ab, interpret=None):
         return hb2st_wave(ab)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    obs.count("hb2st.shear", 1, form=chase_shear_form(band))
     d, e, V, tau = _hb2st_vmem_jit(jnp.asarray(ab), band, n,
                                    interpret=interpret)
     # d/e go to the host tridiagonal stage; V/tau stay DEVICE arrays —

@@ -50,7 +50,7 @@ from .band_bulge import max_chase
 from .band_wave_vmem import (TAUP, U_SLOTS, _active_chunk_range,
                              _antishear_sum, _ceil8, _col2row, _fw,
                              _geometry, _larfg_f32, _row2col,
-                             _shear_rowvec, vmem_applies)
+                             _shear_lanes, _shear_rowvec, vmem_applies)
 
 
 def _wave_kernel_bd(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
@@ -89,6 +89,7 @@ def _wave_kernel_bd(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
     liF = lax.broadcasted_iota(jnp.int32, (b, FW), 0)
     colB = lcF - c0B + liF               # B block (urows frame)
     colD = lcF - c0D + liF               # diagonal block (brows frame)
+    shl = _shear_lanes(b, FW, c0B)       # c0B == c0D where not None
     E = (lcF == li1).astype(jnp.float32)    # [b, FW] one-hot
     rowPP = lax.broadcasted_iota(jnp.int32, (PP, 1), 0)
     ohu = lax.broadcasted_iota(jnp.int32, (U, PP), 0)
@@ -162,7 +163,7 @@ def _wave_kernel_bd(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
             wl_at0 = pltpu.roll(
                 _antishear_sum(B0 * up_col, b, FW),
                 shift=FW - c0B, axis=1)
-            WLs = jnp.where(mB, _shear_rowvec(wl_at0, c0B, b, FW),
+            WLs = jnp.where(mB, _shear_rowvec(wl_at0, c0B, b, FW, shl),
                             0.0)
             B1 = B0 - tp * up_col * WLs
             # right/V reflector from B1 row 0 (zero the row tail)
@@ -170,7 +171,7 @@ def _wave_kernel_bd(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
                             axis=0, keepdims=True)
             y_at0 = pltpu.roll(y_row, shift=FW - c0B, axis=1)
             v_ch, tauv_ch, betav = _larfg_f32(y_at0, L2, FW)
-            VBs = jnp.where(mB, _shear_rowvec(v_ch, c0B, b, FW),
+            VBs = jnp.where(mB, _shear_rowvec(v_ch, c0B, b, FW, shl),
                             0.0)
             wr = jnp.sum(B1 * VBs, axis=1, keepdims=True)   # [b, 1]
             B2 = B1 - tauv_ch * wr * VBs
@@ -178,7 +179,7 @@ def _wave_kernel_bd(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
             B2 = jnp.where(rowB0,
                            jnp.where(colB == 0, betav, 0.0), B2)
             # diagonal block: deferred right-apply of v, then new u
-            VDs = jnp.where(mD, _shear_rowvec(v_ch, c0D, b, FW), 0.0)
+            VDs = jnp.where(mD, _shear_rowvec(v_ch, c0D, b, FW, shl), 0.0)
             wd = jnp.sum(D0 * VDs, axis=1, keepdims=True)
             D1 = D0 - tauv_ch * wd * VDs
             x_col = jnp.sum(jnp.where(e0D, D1, 0.0), axis=1,
@@ -190,7 +191,7 @@ def _wave_kernel_bd(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
             wu_at0 = pltpu.roll(_antishear_sum(Qu, b, FW),
                                 shift=FW - c0D, axis=1)
             WUs = jnp.where(mD & (colD >= 1), _shear_rowvec(
-                wu_at0, c0D, b, FW), 0.0)
+                wu_at0, c0D, b, FW, shl), 0.0)
             D2 = D1 - tauu_ch * u_col * WUs
             D2 = jnp.where(e0D,
                            jnp.where(li1 == 0, betau, 0.0), D2)
@@ -213,7 +214,7 @@ def _wave_kernel_bd(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
                 dB_sd = jnp.where(
                     eS, jnp.where(colB == 0, betav_s, 0.0) - urowsB,
                     0.0)
-                VDsd = jnp.where(mD, _shear_rowvec(v_sd, c0D, b, FW),
+                VDsd = jnp.where(mD, _shear_rowvec(v_sd, c0D, b, FW, shl),
                                  0.0)
                 ws = jnp.sum(D0 * VDsd, axis=1, keepdims=True)
                 Bs1 = D0 - tauv_sd * ws * VDsd
@@ -226,7 +227,7 @@ def _wave_kernel_bd(base8_ref, delta_ref, clo_ref, chi_ref, rib_ref,
                 wus_at0 = pltpu.roll(_antishear_sum(Qus, b, FW),
                                      shift=FW - c0D, axis=1)
                 WUSs = jnp.where(mD & (colD >= 1), _shear_rowvec(
-                    wus_at0, c0D, b, FW), 0.0)
+                    wus_at0, c0D, b, FW, shl), 0.0)
                 Bs2 = Bs1 - tauu_sd * usd_col * WUSs
                 Bs2 = jnp.where(e0D,
                                 jnp.where(li1 == 0, betau_s, 0.0), Bs2)

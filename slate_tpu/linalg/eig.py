@@ -72,13 +72,15 @@ def _he_to_dense(A: HermitianMatrix):
 # ``hb2st.backend{rung}`` (the rung whose answer was used),
 # ``hb2st.demotion{from,to}`` (a rung that was stepped past: the
 # ladder's own ``ladder.demotions`` by another name, so a caller of
-# heev need not know the ladder), and ``linalg/stedc.py``'s (merges,
-# poles, deflated poles, and the levels those merges ran in)
+# heev need not know the ladder), ``hb2st.shear{form}`` (how the VMEM
+# chaser built its sheared vectors, ``single_pass`` or ``ladder``:
+# once a call that the ``vmem`` rung ran), and ``linalg/stedc.py``'s
+# (merges, poles, deflated poles, and the levels those merges ran in)
 SPANS = ("slate.heev", "heev.stage1", "heev.gather", "heev.stage2",
          "heev.tridiag", "heev.back.hb2st", "heev.back.he2hb",
          "heev.dense")
 COUNTERS = ("heev.path", "hb2st.backend", "hb2st.demotion",
-            "stedc.merges", "stedc.poles", "stedc.deflated",
+            "hb2st.shear", "stedc.merges", "stedc.poles", "stedc.deflated",
             "stedc.levels")
 
 # one chip: below this n ``Auto`` takes XLA's eigh. The number is

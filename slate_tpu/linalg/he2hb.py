@@ -284,6 +284,9 @@ def hb2st(band: np.ndarray):
                      n=band.shape[1], b=band.shape[0] - 1) as span:
         out = ladder.run(band, start=start)
         span.label(rung=ladder.last_rung)
+        if ladder.last_rung == "vmem":
+            from ..internal.band_wave_vmem import chase_shear_form
+            span.label(shear=chase_shear_form(band.shape[0] - 1))
     # which rung answered, and every rung stepped past on the way: a
     # demotion is silent to the caller (vmem -> wave is 2.4x on this
     # stage), so it is counted where a caller of heev can read it

@@ -78,15 +78,38 @@ def test_wave_band1_falls_back():
 # on a TPU yet (ROADMAP R7)
 # ---------------------------------------------------------------------------
 
+from slate_tpu.internal import band_wave_vmem
 from slate_tpu.internal.band_wave_vmem import (hb2st_wave_vmem,
-                                               vmem_applies)
+                                               shear_form, vmem_applies)
+
+
+def _counted_forms(fn, *args, **kw):
+    """fn's result and the ``hb2st.shear`` counts it added, by form."""
+    from slate_tpu import obs
+    from slate_tpu.obs import metrics
+    was = obs.metrics_enabled()
+    obs.metrics_on()
+    try:
+        before = dict(metrics.counters_named("hb2st.shear"))
+        out = fn(*args, **kw)
+        after = metrics.counters_named("hb2st.shear")
+    finally:
+        if not was:
+            obs.metrics_off()
+    return out, {dict(k)["form"]: v - before.get(k, 0)
+                 for k, v in after.items() if v != before.get(k, 0)}
 
 
 @pytest.mark.parametrize("n,band", [(50, 8), (70, 8), (100, 16)])
 def test_vmem_matches_numpy_twin(n, band):
     ab = _rand_band(n, band, np.float32, seed=n * band)
     d0, e0, V0, t0 = band_bulge.hb2st(ab.copy())
-    d1, e1, V1, t1 = hb2st_wave_vmem(ab.copy(), interpret=True)
+    # bands under 128 keep the masked-roll ladder (FW = 4b, col0s that
+    # differ by frame): the single-pass forms are the band-128 layout's
+    assert band_wave_vmem.chase_shear_form(band) == "ladder"
+    (d1, e1, V1, t1), forms = _counted_forms(
+        hb2st_wave_vmem, ab.copy(), interpret=True)
+    assert forms == {"ladder": 1}
     # f32 only (the kernel's envelope): same loose tolerance as the
     # f32 XLA-wave rows — the chase is a long sequential recurrence
     # and the sheared lane reductions associate differently
@@ -98,15 +121,27 @@ def test_vmem_matches_numpy_twin(n, band):
     assert np.allclose(t0, t1, atol=tol, rtol=tol)
 
 
-def test_vmem_frames_path_matches_twin():
+@pytest.fixture(scope="module")
+def frames_run():
+    """One interpret-mode run of the chaser at band 128 (the FRAMES
+    layout, whose shears are single-pass), shared by the tests that
+    read it: the strided rotate expands to a roll a row in interpret
+    mode, so the program is slow to compile on the CPU."""
+    ab = _rand_band(300, 128, np.float32, seed=31)
+    out, forms = _counted_forms(hb2st_wave_vmem, ab.copy(),
+                                interpret=True)
+    return ab, out, forms
+
+
+def test_vmem_frames_path_matches_twin(frames_run):
     """The half-width FRAMES layout (b % 128 == 0 — the production
     bands' code path: frame slicing, c0 remaps, zb-concat delta
     recomposition) differentially checked against the numpy twin in
     interpret mode at band 128."""
     n, band = 300, 128
-    ab = _rand_band(n, band, np.float32, seed=31)
+    ab, (d1, e1, V1, t1), forms = frames_run
+    assert forms == {"single_pass": 1}
     d0, e0, V0, t0 = band_bulge.hb2st(ab.copy())
-    d1, e1, V1, t1 = hb2st_wave_vmem(ab.copy(), interpret=True)
     tol = 5e-3
     assert np.allclose(d0, d1, atol=tol, rtol=tol)
     assert np.allclose(e0, e1, atol=tol, rtol=tol)
@@ -119,6 +154,114 @@ def test_vmem_frames_path_matches_twin():
         + np.diag(e1.astype(np.float64), -1))
     ref = np.linalg.eigvalsh(_dense_from_band(ab).astype(np.float64))
     assert np.allclose(lam, ref, atol=2e-3 * max(1, np.abs(ref).max()))
+
+
+def test_vmem_frames_single_pass_matches_the_ladder(frames_run,
+                                                    monkeypatch):
+    """The same chase with every shear built by the ladder (the
+    reference form, kept callable): d, e, V, tau agree to the twin
+    tests' tolerance. Not bitwise: the sheared arrays are, but XLA's
+    CPU fuses the sublane sum after the rotate another way, and the
+    chase is a long recurrence."""
+    import jax
+    n, band = 300, 128
+    ab, single, _ = frames_run
+    monkeypatch.setattr(band_wave_vmem, "shear_form",
+                        lambda *a, **k: "ladder")
+
+    def ladder_chaser(ab, band, n, interpret):
+        # a function of its own: jit's trace cache is keyed on it
+        return band_wave_vmem._hb2st_vmem_jit.__wrapped__(
+            ab, band, n, interpret)
+
+    ladder = jax.jit(ladder_chaser, static_argnames=(
+        "band", "n", "interpret"))(ab.copy(), band=band, n=n,
+                                   interpret=True)
+    tol = 5e-3
+    for got, want in zip(single, ladder):
+        got, want = np.asarray(got), np.asarray(want)
+        assert got.shape == want.shape
+        assert np.allclose(got, want, atol=tol, rtol=tol)
+
+
+def _shear_pair(rows, vec, Q):
+    """Both forms of both helpers on one [rows, 2 rows] frame, in
+    interpret mode: (single-pass shear, ladder shear, single-pass
+    rotate, ladder rotate, single-pass column sums, ladder's)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    W = 2 * rows
+
+    def kern(v_ref, q_ref, s1, s0, a1, a0, c1, c0):
+        v, q = v_ref[...], q_ref[...]
+        s1[...] = band_wave_vmem._shear_rowvec(v, rows - 1, rows, W)
+        s0[...] = band_wave_vmem._shear_rowvec_ladder(v, rows - 1, rows, W)
+        a1[...] = band_wave_vmem._antishear(q, rows, W)
+        a0[...] = band_wave_vmem._antishear_ladder(q, rows, W)
+        c1[...] = band_wave_vmem._antishear_sum(q, rows, W)
+        c0[...] = jnp.sum(band_wave_vmem._antishear_ladder(q, rows, W),
+                          axis=0, keepdims=True)
+
+    blk = jax.ShapeDtypeStruct((rows, W), jnp.float32)
+    row = jax.ShapeDtypeStruct((1, W), jnp.float32)
+    return [np.asarray(x) for x in pl.pallas_call(
+        kern, out_shape=(blk, blk, blk, blk, row, row),
+        interpret=True)(vec, Q)]
+
+
+@pytest.fixture(scope="module", params=[128, 256])
+def shear_pair(request):
+    rows = request.param
+    rng = np.random.default_rng(rows)
+    vec = np.zeros((1, 2 * rows), np.float32)
+    vec[0, :rows] = rng.standard_normal(rows)
+    Q = rng.standard_normal((rows, 2 * rows)).astype(np.float32)
+    return rows, vec, Q, _shear_pair(rows, vec, Q)
+
+
+# the masks a task body puts on a sheared vector, by the block's own
+# column index col = c - col0 + i: mB / mD / mU are (0 <= col < L) and
+# a row bound; Zb adds col >= 1
+@pytest.mark.parametrize("lo", [0, 1], ids=["mB", "mB_past_col0"])
+@pytest.mark.parametrize("L", [1.0, 0.71, 0.3, 1 / 64])
+def test_single_pass_shear_is_the_ladders_under_the_masks(shear_pair, L,
+                                                          lo):
+    rows, vec, _, (s1, s0, *_rest) = shear_pair
+    assert shear_form(rows, 2 * rows, rows - 1) == "single_pass"
+    L = max(1, int(L * rows))
+    i = np.arange(rows)[:, None]
+    col = np.arange(2 * rows)[None, :] - (rows - 1) + i
+    m = (col >= lo) & (col < L) & (i < L)
+    assert m.any()
+    # bitwise: both forms only move data
+    assert np.array_equal(np.where(m, s1, 0), np.where(m, s0, 0))
+    assert np.array_equal(np.where(m, s1, 0),
+                          np.where(m, vec[0][np.clip(col, 0, rows - 1)],
+                                   0))
+
+
+def test_single_pass_antishear_is_the_ladders(shear_pair):
+    rows, _, Q, (_s1, _s0, a1, a0, c1, c0) = shear_pair
+    assert shear_form(rows, 2 * rows) == "single_pass"
+    # the rotated block bitwise; its column sums to a few ulp of the
+    # column's absolute sum (the CPU fuses that reduction otherwise)
+    assert np.array_equal(a1, a0)
+    assert np.array_equal(a1, np.stack([np.roll(Q[r], r)
+                                        for r in range(rows)]))
+    ulp = np.finfo(np.float32).eps * np.abs(a0).sum(axis=0)
+    assert (np.abs(c1[0] - c0[0]) <= 4 * ulp).all()
+
+
+@pytest.mark.parametrize("rows, W4, col0, form", [
+    (128, 256, 127, "single_pass"), (256, 512, 255, "single_pass"),
+    (128, 256, None, "single_pass"),
+    (128, 512, 127, "ladder"),      # full-width frames
+    (128, 256, 255, "ladder"),      # another col0
+    (64, 256, 63, "ladder"), (8, 32, 7, "ladder"),
+    (96, 192, 95, "ladder")])       # not a lane-tile multiple
+def test_shear_form_is_read_off_the_shape(rows, W4, col0, form):
+    assert shear_form(rows, W4, col0) == form
 
 
 def test_vmem_eigenvalues_match_dense():

@@ -311,6 +311,10 @@ def test_span_tree_of_a_two_stage_call(grid, observed):
     rung = labels["chase_backend"]
     assert metrics.counter_value("hb2st.backend", rung=rung) == 1
     assert metrics.counter_total("hb2st.demotion") == 0
+    # a shear form is the VMEM chaser's alone
+    (chase,) = [s for s in spans if s["name"] == "hb2st"]
+    assert ("shear" in chase["labels"]) == (rung == "vmem")
+    assert metrics.counter_total("hb2st.shear") == (rung == "vmem")
     poles = metrics.counter_total("stedc.poles")
     assert 0 <= metrics.counter_total("stedc.deflated") < poles
     assert poles >= N               # the top merge alone has n poles
@@ -357,6 +361,43 @@ def test_a_demoted_rung_is_counted(grid11, observed, monkeypatch):
     assert root["labels"]["chase_backend"] == below
     logged = ladder.demotion_log()[before:]
     assert [(d.from_rung, d.to_rung) for d in logged] == [(first, below)]
+
+
+@pytest.mark.parametrize("band, form", [(8, "ladder"),
+                                        (128, "single_pass")])
+def test_the_vmem_chasers_shear_form_is_counted(observed, monkeypatch,
+                                                band, form):
+    """``hb2st.shear{form}`` once a call of the VMEM chaser and the same
+    word on the ``hb2st`` span: the form is read off the band (the
+    kernel itself runs at band 8 only; at 128 its program is stood in
+    for, tests/test_band_wave.py runs it)."""
+    from slate_tpu.internal import band_bulge, band_wave_vmem
+    from slate_tpu.linalg.he2hb import hb2st
+    # off the chip the rung's probe says no: made to say yes (the
+    # kernel then runs in interpret mode)
+    lad = ladder.hb2st_ladder()
+    rungs = list(lad.rungs)
+    i = lad._names.index("vmem")
+    rungs[i] = dataclasses.replace(rungs[i], probe=lambda band: True)
+    monkeypatch.setattr(lad, "rungs", rungs)
+    monkeypatch.setenv("SLATE_HB2ST", "vmem")
+    n = 3 * band + 2
+    rng = np.random.default_rng(band)
+    ab = rng.standard_normal((band + 1, n)).astype(np.float32)
+    want = band_bulge.hb2st(ab.copy())
+    if band == 128:
+        monkeypatch.setattr(
+            band_wave_vmem, "_hb2st_vmem_jit",
+            lambda ab, band, n, interpret=False: tuple(
+                jnp.asarray(x) for x in want))
+    d, e, _, _ = hb2st(ab.copy())
+    assert np.allclose(d, want[0], atol=5e-3, rtol=5e-3)
+    assert metrics.counter_value("hb2st.shear", form=form) == 1
+    assert metrics.counter_total("hb2st.shear") == 1
+    assert metrics.counter_value("hb2st.backend", rung="vmem") == 1
+    (span,) = [s for s in obs.captured_spans() if s["name"] == "hb2st"]
+    assert (span["labels"]["rung"], span["labels"]["shear"]) == (
+        "vmem", form)
 
 
 # ------------------------------------------- the control's principle

@@ -482,3 +482,43 @@ def test_blocked_unmtr_hb2st_compiles(one_chip):
     # a sweep any more
     assert back.memory_analysis().temp_size_in_bytes < 4 * 4 * N_EIG ** 2
     assert "dot" in back.as_text() or "convolution" in back.as_text()
+
+
+@pytest.mark.parametrize("rows", [128, 256])
+def test_single_pass_shears_compile(one_chip, rows):
+    """Both single-pass forms of the VMEM chaser's shears on the
+    band-128 frame ([128, 256]) and, past one lane tile a vector, at
+    band 256: the lane gather (one source vreg a gather, so two gathers
+    and a select at 256) and the strided rotate. A form Mosaic refuses
+    would demote the whole chase to the XLA wave in silence."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from slate_tpu.internal import band_wave_vmem as bwv
+    W = 2 * rows
+    assert bwv.shear_form(rows, W, rows - 1) == "single_pass"
+
+    def kern(v_ref, q_ref, s_ref, z_ref):
+        s_ref[...] = bwv._shear_rowvec(v_ref[...], rows - 1, rows, W)
+        z_ref[...] = bwv._antishear_sum(q_ref[...], rows, W)
+
+    def both(v, q):
+        vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+        return pl.pallas_call(
+            kern, in_specs=[vmem, vmem], out_specs=[vmem, vmem],
+            out_shape=(jax.ShapeDtypeStruct((rows, W), F32),
+                       jax.ShapeDtypeStruct((1, W), F32)))(v, q)
+
+    c = _compile(both, _shape(one_chip, 1, W), _shape(one_chip, rows, W))
+    assert _kernels(c) == 1
+
+
+def test_hb2st_vmem_chaser_compiles(one_chip):
+    """The whole chaser at band 128 (the frame layout the cell runs,
+    single-pass shears in every task body) at a small n: the body is
+    the cell's, n only sets the grid."""
+    from slate_tpu.internal import band_wave_vmem as bwv
+    n = 1024
+    assert bwv.vmem_applies(n, BAND_EIG, F32)
+    c = bwv._hb2st_vmem_jit.lower(_shape(one_chip, BAND_EIG + 1, n),
+                                  band=BAND_EIG, n=n).compile()
+    assert _kernels(c) == 1
