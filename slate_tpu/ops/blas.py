@@ -22,7 +22,11 @@ gbmm.cc, hbmm.cc, tbsm.cc) as functional JAX programs:
   ``shard_map`` body moves what it is given. ``trsm(Side.Left)``
   chooses as the reference's trsmA / trsmB do, from the shape of B
   alone (``_moves_x``): a B of one tile column leaves A where it is
-  stored and moves block-rows of X.
+  stored and moves block-rows of X. Where A does not travel it is read
+  where it is stored, a ``[nb, nb]`` tile at a time: on one device
+  column (``_reads_tiles``) a step of the solve visits the tiles of
+  column k past the diagonal tile and no other, so the program holds
+  no re-laid copy of A and multiplies no masked half.
 
 * A B narrower than its storage (a few right-hand sides in an
   nb-wide tile column, the rest stored zeros) is multiplied by
@@ -606,6 +610,15 @@ def trsm(side: Side, alpha, A, B: Matrix, opts=None):
     leaves A in place and moves ``[nb, w]`` block-rows of X over q
     (work::trsmA); a wider B, whose columns are spread over q, has the
     tile column k of A broadcast over q each step (work::trsm).
+
+    On one device column (q = 1: every one-chip ``potrs``, ``getrs``,
+    ``gels`` and refinement step) no operand crosses q, and a step reads
+    column k of A tile by tile from where each tile is stored, the slots
+    past the diagonal tile only (``_reads_tiles``; the span's ``read``
+    label says ``tiles``, else ``column``, and the counter
+    ``trsm.read_tiles`` counts the solves that took it): no copy of A in
+    front of the loop, no product with the half of the column a mask had
+    made zero.
     """
     op = {Op.NoTrans: "N", Op.Trans: "T", Op.ConjTrans: "C"}[A.op]
     with trace.block("trsm", op=op) as span:
@@ -616,11 +629,15 @@ def trsm(side: Side, alpha, A, B: Matrix, opts=None):
             ntl = B.data.shape[3]
             w = _carried_cols(B.n, B.nb, B.grid.q, ntl)
             move_x = _moves_x(B.n, B.nb, B.grid.q)
-            span.label(nrhs=B.n, w=w, form="move_x" if move_x else "move_a")
+            read_tiles = _reads_tiles(B.grid.q)
+            span.label(nrhs=B.n, w=w, form="move_x" if move_x else "move_a",
+                       read="tiles" if read_tiles else "column")
             if w < ntl * B.nb:
                 obs.count("trsm.narrow", 1, op=op)
             if move_x:
                 obs.count("trsm.move_x", 1, op=op)
+            if read_tiles:
+                obs.count("trsm.read_tiles", 1, op=op)
         else:
             span.label(nrhs=B.m, w=B.data.shape[2] * B.nb)
         flags = {}
@@ -677,7 +694,12 @@ def _tile_column(a: jax.Array, s) -> jax.Array:
     """Local tile column ``a[:, s]`` of ``[mtl, ktl, nb, nb]`` read tile
     by tile, in the order A is stored in. One slice of the column inside
     a loop wants it contiguous, and XLA then re-orders a copy of all the
-    local A before the loop (PERF 7, fault 3a)."""
+    local A before the loop (PERF 7, fault 3a). ``gemm``'s form on a
+    grid, where the column is sent whole. ``_trsm_left_jit`` on one
+    device column builds no column at all: it multiplies each tile as
+    it reads it and stops at the diagonal (``_reads_tiles``; feeding
+    its masked product from here instead was 5.77 ms a solve against
+    3.49 at n = 16,384, by hand, PR 46)."""
     mtl, _, nb, _ = a.shape
 
     def slot(r, col):
@@ -709,6 +731,25 @@ def _moves_x(n: int, nb: int, q: int) -> bool:
     return q > 1 and n <= nb
 
 
+def _reads_tiles(q: int) -> bool:
+    """Whether a step of a left solve on ``q`` device columns takes tile
+    column k of A one ``[nb, nb]`` tile at a time, each from where it is
+    stored, and only the slots past the diagonal tile.
+
+    True when nothing crosses q for the column (one device column). As
+    one ``[mtl, nb, nb]`` value the column costs a re-laid copy of all
+    the local A in front of the loop (PERF 7, fault 3a) and a product
+    with its masked half; tile by tile both go. By hand on a v5e, ms a
+    call against the column whole: 3.49 against 7.44 at n = 16,384,
+    nb = 1,024, 8 right-hand sides (3.65 / 7.74 under an op), 2.70 / 3.35
+    at n = 10,000, nb = 384, 24.1 / 45.1 against 2,048 right-hand sides,
+    level at n = 1,024, nb = 256 (0.28 / 0.27): one form at every shape
+    measured (PERF 6, PR 46). Over q > 1 the column is what
+    ``bcast_from_col`` sends, one value, unless B is one tile column
+    (``_moves_x``)."""
+    return q == 1
+
+
 @partial(cached_jit, static_argnames=("lower", "unit", "trans", "conj"))
 def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
     """op(A)·X = alpha·B on A's storage: ``lower`` names the stored
@@ -732,7 +773,18 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
     q before solving it; an op keeps X whole on every device column,
     contracts column k tile by tile in the order A is stored in, and
     reduces the partial sums over q as well as over p. Either way X
-    leaves as it is stored: on device column 0, exact zeros beside."""
+    leaves as it is stored: on device column 0, exact zeros beside.
+
+    On one device column nothing crosses q (``_reads_tiles``), and a
+    step reads column k as the op'd form above does, one ``[nb, nb]``
+    tile at a time from where it is stored, with the loop's bounds at
+    the diagonal tile (a trip count read off k): NoTrans subtracts
+    A(s,k)·X(k,:) from the slots s left to update, an op sums
+    A(s,k)ᴴ·X(s,:) in f32 over the slots already solved. The slots at
+    and before the diagonal are neither read nor multiplied, and the
+    program holds no copy of A (what the column as one value cost:
+    ``_reads_tiles``). NoTrans gives the values the masked column gave, tile for tile; an
+    op's sum runs over the same tiles in slot order."""
     g = B.grid
     p, q, nb = g.p, g.q, B.nb
     mt = cdiv(A.m, nb)
@@ -742,6 +794,7 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
     # The rest is B's zero padding, whose solution is zero.
     w = _carried_cols(B.n, nb, q, ntl)
     move_x = _moves_x(B.n, nb, q)
+    read_tiles = _reads_tiles(q)
     # policy (internal/precision.py): triangular solves always bf16_6x
     pk6 = trailing_dot_kwargs("bf16_6x", B.dtype)
 
@@ -774,8 +827,20 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
             rows = (gi > k) if lower else (gi < k)
             return rows & (c == k % q) if move_x else rows
 
+        def past_diag_slots(k):
+            """The same slots as the range [lo, hi) they fill (``gi``
+            ascends), for a loop that visits no other."""
+            if lower:
+                return jnp.sum(gi <= k), mtl
+            return 0, jnp.sum(gi < k)
+
+        def tile(s, k):
+            """Local slot s of tile column k, from where it is stored."""
+            return lax.dynamic_slice(
+                a, (s, k // q, 0, 0), (1, 1, nb, nb))[0, 0]
+
         def column(k):
-            """Those slots of tile column k, the rest zero."""
+            """Those slots of tile column k as one value, the rest zero."""
             acol = lax.dynamic_index_in_dim(a, k // q, axis=1, keepdims=False)
             if not move_x:
                 acol = comm.bcast_from_col(acol, k % q)      # [mtl, nb, nb]
@@ -785,7 +850,7 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
         def column_h_times(k, x):
             """Σ_i A(i,k)ᴴ · X(i,:) over this device's slots of tile
             column k past the diagonal tile."""
-            if not move_x:
+            if not (move_x or read_tiles):
                 acol = column(k)
                 if conj:
                     acol = jnp.conj(acol)
@@ -793,18 +858,35 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
             # A stationary: read tile by tile, in the order A is stored
             # in. One product over all the slots wants tile column k
             # contiguous, and XLA then re-orders a copy of all the
-            # local A before the loop (PERF 7, fault 3a)
+            # local A before the loop (PERF 7, fault 3a). On one device
+            # column the trips are the slots past the diagonal; where X
+            # moved they are all mtl, masked
             rows = past_diag(k)
 
             def slot(s, acc):
-                tile = lax.dynamic_slice(
-                    a, (s, k // q, 0, 0), (1, 1, nb, nb))[0, 0]
-                tile = jnp.where(rows[s], tile, jnp.zeros_like(tile))
+                t = tile(s, k)
+                if not read_tiles:
+                    t = jnp.where(rows[s], t, jnp.zeros_like(t))
                 if conj:
-                    tile = jnp.conj(tile)
-                return acc + jnp.einsum("ki,kj->ij", tile, x[s], **pk6)
+                    t = jnp.conj(t)
+                return acc + jnp.einsum("ki,kj->ij", t, x[s], **pk6)
 
-            return lax.fori_loop(0, mtl, slot, jnp.zeros((nb, w), x.dtype))
+            lo, hi = past_diag_slots(k) if read_tiles else (0, mtl)
+            return lax.fori_loop(lo, hi, slot, jnp.zeros((nb, w), x.dtype))
+
+        def minus_column_times(k, x, xrow):
+            """X(i,:) − A(i,k) · X(k,:) on this device's slots of tile
+            column k past the diagonal tile, ``xrow`` the solved X(k,:)."""
+            if not read_tiles:
+                return x - jnp.einsum("aik,kj->aij", column(k), xrow, **pk6)
+
+            # tile by tile as above: neither the copy of A nor the
+            # product with the masked half of the column
+            def slot(s, x):
+                xs = x[s] - jnp.einsum("ik,kj->ij", tile(s, k), xrow, **pk6)
+                return lax.dynamic_update_index_in_dim(x, xs, s, 0)
+
+            return lax.fori_loop(*past_diag_slots(k), slot, x)
 
         def step(t, x):
             k = t if lower else mt - 1 - t
@@ -823,8 +905,7 @@ def _trsm_left_jit(alpha, A, B, lower, unit, trans=False, conj=False):
             with jax.named_scope("update"):
                 xrow_b = comm.bcast_from_row(xrow, k % p)    # [nb, w]
                 # trailing update: B(i,:) -= A(i,k) · X(k,:) for remaining i
-                upd = jnp.einsum("aik,kj->aij", column(k), xrow_b, **pk6)
-                return x - upd
+                return minus_column_times(k, x, xrow_b)
 
         def step_op(t, x):
             # op(A) is lower iff the stored triangle is upper: a stored

@@ -219,11 +219,15 @@ def test_trsm_right_native_no_transpose(grid24, materialized_ops):
     assert materialized_ops == [], materialized_ops
 
 
-@pytest.mark.parametrize("shape", ["1x1", "2x4", "4x2", "2x2"])
+@pytest.mark.parametrize("shape,n", [
+    ("1x1", 19), ("2x4", 19), ("4x2", 19), ("2x2", 19),
+    # one device column, six tile rows: the sum over the tiles of
+    # column k stops at the diagonal tile, whatever p deals a device
+    ("1x1", 45), ("2x1", 45)])
 @pytest.mark.parametrize("diag", [Diag.NonUnit, Diag.Unit])
 @pytest.mark.parametrize("op", ["t", "c"])
 @pytest.mark.parametrize("uplo", [Uplo.Lower, Uplo.Upper])
-def test_trsm_left_reads_op_in_place(uplo, op, diag, shape,
+def test_trsm_left_reads_op_in_place(uplo, op, diag, shape, n,
                                      materialized_ops):
     """``trsm(Side.Left, op(A), B)`` solves on A's storage: no operand
     with an op is materialized (a re-laid copy of A, an all-to-all),
@@ -232,7 +236,7 @@ def test_trsm_left_reads_op_in_place(uplo, op, diag, shape,
     p, q = map(int, shape.split("x"))
     grid = st.Grid(p, q, devices=jax.devices()[:p * q])
     dt = np.complex128 if op == "c" else np.float64
-    n, nrhs, nb = 19, 5, 8
+    nrhs, nb = 5, 8
     unit = diag == Diag.Unit
     a = rand(n, n, dt, 30) * 0.3 + (0 if unit else n * np.eye(n))
     t = tri(a, uplo == Uplo.Lower, unit=unit)
@@ -348,24 +352,42 @@ def test_trsm_moves_x(n, nb, q, moves):
     assert blas._moves_x(n, nb, q) is moves
 
 
+@pytest.mark.parametrize("q,tiles", [
+    (1, True),          # the one-chip cells, and p x 1: nothing crosses q
+    (2, False),         # the 2x2: X moves (one tile column) or A's column
+    (4, False),
+])
+def test_trsm_reads_tiles(q, tiles):
+    from slate_tpu.ops import blas
+    assert blas._reads_tiles(q) is tiles
+
+
 def _narrow_b_cases():
     """Every width on the three older shapes; on 1x2 and 4x2, which are
     here for the form that moves X, the widths that take it (nrhs <= nb,
-    its edge nrhs = nb included)."""
+    its edge nrhs = nb included). One device column reads column k of A
+    tile by tile from the diagonal on: five tile rows with a ragged
+    last one (n = 1100), dealt to one, two and four device rows."""
     widths = [(1, Diag.NonUnit), (5, Diag.NonUnit), (5, Diag.Unit),
               (130, Diag.NonUnit), (256, Diag.NonUnit), (300, Diag.NonUnit)]
     for shape in ["1x1", "2x2", "2x4", "1x2", "4x2"]:
         for nrhs, diag in widths:
             if shape in ("1x2", "4x2") and nrhs not in (5, 256):
                 continue
-            yield pytest.param(shape, nrhs, diag,
+            yield pytest.param(shape, 600, nrhs, diag,
                                id=f"{shape}-{nrhs}-{diag.name}")
+    for shape, nrhs, diag in [("1x1", 5, Diag.NonUnit), ("1x1", 5, Diag.Unit),
+                              ("1x1", 300, Diag.NonUnit),
+                              ("2x1", 5, Diag.NonUnit), ("2x1", 5, Diag.Unit),
+                              ("4x1", 5, Diag.NonUnit)]:
+        yield pytest.param(shape, 1100, nrhs, diag,
+                           id=f"{shape}-n1100-{nrhs}-{diag.name}")
 
 
 @pytest.mark.parametrize("uplo", [Uplo.Lower, Uplo.Upper])
 @pytest.mark.parametrize("op", ["n", "t", "c"])
-@pytest.mark.parametrize("shape,nrhs,diag", _narrow_b_cases())
-def test_trsm_left_narrow_b(shape, op, uplo, nrhs, diag):
+@pytest.mark.parametrize("shape,n,nrhs,diag", _narrow_b_cases())
+def test_trsm_left_narrow_b(shape, n, op, uplo, nrhs, diag):
     """8 right-hand sides in a 256-wide tile: the answer, the stored
     padding, and the same columns out of a B of two or more tile
     columns, which on a grid is the other form (A moves, not X)."""
@@ -373,7 +395,7 @@ def test_trsm_left_narrow_b(shape, op, uplo, nrhs, diag):
     p, q = map(int, shape.split("x"))
     grid = st.Grid(p, q, devices=jax.devices()[:p * q])
     dt = np.complex64 if op == "c" else np.float32
-    n, nb = 600, 256
+    nb = 256
     unit = diag == Diag.Unit
     a = rand(n, n, dt, 40) * 0.3 + n * np.eye(n, dtype=dt)
     if unit:

@@ -68,10 +68,18 @@ def _posv_operands(grid, n=256, nb=64):
     return A, B
 
 
-def _moved_x():
-    """``trsm.move_x`` by its ``op`` label."""
+def _by_op(counter):
+    """A ``trsm`` counter by its ``op`` label."""
     return {dict(labels)["op"]: v for labels, v
-            in metrics.counters_named("trsm.move_x").items()}
+            in metrics.counters_named(counter).items()}
+
+
+def _moved_x():
+    return _by_op("trsm.move_x")
+
+
+def _read_tiles():
+    return _by_op("trsm.read_tiles")
 
 
 def _tree(spans):
@@ -122,6 +130,10 @@ def test_posv_span_tree_has_parents_and_one_solve_id(grid_name, request,
         # B is one tile column: on a grid A stays and X moves over q
         assert [s["labels"]["form"] for s in trsms] == [
             "move_a" if grid.q == 1 else "move_x"] * 2
+        # ... and on one device column nothing crosses q, so a step
+        # reads column k tile by tile, past the diagonal tile only
+        assert [s["labels"]["read"] for s in trsms] == [
+            "tiles" if grid.q == 1 else "column"] * 2
         # children lie inside their parents, on one clock
         by_id = {s["id"]: s for s in mine}
         for s in mine:
@@ -134,22 +146,29 @@ def test_posv_span_tree_has_parents_and_one_solve_id(grid_name, request,
     assert len(chunks) == (1 if grid.size == 1 else 2)
     assert metrics.counter_total("trsm.in_place") == len(roots)
     assert metrics.counter_total("matrix.relayout_bytes") == 0
-    assert _moved_x() == ({} if grid.q == 1 else {"N": len(roots),
-                                                  "C": len(roots)})
+    once_each = {"N": len(roots), "C": len(roots)}
+    assert _moved_x() == ({} if grid.q == 1 else once_each)
+    assert _read_tiles() == (once_each if grid.q == 1 else {})
 
 
-@pytest.mark.parametrize("grid_name,nrhs,w,narrow,form", [
-    ("grid11", 8, 128, 2, "move_a"), ("grid11", 256, 256, 0, "move_a"),
-    ("grid22", 8, 128, 2, "move_x"), ("grid22", 256, 256, 0, "move_x"),
-    ("grid22", 512, 256, 0, "move_a"),      # two tile columns: X spread over q
+@pytest.mark.parametrize("grid_name,nrhs,w,narrow,form,read", [
+    ("grid11", 8, 128, 2, "move_a", "tiles"),
+    ("grid11", 256, 256, 0, "move_a", "tiles"),
+    ("grid11", 512, 512, 0, "move_a", "tiles"),
+    ("grid22", 8, 128, 2, "move_x", "column"),
+    ("grid22", 256, 256, 0, "move_x", "column"),
+    # two tile columns: X spread over q
+    ("grid22", 512, 256, 0, "move_a", "column"),
 ])
 def test_trsm_span_says_the_width_it_carried(request, profiler, grid_name,
-                                             nrhs, w, narrow, form):
+                                             nrhs, w, narrow, form, read):
     """8 right-hand sides in a 256-wide tile ride both solves of a posv
     at 128 columns; a B of whole tiles is carried as it is stored. On a
     grid a B of one tile column stays put while X moves (``form``,
     ``trsm.move_x`` once a solve and op); on one chip, or with a wider
-    B, never."""
+    B, never. Where no operand crosses q (one device column) a step
+    reads the tiles of column k past the diagonal where they are
+    stored (``read``, ``trsm.read_tiles`` once a solve and op)."""
     grid = request.getfixturevalue(grid_name)
     n, nb = 512, 256
     A = st.HermitianMatrix.from_dense(spd(n, np.float32, seed=5), nb=nb,
@@ -163,11 +182,13 @@ def test_trsm_span_says_the_width_it_carried(request, profiler, grid_name,
     profiler()
     trsms = [s["labels"] for s in obs.captured_spans()
              if s["name"] == "trsm"]
-    assert [(t["op"], t["nrhs"], t["w"], t["form"]) for t in trsms] == [
-        ("N", nrhs, w, form), ("C", nrhs, w, form)]
+    assert [(t["op"], t["nrhs"], t["w"], t["form"], t["read"])
+            for t in trsms] == [("N", nrhs, w, form, read),
+                                ("C", nrhs, w, form, read)]
     assert metrics.counter_total("trsm.narrow") == narrow
     assert metrics.counter_total("trsm.in_place") == 1
     assert _moved_x() == ({"N": 1, "C": 1} if form == "move_x" else {})
+    assert _read_tiles() == ({"N": 1, "C": 1} if read == "tiles" else {})
 
 
 def test_capture_follows_the_profiler(grid11, profiler):
@@ -297,6 +318,11 @@ def test_host_sync_once_for_gesv_fast_path_never_for_posv(grid11,
     assert "slate.gesv/getrf.chunk" in paths
     assert "slate.gesv/getrs/getrs.apply_pivots" in paths
     assert "slate.gesv/gesv.order_to_ipiv" in paths
+    # getrs: unit-lower L then upper U, both NoTrans, tile by tile
+    trsms = [s["labels"] for s in spans if s["name"] == "trsm"]
+    assert [(t["op"], t["form"], t["read"]) for t in trsms] == [
+        ("N", "move_a", "tiles")] * 2
+    assert _read_tiles() == {"N": 2}
     x = np.asarray(X.to_dense())
     a, b = np.asarray(A.to_dense()), np.asarray(B.to_dense())
     assert np.linalg.norm(a @ x - b) < 1e-3 * np.linalg.norm(b)

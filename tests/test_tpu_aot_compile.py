@@ -80,6 +80,15 @@ def _loop_bodies(hlo: str) -> str:
                      if c.lstrip().split(" ", 1)[0] in bodies)
 
 
+def _widest_product(text: str) -> int:
+    """Elements of the largest f32 result of a ``dot`` or ``convolution``
+    in a compiled text."""
+    products = re.findall(
+        r"= f32\[([\d,]+)\]\S* (?:convolution|dot)\(", text)
+    assert products
+    return max(math.prod(map(int, dims.split(","))) for dims in products)
+
+
 # -- the production LU panel kernels (internal/panel_plu.py) ---------------
 
 @pytest.mark.parametrize("fold", [True, False], ids=["fold", "nofold"])
@@ -313,10 +322,42 @@ def test_trsm_left_op_in_place_compiles_with_no_relayout(topo, tpu_grid22,
     mem = c.memory_analysis()
     assert abs(mem.argument_size_in_bytes
                - stored // grid.size) < 2 ** 20
+    # no copy of the factor: the left-looking step needs tiles only (a
+    # column of them as one value cost the one chip a re-laid copy of
+    # all of L, 1 GiB of temporaries, until PR 46)
+    assert mem.temp_size_in_bytes < 2 ** 24, mem.temp_size_in_bytes
     if shape == "2x2":
-        # no copy of the factor: the left-looking step needs tiles only
         assert "all-reduce" in text
-        assert mem.temp_size_in_bytes < 2 ** 24, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("lower,unit,trans", [
+    (True, False, False), (True, False, True),      # potrs: L, then L^H
+    (True, True, False), (False, False, False),     # getrs: unit L, then U
+    (False, False, True)],
+    ids=["N", "C", "unit_lower_N", "upper_N", "upper_C"])
+def test_trsm_left_one_chip_reads_tiles_past_the_diagonal(topo, tpu_grid22,
+                                                          lower, unit, trans):
+    """The one-chip cells' solves (``potrs``, ``getrs``, every M^-1 of
+    ``gesv_mixed_gmres``): a step takes the tiles of column k one at a
+    time from where each is stored, from the diagonal on. The program
+    holds no re-laid copy of A (the parent's 1 GiB of temporaries), and
+    what a step multiplies is one [nb, nb] by [nb, w] product in a loop
+    whose trips end at the diagonal, not the column whole with its
+    masked half."""
+    c, grid, stored = _trsm_h_by_8(topo, tpu_grid22, "1x1", trans, lower,
+                                   unit)
+    mtl = H // NB
+    mem = c.memory_analysis()
+    assert abs(mem.argument_size_in_bytes - stored) < 2 ** 20
+    assert mem.temp_size_in_bytes < 2 ** 24, mem.temp_size_in_bytes
+    text = c.as_text()
+    assert f"f32[{mtl},{mtl},{NB},{NB}]" in text        # A, the argument
+    assert not re.findall(
+        rf"= f32\[{mtl},{mtl},{NB},{NB}\]\S* (?:copy|fusion)\(", text)
+    assert _widest_product(text) == NB * W
+    # a step's flops as XLA counts them (an inner trip once): under the
+    # unmasked half of the column, where the parent's were the column's
+    assert c.cost_analysis()["flops"] <= 2 * (mtl // 2) * NB * NB * W
 
 
 def _assert_a_stays(c, mtl):
@@ -343,13 +384,10 @@ def test_trsm_left_8_rhs_multiplies_128_lanes(topo, tpu_grid22, shape,
     c, grid, _ = _trsm_h_by_8(topo, tpu_grid22, shape, trans)
     mtl = H // NB // grid.p
     text = c.as_text()
-    products = re.findall(
-        r"= f32\[([\d,]+)\]\S* (?:convolution|dot)\(", text)
-    assert products
     # the widest is column k of A by X(k,:), [mtl, 1024, 128] (one tile
-    # of it by its rows of X, [1024, 128], summed, under an op); the
-    # padded tile's was 8x that
-    widest = max(math.prod(map(int, dims.split(","))) for dims in products)
+    # of it by its rows of X, [1024, 128], where A is read tile by
+    # tile); the padded tile's was 8x that
+    widest = _widest_product(text)
     assert widest in (mtl * NB * W, NB * W), widest
     # a step's flops: that product and the diagonal block's solve (the
     # padded tile cost 8x: 35.6e9 on one chip, 18.5e9 on the 2x2)
@@ -393,11 +431,7 @@ def test_gemm_one_column_multiplies_128_lanes(topo, tpu_grid22, shape):
     s = jax.ShapeDtypeStruct((), F32)
     c = blas._gemm_jit.lower(s, A, B, s, C, tier="bf16_6x").compile()
     text = c.as_text()
-    products = re.findall(
-        r"= f32\[([\d,]+)\]\S* (?:convolution|dot)\(", text)
-    assert products
-    widest = max(math.prod(map(int, dims.split(","))) for dims in products)
-    assert widest <= mtl * NB * W, products
+    assert _widest_product(text) <= mtl * NB * W
     # one chip: the whole product, 2 H H w; the grid: a step's, whose
     # loop cost_analysis counts once (the 1024-wide tile cost 8x)
     assert c.cost_analysis()["flops"] < 1.2 * 2 * H * H * W / grid.size
