@@ -29,6 +29,7 @@ compile path.
 
 from __future__ import annotations
 
+import math
 import re
 
 from . import flops as _flops
@@ -171,16 +172,17 @@ _SHAPE_DTYPE_BYTES = {
 }
 
 
-def collective_stats(hlo_text: str) -> dict:
-    """Parse optimized HLO text for collective ops.
-
-    Returns ``{kind: {"count": int, "bytes": float}}`` where bytes is
-    the summed result-shape footprint of each collective — the data
-    volume the op materializes per program execution (``-start``
-    halves of async pairs are counted, ``-done`` halves skipped so an
-    overlapped collective isn't double-counted).
+def collective_shapes(hlo_text: str) -> dict:
+    """The one reader of collectives in optimized HLO text:
+    ``{kind: [[(bytes an element, dims), ...], ...]}``, for every
+    collective op of a kind the shapes of its results. XLA combines
+    collectives of one kind into one op with a tuple result, in an
+    order it picks: each member of the tuple is read. ``-done`` halves
+    of async pairs are skipped; a ``-start`` half that lists its
+    operands before its results (every kind but all-reduce) gives the
+    results only.
     """
-    out: dict[str, dict] = {}
+    out: dict[str, list] = {}
     for line in hlo_text.splitlines():
         if "-done(" in line:
             continue
@@ -188,21 +190,33 @@ def collective_stats(hlo_text: str) -> dict:
         if not m:
             continue
         kind = m.group(1)
-        nbytes = 0.0
-        sm = _SHAPE_RE.search(line)          # result shape: first on line
-        if sm:
-            dt, dims = sm.group(1), sm.group(2)
-            sz = _SHAPE_DTYPE_BYTES.get(dt)
-            if sz:
-                n = 1
-                for d in dims.split(","):
-                    if d:
-                        n *= int(d)
-                nbytes = float(n * sz)
-        s = out.setdefault(kind, {"count": 0, "bytes": 0.0})
-        s["count"] += 1
-        s["bytes"] += nbytes
+        head = line[:m.start(1)].partition("=")[2]
+        shapes = [(_SHAPE_DTYPE_BYTES[dt],
+                   tuple(int(d) for d in dims.split(",") if d))
+                  for dt, dims in _SHAPE_RE.findall(head)
+                  if dt in _SHAPE_DTYPE_BYTES]
+        started = line[m.end(1):].startswith("-start")
+        if started and kind != "all-reduce" and len(shapes) > 1:
+            shapes = [s for s in shapes if s[1]]     # context scalars
+            shapes = shapes[len(shapes) // 2:]
+        out.setdefault(kind, []).append(shapes)
     return out
+
+
+def collective_stats(hlo_text: str) -> dict:
+    """Parse optimized HLO text for collective ops.
+
+    Returns ``{kind: {"count": int, "bytes": float}}`` where bytes is
+    the summed result-shape footprint of each collective — the data
+    volume the op materializes per program execution, every member of
+    a combined (tuple) result included (``-start`` halves of async
+    pairs are counted, ``-done`` halves skipped so an overlapped
+    collective isn't double-counted).
+    """
+    return {kind: {"count": len(ops),
+                   "bytes": float(sum(size * math.prod(dims)
+                                      for op in ops for size, dims in op))}
+            for kind, ops in collective_shapes(hlo_text).items()}
 
 
 def capture(compiled, *, hlo_text: str | None = None) -> dict | None:

@@ -1,0 +1,213 @@
+"""The LU and Cholesky programs of the main path, compiled for the real
+chip: the Pallas LU panel kernels, the one-chip fast-path group, a 2x2
+super-step chunk of each factorization, ``getrs``'s pivots, one served
+executable. (QR and the eigensolver: tests/test_aot_tpu_qr_eig.py; the
+``trsm`` and ``gemm`` forms: tests/test_aot_tpu_blas.py.)
+
+Nothing runs: the TPU compiler installed in the sandbox compiles for a
+DESCRIBED ``v5e:2x2`` (no chip attached), which refuses what interpret
+mode cannot see — misaligned slices, too much VMEM, a kernel that
+cannot be partitioned, a program that does not fit HBM. A kernel is
+compiled at its true block; the program around it at the fewest steps
+the assertion needs, and at the cell's size where the assertion is about
+that size (temporaries, bytes a device). The fixtures (``topo``,
+``one_chip``, ``tpu_grid22``) are in tests/conftest.py.
+Real widths (h=16384, nb=1024); the n=16384 whole-factorization programs
+take minutes and are compiled by hand, not here.
+"""
+
+import re
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import slate_tpu as slate
+from tests.conftest import (AOT_H as H, AOT_NB as NB, AOT_W as W,
+                            all_reduce_shapes, aot_compile, aot_kernels,
+                            aot_tiles)
+
+F32 = jnp.float32
+
+
+# -- the production LU panel kernels (internal/panel_plu.py) ---------------
+
+
+@pytest.mark.parametrize("fold", [True, False], ids=["fold", "nofold"])
+def test_plu_subpanel_compiles(one_chip, fold):
+    from slate_tpu.internal import panel_plu
+    sub = jax.ShapeDtypeStruct((H, W), F32, sharding=one_chip)
+    act = jax.ShapeDtypeStruct((H,), F32, sharding=one_chip)
+    c = aot_compile(partial(panel_plu.plu_subpanel, fold=fold), sub, act)
+    assert aot_kernels(c) >= 3      # transpose in, factor, transpose out
+
+
+def test_fold_unfold_panelaot_compile(one_chip):
+    from slate_tpu.internal import panel_plu
+    flat = jax.ShapeDtypeStruct((H, NB), F32, sharding=one_chip)
+    folded = jax.ShapeDtypeStruct((8, NB, H // 8), F32, sharding=one_chip)
+    assert aot_kernels(aot_compile(panel_plu.fold_panel, flat)) == 1
+    assert aot_kernels(aot_compile(panel_plu.unfold_panel, folded)) == 1
+
+
+def test_plu_call_folded_block_compiles(one_chip):
+    from slate_tpu.internal import panel_plu
+    pcf = jax.ShapeDtypeStruct((8, NB, H // 8), F32, sharding=one_chip)
+    act = jax.ShapeDtypeStruct((8, H // 8), F32, sharding=one_chip)
+    sidx = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    assert aot_kernels(aot_compile(panel_plu.plu_call_folded_block,
+                             pcf, act, sidx)) == 1
+
+
+def test_getrf_fast_group_program_compiles_with_the_kernel(topo, one_chip):
+    """A small twin of the program slate.gesv runs at n=16384 on one
+    chip: layout-pinned, donated, Pallas panels. One group of two panels
+    (the in-group trailing update) of four subpanels each (the
+    intra-panel solve) on an 8,192-row window: twelve kernels, each at
+    its own height, which is what the compile costs. The kernels at the
+    cell's height are compiled alone above."""
+    from slate_tpu.linalg import getrf
+    n, nb = 8192, 512
+    a = jax.ShapeDtypeStruct((n, n), F32, sharding=one_chip)
+    content = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    info = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    c = getrf._fast_group_program(topo.devices[0]).lower(
+        a, content, info, 0, 2, nb, False, True, None).compile()
+    assert aot_kernels(c) > 0
+
+
+# -- one 2x2 super-step chunk of each factorization -------------------------
+
+
+def _assert_sharded_with_collectives(compiled, whole_bytes):
+    text = compiled.as_text()
+    assert "all-gather" in text or "all-reduce" in text
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert abs(per_device - whole_bytes // 4) < 2 ** 20, per_device
+
+
+def test_potrf_chunk_2x2_compiles_sharded(tpu_grid22):
+    from slate_tpu.linalg import potrf
+    data = aot_tiles(tpu_grid22)
+    A = slate.HermitianMatrix(data=data, m=H, n=H, nb=NB, grid=tpu_grid22)
+    info0 = jax.ShapeDtypeStruct((), jnp.int32)
+    c = potrf._potrf_chunk_jit.lower(A, info0, 0, 2,
+                                     tier="bf16_6x").compile()
+    _assert_sharded_with_collectives(c, H * H * 4)
+
+
+def _getrf_chunk(grid, k0):
+    """The chunk program of block columns [k0, k0 + 2), compiled."""
+    from slate_tpu.linalg import getrf
+    A = slate.Matrix(data=aot_tiles(grid), m=H, n=H, nb=NB, grid=grid)
+    piv0 = jax.ShapeDtypeStruct((H // NB, NB), jnp.int32)
+    info0 = jax.ShapeDtypeStruct((), jnp.int32)
+    return getrf._getrf_chunk_jit.lower(A, piv0, info0, k0, 2,
+                                        tier="bf16_6x").compile()
+
+
+def _all_gathers(text) -> int:
+    return text.count(" all-gather(") + text.count(" all-gather-start(")
+
+
+def _all_gather_shapes(text) -> set:
+    """(dtype, dims) of every all-gather's result in an optimized HLO
+    text (an async one appears once in each fusion it is split over)."""
+    return {(dt, tuple(int(d) for d in dims.split(",")))
+            for dt, dims in re.findall(
+                r"= (\w+)\[([\d,]+)\]\S* all-gather(?:-start)?\(", text)}
+
+
+def test_getrf_chunk_2x2_compiles_sharded(tpu_grid22):
+    c = _getrf_chunk(tpu_grid22, 0)
+    _assert_sharded_with_collectives(c, H * H * 4)
+    # the first chunk's temp decides the cell's peak_hbm_gib: 396 MiB
+    # compiled here (2026-09-28, jax 0.9.0) with the panel factored where
+    # its rows are stored; 860 while the [M, nb] panel was gathered
+    temp_mib = c.memory_analysis().temp_size_in_bytes / 2 ** 20
+    assert temp_mib < 450, temp_mib
+
+
+def test_getrf_last_chunk_2x2_collectives_and_temp(tpu_grid22):
+    """The last of the eight chunk programs ``gesv_16k_2x2`` runs
+    (k0 = 14, two block columns). What crosses chips in a step, and
+    nothing else: column k's local slots over q (mtl*nb*nb elements);
+    over p the tournament's p*nb winner rows and their row ids (the
+    16,384-row panel is over the cap of one ``lu``, so it is factored
+    where its rows are stored and never gathered), the diagonal tile,
+    the row swaps' candidate rows (2*nb rows of the local stack: twice
+    column k's bytes, the largest thing that moves), the U block-row
+    of the window. No all-to-all. The next change to the panel or the
+    swaps has these numbers to beat."""
+    import math
+    mtl = ntl = H // NB // 2
+    c = _getrf_chunk(tpu_grid22, H // NB - 2)
+    _assert_sharded_with_collectives(c, H * H * 4)
+    text = c.as_text()
+    assert "all-to-all" not in text and "collective-permute" not in text
+    reduced = sorted(math.prod(dims) for _, dims in all_reduce_shapes(text))
+    assert reduced == [NB * NB,                 # U(k, last tile column)
+                       NB * NB,                 # the diagonal tile over p
+                       mtl * NB * NB,           # column k over q
+                       2 * NB * ntl * NB], reduced      # swapped rows
+    assert _all_gather_shapes(text) == {("f32", (2 * NB, NB)),
+                                        ("s32", (2 * NB,))}
+    assert f"f32[2,{mtl},{NB},{NB}]" not in text    # no [M, nb] panel
+    # 362 MiB compiled here (2026-09-28, jax 0.9.0; 330 with the
+    # gathered panel, whose first chunk held 860): the swaps' rows
+    # (2 x 64 MiB of candidates, 256 MiB of replacements) lead it
+    temp_mib = c.memory_analysis().temp_size_in_bytes / 2 ** 20
+    assert temp_mib < 400, temp_mib
+
+
+def _while_trips(text):
+    """Trip counts of the compiled text's ``while`` loops, each read
+    off the constant its condition compares the counter with."""
+    trips = []
+    for cond in re.findall(r" while\(.*?condition=%([\w.\-]+)", text):
+        body = text[text.index(f"\n%{cond} ("):]
+        body = body[:body.index("\n}")]
+        assert "direction=LT" in body, body
+        trips += [int(n) for n in re.findall(r"constant\((\d+)\)", body)]
+    return sorted(trips)
+
+
+def test_apply_piv_2x2_one_rhs_compiles(tpu_grid22):
+    """``getrs``'s pivots on the cell's B, [16384, 1] in one tile
+    column a device column: the stored 2 x 64 MiB (one real column) are
+    gathered to every device, the 16 panels' swaps replayed at once in
+    one ``while`` of 1,024 trips and composed in one of 16
+    (``_sim_perm``), and the rows taken: one all-gather, nothing else
+    crosses."""
+    from slate_tpu.linalg import getrf
+    b = jax.ShapeDtypeStruct((2, 2, H // NB // 2, 1, NB, NB), F32,
+                             sharding=tpu_grid22.sharding())
+    B = slate.Matrix(data=b, m=H, n=1, nb=NB, grid=tpu_grid22)
+    piv = jax.ShapeDtypeStruct((H // NB, NB), jnp.int32)
+    c = getrf._apply_piv_jit.lower(B, piv, forward=True).compile()
+    text = c.as_text()
+    assert "all-reduce" not in text and "all-to-all" not in text
+    assert _all_gathers(text) == 1
+    assert _while_trips(text) == [H // NB, NB]
+    mem = c.memory_analysis()
+    assert abs(mem.argument_size_in_bytes
+               - b.size * 4 // tpu_grid22.size) < 2 ** 20
+    # 64 MiB compiled here: the gathered B; a reader of one column
+    # would hold 64 KiB
+    assert mem.temp_size_in_bytes / 2 ** 20 < 80
+
+
+# -- one served executable ---------------------------------------------------
+
+
+def test_served_posv_bucket_compiles(one_chip):
+    from slate_tpu.cache import buckets
+    from slate_tpu.serve import batched
+    bucket, rung = 1024, 4
+    a = jax.ShapeDtypeStruct((rung, bucket, bucket), F32,
+                             sharding=one_chip)
+    b = jax.ShapeDtypeStruct((rung, bucket, 8), F32, sharding=one_chip)
+    c = batched._posv_jit.lower(a, b, nb=buckets.default_nb(bucket),
+                                tier="bf16_6x").compile()
+    assert c.memory_analysis().argument_size_in_bytes >= a.size * 4

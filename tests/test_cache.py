@@ -404,6 +404,11 @@ def _subproc_env(cache_root):
     else:
         env.pop("XLA_FLAGS", None)
     env["SLATE_TPU_CACHE_DIR"] = str(cache_root)
+    # jax's own persistent cache beside it, not in the checkout: an
+    # entry a child of an earlier run left in <checkout>/.jax_cache is
+    # not always loadable by a child of this one (``Function ... not
+    # found``)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache_root.parent / "jax")
     return env
 
 
@@ -423,14 +428,24 @@ def _parsed(out):
     return d
 
 
-@pytest.mark.parametrize("routine", ["posv", "gesv"])
-def test_two_process_warmup_then_hit(routine, tmp_path):
-    env = _subproc_env(tmp_path / "exec")
-    # process A: warmup the 64-bucket for this routine
+@pytest.fixture(scope="module")
+def warmed(tmp_path_factory):
+    """A cache directory in which one child process has warmed the
+    64-bucket of both routines (a cold interpreter and jax start, then
+    the compiles), made once for the tests that read it from children
+    of their own."""
+    root = tmp_path_factory.mktemp("warmed") / "exec"
     out = _run([sys.executable, "-m", "slate_tpu.cache", "warmup",
-                "--routines", routine, "--buckets", "64", "--nb", "32"],
-               env)
+                "--routines", "posv,gesv", "--buckets", "64", "--nb", "32"],
+               _subproc_env(root))
     assert "compiled=" in out
+    return root
+
+
+@pytest.mark.parametrize("routine", ["posv", "gesv"])
+def test_two_process_warmup_then_hit(routine, warmed):
+    # process A, the fixture's: warmed the 64-bucket for this routine
+    env = _subproc_env(warmed)
     # process B: first solve must be all hits, zero compiles
     out_b = _parsed(_run(
         [sys.executable, "-c", _SOLVE_SCRIPT, routine, "37"], env))
@@ -452,10 +467,12 @@ def test_two_process_warmup_then_hit(routine, tmp_path):
     assert "OK" in out_d
 
 
-def test_cli_stats_and_clear(tmp_path):
+def test_cli_stats_and_clear(warmed, tmp_path):
+    # a copy of the warmed directory: ``clear`` must not empty the one
+    # the other tests read
+    import shutil
+    shutil.copytree(warmed, tmp_path / "exec")
     env = _subproc_env(tmp_path / "exec")
-    _run([sys.executable, "-m", "slate_tpu.cache", "warmup",
-          "--routines", "posv", "--buckets", "64", "--nb", "32"], env)
     out = _run([sys.executable, "-m", "slate_tpu.cache", "stats",
                 "--json"], env)
     st_json = json.loads(out)

@@ -233,6 +233,42 @@ def test_the_panel_form_is_read_off_the_shape(grid11):
     assert form(tall, 512, 256) == "mixed"             # the first is over
 
 
+def _operand_stub(m, n, nb, devices=1, platform="tpu", mtl=None, ntl=None):
+    """What ``_qr_fast_applies`` reads of an A: no array behind it."""
+    from types import SimpleNamespace as NS
+    mt, nt = -(-m // nb), -(-n // nb)
+    return NS(m=m, n=n, nb=nb, mt=mt, nt=nt,
+              data=NS(shape=(1, 1, mtl or mt, ntl or nt, nb, nb)),
+              grid=NS(size=devices, devices=[NS(platform=platform)]))
+
+
+@pytest.mark.parametrize("A,flag,fast", [
+    (_operand_stub(16384, 1024, 256), "", True),       # the cell
+    (_operand_stub(16384, 512, 256), "", True),        # n = FAST_FROM_N
+    (_operand_stub(16384, 256, 256), "", False),       # one panel
+    (_operand_stub(2048, 2048, 256), "", True),        # square
+    (_operand_stub(1024, 2048, 256), "", False),       # m < n
+    (_operand_stub(16000, 1024, 256), "", False),      # a ragged tile row
+    (_operand_stub(16384, 1000, 256), "", False),      # a ragged column
+    (_operand_stub(16384, 1024, 256, mtl=65), "", False),  # stored padding
+    (_operand_stub(16384, 1024, 256, devices=4), "", False),   # a grid
+    (_operand_stub(16640, 16640, 256), "", False),     # kt = 65: unrolled
+    (_operand_stub(16384, 16384, 256), "", True),      # kt = 64
+    (_operand_stub(16384, 1024, 256, platform="cpu"), "", False),
+    # the variable: off wins over everything, on only over the size and
+    # the platform, not over the shape
+    (_operand_stub(16384, 1024, 256), "0", False),
+    (_operand_stub(128, 64, 32, platform="cpu"), "1", True),
+    (_operand_stub(120, 64, 32, platform="cpu"), "1", False),
+    (_operand_stub(128, 64, 32, devices=8, platform="cpu"), "1", False),
+])
+def test_the_exact_shape_program_is_read_off_the_shape(A, flag, fast,
+                                                       monkeypatch):
+    monkeypatch.setenv("SLATE_QR_FAST", flag)
+    assert qr.FAST_FROM_N == 512
+    assert qr._qr_fast_applies(A) is fast
+
+
 @pytest.mark.parametrize("program", ["one_program", "fast", "unmqr"])
 def test_the_device_programs_carry_their_scopes(program, grid11):
     _, _, A, B = problem(grid11, 1)

@@ -8,18 +8,21 @@ X-moving ``trsm``), against the benchmark's plain numpy solver
 Both forms of the panel run. ``partial``: the row cap as the CPU has it
 (none), the [M, nb] panel gathered to every device, one
 ``lax.linalg.lu`` of it, LAPACK's pivots. ``tournament``: the cap
-lowered to 256 rows, so that the 512-row panel is over it, as the
+lowered to 128 rows, so that the 256-row panel is over it, as the
 chip's 16,384-row panel is over its cap of 10,240 (the rule itself,
 ``getrf._panel_max_rows``, reads the platform and is not changed); the
 panel is then factored where its rows are stored
-(``getrf._panel_stored_rows``): one ``lu`` of each device's 256 rows,
+(``getrf._panel_stored_rows``): one ``lu`` of each device's 128 rows,
 the 2 x 32 winners gathered over p, a last ``lu`` of them.
-``tournament-cap128``: the cap under the local height, so a device's
+``tournament-cap64``: the cap under the local height, so a device's
 rows go through two chunks and a second round before anything crosses.
 
-Geometries: kt = 16 block columns of 32, so eight chunks as on the
-chip; n = 512 on the tile grid and n = 500 with 20 real rows in the
-last tile. One right-hand side (HPL's, the cell's) and eight.
+Geometries: kt = 8 block columns of 32, so four chunk programs of two
+block columns (a first, two inner, a last; the chip's kt = 16 makes
+eight, and each (form, n) compiles every one of its own, so the count
+is kept to what has an inner chunk); n = 256 on the tile
+grid and n = 244 with 20 real rows in the last tile. One right-hand
+side (HPL's, the cell's) and eight.
 
 CPU only (every tier is f32 here): what the chip adds is in PERF.md.
 """
@@ -37,11 +40,11 @@ from slate_tpu.obs import metrics, tracing
 from benchmarks.harness import check, plain_solver
 from tests.test_gesv_ragged import _paths, errors_in_eps
 
-NB, KT = 32, 16
-# the row cap of each form: None as the CPU has it; 256 = a device's
-# rows of the 512-row panel; 128 = two local chunks, a second round
-CAPS = {"partial": None, "tournament": 256, "tournament-cap128": 128}
-GEOMETRIES = [512, 500]
+NB, KT = 32, 8
+# the row cap of each form: None as the CPU has it; 128 = a device's
+# rows of the 256-row panel; 64 = two local chunks, a second round
+CAPS = {"partial": None, "tournament": 128, "tournament-cap64": 64}
+GEOMETRIES = [256, 244]
 FORMS = list(CAPS)
 CASES = [(n, nrhs, form) for form in FORMS for n in GEOMETRIES
          for nrhs in (1, 8)]
@@ -49,22 +52,22 @@ IDS = [f"n{n}-nrhs{nrhs}-{form}" for n, nrhs, form in CASES]
 TIER = {st.Option.TrailingPrecision: "bf16_6x"}
 
 # Backward errors in units of eps = 2^-24, evaluated in float64. Over
-# these cases the program reads 1.4-3.5 (inf) and 1.6-2.2 (Frobenius)
-# with any of the three panels (5.2 and 3.0 at most through Op.Trans,
-# on the stored tournament), the plain f32 solver 1.8-3.0 and 1.8-3.3;
-# the plain solver with its trailing products at bf16_3x reads 69-85
-# and 52-56 (sixteen trailing updates of depth 32). The limit 12 is
-# 2.3x over the largest sound reading and 4.4x under the smallest
-# lowered one.
+# these cases the program reads 1.2-2.6 (inf) and 1.1-1.9 (Frobenius)
+# with any of the three panels (2.4 and 1.8 at most through Op.Trans),
+# the plain f32 solver 1.3-1.9 and 1.1-1.9; the plain solver with its
+# trailing products at bf16_3x reads 55-71 and 38-44 (eight trailing
+# updates of depth 32; 69-85 and 52-56 with sixteen, at n = 512). The
+# limit 12 is 4.6x over the largest sound reading and 3.2x under the
+# smallest lowered one.
 TOL_EPS = 12.0
 # As tests/test_gesv_ragged.py: two answers that each solve a nearby
 # system lie within (the sum of their backward errors) x cond of each
-# other; measured 0.004-0.05 cond*eps here, tournament pivots included
-# (another choice of pivots, the same system); cond is 2.5e5-3.6e5.
+# other; measured 0.025-0.08 cond*eps here, tournament pivots included
+# (another choice of pivots, the same system); cond is 5.2e3-1.1e4.
 TOL_COND_EPS = 1.0
-# ||P A - L U||_F / ||A||_F in eps: 22.6-22.8 with LAPACK's pivots,
-# 26.4-27.7 with the stored tournament's, max |L| 2.0-2.2 (n rounding
-# errors of random sign an entry: sqrt(n) = 22.6). 100 leaves 3.6x; a
+# ||P A - L U||_F / ||A||_F in eps: 12.5-12.6 with LAPACK's pivots,
+# 12.6-14.2 with the stored tournament's, max |L| 1.6-1.9 (n rounding
+# errors of random sign an entry: sqrt(n) = 16). 100 leaves 7x; a
 # wrong permutation or a row of L out of place reads 10^6 and more.
 TOL_FACTOR_EPS = 100.0
 

@@ -1,5 +1,7 @@
 """LU tier-2 tests (reference test/test_getrf.cc / test_gesv.cc:
-‖PA − LU‖ backward error + solve residuals, pivoted and unpivoted)."""
+‖PA − LU‖ backward error + solve residuals, pivoted and unpivoted).
+The one-chip fast path (Pallas panels in interpret mode) is in
+tests/test_getrf_fast.py."""
 
 import numpy as np
 import pytest
@@ -357,6 +359,52 @@ def test_panel_form_is_read_from_the_shape(M, cap, depth, form):
     assert getrf._panel_form(M, cap, depth) == form
 
 
+def test_panel_max_rows_is_the_platforms():
+    """The cap of one ``lax.linalg.lu`` panel: a TPU's scoped vmem, no
+    other platform's (what ``_panel_form`` is handed as ``cap``)."""
+    from slate_tpu.internal import tile_kernels
+    from slate_tpu.linalg import getrf
+    assert getrf._panel_max_rows("tpu") == tile_kernels.LU_PANEL_MAX_ROWS
+    assert getrf._panel_max_rows("tpu") == 10240
+    assert getrf._panel_max_rows("cpu") is None
+    assert getrf._panel_max_rows("gpu") is None
+
+
+def _rhs_stub(p, q, mtl, ntl, nb, n, itemsize=4):
+    """What ``_apply_pivots_kind`` reads of a B: no array behind it."""
+    from types import SimpleNamespace as NS
+    return NS(grid=NS(size=p * q, p=p, q=q), nb=nb, n=n,
+              data=NS(shape=(p, q, mtl, ntl, nb, nb),
+                      dtype=NS(itemsize=itemsize)))
+
+
+@pytest.mark.parametrize("B,order,kind", [
+    # an elimination order is one gather, whatever B is
+    (_rhs_stub(2, 2, 8, 8, 1024, 16384), True, "order_gather"),
+    (_rhs_stub(1, 1, 16, 1, 1024, 8), False, "swap_sim"),   # one chip
+    # the 2x2 cells' B: one and eight columns in one tile column
+    (_rhs_stub(2, 2, 8, 1, 1024, 1), False, "swap_sim"),
+    (_rhs_stub(2, 2, 8, 1, 1024, 8), False, "swap_sim"),
+    (_rhs_stub(2, 2, 8, 2, 1024, 4096), False, "swap_sim"),  # n = 4 nb
+    # getri's scale: over four tile columns, 1 GiB replicated
+    (_rhs_stub(2, 2, 8, 8, 1024, 16384), False, "dist"),
+    # wide in tiles but 1 MiB replicated
+    (_rhs_stub(2, 2, 8, 8, 32, 512), False, "swap_sim"),
+    # 512 tile rows: that many psum rounds lose to one gather of 32 MiB
+    (_rhs_stub(2, 2, 256, 8, 32, 512), False, "swap_sim"),
+    # ... unless the replicated B is 2 GiB
+    (_rhs_stub(2, 2, 256, 32, 128, 8192), False, "dist"),
+    # float64 doubles the bytes: 32 MiB is no longer under the floor,
+    # 16 tile rows are no latency to guard against
+    (_rhs_stub(2, 2, 8, 8, 128, 2048, itemsize=8), False, "dist"),
+    (_rhs_stub(2, 2, 8, 8, 128, 2048), False, "swap_sim"),
+])
+def test_apply_pivots_kind_is_read_off_the_shape(B, order, kind):
+    from slate_tpu.linalg import getrf
+    piv = getrf.PivotOrder(None) if order else object()
+    assert getrf._apply_pivots_kind(B, piv) == kind
+
+
 # sha256 of _getrf_chunk_jit's lowered StableHLO text under the cap
 # (the gathered panel) on the CPU 2x2 at nb = 32, as the commit before
 # the stored form (8d50beb) lowers it: (n, k0, window) -> digest;
@@ -511,286 +559,3 @@ def test_apply_piv_program_has_no_loop_of_kt_nb_trips(cell, p, q, kt, nb,
     trips = sorted(int(n) for n in re.findall(
         r'"known_trip_count":\{"n":"(\d+)"\}', text))
     assert trips == sorted([kt, nb]), trips
-
-
-def test_getrf_fast_path(grid24, monkeypatch):
-    """The no-row-movement fast LU (Pallas panel kernel, pivoting by
-    index — internal/panel_plu.py) through the public API on CPU via
-    interpret mode. Reference parity target: internal_getrf.cc panel +
-    swap semantics, LAPACK ipiv convention."""
-    import jax
-    monkeypatch.setenv("SLATE_LU_FAST", "1")
-    from slate_tpu import Grid
-    g1 = Grid(1, 1, devices=jax.devices()[:1])
-    n, nb = 384, 128
-    a = rand(n, n, seed=9).astype(np.float32)
-    a[0, 0] = 0.0                      # force a nontrivial pivot
-    A = st.Matrix.from_dense(a, nb=nb, grid=g1)
-    LU, piv, info = st.getrf(A)
-    assert int(info) == 0
-    lu = np.asarray(LU.to_dense())
-    l, u = lu_parts(lu)
-    perm = perm_from_piv(piv, n)
-    err = np.linalg.norm(a[perm] - l @ u) / (n * np.linalg.norm(a))
-    assert err < 1e-5
-    assert np.abs(l).max() <= 1.0 + 1e-5   # partial-pivoting bound
-    # solve through getrs with the returned LAPACK-style pivots
-    b = rand(n, 2, seed=10).astype(np.float32)
-    B = st.Matrix.from_dense(b, nb=nb, grid=g1)
-    X = st.getrs(LU, piv, B)
-    x = np.asarray(X.to_dense())
-    r = np.linalg.norm(a @ x - b) / (np.linalg.norm(a) * np.linalg.norm(x))
-    assert r < 1e-4
-
-
-def test_getrf_fast_path_nb256_multigroup(grid24, monkeypatch):
-    """Fast-path coverage at nb=256 (sb=2: the intra-panel ubuf /
-    triangular-solve branch runs) and kt=6 (two compaction groups: the
-    cross-group permutation of a[done:, :done] runs) — the auto-on TPU
-    configuration's structure at test scale (ADVICE r3)."""
-    import jax
-    monkeypatch.setenv("SLATE_LU_FAST", "1")
-    from slate_tpu import Grid
-    g1 = Grid(1, 1, devices=jax.devices()[:1])
-    n, nb = 1536, 256
-    a = rand(n, n, seed=21).astype(np.float32)
-    A = st.Matrix.from_dense(a, nb=nb, grid=g1)
-    LU, piv, info = st.getrf(A)
-    assert int(info) == 0
-    lu = np.asarray(LU.to_dense())
-    l, u = lu_parts(lu)
-    perm = perm_from_piv(piv, n)
-    err = np.linalg.norm(a[perm] - l @ u) / (n * np.linalg.norm(a))
-    assert err < 1e-5
-    assert np.abs(l).max() <= 1.0 + 1e-5
-
-
-def test_plu_subpanel_folded_twin(monkeypatch):
-    """The folded-layout panel kernel ([8, W, h/8] storage, round-4
-    sweep rework) matches the flat [W, h] kernel: same pivots, same
-    active mask, same info; values agree to last-ULP association
-    differences (the strip-end contraction sums 8 folded segments
-    instead of one flat axis — a summation-order change only)."""
-    from slate_tpu.internal import panel_plu as pp
-    rng = np.random.default_rng(5)
-    for h, kill in [(1024, 0), (2048, 3)]:
-        sub = np.asarray(rng.standard_normal((h, pp.W)), np.float32)
-        act = np.ones(h, np.float32)
-        act[:kill] = 0.0               # some rows already eliminated
-        monkeypatch.setenv("SLATE_LU_FOLD", "0")
-        o1, p1, a1, i1 = pp.plu_subpanel(
-            np.asarray(sub), np.asarray(act), interpret=True)
-        monkeypatch.setenv("SLATE_LU_FOLD", "1")
-        o2, p2, a2, i2 = pp.plu_subpanel(
-            np.asarray(sub), np.asarray(act), interpret=True)
-        assert np.array_equal(np.asarray(p1), np.asarray(p2))
-        assert np.array_equal(np.asarray(a1), np.asarray(a2))
-        # cancellation in the 16 compounded strip updates amplifies
-        # the reorder noise on ~0.2% of (small) entries; both kernels
-        # measure identical 8.7e-9 backward error vs L·U reconstruction
-        np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
-                                   rtol=0, atol=1e-4)
-        assert int(i1) == int(i2)
-
-
-def test_getrf_fast_path_folded_group(grid24, monkeypatch):
-    """The full fast path with the folded kernel active (h a multiple
-    of 1024) and the round-4 group-blocked trailing: per-panel updates
-    stay inside the compaction group; the cross-group trailing is one
-    exact-height gemm after a blocked forward substitution builds the
-    U block rows."""
-    import jax
-    monkeypatch.setenv("SLATE_LU_FAST", "1")
-    monkeypatch.setenv("SLATE_LU_FOLD", "1")
-    from slate_tpu.linalg import getrf as getrf_mod
-    monkeypatch.setattr(getrf_mod, "_FAST_GROUP", 1)
-    from slate_tpu import Grid
-    g1 = Grid(1, 1, devices=jax.devices()[:1])
-    n, nb = 2048, 1024       # kt=2, group=1: folded h + the Ug leg
-    a = rand(n, n, seed=33).astype(np.float32)
-    A = st.Matrix.from_dense(a, nb=nb, grid=g1)
-    LU, piv, info = st.getrf(A)
-    assert int(info) == 0
-    lu = np.asarray(LU.to_dense())
-    l, u = lu_parts(lu)
-    perm = perm_from_piv(piv, n)
-    err = np.linalg.norm(a[perm] - l @ u) / (n * np.linalg.norm(a))
-    assert err < 1e-5
-    assert np.abs(l).max() <= 1.0 + 1e-5
-
-
-def test_getrf_fast_path_folded_multipanel_group(grid24, monkeypatch):
-    """Folded panels inside a MULTI-panel compaction group (gsz >= 2,
-    default _FAST_GROUP): the ordg/upend interplay and the p < kk
-    blocked-substitution leg run with the folded kernel active —
-    round 4 only covered the folded branch with _FAST_GROUP
-    monkeypatched to 1 (ADVICE r4)."""
-    import jax
-    monkeypatch.setenv("SLATE_LU_FAST", "1")
-    monkeypatch.setenv("SLATE_LU_FOLD", "1")
-    from slate_tpu.linalg import getrf as getrf_mod
-    assert getrf_mod._FAST_GROUP >= 2     # default grouping, no patch
-    from slate_tpu import Grid
-    g1 = Grid(1, 1, devices=jax.devices()[:1])
-    n, nb = 3072, 1024       # kt=3 → one group, gsz=3; hw % 1024 == 0
-    a = rand(n, n, seed=35).astype(np.float32)
-    A = st.Matrix.from_dense(a, nb=nb, grid=g1)
-    LU, piv, info = st.getrf(A)
-    assert int(info) == 0
-    lu = np.asarray(LU.to_dense())
-    l, u = lu_parts(lu)
-    perm = perm_from_piv(piv, n)
-    err = np.linalg.norm(a[perm] - l @ u) / (n * np.linalg.norm(a))
-    assert err < 1e-5
-    assert np.abs(l).max() <= 1.0 + 1e-5
-
-
-def test_fast_path_compaction_chunked(grid24, monkeypatch):
-    """The column-chunked in-place compaction (the n >
-    _COMPACT_TAKE_MAX_N leg that admits the 45k-64k class) produces
-    the same factorization as the one-shot full-window take: force it
-    at test scale by dropping the threshold and shrinking the chunk
-    so multiple chunks run."""
-    import jax
-    monkeypatch.setenv("SLATE_LU_FAST", "1")
-    from slate_tpu.linalg import getrf as getrf_mod
-    from slate_tpu import Grid
-    g1 = Grid(1, 1, devices=jax.devices()[:1])
-    n, nb = 1024, 256
-    a = rand(n, n, seed=36).astype(np.float32)
-    A = st.Matrix.from_dense(a, nb=nb, grid=g1)
-    LU0, piv0, info0 = st.getrf(A)          # take leg (n <= threshold)
-    # the constants are baked at trace time: drop the jit caches so
-    # the patched values actually retrace (and again after, so traces
-    # with patched constants cannot leak into other tests)
-    from slate_tpu.cache import clear_in_process
-    getrf_mod._getrf_fast_jit.clear_cache()
-    clear_in_process("getrf")
-    monkeypatch.setattr(getrf_mod, "_COMPACT_TAKE_MAX_N", 0)
-    monkeypatch.setattr(getrf_mod, "_COMPACT_CB", 256)
-    try:
-        LU1, piv1, info1 = st.getrf(A)      # chunked leg, 4 chunks
-    finally:
-        getrf_mod._getrf_fast_jit.clear_cache()
-        clear_in_process("getrf")
-    assert np.array_equal(np.asarray(piv0), np.asarray(piv1))
-    np.testing.assert_allclose(np.asarray(LU0.to_dense()),
-                               np.asarray(LU1.to_dense()),
-                               rtol=0, atol=1e-6)
-    assert int(info0) == int(info1) == 0
-
-
-def test_gesv_fast_pivot_order(grid24, monkeypatch):
-    """gesv through the fast path: the solve consumes the elimination
-    order directly (PivotOrder — one gather, no swap simulation) and
-    the returned LAPACK ipiv comes from the host chain conversion
-    (runtime.order_to_ipiv), matching the device simulation exactly."""
-    import jax
-    monkeypatch.setenv("SLATE_LU_FAST", "1")
-    from slate_tpu import Grid
-    from slate_tpu.linalg.getrf import (_getrf_fast_jit, PivotOrder,
-                                        pivot_order_to_ipiv)
-    g1 = Grid(1, 1, devices=jax.devices()[:1])
-    n, nb = 384, 128
-    a = rand(n, n, seed=22).astype(np.float32)
-    A = st.Matrix.from_dense(a, nb=nb, grid=g1)
-    _, piv_dev, _ = _getrf_fast_jit(A, interpret=True, want_ipiv=True)
-    _, order, _ = _getrf_fast_jit(A, interpret=True, want_ipiv=False)
-    assert np.array_equal(np.asarray(pivot_order_to_ipiv(order)),
-                          np.asarray(piv_dev))
-    b = rand(n, 3, seed=23).astype(np.float32)
-    B = st.Matrix.from_dense(b, nb=nb, grid=g1)
-    X, LU, piv, info = st.gesv(A, B)
-    assert int(info) == 0
-    assert np.array_equal(np.asarray(piv), np.asarray(piv_dev))
-    x = np.asarray(X.to_dense())
-    r = np.linalg.norm(a @ x - b) / (np.linalg.norm(a) * np.linalg.norm(x))
-    assert r < 1e-4
-    # transposed solve applies the inverse permutation (scatter side)
-    Xt = st.getrs(LU, PivotOrder(order), B, Op.Trans)
-    xt = np.asarray(Xt.to_dense())
-    rt_ = np.linalg.norm(a.T @ xt - b) / (np.linalg.norm(a)
-                                          * np.linalg.norm(xt))
-    assert rt_ < 1e-4
-
-
-def test_plu_panel_tournament(monkeypatch):
-    """The CALU tournament branch of plu_panel (panel taller than
-    H_MAX), exercised at small n by shrinking H_MAX (ADVICE r3: the
-    production branch for 16k < n <= 32k panels was untested).
-    Checks the factorization invariants the driver relies on:
-    pivot rows carry the LU of the winner rows (L11·U11 = A[piv]) and
-    every still-active row holds multipliers out[r]·U11 = A[r]."""
-    from slate_tpu.internal import panel_plu
-    monkeypatch.setattr(panel_plu, "H_MAX", 256)
-    import jax.numpy as jnp
-    # h/H_MAX = 2 chunks -> 256 winner rows = one final-round subpanel
-    h, w = 512, 128
-    a = rand(h, w, seed=24).astype(np.float32)
-    sub = jnp.asarray(a)
-    act = jnp.ones(h, jnp.float32)
-    out, piv, act_new, info = panel_plu.plu_panel(sub, act,
-                                                  interpret=True)
-    out = np.asarray(out)
-    piv = np.asarray(piv)
-    act_new = np.asarray(act_new)
-    assert int(info) == 0
-    assert len(np.unique(piv)) == w            # w distinct pivot rows
-    assert np.array_equal(np.where(act_new == 0)[0], np.sort(piv))
-    lu_rows = out[piv]                         # [w, w] LU in elim order
-    l11 = np.tril(lu_rows, -1) + np.eye(w, dtype=np.float32)
-    u11 = np.triu(lu_rows)
-    err = (np.linalg.norm(a[piv] - l11 @ u11)
-           / (w * np.linalg.norm(a[piv])))
-    assert err < 1e-5
-    active = act_new > 0
-    rec = out[active] @ u11                    # L·U11 = original rows
-    err2 = (np.linalg.norm(a[active] - rec)
-            / (w * np.linalg.norm(a[active])))
-    assert err2 < 1e-5
-
-
-def test_plu_panel_tournament_zero_pivot(monkeypatch):
-    """CALU singular-panel semantics (ADVICE r3): a column that is
-    entirely zero among the candidates must produce ZERO multipliers
-    in the active rows (matching the in-VMEM kernel and LAPACK), with
-    info counting the zero pivot."""
-    from slate_tpu.internal import panel_plu
-    monkeypatch.setattr(panel_plu, "H_MAX", 256)
-    import jax.numpy as jnp
-    h, w = 512, 128
-    a = rand(h, w, seed=25).astype(np.float32)
-    a[:, 5] = 0.0                              # exactly singular column
-    sub = jnp.asarray(a)
-    out, piv, act_new, info = panel_plu.plu_panel(
-        sub, jnp.ones(h, jnp.float32), interpret=True)
-    assert int(info) >= 1
-    out = np.asarray(out)
-    active = np.asarray(act_new) > 0
-    # the multiplier column of the zero pivot is zero in active rows
-    lu_rows = out[np.asarray(piv)]
-    zcol = np.where(np.diag(np.triu(lu_rows)) == 0.0)[0]
-    assert zcol.size >= 1
-    assert np.all(out[active][:, zcol] == 0.0)
-
-
-def test_getrf_dense_inplace(grid24, monkeypatch):
-    """Dense donated LU entry (the 45k-class path, VERDICT r3 #3) —
-    same pivots/factor as the tiled fast path, no tile conversion."""
-    import jax
-    import jax.numpy as jnp
-    from slate_tpu.linalg import getrf as G
-    monkeypatch.setattr(
-        G, "_getrf_fast_group_jit",
-        lambda a, c, i, g0, gsz, nb, interpret, fold=True, tier=None:
-        G._getrf_fast_group_core(a, c, i, g0, gsz, nb, True, fold, tier))
-    n, nb = 768, 128
-    a = rand(n, n, seed=51).astype(np.float32)
-    lu, piv, info = st.getrf_dense_inplace(jnp.asarray(a), nb=nb)
-    assert int(info) == 0
-    lu = np.asarray(lu)
-    l, u = lu_parts(lu)
-    perm = perm_from_piv(piv, n)
-    err = np.linalg.norm(a[perm] - l @ u) / (n * np.linalg.norm(a))
-    assert err < 1e-5
-    assert np.abs(l).max() <= 1.0 + 1e-5

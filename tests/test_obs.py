@@ -455,3 +455,29 @@ def test_chaos_injections_all_visible_as_obs_counters():
                                  where=rec.where) >= 1, rec
     if fired:
         assert obs.count_total("faults.injected") >= len(fired)
+
+
+def test_collective_stats_sums_a_combined_all_reduce():
+    """XLA combines all-reduces into one op with a tuple result, in an
+    order it picks: the bytes are every member's (the first shape alone
+    gave 2 KiB or 64 KiB here by that order), and the shapes are handed
+    out per op."""
+    from slate_tpu.obs import costmodel
+    hlo = "\n".join([
+        "ENTRY main {",
+        "  %ar.1 = (f32[4,128]{1,0}, f32[128,128]{1,0:T(8,128)}, s32[]) "
+        "all-reduce(%a, %b, %c), channel_id=1, to_apply=%add",
+        "  %ar.2 = f32[16]{0} all-reduce-start(%d), to_apply=%add",
+        "  %ar.3 = f32[16]{0} all-reduce-done(%ar.2)",
+        "  %ag = (f32[8,64]{1,0}, f32[32,64]{1,0}) all-gather-start(%e)",
+        "  %cp = (bf16[64], bf16[64], u32[], u32[]) "
+        "collective-permute-start(%f)",
+        "}"])
+    stats = costmodel.collective_stats(hlo)
+    assert stats["all-reduce"] == {
+        "count": 2, "bytes": (4 * 128 + 128 * 128 + 1 + 16) * 4.0}
+    # an async start lists its operand before its result: the result
+    assert stats["all-gather"] == {"count": 1, "bytes": 32 * 64 * 4.0}
+    assert stats["collective-permute"] == {"count": 1, "bytes": 64 * 2.0}
+    assert costmodel.collective_shapes(hlo)["all-reduce"] == [
+        [(4, (4, 128)), (4, (128, 128)), (4, ())], [(4, (16,))]]

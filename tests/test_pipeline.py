@@ -11,6 +11,8 @@ work but keep per-element operation order, so factors match the
 sequential path and getrf pivots are bit-identical.
 """
 
+import math
+
 import numpy as np
 import pytest
 
@@ -24,6 +26,17 @@ GRIDS = [(1, 8), (2, 4), (4, 2)]
 
 def _grid(p, q):
     return st.Grid(p, q)
+
+
+def _nt_chunked(p, q):
+    """The fewest block columns the drivers run as chunked super-steps
+    on a p x q mesh (``kt >= 2 lcm(p, q)``): two chunks of lcm(p, q)
+    columns, a first and a last, each with its own prologue, body and
+    epilogue (``dag.chunk_plan`` clamps the depth to ``klen - 1``, so a
+    chunk of four columns holds a depth of 3). A chunk is a program of
+    its own, so compile time goes with their number; the inner chunks
+    of a longer matrix are in the depth-3 cases below, at nt = 16."""
+    return 2 * math.lcm(p, q)
 
 
 # ---------------------------------------------------------------------------
@@ -121,7 +134,7 @@ def test_gemm_a_reduce_scatter_epilogue(grid24):
 @pytest.mark.parametrize("p,q", GRIDS)
 def test_potrf_pipelined_matches_sequential(p, q):
     g = _grid(p, q)
-    n, nb = 16 * 8, 8                     # nt=16 ≥ 2·lcm ⇒ chunked
+    n, nb = _nt_chunked(p, q) * 8, 8      # nt = 2·lcm ⇒ chunked
     a = spd(n, np.float64, seed=p * 100 + q)
     A1 = st.HermitianMatrix.from_dense(a, nb=nb, grid=g)
     Lp, ip = st.potrf(A1, opts={Option.PipelineDepth: 1})
@@ -137,7 +150,7 @@ def test_potrf_pipelined_matches_sequential(p, q):
 @pytest.mark.parametrize("p,q", GRIDS)
 def test_getrf_pipelined_matches_sequential_pivots_bitwise(p, q):
     g = _grid(p, q)
-    n, nb = 16 * 8, 8
+    n, nb = _nt_chunked(p, q) * 8, 8
     a = np.asarray(rand(n, n, np.float64, seed=p * 100 + q + 7))
     A1 = st.Matrix.from_dense(a, nb=nb, grid=g)
     LUp, pivp, ip = st.getrf(A1, opts={Option.PipelineDepth: 1})
@@ -171,7 +184,7 @@ def test_potrf_pipelined_one_program_path(grid24):
 def test_potrf_pipelined_matches_sequential_tiers(grid24, tier):
     # every TrailingPrecision tier flows through the pipelined loop's
     # trailing einsum with the same dot kwargs as the sequential one
-    n, nb = 16 * 8, 8
+    n, nb = _nt_chunked(2, 4) * 8, 8
     a = spd(n, np.float32, seed=81).astype(np.float32)
     A1 = st.HermitianMatrix.from_dense(a, nb=nb, grid=grid24)
     Lp, ip = st.potrf(A1, opts={Option.TrailingPrecision: tier,
@@ -192,8 +205,10 @@ def test_potrf_pipelined_matches_sequential_tiers(grid24, tier):
 @pytest.mark.parametrize("depth", [2, 3])
 def test_potrf_depth_k_bitwise(grid24, depth):
     # the plan-driven ring (dag.chunk_plan) reorders scheduling only:
-    # every depth reproduces the sequential factors EXACTLY
-    n, nb = 16 * 8, 8                     # nt=16, chunked supersteps
+    # every depth reproduces the sequential factors EXACTLY. Depth 3
+    # at nt=16 (four chunks: a first, two inner, a last), depth 2 at
+    # the fewest chunked columns
+    n, nb = (16 if depth == 3 else _nt_chunked(2, 4)) * 8, 8
     a = spd(n, np.float64, seed=60 + depth)
     A0 = st.HermitianMatrix.from_dense(a, nb=nb, grid=grid24)
     Ls, is_ = st.potrf(A0, opts={Option.PipelineDepth: 0})
@@ -208,8 +223,9 @@ def test_potrf_depth_k_bitwise(grid24, depth):
 def test_getrf_depth_k_bitwise_pivots(grid24, depth):
     # LU at depth k: the exclusion-window swaps and column advances
     # must reproduce the sequential elimination bit-for-bit — factors
-    # AND the pivot vector
-    n, nb = 16 * 8, 8
+    # AND the pivot vector (depth 3 at nt=16: four chunks, inner ones
+    # included; depth 2 at the fewest chunked columns)
+    n, nb = (16 if depth == 3 else _nt_chunked(2, 4)) * 8, 8
     a = np.asarray(rand(n, n, np.float64, seed=160 + depth))
     A0 = st.Matrix.from_dense(a, nb=nb, grid=grid24)
     LUs, pivs, is_ = st.getrf(A0, opts={Option.PipelineDepth: 0})
@@ -223,7 +239,7 @@ def test_getrf_depth_k_bitwise_pivots(grid24, depth):
 
 @pytest.mark.parametrize("p,q", [(2, 4), (4, 2)])
 def test_getrf_depth2_bitwise_meshes(p, q):
-    n, nb = 16 * 8, 8
+    n, nb = _nt_chunked(p, q) * 8, 8
     g = _grid(p, q)
     a = np.asarray(rand(n, n, np.float64, seed=p * 100 + q + 60))
     A0 = st.Matrix.from_dense(a, nb=nb, grid=g)
