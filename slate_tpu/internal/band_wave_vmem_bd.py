@@ -46,6 +46,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
 from .band_bulge import max_chase
 from .band_wave_vmem import (TAUP, U_SLOTS, _active_chunk_range,
                              _antishear_sum, _ceil8, _col2row, _fw,
@@ -405,10 +406,15 @@ def tb2bd_wave_vmem(ub, interpret=None):
     """VMEM-resident wavefront tb2bd: contract of band_bulge.tb2bd
     (upper band storage ub[d, j] = A[j, j+d], d = 0..band), f32 real
     only; returns (d, e, Vu, tauu, Vv, tauv, phase0) — d/e as numpy
-    (host bdsqr stage), the reflector packs as DEVICE arrays in the
-    shared packed format of linalg/bulge.apply_bulge_reflectors (the
-    fallback wave path returns numpy packs; consumers accept both).
-    Falls back to the XLA wavefront for unsupported shapes/dtypes.
+    (one blocking read, ``tb2bd.bidiagonal``: the bidiagonal solve's
+    merges walk them on the host), the reflector packs as DEVICE
+    arrays in the shared packed format of
+    linalg/bulge.apply_bulge_reflectors (the wave path returns numpy
+    packs; consumers accept both). A shape the kernel does not take
+    (``vmem_applies_bd``) goes to the XLA wavefront: ``linalg/ge2tb
+    .tb2bd`` never sends one (its ladder's ``vmem`` probe is the same
+    gate, and a demotion is counted there), so that branch serves
+    direct callers alone.
     ``interpret=None`` compiles on TPU and interprets elsewhere."""
     ub = np.asarray(ub)
     band = ub.shape[0] - 1
@@ -421,6 +427,7 @@ def tb2bd_wave_vmem(ub, interpret=None):
     phase0 = ub.dtype.type(1)        # real f32: no column-0 phase
     d, e, Vu, tauu, Vv, tauv = _tb2bd_vmem_jit(jnp.asarray(ub), band,
                                                n, interpret=interpret)
-    # d/e host-bound (bdsqr); reflector packs stay device arrays (see
+    # the reflector packs stay device arrays (see
     # band_wave_vmem.hb2st_wave_vmem)
-    return (np.asarray(d), np.asarray(e), Vu, tauu, Vv, tauv, phase0)
+    d, e = obs.sync_read("tb2bd.bidiagonal", jax.device_get, (d, e))
+    return d, e, Vu, tauu, Vv, tauv, phase0

@@ -9,6 +9,9 @@ bidiagonal QR iteration.  TPU redesign:
   distributed stacked-tile array (one jitted gather, O(n·nb) bytes) —
   the analog of he2hbGather (HermitianBandMatrix.hh:316) without the
   round-1 dense materialization.
+* ``chase`` is the one dispatch of both chases (``he2hb.hb2st``,
+  ``ge2tb.tb2bd``) through their ``robust.ladder`` ladders, with the
+  span and the counters a caller reads the rung back from.
 * ``apply_bulge_reflectors`` applies a packed (sweep, chase) reflector
   family (internal/band_bulge.py format) to the rows of a device
   array.  Within a sweep the reflectors span disjoint row blocks, so a
@@ -16,10 +19,17 @@ bidiagonal QR iteration.  TPU redesign:
   sweeps.  This is the whole-matrix analog of the reference's
   per-tile unmtr_hb2st batching, with columns free to be sharded
   across the mesh (row-wise reflectors need no communication).
-* ``bdsqr`` computes the SVD of a real bidiagonal matrix via the
-  Golub-Kahan-tridiagonal eigenproblem (the LAPACK ?bdsvdx approach;
-  scipy exposes no bdsqr/bdsdc): eigenpairs of the (2n)×(2n)
-  perfect-shuffle TGK matrix give σ and interleaved (v, u) vectors.
+* ``bdsdc`` computes the SVD of a real bidiagonal matrix ON THE
+  DEVICE via the Golub-Kahan-tridiagonal eigenproblem (the LAPACK
+  ?bdsvdx form, solved by divide & conquer as ?bdsdc is): eigenpairs
+  of the (2n)×(2n) perfect-shuffle TGK matrix give σ and interleaved
+  (v, u) vectors; ``linalg/stedc.py`` solves it with Z, the secular
+  solves and the merge products on the device, and U_B, V_B are cut
+  out of Z there.  The host keeps the O(n) scalar work of a merge.
+* ``bdsqr`` is the same form on the host in float64 (scipy's
+  ``eigh_tridiagonal``): the values-only solve, the branch that
+  repairs a rank-deficient B (σ = 0: the ± spaces collide), and the
+  float64 answer the tests hold ``bdsdc`` to.
 """
 
 from __future__ import annotations
@@ -108,6 +118,46 @@ def gather_band_upper(A) -> np.ndarray:
         if cross.any():
             ub[d, js[cross]] = Ts[ks[cross], cs[cross], cs[cross] + d - nb]
     return ub
+
+
+# ---------------------------------------------------------------------------
+# The chase itself: one dispatch for hb2st and tb2bd
+# ---------------------------------------------------------------------------
+
+def chase(ladder, env: str, band):
+    """Run the band bulge chase ``ladder`` (``robust.ladder``'s
+    ``hb2st_ladder()`` or ``tb2bd_ladder()``) on the compact ``band``
+    and report it under the ladder's name: the span ``<name>``
+    (``routine``, ``n``, ``b``; at its end ``rung`` and, where the
+    ``vmem`` rung answered, ``shear``), the counter
+    ``<name>.backend{rung}`` (the rung whose answer was used) and
+    ``<name>.demotion{from,to}`` for every rung stepped past on the way
+    (a demotion is silent to the caller and the answer is still right,
+    so it is counted where a caller of heev / gesvd can read it; the
+    log is ``robust.ladder.demotion_log()``). The environment variable
+    ``env`` = ``vmem|wave|native|numpy`` pins the STARTING rung only: a
+    rung that cannot take the problem (failed probe, raise, non-finite
+    output) still demotes to the next one."""
+    import os
+    from ..robust.ladder import demotion_log
+    band = np.asarray(band)
+    choice = os.environ.get(env, "")
+    start = (choice if choice in ("vmem", "wave", "native", "numpy")
+             else None)
+    logged = len(demotion_log())
+    with trace.block(ladder.name, routine=ladder.name,
+                     n=band.shape[1], b=band.shape[0] - 1) as span:
+        out = ladder.run(band, start=start)
+        span.label(rung=ladder.last_rung)
+        if ladder.last_rung == "vmem":
+            from ..internal.band_wave_vmem import chase_shear_form
+            span.label(shear=chase_shear_form(band.shape[0] - 1))
+    obs.count(ladder.name + ".backend", 1, rung=ladder.last_rung)
+    for d in demotion_log()[logged:]:
+        if d.ladder == ladder.name:
+            obs.count(ladder.name + ".demotion", 1, to=d.to_rung,
+                      **{"from": d.from_rung})
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -216,6 +266,69 @@ def apply_bulge_reflectors(V, tau, Z, band, forward=False, conj_tau=True,
 # Bidiagonal SVD (reference src/bdsqr.cc slot)
 # ---------------------------------------------------------------------------
 
+def golub_kahan(d, e):
+    """Off-diagonal (d₁, e₁, d₂, e₂, …, dₙ) of the Golub-Kahan form
+    T = Π·[0 Bᵀ; B 0]·Πᵀ of the upper bidiagonal B = (d, e): symmetric
+    tridiagonal of order 2n with zero diagonal, eigenpairs (±σⱼ, zⱼ),
+    zⱼ = (v₁ⱼ, u₁ⱼ, v₂ⱼ, u₂ⱼ, …)/√2."""
+    d = np.asarray(d, np.float64)
+    off = np.zeros(2 * d.shape[0] - 1)
+    off[0::2] = d
+    off[1::2] = np.asarray(e, np.float64)
+    return off
+
+
+@partial(cached_jit, static_argnames=("n",))
+def _gk_halves_jit(Z, n):
+    """U_B, V_B out of the Golub-Kahan eigenvectors Z [2n, 2n]
+    (ascending): the n columns of the positive half, reversed to σ
+    descending, rows 1::2 and 0::2, each column made unit. Also the
+    norms the halves had (1/√2 each where +σ and −σ are apart; a
+    σ = 0 pair shares a plane and its halves need not be)."""
+    # reversed first, then split by a reshape: the strided form
+    # Z[0::2, :n - 1:-1] of the same read 3.7 s a call at 2n = 16384
+    # on the chip where this one is under 0.05 (PERF.md section 6, PR 48)
+    pos = Z[:, n:][:, ::-1].reshape(n, 2, n)
+    V, U = pos[:, 0, :], pos[:, 1, :]
+    nu = jnp.sqrt(2.0) * jnp.linalg.norm(U, axis=0)
+    nv = jnp.sqrt(2.0) * jnp.linalg.norm(V, axis=0)
+    root2 = jnp.sqrt(jnp.asarray(2.0, Z.dtype))
+    U = U * (root2 / jnp.where(nu > 0.5, nu, 1).astype(Z.dtype))
+    V = V * (root2 / jnp.where(nv > 0.5, nv, 1).astype(Z.dtype))
+    return U, V, jnp.stack([nu, nv])
+
+
+def bdsdc(d, e, grid, dtype=None):
+    """SVD B = U_B·diag(σ)·V_Bᵀ of the real upper bidiagonal (d, e)
+    with everything O(n²) and up on the device (the reference slot is
+    src/bdsqr.cc; the method is LAPACK ?bdsdc's, a divide & conquer,
+    on ?bdsvdx's Golub-Kahan form): ``stedc`` of the order-2n
+    tridiagonal with Z on ``grid`` in ``dtype``, then U_B and V_B cut
+    out of Z by one program. The host holds d, e, σ and the O(k)
+    vectors of a merge.
+
+    Returns (σ [n] descending float64 on the host, U_B [n, n], V_B
+    [n, n] device arrays), or None when the Golub-Kahan form does not
+    answer in ``dtype``: the u and v halves of an eigenvector are
+    each 1/√2 long while +σ and −σ are apart, and drift from that by
+    about eps·‖B‖/σ as a σ nears 0, where the pair shares a plane and
+    the columns of U_B (and of V_B) lose their orthogonality at the
+    same rate. Past √eps (half the working digits; B rank deficient
+    to working precision is the limit case) the caller takes
+    ``bdsqr``, which repairs it on the host in float64. The 2n norms
+    are the one read (``gesvd.values``)."""
+    from .stedc import stedc
+    n = np.shape(d)[0]
+    lam, Z = stedc(np.zeros(2 * n), golub_kahan(d, e), True, grid=grid,
+                   dtype=dtype)
+    U, V, norms = _gk_halves_jit(jnp.asarray(Z), n=n)
+    norms = obs.sync_read("gesvd.values", np.asarray, norms)
+    drift = np.abs(norms - 1.0).max() if n else 0.0
+    if not drift <= np.sqrt(np.finfo(norms.dtype).eps):
+        return None
+    return np.maximum(lam[n:][::-1], 0.0), U, V
+
+
 def bdsqr(d, e, want_uv: bool = False):
     """SVD of the real upper bidiagonal B = diag(d) + superdiag(e).
 
@@ -240,9 +353,7 @@ def bdsqr(d, e, want_uv: bool = False):
         return s, np.ones((1, 1)) * sign, np.ones((1, 1))
     # TGK: 2n×2n, zero diagonal, off-diag [d0, e0, d1, e1, …, d_{n-1}];
     # eigenvector z for +σ interleaves z = (v0, u0, v1, u1, …)/√2.
-    off = np.zeros(2 * n - 1)
-    off[0::2] = d
-    off[1::2] = e
+    off = golub_kahan(d, e)
     diag = np.zeros(2 * n)
     if not want_uv:
         w = eigvalsh_tridiagonal(diag, off)

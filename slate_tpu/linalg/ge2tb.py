@@ -20,11 +20,25 @@ upper-triangular, superdiagonal blocks lower-triangular) with the QR
 reflectors stored below the diagonal and the LQ reflectors right of
 the superdiagonal — LAPACK gebrd's in-place convention at block scale.
 
-Stage 2 (band → bidiagonal → singular values) runs on the host over
-the gathered (nb+1)-wide band — the reference's tb2bd/bdsqr stages are
-serial on rank 0 as well (SURVEY §3.5); scipy lacks gbbrd/bdsqr so the
-host solve is a dense SVD of the *band* matrix, whose O(n³) constant
-is small next to the distributed O(mn²) reduction this stage offloads.
+Stage 2 (band → bidiagonal) is ``tb2bd``: the band is gathered to the
+host (2·nt tiles; the reference gathers it to rank 0 and chases there,
+SURVEY §3.5) and chased by the ``robust.ladder`` rung that takes it, on
+a TPU in f32 the VMEM-resident Pallas kernel
+(``internal/band_wave_vmem_bd.py``). Stage 3 is the SVD of the
+bidiagonal: with vectors ``bulge.bdsdc``, the divide & conquer of
+``linalg/stedc.py`` on the Golub-Kahan form of order 2n with Z, the
+secular solves and the merge products on the device and U_B, V_B cut
+out of Z there; ``bulge.bdsqr`` on the host for values alone and for a
+B that is rank deficient to working precision. The two back-transforms
+a side (``unmbr_tb2bd`` = ``bulge.apply_bulge_reflectors``,
+``unmbr_ge2tb_u`` / ``_v``) run on the device, the second distributed,
+on operands made where they lie. ``gesvd_two_stage`` strings them
+together under ``svd.gesvd``'s root span ``slate.gesvd``, as the spans
+``gesvd.stage1`` (``ge2tb``), ``gesvd.gather``, ``gesvd.stage2``
+(``tb2bd``), ``gesvd.bidiag``, ``gesvd.back.tb2bd.u`` / ``.v`` and
+``gesvd.back.ge2tb.u`` / ``.v`` (docs/observability.md).
+``Option.TrailingPrecision`` reaches the trailing products of ``ge2tb``
+alone.
 """
 
 from __future__ import annotations
@@ -43,8 +57,10 @@ from ..matrix import Matrix, cdiv
 from ..types import Op
 from ..errors import slate_error_if
 from ..internal import comm, masks
+from ..internal.precision import resolve_tier, trailing_dot_kwargs
 from ..internal.tile_kernels import panel_qr_factor, extract_v, larft
 from ..utils import trace
+from .. import obs
 
 
 def ge2tb(A: Matrix, opts=None):
@@ -53,13 +69,18 @@ def ge2tb(A: Matrix, opts=None):
     sets in place; Tq [nt, nb, nb], Tl [nt-1, nb, nb]."""
     slate_error_if(A.m < A.n, "ge2tb v1 expects m >= n")
     A = A.materialize()
-    with trace.block("ge2tb"):
-        data, Tq, Tl = _ge2tb_jit(A)
+    tier = resolve_tier(opts)
+    with trace.block("ge2tb", routine="ge2tb", m=A.m, n=A.n, nb=A.nb,
+                     precision=tier):
+        data, Tq, Tl = _ge2tb_jit(A, tier)
     return A._replace(data=data), Tq, Tl
 
 
-@cached_jit
-def _ge2tb_jit(A):
+@partial(cached_jit, static_argnames=("tier",))
+def _ge2tb_jit(A, tier=None):
+    """``tier`` (``Option.TrailingPrecision``) reaches the trailing
+    products alone (``qr_trailing``, ``lq_trailing``); the panels and
+    their T factors stay at the package default."""
     g = A.grid
     p, q, nb = g.p, g.q, A.nb
     m, n = A.m, A.n
@@ -70,6 +91,7 @@ def _ge2tb_jit(A):
     Nc = nt_p * nb            # padded col space
     kq = nt                   # QR panels
     kl = max(nt - 1, 0)       # LQ panels
+    pk = trailing_dot_kwargs(tier, A.dtype)
 
     def body(a):
         a = a[0, 0]
@@ -80,31 +102,35 @@ def _ge2tb_jit(A):
 
         def qr_step(k, a, Ts):
             """Left reduction of column k (reference ge2tb QR half)."""
-            pcol = lax.dynamic_index_in_dim(a, k // q, axis=1,
-                                            keepdims=False)
-            full = comm.allgather_panel_rows(pcol, p, k % q)
-            panel2d = full.reshape(Nr, nb)
-            panel2d, taus = panel_qr_factor(panel2d, k * nb, m)
-            V = extract_v(panel2d, k * nb, m)
-            T = larft(V, taus)
-            Ts = Ts.at[k].set(T)
-            ptiles = panel2d.reshape(mt_p, nb, nb)
-            newcol = jnp.take(ptiles, gi, axis=0)
-            a = jnp.where(
-                c == k % q,
-                lax.dynamic_update_index_in_dim(a, newcol, k // q, axis=1),
-                a)
-            vt = V.reshape(mt_p, nb, nb)
-            vloc = jnp.take(vt, gi, axis=0)
-            right = (gj > k) & (gj < nt)
-            amask = jnp.where(right[None, :, None, None], a,
-                              jnp.zeros_like(a))
-            w = jnp.einsum("aiv,abij->bvj", jnp.conj(vloc), amask)
-            w = comm.psum_rows(w)
-            tw = jnp.einsum("uv,bvj->buj", jnp.conj(T).T, w)
-            upd = jnp.einsum("aiv,bvj->abij", vloc, tw)
-            a = a - jnp.where(right[None, :, None, None], upd,
-                              jnp.zeros_like(upd))
+            with jax.named_scope("qr_panel"):
+                pcol = lax.dynamic_index_in_dim(a, k // q, axis=1,
+                                                keepdims=False)
+                full = comm.allgather_panel_rows(pcol, p, k % q)
+                panel2d = full.reshape(Nr, nb)
+                panel2d, taus = panel_qr_factor(panel2d, k * nb, m)
+                V = extract_v(panel2d, k * nb, m)
+                T = larft(V, taus)
+                Ts = Ts.at[k].set(T)
+                ptiles = panel2d.reshape(mt_p, nb, nb)
+                newcol = jnp.take(ptiles, gi, axis=0)
+                a = jnp.where(
+                    c == k % q,
+                    lax.dynamic_update_index_in_dim(a, newcol, k // q,
+                                                    axis=1),
+                    a)
+            with jax.named_scope("qr_trailing"):
+                vt = V.reshape(mt_p, nb, nb)
+                vloc = jnp.take(vt, gi, axis=0)
+                right = (gj > k) & (gj < nt)
+                amask = jnp.where(right[None, :, None, None], a,
+                                  jnp.zeros_like(a))
+                w = jnp.einsum("aiv,abij->bvj", jnp.conj(vloc), amask,
+                               **pk)
+                w = comm.psum_rows(w)
+                tw = jnp.einsum("uv,bvj->buj", jnp.conj(T).T, w, **pk)
+                upd = jnp.einsum("aiv,bvj->abij", vloc, tw, **pk)
+                a = a - jnp.where(right[None, :, None, None], upd,
+                                  jnp.zeros_like(upd))
             return a, Ts
 
         def lq_step(k, a, Ts):
@@ -112,39 +138,45 @@ def _ge2tb_jit(A):
             Row panel tiles (k, j), j ≥ k+1, conj-transposed into a
             column panel over the col-index space, then geqrf."""
             start = (k + 1) * nb
-            prow = lax.dynamic_index_in_dim(a, k // p, axis=0,
-                                            keepdims=False)  # [ntl,nb,nb]
-            # gather along mesh cols; mask to owner row
-            prow = jnp.where(r == k % p, prow, jnp.zeros_like(prow))
-            prow = comm.psum_rows(prow)
-            fullrow = comm.allgather_cyclic(prow, q, AXIS_Q)  # [nt_p,nb,nb]
-            # conj-transpose the row block into column-panel form:
-            # element (row i of panel) = global col index
-            panel2d = jnp.conj(fullrow.transpose(0, 2, 1)).reshape(Nc, nb)
-            panel2d, taus = panel_qr_factor(panel2d, start, n)
-            V = extract_v(panel2d, start, n)         # [Nc, nb]
-            T = larft(V, taus)
-            Ts = Ts.at[k].set(T)
-            # write factored panel back into row k (conj-transpose back)
-            ptiles = jnp.conj(panel2d.reshape(nt_p, nb, nb)
-                              .transpose(0, 2, 1))  # [nt_p, nb, nb]
-            newrow = jnp.take(ptiles, gj, axis=0)
-            a = jnp.where(
-                r == k % p,
-                lax.dynamic_update_index_in_dim(a, newrow, k // p, axis=0),
-                a)
+            with jax.named_scope("lq_panel"):
+                prow = lax.dynamic_index_in_dim(
+                    a, k // p, axis=0, keepdims=False)   # [ntl,nb,nb]
+                # gather along mesh cols; mask to owner row
+                prow = jnp.where(r == k % p, prow, jnp.zeros_like(prow))
+                prow = comm.psum_rows(prow)
+                fullrow = comm.allgather_cyclic(prow, q, AXIS_Q)
+                # conj-transpose the row block [nt_p,nb,nb] into
+                # column-panel form: row i of the panel = global col i
+                panel2d = jnp.conj(
+                    fullrow.transpose(0, 2, 1)).reshape(Nc, nb)
+                panel2d, taus = panel_qr_factor(panel2d, start, n)
+                V = extract_v(panel2d, start, n)         # [Nc, nb]
+                T = larft(V, taus)
+                Ts = Ts.at[k].set(T)
+                # write the factored panel back into row k
+                # (conj-transpose back)
+                ptiles = jnp.conj(panel2d.reshape(nt_p, nb, nb)
+                                  .transpose(0, 2, 1))  # [nt_p, nb, nb]
+                newrow = jnp.take(ptiles, gj, axis=0)
+                a = jnp.where(
+                    r == k % p,
+                    lax.dynamic_update_index_in_dim(a, newrow, k // p,
+                                                    axis=0),
+                    a)
             # right update of trailing rows: A ← A − (A·V)·T·Vᴴ
-            vt = V.reshape(nt_p, nb, nb)
-            vcols = jnp.take(vt, gj, axis=0)         # [ntl, nb, nb]
-            below = (gi > k) & (gi < mt)
-            amask = jnp.where(below[:, None, None, None], a,
-                              jnp.zeros_like(a))
-            w2 = jnp.einsum("abij,bjv->aiv", amask, vcols)
-            w2 = comm.psum_cols(w2)                # [mtl, nb, nb] rows
-            w2t = jnp.einsum("aiv,vu->aiu", w2, T)
-            upd = jnp.einsum("aiu,bju->abij", w2t, jnp.conj(vcols))
-            a = a - jnp.where(below[:, None, None, None], upd,
-                              jnp.zeros_like(upd))
+            with jax.named_scope("lq_trailing"):
+                vt = V.reshape(nt_p, nb, nb)
+                vcols = jnp.take(vt, gj, axis=0)         # [ntl, nb, nb]
+                below = (gi > k) & (gi < mt)
+                amask = jnp.where(below[:, None, None, None], a,
+                                  jnp.zeros_like(a))
+                w2 = jnp.einsum("abij,bjv->aiv", amask, vcols, **pk)
+                w2 = comm.psum_cols(w2)            # [mtl, nb, nb] rows
+                w2t = jnp.einsum("aiv,vu->aiu", w2, T, **pk)
+                upd = jnp.einsum("aiu,bju->abij", w2t, jnp.conj(vcols),
+                                 **pk)
+                a = a - jnp.where(below[:, None, None, None], upd,
+                                  jnp.zeros_like(upd))
             return a, Ts
 
         def step(k, carry):
@@ -181,56 +213,36 @@ def tb2bd(ub: np.ndarray):
     chasing, O(n²·nb) work — never materializing a dense n×n matrix
     (reference src/tb2bd.cc:40-140 + internal_gebr.cc task types).
 
-    Backend dispatch, mirroring hb2st (the reference pipelines this
-    stage with an OpenMP taskloop, tb2bd.cc:272-294; here the same
-    (sweep, chase) DAG runs ON DEVICE as batched anti-diagonal waves):
+    Backend dispatch, as hb2st's (the reference pipelines this stage
+    with an OpenMP taskloop, tb2bd.cc:272-294; here the same (sweep,
+    chase) DAG runs ON DEVICE as batched anti-diagonal waves), through
+    ``robust.ladder.tb2bd_ladder``:
 
     * ``vmem`` — VMEM-resident Pallas chaser (internal/
       band_wave_vmem_bd.py): the whole ribbon stays in VMEM across
       the wave grid (the XLA wave's per-wave cost is HBM segment
       traffic — BASELINE.md r4). Auto-selected on TPU when the shape
-      qualifies (f32, band a power of two in [8, 256], ribbon fits
-      VMEM); falls back to ``wave`` otherwise.
+      passes the twin's own gate ``vmem_applies_bd`` (f32, band a
+      power of two in [8, 256], ribbon and output windows fit VMEM).
     * ``wave`` — device wavefront (internal/band_bulge_wave_bd.py),
-      auto on accelerators at useful sizes;
+      auto on accelerators at n >= 1024;
     * ``native`` — single-thread C++ chase (host), default on CPU;
     * ``numpy`` — pure-numpy twin (tests).
 
-    Override with ``SLATE_TB2BD=vmem|wave|native|numpy``.
+    ``SLATE_TB2BD=vmem|wave|native|numpy`` pins the STARTING rung; a
+    rung that cannot take the problem (failed probe, raise, non-finite
+    output) still demotes to the next one, logged in
+    ``robust.ladder.demotion_log()`` and counted as
+    ``tb2bd.demotion{from,to}``; ``tb2bd.backend{rung}`` counts the
+    rung whose answer was used.
 
     Returns (d, e, Vu, tauu, Vv, tauv, phase0): bidiagonal plus the
     packed U-side and V-side reflectors and the column-0 phase;
     A_band = U2·B·V2ᴴ·diag(conj(phase0), 1, …) with U2/V2 the
     H_1ᴴ·…·H_Kᴴ products (apply with bulge.apply_bulge_reflectors)."""
-    import os
-    import jax
-    ub = np.asarray(ub)
-    b, n = ub.shape[0] - 1, ub.shape[1]
-    choice = os.environ.get("SLATE_TB2BD", "")
-    if choice not in ("vmem", "wave", "native", "numpy"):
-        try:
-            accel = jax.default_backend() not in ("cpu",)
-        except Exception:  # pragma: no cover
-            accel = False
-        choice = "wave" if (accel and n >= 1024 and b >= 2) else "native"
-        if choice == "wave":
-            # the bd chaser carries its own footprint gate: its four
-            # per-step output windows are not in the eig twin's model
-            from ..internal.band_wave_vmem_bd import vmem_applies_bd
-            if (jax.default_backend() == "tpu"
-                    and vmem_applies_bd(n, b, ub.dtype)):
-                choice = "vmem"
-    if choice == "vmem" and b >= 2 and n >= 2:
-        from ..internal.band_wave_vmem_bd import tb2bd_wave_vmem
-        return tb2bd_wave_vmem(ub)
-    if choice == "wave" and b >= 2 and n >= 2:
-        from ..internal.band_bulge_wave_bd import tb2bd_wave
-        return tb2bd_wave(ub)
-    if choice == "numpy":
-        from ..internal import band_bulge
-        return band_bulge.tb2bd(ub)
-    from ..internal import band_bulge_native
-    return band_bulge_native.tb2bd(ub)
+    from ..robust.ladder import tb2bd_ladder
+    from .bulge import chase
+    return chase(tb2bd_ladder(), "SLATE_TB2BD", ub)
 
 
 def unmbr_ge2tb_u(trans: Op, Aout: Matrix, Tq, C: Matrix, opts=None):
@@ -245,7 +257,7 @@ def unmbr_ge2tb_v(trans: Op, Aout: Matrix, Tl, C: Matrix, opts=None):
     """Apply V-side reflectors (LQ panels) to C:
     NoTrans: C ← Qr_1…Qr_K·C (reverse order), Qr_k = I − V_k·T_k·V_kᴴ
     with V_k gathered from block row k of Aout."""
-    with trace.block("unmbr_ge2tb_v")                :
+    with trace.block("unmbr_ge2tb_v"):
         return _unmbr_v_jit(Aout, Tl, C, trans == Op.NoTrans)
 
 
@@ -302,12 +314,29 @@ def _unmbr_v_jit(AV, T, C, notrans):
     return C._replace(data=data)
 
 
-def gesvd_two_stage(A: Matrix, opts=None, want_u=False, want_vt=False):
-    """Two-stage SVD (reference gesvd.cc:77-102 pipeline):
-    ge2tb (distributed) → tb2bd bulge chasing (host, band-limited) →
-    bdsqr bidiagonal SVD → back-transforms unmbr_tb2bd (device,
-    column-sharded) and unmbr_ge2tb (distributed)."""
-    from .bulge import apply_bulge_reflectors, bdsqr
+@partial(cached_jit, static_argnames=("rows",))
+def _rows_padded_jit(x, rows):
+    """[x; 0] with ``rows`` rows, made where x lies."""
+    return jnp.zeros((rows, x.shape[1]), x.dtype).at[:x.shape[0]].set(x)
+
+
+def gesvd_two_stage(A: Matrix, opts=None, want_u=False, want_vt=False,
+                    root=None):
+    """Two-stage SVD of a tall or square A (reference gesvd.cc:77-102
+    pipeline), A = Q₁·[B_b; 0]·P₁ᴴ, B_b = U₂·B·V₂ᴴ, B = U_B·Σ·V_Bᵀ:
+    ge2tb (distributed) → band gather (2·nt tiles) → tb2bd bulge
+    chasing (the ``robust.ladder`` rung that takes the band) → the
+    bidiagonal SVD (with vectors ``bulge.bdsdc``: everything O(n²) and
+    up on the device; ``bulge.bdsqr`` on the host for values alone and
+    for a rank-deficient B) → back-transforms unmbr_tb2bd (device,
+    column-sharded) and unmbr_ge2tb (distributed): U = Q₁·[U₂·U_B; 0],
+    V = P₁·V₂·V_B.  Inside a call with vectors only the band, (d, e)
+    and the O(n) reads of the merges cross to the host.  ``root`` is
+    ``slate.gesvd``'s span (``svd.gesvd``), labelled here with what
+    the pipeline chose: ``band``, ``chase_backend``, ``bidiag``."""
+    from .bulge import apply_bulge_reflectors, bdsdc, bdsqr
+    from ..matrix import conj_transpose
+    from ..robust.ladder import tb2bd_ladder
     from ..types import Option, get_option
     # re-block to the two-stage band width (same trade as
     # he2hb.heev_two_stage: stage-2 chase + back-transform are
@@ -325,34 +354,66 @@ def gesvd_two_stage(A: Matrix, opts=None, want_u=False, want_vt=False):
             A = A.retile(band_nb)
         else:
             A = Matrix.from_dense(A.to_dense(), nb=band_nb, grid=A.grid)
-    with trace.block("gesvd_2stage"):
-        m, n = A.m, A.n
+    m, n, nb, grid, dtype = A.m, A.n, A.nb, A.grid, A.dtype
+    rdt = np.zeros(1, dtype).real.dtype
+    with trace.block("gesvd.stage1", phase="ge2tb", m=m, n=n):
         Aout, Tq, Tl = ge2tb(A, opts)
+    del A       # the re-tiled copy: nothing below reads it
+    with trace.block("gesvd.gather", phase="band_gather", n=n):
         ub = ge2tb_gather(Aout)
+    with trace.block("gesvd.stage2", phase="tb2bd", n=n):
         d, e, Vu, tauu, Vv, tauv, phase0 = tb2bd(ub)
-        rdt = np.zeros(1, A.dtype).real.dtype
-        if not (want_u or want_vt):
-            return np.asarray(bdsqr(d, e)).astype(rdt), None, None
-        s, Ubd, VbdT = bdsqr(d, e, want_uv=True)
-        s = s.astype(rdt)
-        U = VT = None
-        if want_u:
-            # U = Q1u · [U2·Ubd ; 0]  (stage-2 then stage-1 left sets)
-            u2 = apply_bulge_reflectors(
-                Vu, tauu, np.ascontiguousarray(Ubd).astype(A.dtype),
-                A.nb, grid=A.grid)
-            ub_full = np.zeros((m, n), A.dtype)
-            ub_full[:n] = np.asarray(u2)
-            Ub = Matrix.from_dense(ub_full, nb=A.nb, grid=A.grid)
+
+    def chose(route):
+        obs.count("gesvd.bidiag", 1, route=route)
+        if root is not None:
+            root.label(band=nb, bidiag=route,
+                       chase_backend=tb2bd_ladder().last_rung)
+
+    if not (want_u or want_vt):
+        with trace.block("gesvd.bidiag", phase="bdsqr_values", n=n):
+            s = np.asarray(bdsqr(d, e)).astype(rdt)
+        chose("host")
+        return s, None, None
+    with trace.block("gesvd.bidiag", phase="bdsdc", n=n):
+        route, solved = "gk_stedc", bdsdc(d, e, grid, rdt)
+        if solved is None:
+            # σ = 0 to working precision: the host branch completes
+            # the null spaces (and is the one O(n²) host solve left)
+            route = "host"
+            s, Ubd, VbdT = bdsqr(d, e, want_uv=True)
+            solved = s, jnp.asarray(Ubd, rdt), jnp.asarray(VbdT.T, rdt)
+        s, Ubd, Vbd = solved
+        del solved
+    chose(route)
+    # each n x n or m x n intermediate is dropped where it was last
+    # read: the call's peak is the top merge's, not the sum of both sides
+    U = VT = None
+    if want_u:
+        # U = Q1u · [U2·Ubd ; 0]  (stage-2 then stage-1 left sets)
+        with trace.block("gesvd.back.tb2bd.u", phase="unmbr_tb2bd", n=n):
+            u2 = apply_bulge_reflectors(Vu, tauu, Ubd.astype(dtype),
+                                        nb, grid=grid)
+        with trace.block("gesvd.back.ge2tb.u", phase="unmbr_ge2tb",
+                         m=m, n=n):
+            Ub = Matrix.from_dense(_rows_padded_jit(u2, rows=m),
+                                   nb=nb, grid=grid)
+            del u2
             U = unmbr_ge2tb_u(Op.NoTrans, Aout, Tq, Ub, opts)
-        if want_vt:
-            # V = Q1v · diag(phase0,1,…)·(V2·Vbd)  →  VT = Vᴴ
-            vb = np.conj(VbdT.T).astype(A.dtype)
-            v2 = apply_bulge_reflectors(
-                Vv, tauv, np.ascontiguousarray(vb), A.nb, grid=A.grid)
-            v2 = v2.at[0].multiply(phase0)
-            Vb = Matrix.from_dense(v2, nb=A.nb, grid=A.grid)
-            Vm = _unmbr_v_jit(Aout, Tl, Vb, True)
-            from ..matrix import conj_transpose
+            del Ub
+    del Vu, tauu, Ubd
+    if want_vt:
+        # V = Q1v · diag(phase0,1,…)·(V2·Vbd)  →  VT = Vᴴ
+        with trace.block("gesvd.back.tb2bd.v", phase="unmbr_tb2bd", n=n):
+            v2 = apply_bulge_reflectors(Vv, tauv, Vbd.astype(dtype),
+                                        nb, grid=grid)
+            if np.iscomplexobj(phase0):
+                v2 = v2.at[0].multiply(phase0)
+        with trace.block("gesvd.back.ge2tb.v", phase="unmbr_ge2tb",
+                         n=n):
+            Vb = Matrix.from_dense(v2, nb=nb, grid=grid)
+            del v2
+            Vm = unmbr_ge2tb_v(Op.NoTrans, Aout, Tl, Vb, opts)
+            del Vb
             VT = conj_transpose(Vm).materialize()
-        return np.asarray(s), U, VT
+    return np.asarray(s).astype(rdt), U, VT

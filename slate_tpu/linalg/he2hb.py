@@ -53,7 +53,6 @@ from ..internal import comm, masks
 from ..internal.precision import resolve_tier, trailing_dot_kwargs
 from ..internal.tile_kernels import panel_qr_factor, extract_v, larft
 from ..utils import trace
-from .. import obs
 
 
 def he2hb(A: HermitianMatrix, opts=None):
@@ -272,30 +271,9 @@ def hb2st(band: np.ndarray):
     non-finite output) still demotes to the next one, with the
     demotion logged in ``robust.ladder.demotion_log()``.
     """
-    import os
-    from ..robust.ladder import demotion_log, hb2st_ladder
-    band = np.asarray(band)
-    choice = os.environ.get("SLATE_HB2ST", "")
-    start = (choice if choice in ("vmem", "wave", "native", "numpy")
-             else None)
-    ladder = hb2st_ladder()
-    logged = len(demotion_log())
-    with trace.block("hb2st", routine="hb2st",
-                     n=band.shape[1], b=band.shape[0] - 1) as span:
-        out = ladder.run(band, start=start)
-        span.label(rung=ladder.last_rung)
-        if ladder.last_rung == "vmem":
-            from ..internal.band_wave_vmem import chase_shear_form
-            span.label(shear=chase_shear_form(band.shape[0] - 1))
-    # which rung answered, and every rung stepped past on the way: a
-    # demotion is silent to the caller (vmem -> wave is 2.4x on this
-    # stage), so it is counted where a caller of heev can read it
-    obs.count("hb2st.backend", 1, rung=ladder.last_rung)
-    for d in demotion_log()[logged:]:
-        if d.ladder == ladder.name:
-            obs.count("hb2st.demotion", 1, to=d.to_rung,
-                      **{"from": d.from_rung})
-    return out
+    from ..robust.ladder import hb2st_ladder
+    from .bulge import chase
+    return chase(hb2st_ladder(), "SLATE_HB2ST", band)
 
 
 def unmtr_hb2st(V, tau, C, band, trans: Op = Op.NoTrans, grid=None):

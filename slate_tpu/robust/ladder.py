@@ -19,9 +19,10 @@ heterogeneous BLAS runtimes):
   (:func:`demotion_log`) so callers and chaos tests can assert what
   actually ran.
 
-The concrete hb2st ladder (vmem → wave → native → numpy) is built by
-:func:`hb2st_ladder`; ``linalg/he2hb.py`` routes its backend dispatch
-through it.
+The two concrete band-chase ladders (vmem → wave → native → numpy) are
+built by :func:`hb2st_ladder` and :func:`tb2bd_ladder`;
+``linalg/he2hb.hb2st`` and ``linalg/ge2tb.tb2bd`` route their backend
+dispatch through them.
 """
 
 from __future__ import annotations
@@ -219,10 +220,11 @@ class BackendLadder:
 
 
 # ---------------------------------------------------------------------------
-# the concrete hb2st ladder: vmem -> wave -> native -> numpy
+# the concrete band-chase ladders: vmem -> wave -> native -> numpy
 # ---------------------------------------------------------------------------
 
 _hb2st: BackendLadder | None = None
+_tb2bd: BackendLadder | None = None
 
 
 def _band_geom(band):
@@ -235,20 +237,22 @@ def _chaseable(band) -> bool:
 
 
 def _hb2st_valid(result) -> bool:
-    """Health check on a chaser result (d, e, V, tau): the tridiagonal
-    must be finite (host-side numpy — the result is already on host)."""
+    """Health check on a chaser result (d, e, ...): the tridiagonal
+    (hb2st) or bidiagonal (tb2bd) must be finite (host-side numpy —
+    the result is already on host)."""
     import numpy as np
     d, e = result[0], result[1]
     return bool(np.isfinite(np.asarray(d)).all()
                 and np.isfinite(np.asarray(e)).all())
 
 
-def hb2st_ladder() -> BackendLadder:
-    """The Hermitian-band bulge-chasing ladder (built lazily; kernel
-    modules import only when their rung is probed/run):
+def _chase_ladder(name: str, vmem_gate, backends) -> BackendLadder:
+    """The four rungs of a band bulge chase (``hb2st``: Hermitian band
+    to tridiagonal; ``tb2bd``: triangular band to bidiagonal), kernel
+    modules imported only when their rung is probed or run:
 
     * ``vmem``  — VMEM-resident Pallas chaser; probe = TPU backend and
-      the ``vmem_applies`` footprint gate;
+      the kernel's own footprint gate ``vmem_gate()(n, b, dtype)``;
     * ``wave``  — XLA wavefront chaser; capable whenever a chase
       exists (b >= 2), auto-preferred on accelerators at n >= 1024
       where it amortizes dispatch;
@@ -256,10 +260,8 @@ def hb2st_ladder() -> BackendLadder:
       actually produced a library (``native_missing`` fault or a
       compilerless host demote past it);
     * ``numpy`` — the pure-numpy reference twin, unconditional floor.
-    """
-    global _hb2st
-    if _hb2st is not None:
-        return _hb2st
+
+    ``backends()`` returns the four callables in that order."""
 
     def vmem_probe(band):
         if not _chaseable(band):
@@ -270,13 +272,8 @@ def hb2st_ladder() -> BackendLadder:
                 return False
         except Exception:
             return False
-        from ..internal.band_wave_vmem import vmem_applies
         b, n = _band_geom(band)
-        return vmem_applies(n, b, band.dtype)
-
-    def vmem_run(band):
-        from ..internal.band_wave_vmem import hb2st_wave_vmem
-        return hb2st_wave_vmem(band)
+        return vmem_gate()(n, b, band.dtype)
 
     def wave_prefer(band):
         if not _chaseable(band):
@@ -289,27 +286,64 @@ def hb2st_ladder() -> BackendLadder:
         b, n = _band_geom(band)
         return accel and n >= 1024
 
-    def wave_run(band):
-        from ..internal.band_bulge_wave import hb2st_wave
-        return hb2st_wave(band)
-
     def native_probe(band):
         from ..internal import band_bulge_native
         return band_bulge_native.get_lib() is not None
 
-    def native_run(band):
-        from ..internal import band_bulge_native
-        return band_bulge_native.hb2st(band)
+    def rung(i):
+        return lambda band: backends()[i](band)
 
-    def numpy_run(band):
-        from ..internal import band_bulge
-        return band_bulge.hb2st(band)
-
-    _hb2st = BackendLadder("hb2st", [
-        Rung("vmem", vmem_run, probe=vmem_probe),
-        Rung("wave", wave_run, probe=_chaseable, prefer=wave_prefer),
-        Rung("native", native_run, probe=native_probe,
+    return BackendLadder(name, [
+        Rung("vmem", rung(0), probe=vmem_probe),
+        Rung("wave", rung(1), probe=_chaseable, prefer=wave_prefer),
+        Rung("native", rung(2), probe=native_probe,
              prefer=lambda band: True),
-        Rung("numpy", numpy_run),
+        Rung("numpy", rung(3)),
     ], validate=_hb2st_valid)
+
+
+def hb2st_ladder() -> BackendLadder:
+    """The Hermitian-band bulge-chasing ladder (built lazily; see
+    :func:`_chase_ladder` for its rungs)."""
+    global _hb2st
+    if _hb2st is not None:
+        return _hb2st
+
+    def gate():
+        from ..internal.band_wave_vmem import vmem_applies
+        return vmem_applies
+
+    def backends():
+        from ..internal import band_bulge, band_bulge_native
+        from ..internal.band_bulge_wave import hb2st_wave
+        from ..internal.band_wave_vmem import hb2st_wave_vmem
+        return (hb2st_wave_vmem, hb2st_wave, band_bulge_native.hb2st,
+                band_bulge.hb2st)
+
+    _hb2st = _chase_ladder("hb2st", gate, backends)
     return _hb2st
+
+
+def tb2bd_ladder() -> BackendLadder:
+    """The triangular-band bulge-chasing ladder of the two-stage SVD
+    (``linalg/ge2tb.tb2bd`` routes its backend dispatch through it).
+    The ``vmem`` rung's gate is the bidiagonal twin's own
+    (``vmem_applies_bd``: its four per-step output windows are not in
+    the eig twin's footprint model)."""
+    global _tb2bd
+    if _tb2bd is not None:
+        return _tb2bd
+
+    def gate():
+        from ..internal.band_wave_vmem_bd import vmem_applies_bd
+        return vmem_applies_bd
+
+    def backends():
+        from ..internal import band_bulge, band_bulge_native
+        from ..internal.band_bulge_wave_bd import tb2bd_wave
+        from ..internal.band_wave_vmem_bd import tb2bd_wave_vmem
+        return (tb2bd_wave_vmem, tb2bd_wave, band_bulge_native.tb2bd,
+                band_bulge.tb2bd)
+
+    _tb2bd = _chase_ladder("tb2bd", gate, backends)
+    return _tb2bd

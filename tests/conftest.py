@@ -55,6 +55,26 @@ def grid11():
     return Grid(1, 1, devices=jax.devices()[:1])
 
 
+@pytest.fixture
+def observed(monkeypatch):
+    """Spans captured as inside a profiler session, counters on (what a
+    public call reports: tests of ``slate.heev``, ``slate.gels``,
+    ``slate.gesvd``)."""
+    from slate_tpu import obs
+    from slate_tpu.obs import flight, tracing
+    was_metrics, was_flight = obs.metrics_enabled(), flight.enabled()
+    flight.enable()
+    obs.reset()
+    monkeypatch.setattr(tracing, "_profiling", lambda: True)
+    obs.metrics_on()
+    yield
+    if not was_metrics:
+        obs.metrics_off()
+    if not was_flight:
+        flight.disable()
+    obs.reset()
+
+
 def rand(m, n, dtype=np.float64, seed=0):
     rng = np.random.default_rng(seed)
     if np.issubdtype(np.dtype(dtype), np.complexfloating):

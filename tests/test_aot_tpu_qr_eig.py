@@ -181,3 +181,27 @@ def test_hb2st_vmem_chaser_compiles(one_chip):
     c = bwv._hb2st_vmem_jit.lower(_shape(one_chip, BAND_EIG + 1, n),
                                   band=BAND_EIG, n=n).compile()
     assert aot_kernels(c) == 1
+
+
+def test_tb2bd_vmem_chaser_compiles(one_chip):
+    """The bidiagonal twin at band 128 (the frame layout the
+    ``gesvd_12288x8192_vec_1x1`` cell runs, PR 48) at a small n: the
+    body is the cell's, n only sets the grid (at n=8192 the same
+    compile is two minutes). And the program that cuts U_B and V_B out
+    of the Golub-Kahan Z at the cell's order, 2n = 16384: its
+    temporaries are no more than Z itself (the reversed positive half
+    and its split; a strided form that holds a quarter of that was 70
+    times slower on the chip, PR 48)."""
+    from slate_tpu.internal import band_wave_vmem_bd as bd
+    from slate_tpu.linalg import bulge
+    n = 1024
+    assert bd.vmem_applies_bd(n, BAND_EIG, F32)
+    assert bd.vmem_applies_bd(N_EIG, BAND_EIG, F32)
+    c = bd._tb2bd_vmem_jit.lower(_shape(one_chip, BAND_EIG + 1, n),
+                                 band=BAND_EIG, n=n).compile()
+    assert aot_kernels(c) == 1
+    halves = bulge._gk_halves_jit._jit.lower(
+        _shape(one_chip, 2 * N_EIG, 2 * N_EIG), n=N_EIG).compile()
+    assert halves.memory_analysis().temp_size_in_bytes \
+        <= 1.01 * 4 * (2 * N_EIG) ** 2
+

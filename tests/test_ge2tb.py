@@ -29,11 +29,15 @@ def test_ge2tb_band_similarity(grid24, m, n, nb, dt):
                                atol=1e-9)
 
 
+@pytest.mark.parametrize("solver", ["host", "device"])
 @pytest.mark.parametrize("dt", [np.float64, np.complex128])
-def test_tb2bd_bdsqr(grid24, dt):
-    """tb2bd bulge chase + bdsqr reproduce the band singular values."""
+def test_tb2bd_bidiagonal_solve(grid24, dt, solver):
+    """tb2bd bulge chase + the bidiagonal solve reproduce the band's
+    singular values and B itself: the host ``bdsqr`` and the device
+    ``bdsdc`` (the same Golub-Kahan form through stedc's merges, Z on
+    the grid) are cases of one test."""
     from slate_tpu.linalg.ge2tb import tb2bd
-    from slate_tpu.linalg.bulge import bdsqr
+    from slate_tpu.linalg.bulge import bdsdc, bdsqr
     rng = np.random.default_rng(11)
     nb, n = 6, 37
     ub = rng.standard_normal((nb + 1, n)).astype(dt)
@@ -46,10 +50,17 @@ def test_tb2bd_bdsqr(grid24, dt):
         dense[idx, idx + dd] = ub[dd, : n - dd]
     ref = np.linalg.svd(dense, compute_uv=False)
     np.testing.assert_allclose(bdsqr(d, e), ref, rtol=1e-10, atol=1e-10)
-    s, U, VT = bdsqr(d, e, want_uv=True)
+    if solver == "host":
+        s, U, VT = bdsqr(d, e, want_uv=True)
+    else:
+        s, U, V = bdsdc(d, e, grid24, np.float64)
+        U, VT = np.asarray(U), np.asarray(V).T
+    np.testing.assert_allclose(s, ref, rtol=1e-10, atol=1e-10)
     B = np.diag(d) + np.diag(e, 1)
     np.testing.assert_allclose(U @ (np.diag(s) @ VT), B,
                                rtol=1e-9, atol=1e-9)
+    np.testing.assert_allclose(U.T @ U, np.eye(n), atol=1e-9)
+    np.testing.assert_allclose(VT @ VT.T, np.eye(n), atol=1e-9)
 
 
 @pytest.mark.parametrize("dt", [np.float64, np.complex128])

@@ -20,7 +20,7 @@ import pytest
 import slate_tpu as st
 from slate_tpu import obs
 from slate_tpu.linalg import geqrf as qr
-from slate_tpu.obs import flight, metrics, tracing
+from slate_tpu.obs import metrics
 from slate_tpu.types import MethodGels, Option
 from benchmarks.harness import flops_ls, plain_ls
 
@@ -36,22 +36,6 @@ PROGRAMS = {
     "fast_pallas": ({"SLATE_QR_FAST": "1", "SLATE_QR_PANEL": "1"},
                     (M, 2 * N, 128), "pallas"),
 }
-
-
-@pytest.fixture
-def observed(monkeypatch):
-    """Spans captured as inside a profiler session, counters on."""
-    was_metrics, was_flight = obs.metrics_enabled(), flight.enabled()
-    flight.enable()
-    obs.reset()
-    monkeypatch.setattr(tracing, "_profiling", lambda: True)
-    obs.metrics_on()
-    yield
-    if not was_metrics:
-        obs.metrics_off()
-    if not was_flight:
-        flight.disable()
-    obs.reset()
 
 
 def opts(method=MethodGels.Geqrf, tier="bf16_6x"):

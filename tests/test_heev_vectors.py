@@ -18,7 +18,7 @@ import pytest
 import slate_tpu as st
 from slate_tpu import obs
 from slate_tpu.linalg import bulge, eig, stedc
-from slate_tpu.obs import flight, metrics, tracing
+from slate_tpu.obs import metrics
 from slate_tpu.robust import ladder
 from slate_tpu.types import MethodEig, Option, Uplo
 from benchmarks.harness import plain_eig
@@ -30,22 +30,6 @@ N, NB, BAND = 384, 64, 32       # the band forced under the tile
 @pytest.fixture(params=["1x1", "2x2"])
 def grid(request, grid11, grid22):
     return grid11 if request.param == "1x1" else grid22
-
-
-@pytest.fixture
-def observed(monkeypatch):
-    """Spans captured as inside a profiler session, counters on."""
-    was_metrics, was_flight = obs.metrics_enabled(), flight.enabled()
-    flight.enable()
-    obs.reset()
-    monkeypatch.setattr(tracing, "_profiling", lambda: True)
-    obs.metrics_on()
-    yield
-    if not was_metrics:
-        obs.metrics_off()
-    if not was_flight:
-        flight.disable()
-    obs.reset()
 
 
 def dc(tier="bf16_6x"):
