@@ -5,7 +5,9 @@ Reference: src/ge2tb.cc (585 LoC), src/tb2bd.cc (378, bulge chasing),
 src/bdsqr.cc, wired in src/gesvd.cc:77-102; back-transforms
 unmbr_ge2tb / unmbr_tb2bd.
 
-TPU redesign — one jitted ``shard_map`` fori-loop alternating:
+TPU redesign — one jitted program, ``_ge2tb_jit``, with two bodies
+chosen from the operand's shape (``_program``). On a grid, or with a
+ragged edge, a ``shard_map`` fori-loop alternating:
 
 * **QR panel** on block column k (rows ≥ k·nb): XLA-native geqrf on
   the gathered panel; compact-WY left update of the trailing columns
@@ -15,7 +17,12 @@ TPU redesign — one jitted ``shard_map`` fori-loop alternating:
   same geqrf kernel; right update A ← A − (A·V)·T·Vᴴ (one psum across
   mesh columns; the W stays row-local — no gather needed).
 
-The result is an upper triangular band of width nb+1 (diagonal blocks
+On one device at whole tiles (m ≥ n) the same two half-steps run on
+what is left of the matrix (``_ge2tb_exact``): stages of ``STAGE``
+steps, each a fori-loop on the window its first step owns, panels at
+the window's height, no mask on the matrix and no collective.
+
+Either way the result is an upper triangular band of width nb+1 (diagonal blocks
 upper-triangular, superdiagonal blocks lower-triangular) with the QR
 reflectors stored below the diagonal and the LQ reflectors right of
 the superdiagonal — LAPACK gebrd's in-place convention at block scale.
@@ -58,29 +65,156 @@ from ..types import Op
 from ..errors import slate_error_if
 from ..internal import comm, masks
 from ..internal.precision import resolve_tier, trailing_dot_kwargs
-from ..internal.tile_kernels import panel_qr_factor, extract_v, larft
+from ..internal.tile_kernels import (panel_qr_factor, extract_v, larft,
+                                     _factor_dtype)
 from ..utils import trace
 from .. import obs
+from .geqrf import _blocked_T
 
 
 def ge2tb(A: Matrix, opts=None):
     """Reduce A (m ≥ n) to upper triangular band: A = U·B·Vᴴ.
     Returns (Aout, Tq, Tl): Aout stores the band + both reflector
-    sets in place; Tq [nt, nb, nb], Tl [nt-1, nb, nb]."""
+    sets in place; Tq [nt, nb, nb], Tl [nt-1, nb, nb]. The program is
+    chosen from the operand's shape (``_program``) and said on the span
+    (``program``; ``panel``: XLA's geqrf in both, the Pallas Householder
+    kernel read no faster at the gesvd cell and cost 25 s of set-up,
+    PERF.md section 6, PR 49) and in the counter
+    ``ge2tb.path{program}``."""
     slate_error_if(A.m < A.n, "ge2tb v1 expects m >= n")
     A = A.materialize()
     tier = resolve_tier(opts)
+    program = _program(A)
+    obs.count("ge2tb.path", 1, program=program)
     with trace.block("ge2tb", routine="ge2tb", m=A.m, n=A.n, nb=A.nb,
-                     precision=tier):
+                     precision=tier, program=program, panel="xla"):
         data, Tq, Tl = _ge2tb_jit(A, tier)
     return A._replace(data=data), Tq, Tl
 
 
+# steps a stage of the one-chip program: a stage is one ``fori_loop`` on
+# the window its first step owns, so its panels and products have one
+# shape (eight loop bodies where the ``gesvd_12288x8192_vec_1x1`` cell
+# has 127 panels; 46 % of the SPMD body's tiles summed over the call,
+# where a window a step would be 40 % and was 546 MB of code and 275 s
+# of compile, PERF.md section 6, PR 49)
+STAGE = 8
+
+
+def _program(A) -> str:
+    """``exact``: the one-chip program on what is left of the matrix
+    (``_ge2tb_exact``), for an operand on one device whose m and n are
+    whole tiles, m ≥ n; ``spmd``: the ``shard_map`` loop of uniform
+    full-height panels under masks, which a block-cyclic grid and a
+    ragged edge need. Read off the operand alone, on every platform."""
+    mtl, ntl = A.data.shape[2], A.data.shape[3]
+    exact = (A.grid.size == 1 and A.m == mtl * A.nb
+             and A.n == ntl * A.nb and A.m >= A.n)
+    return "exact" if exact else "spmd"
+
+
+# a function inside _ge2tb_jit's program, never a program of its own:
+# jitted so that the T, one shape for every panel, is traced and lowered
+# once and not once a stage and a side (0.1 s each, in every process's
+# set-up)
+# slatelint: disable-next-line=SL009 -- inside _ge2tb_jit's program
+_panel_T = jax.jit(_blocked_T, static_argnums=(2,))
+
+
+def _panel_step(pan, d0):
+    """One panel of the one-chip program, its diagonal at the traced
+    row ``d0`` (the rows above ride along untouched): (factored panel,
+    V, T) by XLA's geqrf on the rows from ``d0`` (``panel_qr_factor``),
+    V the unit lower trapezoid and T from its Gram matrix as
+    ``_geqrf_fast_core`` builds it (``geqrf._blocked_T``: no per-column
+    scan over V)."""
+    h, nb = pan.shape
+    qr_, taus = panel_qr_factor(pan, d0, h)
+    V = extract_v(qr_, d0, h)
+    return qr_, V, _panel_T(jnp.conj(V.T) @ V, taus, nb)
+
+
+def _ge2tb_exact(A, tier):
+    """ge2tb on one chip, on what is left of the matrix. Steps run in
+    stages of ``STAGE``: a stage is one ``fori_loop`` on the window
+    ``a[s0:, s0:]`` its first step owns. Step k of it factors the
+    window's column at the window's height with the diagonal's row
+    handed to the panel step (``_panel_step``) and the conj-transposed
+    row likewise, and updates the window alone, each side as three
+    plain matmuls around the Gram-built T. What a step no longer owns
+    inside its stage's window is kept by zeroing those columns (rows)
+    of the thin factor W, [nb, width] or [height, nb]: no mask on the
+    matrix, no gathered full-height panel, no work outside the window
+    (the SPMD body's uniform shapes cost 2.6× the products on one
+    chip). The last QR panel has no trailing window and is factored at
+    its own height. Tiles → dense → tiles inside the program: nothing
+    but the three outputs outlives it."""
+    from ..matrix import tiles_to_dense, dense_to_tiles, bc_from_tiles
+    nb, m, n, nt = A.nb, A.m, A.n, A.nt
+    fd = _factor_dtype(A.dtype)
+    pk = trailing_dot_kwargs(tier, fd)
+    a = tiles_to_dense(A.data[0, 0], m, n).astype(fd)
+
+    def step(j, carry, k0):
+        """Step k0 + j on its stage's window: QR of the column at
+        r = j·nb, LQ of the row there."""
+        win, Tq, Tl = carry
+        h, w = win.shape
+        r, past = j * nb, (j + 1) * nb
+        with jax.named_scope("qr_panel"):
+            pan, V, T = _panel_step(
+                lax.dynamic_slice(win, (0, r), (h, nb)), r)
+            win = lax.dynamic_update_slice(win, pan, (0, r))
+            Tq = Tq.at[k0 + j].set(T)
+        with jax.named_scope("qr_trailing"):
+            # Qᴴ·C = C − V·Tᴴ·(Vᴴ·C), on the columns past the panel
+            W = jnp.conj(T).T @ jnp.matmul(jnp.conj(V.T), win, **pk)
+            W = jnp.where(jnp.arange(w) >= past, W, jnp.zeros_like(W))
+            win = win - jnp.matmul(V, W, **pk)
+        with jax.named_scope("lq_panel"):
+            # the row, conj-transposed into a column panel over the
+            # window's columns: its QR is the row's LQ
+            row = lax.dynamic_slice(win, (r, 0), (nb, w))
+            pan, V, T = _panel_step(jnp.conj(row.T), past)
+            win = lax.dynamic_update_slice(win, jnp.conj(pan.T), (r, 0))
+            Tl = Tl.at[k0 + j].set(T)
+        with jax.named_scope("lq_trailing"):
+            # C·Q = C − (C·V)·T·Vᴴ, on the rows past the panel
+            W = jnp.matmul(win, V, **pk) @ T
+            W = jnp.where(jnp.arange(h)[:, None] >= past, W,
+                          jnp.zeros_like(W))
+            win = win - jnp.matmul(W, jnp.conj(V.T), **pk)
+        return win, Tq, Tl
+
+    Tq = jnp.zeros((nt, nb, nb), fd)
+    Tl = jnp.zeros((max(nt - 1, 1), nb, nb), fd)
+    for k0 in range(0, nt - 1, STAGE):
+        s0 = k0 * nb
+        win, Tq, Tl = lax.fori_loop(
+            0, min(STAGE, nt - 1 - k0), partial(step, k0=k0),
+            (a[s0:, s0:], Tq, Tl))
+        a = a.at[s0:, s0:].set(win)
+    with jax.named_scope("qr_panel"):
+        r0 = (nt - 1) * nb
+        pan, _, T = _panel_step(a[r0:, r0:], 0)
+        a = a.at[r0:, r0:].set(pan)
+        Tq = Tq.at[nt - 1].set(T)
+    tiles = dense_to_tiles(a.astype(A.dtype), nb, A.data.shape[2],
+                           A.data.shape[3])
+    return (bc_from_tiles(tiles, 1, 1), Tq.astype(A.dtype),
+            Tl.astype(A.dtype))
+
+
 @partial(cached_jit, static_argnames=("tier",))
 def _ge2tb_jit(A, tier=None):
-    """``tier`` (``Option.TrailingPrecision``) reaches the trailing
-    products alone (``qr_trailing``, ``lq_trailing``); the panels and
-    their T factors stay at the package default."""
+    """Two bodies under one name, chosen from the operand's shape
+    (``_program``): ``_ge2tb_exact`` on one chip at whole tiles, the
+    ``shard_map`` loop below for everything else. ``tier``
+    (``Option.TrailingPrecision``) reaches the trailing products alone
+    (``qr_trailing``, ``lq_trailing``); the panels and their T factors
+    stay at the package default."""
+    if _program(A) == "exact":
+        return _ge2tb_exact(A, tier)
     g = A.grid
     p, q, nb = g.p, g.q, A.nb
     m, n = A.m, A.n

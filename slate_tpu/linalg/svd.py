@@ -49,14 +49,17 @@ from .. import obs
 # ``gesvd.path{path}``, ``tb2bd.backend{rung}`` (the rung whose answer
 # was used), ``tb2bd.demotion{from,to}`` (a rung that was stepped
 # past), ``gesvd.bidiag{route}`` (``gk_stedc``: the device divide &
-# conquer on the Golub-Kahan form; ``host``: ``bulge.bdsqr``), and
-# ``linalg/stedc.py``'s four
+# conquer on the Golub-Kahan form; ``host``: ``bulge.bdsqr``),
+# ``linalg/stedc.py``'s four, and ``ge2tb.path{program}`` (``exact``:
+# the one-chip program on what is left of the matrix; ``spmd``: the
+# ``shard_map`` loop), which the ``ge2tb`` span under ``gesvd.stage1``
+# carries as ``program`` beside ``panel`` (``xla`` in both)
 SPANS = ("slate.gesvd", "gesvd.stage1", "gesvd.gather", "gesvd.stage2",
          "gesvd.bidiag", "gesvd.back.tb2bd.u", "gesvd.back.ge2tb.u",
          "gesvd.back.tb2bd.v", "gesvd.back.ge2tb.v", "gesvd.dense")
 COUNTERS = ("gesvd.path", "tb2bd.backend", "tb2bd.demotion",
             "gesvd.bidiag", "stedc.merges", "stedc.poles",
-            "stedc.deflated", "stedc.levels")
+            "stedc.deflated", "stedc.levels", "ge2tb.path")
 
 # one chip: below this min(m, n) ``Auto`` takes XLA's svd (round 5's
 # number; not moved here: ROADMAP R7b)

@@ -83,6 +83,33 @@ def test_gels_cell_programs_compile_with_the_panel_kernel(topo, program):
     assert c.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
 
 
+def test_ge2tb_exact_body_compiles_a_loop_a_stage(topo):
+    """The one-chip ``_ge2tb_jit`` (PR 49) at a reduced exact shape,
+    [3072, 2048] at nb = 128: 16 tile columns, so two stages (loops of
+    8 and 7 steps) and the last QR panel, 31 panels in all. The module
+    keeps the name the benchmark's ``svd_band_reduce_s`` reads; its
+    panels are XLA's geqrf, so it holds no Mosaic call; and no loop
+    body copies or selects an array of its window's size (the SPMD
+    body copies and selects all of A under a mask, every step)."""
+    from slate_tpu.linalg import ge2tb as g2
+    m, n, nb = 3072, 2048, 128
+    g = slate.Grid(1, 1, devices=[topo.devices[0]])
+    data = jax.ShapeDtypeStruct((1, 1, m // nb, n // nb, nb, nb), F32,
+                                sharding=g.sharding())
+    A = slate.Matrix(data=data, m=m, n=n, nb=nb, grid=g)
+    assert g2._program(A) == "exact"
+    low = g2._ge2tb_jit.lower(A, "bf16_6x")
+    assert "module @jit__ge2tb_jit" in low.as_text()
+    c = low.compile()
+    assert aot_kernels(c) == 0
+    assert -(-(n // nb - 1) // g2.STAGE) == 2
+    bodies = _loop_bodies(c.as_text())
+    smallest_window = (m - g2.STAGE * nb) * (n - g2.STAGE * nb)
+    moved = re.findall(r"= \w+\[([\d,]+)\]\S* (?:copy|select)\(", bodies)
+    assert all(math.prod(map(int, dims.split(","))) < smallest_window
+               for dims in moved), moved
+
+
 # -- heev with vectors: the tridiagonal stage's merges and the blocked
 #    back-transform at the benchmark cell's size (PR 41) ------------------
 

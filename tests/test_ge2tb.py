@@ -4,10 +4,124 @@ import numpy as np
 import pytest
 
 import slate_tpu as st
+from slate_tpu import obs
+from slate_tpu.obs import metrics
 from slate_tpu.types import Op, Option, MethodSVD
+from slate_tpu.linalg import ge2tb as g2
 from slate_tpu.linalg.ge2tb import (ge2tb, ge2tb_gather, gesvd_two_stage,
-                                    unmbr_ge2tb_u)
+                                    unmbr_ge2tb_u, unmbr_ge2tb_v)
 from tests.conftest import rand
+
+
+def band_of(Aout):
+    """The gathered band as a dense n x n matrix."""
+    ub = ge2tb_gather(Aout)
+    nb, n = Aout.nb, Aout.n
+    assert ub.shape == (nb + 1, n)
+    return sum(np.diag(ub[d, :n - d], d) for d in range(nb + 1))
+
+
+def rebuilt(Aout, Tq, Tl, band):
+    """U·[B; 0]·Vᴴ through both back-transforms of stage 1."""
+    m, n, nb, grid = Aout.m, Aout.n, Aout.nb, Aout.grid
+    B = np.zeros((m, n), band.dtype)
+    B[:n] = band
+    UB = unmbr_ge2tb_u(Op.NoTrans, Aout, Tq,
+                       st.Matrix.from_dense(B, nb=nb, grid=grid))
+    X = st.Matrix.from_dense(np.conj(np.asarray(UB.to_dense()).T),
+                             nb=nb, grid=grid)
+    return np.conj(np.asarray(
+        unmbr_ge2tb_v(Op.NoTrans, Aout, Tl, X).to_dense()).T)
+
+
+def program_label():
+    (span,) = [s for s in obs.captured_spans() if s["name"] == "ge2tb"]
+    return span["labels"]["program"], span["labels"]["panel"]
+
+
+# the exact-shape body: one chip, m and n whole tiles, m >= n
+@pytest.mark.parametrize("m,n,nb,dt,tol", [
+    (48, 32, 8, np.float64, 1e-13), (32, 32, 8, np.float64, 1e-13),
+    (48, 32, 8, np.float32, 2e-5), (32, 32, 8, np.float32, 2e-5),
+    (48, 32, 8, np.complex128, 1e-13), (8, 8, 8, np.float64, 1e-13),
+    (96, 80, 8, np.float64, 1e-13), (512, 384, 128, np.float32, 2e-5)],
+    ids=["tall-f64", "square-f64", "tall-f32", "square-f32",
+         "tall-c128", "one-tile", "two-stages", "tall-f32-nb128"])
+def test_exact_body_reduces_what_is_left(grid11, observed, m, n, nb, dt,
+                                         tol):
+    """On one chip at whole tiles ``_ge2tb_jit`` runs the exact-shape
+    body: a band with A's singular values, Tq and Tl of the SPMD
+    body's shapes, and both back-transforms on its output rebuild A
+    (ten tile columns: a stage of eight steps on the whole matrix, one
+    of one step on its window, the last QR panel alone)."""
+    a = rand(m, n, dt, 1)
+    A = st.Matrix.from_dense(a, nb=nb, grid=grid11)
+    Aout, Tq, Tl = ge2tb(A)
+    assert program_label() == ("exact", "xla")
+    assert metrics.counter_value("ge2tb.path", program="exact") == 1
+    nt = n // nb
+    assert Tq.shape == (nt, nb, nb) and Tl.shape == (max(nt - 1, 1), nb, nb)
+    assert Aout.data.shape == A.data.shape and Aout.dtype == A.dtype
+    band = band_of(Aout)
+    s_a = np.linalg.svd(a, compute_uv=False)
+    assert np.abs(np.linalg.svd(band, compute_uv=False)
+                  - s_a).max() < tol * s_a[0]
+    assert np.abs(rebuilt(Aout, Tq, Tl, band) - a).max() < tol * s_a[0]
+
+
+@pytest.mark.parametrize("shape,grid_name,program", [
+    ((48, 32, 8), "grid11", "exact"), ((29, 21, 8), "grid11", "spmd"),
+    ((40, 24, 8), "grid24", "spmd"), ((32, 48, 8), "grid11", "spmd")],
+    ids=["whole-tiles", "ragged", "grid", "wide"])
+def test_the_shape_picks_the_program(request, observed, shape,
+                                     grid_name, program):
+    """One chip at whole tiles with m >= n takes the exact-shape body;
+    a ragged edge, a grid and m < n (before ``gesvd`` transposes) take
+    the SPMD one. Read from the span's ``program`` label where
+    ``ge2tb`` takes the operand, from the rule where it refuses it."""
+    m, n, nb = shape
+    A = st.Matrix.from_dense(rand(m, n, np.float64, 5), nb=nb,
+                             grid=request.getfixturevalue(grid_name))
+    assert g2._program(A) == program
+    if m < n:
+        return
+    ge2tb(A)
+    assert program_label() == (program, "xla")
+    assert metrics.counter_value("ge2tb.path", program=program) == 1
+    assert metrics.counter_total("ge2tb.path") == 1
+
+
+# sha256 of _ge2tb_jit's lowered StableHLO text where the shape picks
+# the SPMD body, as the commit before the exact-shape body (89dc2bf)
+# lowers it; jax 0.9.0, x64 on as tests/conftest.py sets it
+_SPMD_TEXT = {
+    ("grid11", 29, 21, 8, "float64"):
+        "cccfa1eee88475fd744782d3ff7224103599109365f80f43fc0c62ee926e41fe",
+    ("grid24", 40, 24, 8, "float64"):
+        "bb839b808a0119eaa4983352c9c92362a6b1e5bed0c47d5c61224efdd3770fed",
+    ("grid22", 384, 256, 32, "float32"):
+        "1258ccb18a9a9cf3997863f743ae424e0d83074eaa894ed6516da259d14775da",
+    ("grid11", 32, 48, 8, "float64"):
+        "d5c653739bc4f72177fd70cda521fd84c4dd46700e1b32e1deee4e995d5d3b7c",
+}
+
+
+@pytest.mark.parametrize("case", list(_SPMD_TEXT),
+                         ids=["ragged", "grid24", "grid22-f32", "wide"])
+def test_spmd_body_lowers_to_the_text_it_had(request, case):
+    import hashlib
+    import jax
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests were taken with jax 0.9.0")
+    grid_name, m, n, nb, dt = case
+    A = st.random_matrix(m, n, nb, request.getfixturevalue(grid_name),
+                         np.dtype(dt), seed=1)
+    # a trace that an earlier call left (the same shape from
+    # ``from_dense``) would hand its own spelling of the sharding back
+    g2._ge2tb_jit._jit.clear_cache()
+    text = g2._ge2tb_jit.lower(A, "bf16_6x").as_text()
+    assert "module @jit__ge2tb_jit" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == _SPMD_TEXT[case]
 
 
 @pytest.mark.parametrize("m,n,nb", [(32, 32, 8), (40, 24, 8), (29, 21, 8)])

@@ -175,14 +175,18 @@ def test_two_stage_and_dense_agree_up_to_column_signs(grid11):
 
 # -------------------------------------------- the tier reaches stage 1
 
-def test_the_tier_reaches_ge2tbs_trailing_products(grid11):
-    """``Option.TrailingPrecision`` is on the six einsums of the two
-    trailing updates and on nothing else of ``_ge2tb_jit``; the default
-    call traces the program it traced before (``bf16_6x`` is the
-    package default, so ``tests/test_ge2tb.py`` reads the same
-    numbers)."""
+def test_the_tier_reaches_ge2tbs_trailing_products(grid):
+    """``Option.TrailingPrecision`` is on the products of the two
+    trailing updates and on nothing else of ``_ge2tb_jit``, in either
+    body: the four matmuls of a stage's step in the exact-shape one
+    (VᴴC, V·W, C·V, W·Vᴴ; not the panels, the Gram matrices or the
+    T's), the six einsums of the SPMD one. The default call traces the
+    program it traced before (``bf16_6x`` is the package default, so
+    ``tests/test_ge2tb.py`` reads the same numbers)."""
     a = normal(96, 64, 2)
-    A = st.Matrix.from_dense(a, nb=16, grid=grid11)
+    A = st.Matrix.from_dense(a, nb=16, grid=grid)
+    program = g2._program(A)
+    assert program == {1: "exact", 4: "spmd"}[grid.size]
 
     def dots(tier):
         text = g2._ge2tb_jit.lower(A, tier).as_text()
@@ -191,7 +195,8 @@ def test_the_tier_reaches_ge2tbs_trailing_products(grid11):
 
     high = [d for d in dots("bf16_3x") if "HIGH>" in d or "HIGH," in d
             or "HIGH]" in d]
-    assert len(high) == 6, high
+    # nt = 4: one stage (a loop body) in the exact-shape program
+    assert len(high) == {"exact": 4, "spmd": 6}[program], high
     assert not any("HIGHEST" in d for d in high)
     # at the sound tier (and by default) no product is below HIGHEST
     assert all("HIGHEST" in d or "precision" not in d
@@ -263,6 +268,16 @@ def test_span_tree_of_a_two_stage_call(grid, observed):
     (chase,) = [s for s in spans if s["name"] == "tb2bd"]
     assert chase["labels"]["rung"] == rung
     assert ("shear" in chase["labels"]) == (rung == "vmem")
+    # stage 1 says which program and which panel form answered: the
+    # band's tiles are whole on the one chip, so it takes the
+    # exact-shape body there and the SPMD one on the grid
+    program = "exact" if grid.size == 1 else "spmd"
+    (stage1,) = [s for s in spans if s["name"] == "ge2tb"]
+    assert (stage1["labels"]["program"], stage1["labels"]["panel"]) == (
+        program, "xla")
+    assert metrics.counter_value("ge2tb.path", program=program) == 1
+    assert metrics.counter_total("ge2tb.path") == 1
+    assert "ge2tb.path" in svd.COUNTERS
     # every blocking read of the path is a named sync=1 span
     sites = {}
     for s in spans:
