@@ -34,6 +34,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from ..cache.jitcache import cached_jit
@@ -48,6 +49,7 @@ from ..internal import comm, masks
 from ..internal.tile_kernels import tile_potrf, _factor_dtype
 from ..internal.masks import tile_diag_pad_identity
 from ..internal.precision import resolve_tier, trailing_dot_kwargs
+from .. import obs
 from ..obs import timeline as tl
 from ..runtime import dag
 from ..utils import trace
@@ -107,6 +109,10 @@ def potrf(A: HermitianMatrix, opts=None, overwrite_a: bool = False,
         nt = A.nt
         chunked = g.size > 1 and nt >= 2 * lcm_pq
         guard = _superstep.arm("potrf", A, opts, checkpoint, chunked)
+        # which body factors: the unrolled one-chip loop on the stored
+        # tiles, or the uniform SPMD step (one program or chunks)
+        form = "tiles" if _one_chip_unrolled(A) else "spmd"
+        obs.count("potrf.path", 1, form=form)
         if chunked:
             # chunked super-steps: re-jit on a statically shrinking
             # trailing window every lcm(p,q)-aligned chunk — the
@@ -140,7 +146,7 @@ def potrf(A: HermitianMatrix, opts=None, overwrite_a: bool = False,
                         else _potrf_jit)(A, tier, depth=depth)
 
             with trace.block("potrf.chunk", phase="one_program",
-                             k0=0, klen=nt):
+                             k0=0, klen=nt, form=form):
                 data, info = _superstep.run_one_program(
                     guard, A, launch, nt, overwrite_a)
     L = TriangularMatrix(data=data, m=A.m, n=A.n, nb=A.nb, grid=A.grid,
@@ -217,9 +223,12 @@ def _syrk_update_inplace(a, r0, nsub, v, cplx, cutoff=2048, tier=None):
 def _potrf_dense_loop(a, nb, n, Mp, tier=None):
     """Unrolled blocked Cholesky on a dense [Mp, ≥Mp] array (rows ≥ n
     padded with an identity diagonal by the caller). Peak memory =
-    the array itself + one [*, nb] panel + ≤[*, 2048] syrk blocks —
-    the in-place body shared by the tiled fast path and the 64k-class
-    dense-in-place entry (potrf_dense_inplace)."""
+    the array itself + one [*, nb] panel + ≤[*, 2048] syrk blocks.
+    The body of the batched serving path, which holds dense instances
+    (``serve/batched.py`` vmaps it); the one-chip ``potrf`` runs the
+    same steps on its stored tiles (:func:`_potrf_dense_1dev`) and the
+    64k-class dense-in-place entry in groups
+    (:func:`_potrf_dense_group_core`)."""
     nt = cdiv(n, nb)
     cplx = jnp.issubdtype(a.dtype, jnp.complexfloating)
     info = jnp.zeros((), jnp.int32)
@@ -290,11 +299,13 @@ _potrf_dense_group_jit = cached_jit(_potrf_dense_group_core,
 
 def potrf_dense_inplace(a, nb: int = 1024, group: int = 16, opts=None):
     """Cholesky of a dense LAPACK-layout array IN PLACE (donated
-    buffer): the 64k-class single-chip entry. The tiled paths must
-    convert storage (tiles ⇄ dense is a layout permutation — a full
-    transient copy, which at an 8 GB matrix exceeds HBM); this entry
-    skips the Matrix container entirely, peak memory ≈ the array
-    itself. The factorization runs as ⌈nt/group⌉ donated jit programs
+    buffer): the 64k-class single-chip entry, for a caller who holds
+    the matrix dense. Bringing it into a tiled Matrix and back is a
+    layout permutation each way — a full transient copy, which at an
+    8 GB matrix exceeds HBM (the tiled :func:`potrf` itself converts
+    nothing: on one chip it factors its tiles where they are stored);
+    this entry skips the Matrix container entirely, peak memory ≈ the
+    array itself. The factorization runs as ⌈nt/group⌉ donated jit programs
     of ``group`` unrolled panels each. n must be a multiple of nb.
     Returns (L_dense, info) — reference analog: slate::potrf's
     in-place semantics on fromLAPACK-style user storage
@@ -320,43 +331,104 @@ def potrf_dense_inplace(a, nb: int = 1024, group: int = 16, opts=None):
     return a, info
 
 
-def _potrf_dense_1dev(A, tier=None):
-    """Single-device fast path: exact-shape unrolled blocked Cholesky
-    on the dense (padded) matrix. The SPMD fori_loop path must keep
-    every step uniform (full-matrix masked einsum, ~3x the flops on
-    one chip); with no communication the loop unrolls at trace time
-    with shrinking trailing shapes instead — measured ~6x faster on a
-    v5e (8→49 TF/s at n=16k). Same numerics, same info semantics."""
-    from ..matrix import tiles_to_dense, dense_to_tiles, bc_from_tiles
-    nb = A.nb
-    n = A.n
-    nt = cdiv(n, nb)
-    mtl, ntl = A.data.shape[2], A.data.shape[3]
-    Mp = mtl * nb
+def _herk_update_tiles(t, i0, p, cplx, ctiles, pk):
+    """t[i0:i0+len(p), i0:i0+len(p)] −= p·pᴴ on stored tiles, the twin
+    of :func:`_syrk_update_inplace` in tile coordinates: ``t`` is
+    ``[mt, nt, nb, nb]``, ``p`` the panel tiles ``[nsub, nb, nb]`` of
+    tile rows i0 onward. The split falls on tile indices; a window of at
+    most ``ctiles`` tiles a side is one product."""
+    nsub = p.shape[0]
 
-    a = tiles_to_dense(A.data[0, 0], Mp, ntl * nb)
-    if Mp > n:  # identity on the padded diagonal (cf. masks.tile_diag_pad_identity)
-        pad = jnp.arange(n, min(Mp, ntl * nb))
-        a = a.at[pad, pad].set(1.0)
-    a, info = _potrf_dense_loop(a, nb, n, Mp, tier=tier)
-    if min(Mp, ntl * nb) > nt * nb:
-        # tiles past the last real block column stay zero (the SPMD
-        # path never writes them); in-tile diagonal padding of block
-        # nt-1 keeps its identity, matching tile_diag_pad_identity.
-        pad = jnp.arange(nt * nb, min(Mp, ntl * nb))
-        a = a.at[pad, pad].set(0.0)
-    tiles = dense_to_tiles(a, nb, mtl, ntl)
-    return bc_from_tiles(tiles, 1, 1), info
+    def window(t, r0, r1, c0, c1):
+        # 'injm' + transpose writes the product in the tiles' own
+        # layout, fused with the in-place update: with 'ijnm' XLA
+        # copies the window to another layout and back (PERF.md §6
+        # PR 50)
+        cols = jnp.conj(p[c0:c1]) if cplx else p[c0:c1]
+        upd = jnp.einsum("inb,jmb->injm", p[r0:r1], cols,
+                         **pk).transpose(0, 2, 1, 3)
+        return t.at[i0 + r0:i0 + r1, i0 + c0:i0 + c1].add(-upd)
+
+    if nsub <= ctiles:
+        return window(t, 0, nsub, 0, nsub)
+    h = nsub // 2
+    t = _herk_update_tiles(t, i0, p[:h], cplx, ctiles, pk)
+    t = window(t, h, nsub, 0, h)
+    return _herk_update_tiles(t, i0 + h, p[h:], cplx, ctiles, pk)
+
+
+def _potrf_dense_1dev(A, tier=None):
+    """One-chip fast path (the twin of ``_getrf_dense_1dev``; the name
+    is the family's): the unrolled blocked Cholesky of
+    :func:`_potrf_dense_loop`, step for step, on the stored tiles
+    ``A.data[0, 0]`` themselves. The SPMD fori_loop path must keep
+    every step uniform (full-matrix masked einsum, ~3x the flops on one
+    chip); with no communication the loop unrolls at trace time with
+    shrinking trailing shapes instead. Every slice is taken in tile
+    coordinates — the diagonal tile ``t[k, k]``, the panel ``t[k+1:,
+    k]`` (a row-major ``[rows, nb]`` panel by a free reshape), the
+    trailing windows of :func:`_herk_update_tiles` — so the matrix is
+    never brought to a dense ``[n, n]`` form and back: that cost five
+    matrix-sized copies a call, 16.1 ms of ``posv_16k_1x1``'s 88.8 ms a
+    solve (ledger, PR 49; PERF.md §6 PR 50). Same numerics, same info
+    semantics; tiles past the last block column are not touched."""
+    nb, n = A.nb, A.n
+    nt = cdiv(n, nb)
+    t = A.data[0, 0]
+    cplx = jnp.issubdtype(t.dtype, jnp.complexfloating)
+    pk = trailing_dot_kwargs(tier, t.dtype)
+    fd = _factor_dtype(t.dtype)
+    ctiles = max(1, 2048 // nb)       # _syrk_update_inplace's cut-off
+    row_major = Layout(major_to_minor=(0, 1, 2, 3))
+    info = jnp.zeros((), jnp.int32)
+    for k in range(nt):
+        with jax.named_scope("panel"):
+            akk = t[k, k]
+            if (k + 1) * nb > n:
+                akk = tile_diag_pad_identity(akk, k, n, nb)
+            low = jnp.tril(akk)
+            strict = jnp.tril(akk, -1)
+            akk = low + (jnp.conj(strict.T) if cplx else strict.T)
+            lkk, info = finite_guard(tile_potrf(akk), info, k + 1,
+                                     diag=True, cplx=cplx)
+            t = t.at[k, k].set(jnp.tril(lkk))
+        if k + 1 < nt:
+            with jax.named_scope("panel"):
+                # low-precision tiles solve the panel in f32 (XLA's
+                # TriangularSolve needs >= f32; storage stays bf16)
+                pan = lax.linalg.triangular_solve(
+                    lkk.astype(fd),
+                    t[k + 1:nt, k].reshape(-1, nb).astype(fd),
+                    left_side=False, lower=True,
+                    transpose_a=True, conjugate_a=cplx).astype(t.dtype)
+                pan, info = finite_guard(pan, info, k + 1, cplx=cplx)
+                pan = pan.reshape(-1, nb, nb)
+                t = t.at[k + 1:nt, k].set(pan)
+            with jax.named_scope("trailing"):
+                t = _herk_update_tiles(t, k + 1, pan, cplx, ctiles, pk)
+            # one layout from the first panel to the last: left to
+            # itself XLA's layout assignment turns the tiles of the
+            # whole array column-major for the tail of the loop and
+            # back, two matrix-sized copies (getrf._fast_group_program
+            # pins its parameter against the same flip). A complex
+            # array cannot be pinned: the TPU compiler carries it as
+            # two real ones and has no such rewrite of the constraint.
+            if not cplx:
+                t = with_layout_constraint(t, row_major)
+    return t[None, None], info
+
+
+def _one_chip_unrolled(A) -> bool:
+    """The rule of the one-chip path, read off the operand: one device
+    and few enough block columns to unroll at trace time (past ~64
+    compile time outgrows the win and the uniform fori_loop program is
+    the better trade)."""
+    return A.grid.size == 1 and cdiv(A.n, A.nb) <= 64
 
 
 def _potrf_core(A, tier=None, depth=0):
     g = A.grid
-    n, nb = A.n, A.nb
-
-    # nt cap: the dense path unrolls at trace time; past ~64 block
-    # columns compile time outgrows the win and the uniform fori_loop
-    # program is the better trade.
-    if g.size == 1 and cdiv(n, nb) <= 64:
+    if _one_chip_unrolled(A):
         return _potrf_dense_1dev(A, tier)
     if g.size > 1 and depth > 0:
         # software-pipelined lookahead loop (Option.PipelineDepth ≥ 1)

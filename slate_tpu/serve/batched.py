@@ -9,8 +9,9 @@ single-matrix drivers distribute one large problem across the mesh;
 these kernels keep each problem on-device-local and parallelize across
 problems instead.
 
-Each kernel is ``jax.vmap`` of the same dense blocked core the
-single-matrix fast paths use (``linalg.potrf._potrf_dense_loop``; the
+Each kernel is ``jax.vmap`` of a dense blocked core that takes the
+single-matrix fast paths' steps (``linalg.potrf._potrf_dense_loop``,
+the dense twin of the one-chip ``potrf``'s loop on stored tiles; the
 LU core mirrors ``linalg.getrf._getrf_dense_1dev``'s partial-pivot
 loop), so per-instance semantics are preserved exactly:
 
@@ -77,8 +78,8 @@ def _count(routine: str, a):
 # ---------------------------------------------------------------------------
 
 def _potrf_one(a, nb, tier):
-    """Blocked Cholesky on one dense [n, n]: the same unrolled core the
-    single-matrix fast path runs (first-block info convention)."""
+    """Blocked Cholesky on one dense [n, n]: the unrolled steps of the
+    single-matrix fast path (first-block info convention)."""
     from ..linalg.potrf import _potrf_dense_loop
     n = a.shape[0]
     l, info = _potrf_dense_loop(a, nb, n, n, tier=tier)

@@ -16,6 +16,7 @@ Real widths (h=16384, nb=1024); the n=16384 whole-factorization programs
 take minutes and are compiled by hand, not here.
 """
 
+import math
 import re
 from functools import partial
 
@@ -95,6 +96,56 @@ def test_potrf_chunk_2x2_compiles_sharded(tpu_grid22):
     c = potrf._potrf_chunk_jit.lower(A, info0, 0, 2,
                                      tier="bf16_6x").compile()
     _assert_sharded_with_collectives(c, H * H * 4)
+
+
+# -- the one-chip Cholesky on the stored tiles ------------------------------
+
+
+def _entry_relayouts(text, elements):
+    """``(name, op, operand)`` of every ``copy``, ``transpose`` or
+    ``reshape`` of the entry computation whose result has at least
+    ``elements`` elements."""
+    entry = text[text.index("ENTRY"):]
+    found = re.findall(
+        r"^\s*(?:ROOT )?(\S+) = \w+\[([\d,]+)\]\S* "
+        r"(copy|transpose|reshape)\((\S+?)[,)]", entry, re.M)
+    return [(name, op, operand) for name, dims, op, operand in found
+            if math.prod(map(int, dims.split(","))) >= elements]
+
+
+@pytest.mark.parametrize("donate,dtype,n,nb", [
+    (True, F32, 4096, NB), (False, F32, 4096, NB),
+    (False, jnp.complex64, 2048, 512)], ids=["donated", "kept", "complex"])
+def test_potrf_one_chip_factors_the_tiles_where_they_are(topo, donate,
+                                                         dtype, n, nb):
+    """``_potrf_core`` on one chip, a twin of ``posv_16k_1x1``'s program
+    at the cell's nb (four block columns: every kind of trailing window,
+    and the tail of the loop where XLA's layout assignment, unpinned,
+    turns the whole array column-major and back). The parent brought the
+    tiles to a dense [n, n] array and back: five matrix-sized copies and
+    a matrix of temporaries at any n (PERF.md section 6, PR 50). The
+    tile body moves the matrix once, and only where the caller keeps A:
+    an in-place loop on an input that is not donated starts from a copy
+    of it. A complex matrix has to compile too: the pin that holds the
+    layout is refused on one by the TPU compiler, so it goes unpinned."""
+    from slate_tpu.linalg import potrf
+    grid = slate.Grid(1, 1, devices=[topo.devices[0]])
+    t = n // nb
+    data = jax.ShapeDtypeStruct((1, 1, t, t, nb, nb), dtype,
+                                sharding=grid.sharding())
+    A = slate.HermitianMatrix(data=data, m=n, n=n, nb=nb, grid=grid)
+    jit = potrf._potrf_jit_overwrite if donate else potrf._potrf_jit
+    c = jit.lower(A, "bf16_6x", depth=0).compile()
+    assert aot_kernels(c) == 0
+    if dtype != F32:
+        return
+    moved = _entry_relayouts(c.as_text(), n * n)
+    if donate:
+        assert moved == []
+    else:
+        assert [(op, operand[:3]) for _, op, operand in moved] == [
+            ("copy", "%A_")], moved
+    assert c.memory_analysis().temp_size_in_bytes < n * n * 4 // 4
 
 
 def _getrf_chunk(grid, k0):

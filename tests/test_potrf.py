@@ -123,12 +123,104 @@ def test_potrf_chunked_spmd_path(grid24):
     np.testing.assert_allclose(l @ l.T, a, rtol=1e-10, atol=1e-9)
 
 
-def test_potrf_overwrite_a():
+# -- the one-chip body on the stored tiles (_potrf_dense_1dev) --------------
+
+# n a multiple of nb and ragged; nt = 1 (no panel), nt = 2 (one panel,
+# a one-tile trailing window), nt = 3..7 (the triangle-aware split:
+# rectangles and diagonal windows of one and of several tiles); f32,
+# complex64 and bf16 storage (panels factored in f32); upper storage.
+TILE_BODY = [
+    (16, 16, np.float32, Uplo.Lower), (13, 16, np.float32, Uplo.Lower),
+    (32, 16, np.float32, Uplo.Lower), (27, 16, np.float32, Uplo.Lower),
+    (96, 32, np.float32, Uplo.Lower), (112, 16, np.float32, Uplo.Lower),
+    (107, 16, np.float32, Uplo.Lower), (96, 32, np.complex64, Uplo.Lower),
+    (75, 16, np.complex64, Uplo.Lower), (96, 32, "bfloat16", Uplo.Lower),
+    (107, 16, "bfloat16", Uplo.Lower), (96, 32, np.float32, Uplo.Upper),
+    (75, 16, np.complex64, Uplo.Upper), (107, 16, np.float64, Uplo.Upper),
+]
+
+
+@pytest.mark.parametrize(
+    "n,nb,dt,uplo", TILE_BODY,
+    ids=[f"{n}-{nb}-{np.dtype(dt).name if dt != 'bfloat16' else dt}-"
+         f"{uplo.name}" for n, nb, dt, uplo in TILE_BODY])
+def test_potrf_tile_body_against_numpy(grid11, n, nb, dt, uplo):
+    import jax.numpy as jnp
+    bf16 = dt == "bfloat16"
+    wide = np.complex128 if dt == np.complex64 else np.float64
+    a = spd(n, np.float32 if bf16 else dt, seed=n + nb)
+    half = np.tril(a) if uplo == Uplo.Lower else np.triu(a)
+    A = st.HermitianMatrix.from_dense(
+        jnp.asarray(half, jnp.bfloat16) if bf16 else half, nb=nb,
+        grid=grid11, uplo=uplo)
+    F, info = st.potrf(A)
+    assert int(info) == 0
+    assert F.data.dtype == A.data.dtype and F.data.shape == A.data.shape
+    f = np.asarray(F.to_dense().astype(jnp.float32) if bf16
+                   else F.to_dense()).astype(wide)
+    ref = np.linalg.cholesky(a.astype(wide))
+    got = np.tril(f) if uplo == Uplo.Lower else np.conj(np.triu(f).T)
+    tol = 3e-2 if bf16 else 1e-12 if dt == np.float64 else 2e-5
+    assert np.linalg.norm(got - ref) / np.linalg.norm(ref) < tol
+    if n % nb and uplo == Uplo.Lower:
+        # the padding of the last diagonal tile keeps its identity and
+        # nothing else of the padding is written
+        last = np.asarray(F.data[0, 0, -1, -1].astype(jnp.float32)
+                          if bf16 else F.data[0, 0, -1, -1])
+        r = n % nb
+        np.testing.assert_array_equal(last[r:, r:], np.eye(nb - r))
+        np.testing.assert_array_equal(last[r:, :r], 0)
+
+
+@pytest.mark.parametrize("n,nb,bad,block", [
+    (64, 16, 0, 1), (64, 16, 37, 3), (61, 16, 60, 4), (16, 16, 5, 1)])
+def test_potrf_tile_body_reports_the_first_bad_block_column(
+        grid11, n, nb, bad, block):
+    a = spd(n, np.float32, seed=bad)
+    a[bad, bad] = -50.0
+    A = st.HermitianMatrix.from_dense(a, nb=nb, grid=grid11)
+    L, info = st.potrf(A)
+    assert int(info) == block       # 1-based, as the SPMD body reports it
+    assert np.isfinite(np.asarray(L.to_dense())).all()
+
+
+@pytest.mark.parametrize("case", ["tiles", "tiles_upper", "spmd_one_program",
+                                  "spmd_chunked"])
+def test_potrf_names_the_body_that_factored(request, observed, case):
+    """``potrf.chunk`` of the one-program launch carries ``form``, and
+    ``potrf.path{form}`` is counted once a factorization (the twin of
+    ``getrf.path{phase}``)."""
+    from slate_tpu import obs
+    from slate_tpu.obs import metrics
+    form = case.split("_")[0]
+    grid = request.getfixturevalue("grid11" if form == "tiles" else "grid24")
+    n = 90 if case == "spmd_chunked" else 40
+    a = spd(n, np.float64, seed=3)
+    upper = case == "tiles_upper"
+    A = st.HermitianMatrix.from_dense(
+        np.triu(a) if upper else np.tril(a), nb=8, grid=grid,
+        uplo=Uplo.Upper if upper else Uplo.Lower)
+    obs.reset()
+    calls = 2
+    for _ in range(calls):
+        _, info = st.potrf(A)
+        assert int(info) == 0
+    assert metrics.counter_value("potrf.path", form=form) == calls
+    assert metrics.counter_total("potrf.path") == calls
+    chunks = [s for s in obs.captured_spans() if s["name"] == "potrf.chunk"]
+    one = [s for s in chunks if s["labels"].get("phase") == "one_program"]
+    if case == "spmd_chunked":
+        assert chunks and not one
+    else:
+        assert [s["labels"]["form"] for s in one] == [form] * calls
+
+
+@pytest.mark.parametrize("n,nb", [(48, 16), (43, 16)])
+def test_potrf_overwrite_a(n, nb):
     """overwrite_a=True (donated buffer) gives identical results; on
     CPU donation is advisory but the API path must work end to end."""
     import jax
     g1 = st.Grid(1, 1, devices=[jax.devices()[0]])
-    n, nb = 48, 16
     a = spd(n, np.float64, seed=21)
     A1 = st.HermitianMatrix.from_dense(np.tril(a), nb=nb, grid=g1)
     A2 = st.HermitianMatrix.from_dense(np.tril(a), nb=nb, grid=g1)
