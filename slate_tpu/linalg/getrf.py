@@ -269,11 +269,11 @@ def _fast_path_mode(A, piv_mode) -> str | None:
     on_tpu = A.grid.devices[0].platform == "tpu"
     if flag == "1":
         return "tpu" if on_tpu else "interpret"
-    # upper cutoff: THIS tiled entry still pays tiles ⇄ dense
-    # conversion copies (input tiles + dense working copy + output
-    # tiles ≈ 3× the matrix), so it is memory-safe only to ~32k f32 on
-    # 16 GB HBM. The 45k class goes through getrf_dense_inplace — the
-    # donated dense entry with column-chunked in-place compaction
+    # upper cutoff: THIS tiled entry holds the input tiles, the dense
+    # working array (one pass from them) and the output tiles (one pass
+    # back) at once, ≈ 3× the matrix, so it is memory-safe only to ~32k
+    # f32 on 16 GB HBM. The 45k class goes through getrf_dense_inplace:
+    # the donated dense entry with column-chunked in-place compaction
     # (matrix 8.1 GB + ~1 GB temporaries; BASELINE.md round 4).
     return "tpu" if (on_tpu and 8192 <= A.n <= 32768) else None
 
@@ -412,12 +412,12 @@ def _getrf_fast_group_core(a, content, info, g0, gsz, nb,
             jnp.arange(gnb, dtype=jnp.int32))
         key = jnp.where(act > 0, gnb + iota_hw, rank)
         perm = jnp.argsort(key)
+        # an argsort's indices are distinct and in [0, hw): said to the
+        # gather, or it NaN-fills by a bounds check in a pass of its own
+        inb = dict(mode="promise_in_bounds", unique_indices=True)
         if n <= _COMPACT_TAKE_MAX_N:
-            # one full-window take: measured 2× the chunked form at 16k
-            # (6.6 vs 13.3 ms per full-size pass) at the cost of a
-            # window-sized temp — affordable below the 32k memory cliff
-            # (see _COMPACT_TAKE_MAX_N)
-            a = a.at[done:].set(jnp.take(a[done:], perm, axis=0))
+            # one whole-window gather: 2× faster than chunks at 16k
+            a = a.at[done:].set(a.at[done + perm].get(**inb))
         else:
             # column-chunked permute (window + stored-L back-pivot): each
             # [hw, CB] block gathers and writes back in place, so the peak
@@ -427,8 +427,8 @@ def _getrf_fast_group_core(a, content, info, g0, gsz, nb,
             for c0 in range(0, n, CB):
                 cw = min(CB, n - c0)
                 a = a.at[done:, c0:c0 + cw].set(
-                    jnp.take(a[done:, c0:c0 + cw], perm, axis=0))
-        content = content.at[done:].set(jnp.take(content[done:], perm))
+                    a[done:, c0:c0 + cw].at[perm].get(**inb))
+        content = content.at[done:].set(content[done:].at[perm].get(**inb))
         i_g = jnp.arange(gnb, dtype=jnp.int32)
         sub_end = (i_g // W + 1) * W                     # group cols
         colmask = i_g[None, :] >= sub_end[:, None]
@@ -544,22 +544,21 @@ def _getrf_fast_core(A, interpret: bool, want_ipiv: bool = True,
     kernel (internal/panel_plu.py) with an active-row mask instead of
     row swaps; U block-rows are built from one nb-row gather + one
     unit-lower solve per panel and parked in a per-group buffer; every
-    ``_FAST_GROUP`` panels one permutation pass compacts the finished
-    rows into LAPACK order and overlays the parked U — in-place,
-    column-chunked. Panels are statically unrolled per group (the
-    fori formulation profiled at ~40% extra MXU flops in masked
-    full-width trailing plus ~70 ms of unfused dynamic-slice copies).
-    This replaces XLA `lu`'s ~6 µs/column latency floor and the
-    ~10.6 ms/panel swap gathers of the plain dense path (BASELINE.md
-    cost model).
+    ``_FAST_GROUP`` panels one row gather compacts the finished rows
+    into LAPACK order and overlays the parked U. Panels are statically
+    unrolled per group (the fori formulation profiled at ~40% extra MXU
+    flops in masked full-width trailing plus ~70 ms of unfused slice
+    copies). This replaces XLA `lu`'s ~6 µs/column latency floor and the
+    ~10.6 ms/panel swap gathers of the dense path (BASELINE.md).
     """
-    from ..matrix import tiles_to_dense, dense_to_tiles, bc_from_tiles
-    nb = A.nb
-    n = A.n
-    kt = n // nb
-    a = tiles_to_dense(A.data[0, 0], n, n)
-    content = jnp.arange(n, dtype=jnp.int32)
-    info = jnp.zeros((), jnp.int32)
+    from ..matrix import bc_from_tiles
+    nb, n, kt = A.nb, A.n, A.n // A.nb
+    # dense [n, n] working array, ONE pass from the stored tiles and one
+    # back: with a sublane group's 8 rows an axis of their own each copy
+    # moves whole [8, nb] runs; as [kt, nb, kt, nb] XLA copies twice
+    a = (A.data[0, 0].reshape(kt, kt, nb // 8, 8, nb)
+         .transpose(0, 2, 3, 1, 4).reshape(n, n))
+    content, info = jnp.arange(n, dtype=jnp.int32), jnp.zeros((), jnp.int32)
     o_parts = []         # original row id per elimination step
     for g0 in range(0, kt, _FAST_GROUP):
         gsz = min(_FAST_GROUP, kt - g0)
@@ -594,7 +593,8 @@ def _getrf_fast_core(A, interpret: bool, want_ipiv: bool = True,
         # elimination order: piv[k, j] = ORIGINAL row eliminated at
         # step k·nb+j (wrap in PivotOrder before handing to getrs)
         piv = o_all.reshape(kt, nb)
-    tiles = dense_to_tiles(a, nb, A.data.shape[2], A.data.shape[3])
+    tiles = (a.reshape(kt, nb // 8, 8, kt, nb)
+             .transpose(0, 3, 1, 2, 4).reshape(kt, kt, nb, nb))
     return bc_from_tiles(tiles, 1, 1), piv, info
 
 

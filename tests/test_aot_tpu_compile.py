@@ -148,6 +148,77 @@ def test_potrf_one_chip_factors_the_tiles_where_they_are(topo, donate,
     assert c.memory_analysis().temp_size_in_bytes < n * n * 4 // 4
 
 
+# -- the one-chip LU between the stored tiles and its dense array ------------
+
+
+def _entry_fusions(text, elements):
+    """``(name, op_name)`` of every fusion of the entry computation
+    whose result has at least ``elements`` elements."""
+    entry = text[text.index("ENTRY"):]
+    found = re.findall(
+        r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* fusion\((.*)$", entry,
+        re.M)
+    return [(name, "".join(re.findall(r'op_name="([^"]*)"', rest)))
+            for name, dims, rest in found
+            if math.prod(map(int, dims.split(","))) >= elements]
+
+
+@pytest.mark.parametrize("n,donate,temp_mib", [
+    (4096, False, 106.0),
+    pytest.param(8192, False, 360.0, marks=pytest.mark.slow),
+    pytest.param(4096, True, 106.0, marks=pytest.mark.slow)],
+    ids=["one_group", "two_groups", "one_group_donated"])
+def test_getrf_one_chip_carries_the_matrix_once_each_way(topo, n, donate,
+                                                         temp_mib):
+    """``_getrf_fast_core`` at the cell's nb, A kept, as ``slate.gesv``
+    calls it on one chip. The parent made four matrix-sized passes
+    between the stored tiles and the dense working array (the
+    transposition [kt, nb, kt, nb] and a second copy to the (8, 128)
+    tiling of [n, n], each way: ``copy.62``, ``copy.148``,
+    ``reshape.16`` and ``copy_bitcast_fusion`` at n = 4096) and, from
+    two groups up, a fifth that ``jnp.take``'s fill mode spent selecting
+    between the gathered rows and NaN (``broadcast_select_fusion``,
+    PERF.md section 6, PR 53); each later group's gather was fed a
+    ``slice`` of the window copied out first. Now: one ``copy`` in, one
+    fusion out, the first group's gather feeding the array it becomes,
+    and the parent's temporaries at n = 4096 (105.97 MiB). At n = 8192
+    ``temp_size_in_bytes`` reads 356.1 MiB where the parent's read
+    309.4 (its buffer assignment: 966 MiB in all against 943; at the
+    cell's n = 16384 3.55 GiB against the parent's 3.68): the working
+    array now lives in the temporaries and the first dense array in the
+    output buffer, where the parent's odd number of copies had them the
+    other way round. Donating A changes none of it (the dense array
+    is another shape: nothing to factor in place). The two-group case
+    takes 100-250 s to compile: by hand, like the donated twin."""
+    from slate_tpu.linalg import getrf
+    grid = slate.Grid(1, 1, devices=[topo.devices[0]])
+    t = n // NB
+    data = jax.ShapeDtypeStruct((1, 1, t, t, NB, NB), F32,
+                                sharding=grid.sharding())
+    A = slate.Matrix(data=data, m=n, n=n, nb=NB, grid=grid)
+    jit = (getrf._getrf_fast_jit_overwrite if donate
+           else getrf._getrf_fast_jit)
+    c = jit.lower(A, interpret=False, want_ipiv=False, fold=True,
+                  tier="bf16_6x").compile()
+    text = c.as_text()
+    assert aot_kernels(c) > 0
+    moved = _entry_relayouts(text, n * n)
+    assert [op for _, op, _ in moved] == ["copy"], moved
+    fusions = _entry_fusions(text, n * n)
+    carried = [name for name, op_name in fusions
+               if "pivot_gather" not in op_name and "panel" not in op_name
+               and "trailing" not in op_name]
+    assert carried == ["copy_bitcast_fusion"], fusions
+    assert not [f for f in fusions if f[0].startswith("broadcast_select")
+                or f[1].endswith("jit(_take)/select_n")], fusions
+    # a later group's gather indexes the whole array: no window
+    # ``a[done:]`` (every column of the rows below) copied out first
+    sliced = re.findall(r"= \w+\[(\d+),(\d+)\]\S* slice\(",
+                        text[text.index("ENTRY"):])
+    assert not [d for d in sliced if int(d[1]) == n], sliced
+    assert c.memory_analysis().temp_size_in_bytes <= temp_mib * 2 ** 20
+
+
 def _getrf_chunk(grid, k0):
     """The chunk program of block columns [k0, k0 + 2), compiled."""
     from slate_tpu.linalg import getrf

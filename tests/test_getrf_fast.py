@@ -7,6 +7,7 @@ smallest (n, nb) that reaches the (kt, group, fold) branch it names and
 asserts that it did. The tiled and grid LU: tests/test_getrf.py."""
 
 import numpy as np
+import pytest
 
 import slate_tpu as st
 from slate_tpu.types import Op
@@ -298,16 +299,24 @@ def test_plu_panel_tournament_zero_pivot(monkeypatch):
     assert np.all(out[active][:, zcol] == 0.0)
 
 
-def test_getrf_dense_inplace(grid24, monkeypatch):
-    """Dense donated LU entry (the 45k-class path, VERDICT r3 #3) —
-    same pivots/factor as the tiled fast path, no tile conversion."""
+def _interpreted_groups(monkeypatch):
+    """``getrf_dense_inplace``'s per-group program with its kernels in
+    interpret mode (the real one pins TPU layouts), one jit a group."""
     import jax
-    import jax.numpy as jnp
     from slate_tpu.linalg import getrf as G
+    group = jax.jit(G._getrf_fast_group_core,
+                    static_argnums=(3, 4, 5, 6, 7, 8))
     monkeypatch.setattr(
         G, "_getrf_fast_group_jit",
         lambda a, c, i, g0, gsz, nb, interpret, fold=True, tier=None:
-        G._getrf_fast_group_core(a, c, i, g0, gsz, nb, True, fold, tier))
+        group(a, c, i, g0, gsz, nb, True, fold, tier))
+
+
+def test_getrf_dense_inplace(grid24, monkeypatch):
+    """Dense donated LU entry (the 45k-class path, VERDICT r3 #3) —
+    same pivots/factor as the tiled fast path, no tile conversion."""
+    import jax.numpy as jnp
+    _interpreted_groups(monkeypatch)
     n, nb = 768, 128
     a = rand(n, n, seed=51).astype(np.float32)
     lu, piv, info = st.getrf_dense_inplace(jnp.asarray(a), nb=nb)
@@ -318,3 +327,79 @@ def test_getrf_dense_inplace(grid24, monkeypatch):
     err = np.linalg.norm(a[perm] - l @ u) / (n * np.linalg.norm(a))
     assert err < 1e-5
     assert np.abs(l).max() <= 1.0 + 1e-5
+
+
+def _parent_spelling(A):
+    """PR 53's parent, spelled out around the same group body: the dense
+    working array by ``tiles_to_dense`` (one transposition of the whole
+    tile array, which the TPU compiler makes in two copies), the groups,
+    the tiles back by ``dense_to_tiles``. Returns (LU tiles, elimination
+    order [kt, nb], info)."""
+    import jax.numpy as jnp
+    from slate_tpu.linalg import getrf as G
+    from slate_tpu.matrix import dense_to_tiles, tiles_to_dense
+    n, nb = A.n, A.nb
+    kt = n // nb
+    a = tiles_to_dense(A.data[0, 0], n, n)
+    content = jnp.arange(n, dtype=jnp.int32)
+    info = jnp.zeros((), jnp.int32)
+    order = []
+    for g0 in range(0, kt, G._FAST_GROUP):
+        gsz = min(G._FAST_GROUP, kt - g0)
+        a, content, o_g, info = G._getrf_fast_group_core(
+            a, content, info, g0, gsz, nb, True)
+        order.append(o_g)
+    return (dense_to_tiles(a, nb, kt, kt),
+            jnp.concatenate(order).reshape(kt, nb), info)
+
+
+def _one_chip_matrix(n, nb, seed):
+    import jax
+    from slate_tpu import Grid
+    g1 = Grid(1, 1, devices=jax.devices()[:1])
+    a = rand(n, n, seed=seed).astype(np.float32)
+    a[0, 0] = 0.0                      # the first pivot is not row 0
+    return a, st.Matrix.from_dense(a, nb=nb, grid=g1)
+
+
+@pytest.mark.parametrize("n,nb", [(256, 128), (640, 128)],
+                         ids=["two_panels", "two_groups_ragged"])
+def test_fast_core_container_passes_keep_every_bit(n, nb):
+    """``_getrf_fast_core`` brings the stored tiles to its dense working
+    array and back with the 8 rows of a sublane group as an axis of
+    their own (one copy each way on the chip where the plain
+    transposition was two: tests/test_aot_tpu_compile.py counts them).
+    Only the spelling moved: the factor, the elimination order and info
+    are the parent's bit for bit, at n = 2 nb (one group) and at kt = 5
+    (a group of four panels and a ragged one of one: the second group's
+    window gather and write-back run)."""
+    import jax
+    from slate_tpu.linalg.getrf import _getrf_fast_jit
+    _, A = _one_chip_matrix(n, nb, seed=53)
+    lu, order, info = _getrf_fast_jit(A, interpret=True, want_ipiv=False)
+    lu0, order0, info0 = jax.jit(_parent_spelling)(A)
+    assert lu.shape == (1, 1, n // nb, n // nb, nb, nb)
+    assert np.array_equal(np.asarray(lu[0, 0]), np.asarray(lu0))
+    assert np.array_equal(np.asarray(order), np.asarray(order0))
+    assert sorted(np.asarray(order).ravel()) == list(range(n))
+    assert int(info) == int(info0) == 0
+
+
+def test_getrf_dense_inplace_matches_the_tiled_entry(monkeypatch):
+    """The donated dense entry shares the group body and has no tiles to
+    convert: at kt = 5 its factor is the tiled entry's element for
+    element, its LAPACK pivots the tiled entry's order converted."""
+    import jax.numpy as jnp
+    from slate_tpu.linalg import getrf as G
+    from slate_tpu.matrix import tiles_to_dense
+    _interpreted_groups(monkeypatch)
+    n, nb = 640, 128
+    a, A = _one_chip_matrix(n, nb, seed=54)
+    lu, order, info = G._getrf_fast_jit(A, interpret=True, want_ipiv=False)
+    lud, pivd, infod = st.getrf_dense_inplace(jnp.asarray(a), nb=nb)
+    assert np.array_equal(np.asarray(lud),
+                          np.asarray(tiles_to_dense(lu[0, 0], n, n)))
+    assert np.array_equal(np.asarray(pivd),
+                          np.asarray(G.pivot_order_to_ipiv(order)))
+    assert int(info) == int(infod) == 0
+
